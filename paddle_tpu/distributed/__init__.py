@@ -122,13 +122,11 @@ def barrier(group: str = "dp"):
 
 
 def collective(fn, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Run per-rank `fn` over global arrays on `mesh` (shard_map wrapper).
-    check_vma keeps its public name; compat maps it onto whatever the
-    installed jax calls the replication check."""
-    from ..compat import shard_map
+    """Run per-rank `fn` over global arrays on `mesh` (shard_map wrapper)."""
+    import jax
 
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def get_group(axis: str = "dp"):

@@ -35,9 +35,13 @@ on FIRST start too — a new job adopts the previous job's tables (epoch +
 generation recorded in the snapshot manifest.json) the way
 fleet.init_server(model_dir) does manually.
 
-TPU notes: one process per HOST is the normal topology (all local chips
-belong to one PJRT client); --nproc_per_node exists for CPU fleets and
-tests. Rendezvous is the JAX coordination service bootstrapped from the
+TPU notes: one process per HOST is the supported topology (all local
+chips belong to one PJRT client, and a chip belongs to one process at a
+time); --nproc_per_node > 1 exists for CPU fleets and tests
+(JAX_PLATFORMS=cpu) and is refused at start-up on a host with TPU chips
+(ChipOwnershipError) — there the second child cannot initialize its
+backend, and --elastic_retries would respawn it until the budget is
+gone. Rendezvous is the JAX coordination service bootstrapped from the
 first endpoint (no gen_nccl_id gRPC exchange).
 """
 from __future__ import annotations
@@ -915,8 +919,40 @@ def watch_local_trainers(trainers: List[Trainer], poll_interval=0.2,
         return 128 + signal.SIGINT
 
 
+class ChipOwnershipError(RuntimeError):
+    """--nproc_per_node > 1 on a host whose TPU chips every child would
+    claim."""
+
+
+def _local_tpu_chips() -> List[str]:
+    """The device nodes libtpu opens, found without importing jax: the
+    launcher stays off JAX so that it never holds a chip itself."""
+    import glob
+
+    return sorted(glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _check_one_process_per_chip_host(nproc_per_node: int) -> None:
+    if nproc_per_node <= 1:
+        return
+    if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
+        return  # a CPU fleet: the children never open a chip
+    chips = _local_tpu_chips()
+    if chips:
+        raise ChipOwnershipError(
+            f"--nproc_per_node {nproc_per_node} on a host with TPU chips "
+            f"({', '.join(chips)}): each child process would claim every "
+            f"local chip, and a chip belongs to one process at a time — "
+            f"all but one fail with \"Unable to initialize backend "
+            f"'tpu'\" and are respawned until the restart budget is "
+            f"gone. Run ONE process per host (it drives all local chips; "
+            f"serving replicas go one per host), or set JAX_PLATFORMS=cpu "
+            f"for a CPU fleet.")
+
+
 def launch(argv=None) -> int:
     args = _parse_args(argv if argv is not None else sys.argv[1:])
+    _check_one_process_per_chip_host(args.nproc_per_node)
     ips = [s.strip() for s in args.ips.split(",") if s.strip()]
     node_ip = args.node_ip or ips[0]
     cluster = get_cluster(ips, args.nproc_per_node, args.started_port)

@@ -81,15 +81,16 @@ def _ln_f32(x, scale, shift, eps):
     return y.astype(x.dtype)
 
 
-def _add_ln(x, y, scale, shift, eps):
+def _add_ln(x, y, scale, shift, eps, mesh=None):
     """LayerNorm(x + y) — the residual+LN pair of both stacks. Dispatches
     the fused Pallas kernel (ops/pallas/add_ln.py; XLA's convert+reduce
     LN fusions measured ~30x the bandwidth roofline inside the encoder
-    scan) with the identical-math jnp fallback."""
+    scan) with the identical-math jnp fallback. mesh: ctx.mesh, or None
+    when already inside a shard_map (GPipe)."""
     from .pallas.add_ln import fused_add_ln, fused_ln_dispatch_ok
 
     if fused_ln_dispatch_ok(x.shape):
-        return fused_add_ln(x, y, scale, shift, eps=eps)
+        return fused_add_ln(x, y, scale, shift, eps=eps, mesh=mesh)
     return _ln_f32(x + y, scale, shift, eps)
 
 
@@ -139,9 +140,6 @@ def fused_encoder_stack(ctx, ins, attrs):
 
     stacked = {k: ins[k][0] for k in _PARAM_KEYS}
 
-    def add_ln(x, y, scale, shift):
-        return _add_ln(x, y, scale, shift, eps)
-
     def dropout(x, prob, key):
         if is_test or prob <= 0.0:
             return x
@@ -152,7 +150,11 @@ def fused_encoder_stack(ctx, ins, attrs):
         bias; batch size is read from the carried hidden state. mb_salt
         (pipeline path) decorrelates dropout masks across microbatches.
         manual=True means we are already inside a shard_map (GPipe) and
-        the flash kernel must not wrap itself in another one."""
+        the Pallas kernels must not wrap themselves in another one."""
+
+        def add_ln(x, y, scale, shift):
+            return _add_ln(x, y, scale, shift, eps,
+                           mesh=None if manual else mesh)
 
         def layer(carry, p):
             hid, idx = carry
@@ -346,7 +348,6 @@ def _gpipe_stack(hidden, stacked, bias, mesh, M, make_layer, ring=False):
     this shard_map (pp x sp composition for long-context pipelines)."""
     from jax import lax
 
-    from ..compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     npp = mesh.shape["pp"]
@@ -417,14 +418,14 @@ def _gpipe_stack(hidden, stacked, bias, mesh, M, make_layer, ring=False):
         def body_nobias(hid_l, *p_locals):
             return body(hid_l, None, *p_locals)
 
-        return shard_map(
+        return jax.shard_map(
             body_nobias, mesh=mesh, in_specs=(hid_spec,) + p_specs,
-            out_specs=hid_spec, check=False,
+            out_specs=hid_spec, check_vma=False,
         )(hidden, *[stacked[k] for k in keys])
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(hid_spec, bias_spec) + p_specs,
-        out_specs=hid_spec, check=False,
+        out_specs=hid_spec, check_vma=False,
     )(hidden, bias, *[stacked[k] for k in keys])
 
 
@@ -482,7 +483,7 @@ def fused_decoder_stack(ctx, ins, attrs):
     stacked = {k: ins[k][0] for k in _DEC_PARAM_KEYS}
 
     def add_ln(x, y, scale, shift):
-        return _add_ln(x, y, scale, shift, eps)
+        return _add_ln(x, y, scale, shift, eps, mesh=mesh)
 
     def dropout(x, prob, key):
         if is_test or prob <= 0.0:

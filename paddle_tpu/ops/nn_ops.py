@@ -468,7 +468,7 @@ def layer_norm(ctx, ins, attrs):
 
         if fused_ln_dispatch_ok(x.shape):
             y = fused_add_ln(x, None, ins["Scale"][0], ins["Bias"][0],
-                             eps=eps)
+                             eps=eps, mesh=ctx.mesh)
             xf = x.astype(jnp.float32)
             m = jnp.mean(xf, axis=-1, keepdims=True)
             v = jnp.var(xf, axis=-1, keepdims=True)

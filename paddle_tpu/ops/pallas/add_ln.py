@@ -20,7 +20,10 @@ writes one tensor read twice by the caller.
 
 Stats ride as [1, R] lane-major rows written through the same MXU
 identity-transpose trick as the flash kernel's lse (a (R, 1)
-sublane-major store costs a vreg-walking relayout).
+sublane-major store costs a vreg-walking relayout). Their (1, br)
+blocks are only legal on the TPU when br is a multiple of 128, so row
+blocks come in multiples of 128 and a row count that is not one (the
+MLM head's batch x 76 masked positions) is zero-padded up to the next.
 """
 from __future__ import annotations
 
@@ -37,14 +40,20 @@ from .flash_attention import _identity, _interpret, _to_lanes, _to_sublanes
 # single source shared with the autotuner's feasibility gate
 _LN_VMEM_BUDGET = _feas.LN_VMEM_BUDGET
 
-_ROW_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
+_ROW_ALIGN = _feas.LN_ROW_ALIGN
+_ROW_CANDIDATES = (1024, 512, 256, 128)
+
+
+def _padded_rows(r):
+    return -(-r // _ROW_ALIGN) * _ROW_ALIGN
 
 
 def default_ln_rows(r, h):
     """THE hand-picked row-block chooser (the autotune cache-miss
-    fallback): largest row block that tiles r under the VMEM budget
-    (x, y, out blocks double-buffered bf16 + ~4 f32 temporaries per
-    row block). None when nothing tiles."""
+    fallback): largest row block that tiles r (a multiple of 128 — see
+    _padded_rows) under the VMEM budget (x, y, out blocks
+    double-buffered bf16 + ~4 f32 temporaries per row block). None when
+    nothing tiles."""
     for cand in _ROW_CANDIDATES:
         if r % cand == 0 and _feas.ln_vmem_bytes(cand, h) <= _LN_VMEM_BUDGET:
             return cand
@@ -76,7 +85,7 @@ def _resolve_ln_rows(r, h, dtype):
 
 
 def ln_shapes_ok(r, h) -> bool:
-    return h % 128 == 0 and default_ln_rows(r, h) is not None
+    return h % 128 == 0 and default_ln_rows(_padded_rows(r), h) is not None
 
 
 def _fwd_kernel(*refs, eps, has_y, br):
@@ -152,6 +161,7 @@ def _ln_fwd(x, y, scale, shift, *, eps):
             jax.ShapeDtypeStruct((1, r), jnp.float32),
             jax.ShapeDtypeStruct((1, r), jnp.float32),
         ],
+        name="add_ln_fwd",
         interpret=_interpret(),
     )(*args)
     return out, mean, rstd
@@ -182,6 +192,7 @@ def _ln_bwd(x, y, scale, mean, rstd, g, *, eps):
             jax.ShapeDtypeStruct((nb, 1, h), jnp.float32),
             jax.ShapeDtypeStruct((nb, 1, h), jnp.float32),
         ],
+        name="add_ln_bwd",
         interpret=_interpret(),
     )(*args)
     return dx, dsc.sum(axis=(0, 1)), dsh.sum(axis=(0, 1))
@@ -230,30 +241,74 @@ def fused_ln_dispatch_ok(shape) -> bool:
     return ok and not _interpret()
 
 
-def fused_add_ln(x, y, scale, shift, eps=1e-5):
+def _row_spec(mesh, shape):
+    """PartitionSpec over the mesh axes that shard LN's rows: the batch
+    (dim 0) on "dp" and, for [B, S, H], the sequence on "sp" — where the
+    axis is populated and divides the dim; replicated otherwise."""
+    from jax.sharding import PartitionSpec as P
+
+    def axis(name, dim):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and dim % n == 0 else None
+
+    dims = [None] * len(shape)
+    dims[0] = axis("dp", shape[0])
+    if len(shape) == 3:
+        dims[1] = axis("sp", shape[1])
+    return P(*dims)
+
+
+def fused_add_ln(x, y, scale, shift, eps=1e-5, mesh=None):
     """LayerNorm(x + y) over the last axis with f32 stats; y may be None.
 
     x/y: [..., H]; scale/shift: [H]. Dispatch gate: `ln_shapes_ok` on the
     flattened row count and H — callers fall back to the jnp composition
     otherwise (identical math).
+
+    mesh: the GSPMD mesh of the surrounding jit (EmitContext.mesh; None
+    inside a manual region). XLA cannot partition a Mosaic call, so over
+    more than one device the kernel runs per shard inside a shard_map
+    over the axes that shard its rows (`_row_spec`), as
+    flash_attention_bsh does.
     """
+    if mesh is None or mesh.size == 1:
+        return _add_ln_local(x, y, scale, shift, eps)
+    from jax.sharding import PartitionSpec as P
+
+    spec = _row_spec(mesh, x.shape)
+    return jax.shard_map(
+        lambda xl, yl, sc, sh: _add_ln_local(xl, yl, sc, sh, eps),
+        mesh=mesh,
+        in_specs=(spec, None if y is None else spec, P(), P()),
+        out_specs=spec, check_vma=False,
+    )(x, y, scale, shift)
+
+
+def _add_ln_local(x, y, scale, shift, eps):
     shape = x.shape
     h = shape[-1]
     r = 1
     for d in shape[:-1]:
         r *= d
+    rp = _padded_rows(r)
     if not ln_shapes_ok(r, h):
         raise _feas.NoFeasibleConfig(
-            "add_ln", {"r": r, "h": h},
-            [({"block_rows": c}, _feas.ln_rows_ok(r, h, c)[1])
+            "add_ln", {"r": rp, "h": h},
+            [({"block_rows": c}, _feas.ln_rows_ok(rp, h, c)[1])
              for c in _ROW_CANDIDATES],
             detail=("hidden dim must be a multiple of 128"
                     if h % 128 else "gate with fused_ln_dispatch_ok"))
+    pad = rp - r
+
+    def rows(a):
+        a = a.reshape(r, h)
+        return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
+
     core = _make_core(float(eps), y is not None)
     out = core(
-        x.reshape(r, h),
-        None if y is None else y.reshape(r, h),
+        rows(x),
+        None if y is None else rows(y),
         scale.reshape(h),
         shift.reshape(h),
     )
-    return out.reshape(shape)
+    return (out[:r] if pad else out).reshape(shape)

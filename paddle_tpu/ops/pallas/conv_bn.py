@@ -54,6 +54,7 @@ from .flash_attention import _interpret
 # The byte value lives in tuning/feasible.py so the autotuner's
 # feasibility gate and the kernel can never disagree about it.
 _CONV_BN_VMEM_BUDGET = _feas.CONV_BN_VMEM_BUDGET
+_ROW_UNIT = _feas.CONV_BN_ROW_UNIT
 
 _ROW_CANDIDATES = (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
 
@@ -118,8 +119,7 @@ def conv_bn_shapes_ok(x_shape, w_shape, strides, pads, dilations=(1, 1),
         ho = -(-h // strides[0])
         wo = -(-w // strides[1])
         r = n * ho * wo
-        # x + y blocks double-buffered bf16-worst + f32 accumulator
-        return _pick_rows(r, c + o, 2 * 2 + 4) is not None
+        return _pick_rows(r, c + o, _ROW_UNIT["mm"]) is not None
     if tuple(strides) != (1, 1):
         return False
     hp = h + pads[0][0] + pads[0][1]
@@ -173,7 +173,7 @@ def conv_bn_s2d_ok(x_shape, w_shape, strides, pads) -> bool:
     if ho <= 0 or wo <= 0:
         return False
     # the normalize/backward sweeps must tile too
-    if default_conv_bn_rows(n * ho * wo, o, 3 * 4) is None:
+    if default_conv_bn_rows(n * ho * wo, o, _ROW_UNIT["apply"]) is None:
         return False
     return (_feas.conv_bn_s2d_per_image_bytes(hp, wp, c, o, kh, kw)
             <= _CONV_BN_VMEM_BUDGET)
@@ -370,6 +370,7 @@ def _conv_fwd(x, w2d, out_dtype, kh, kw, pads):
             jax.ShapeDtypeStruct((1, o), jnp.float32),
             jax.ShapeDtypeStruct((1, o), jnp.float32),
         ],
+        name="conv_bn_conv_stats",
         interpret=_interpret(),
     )(xp, w2d)
     return y.reshape(n * ho * wo, o), (n, ho, wo, o), s, ss
@@ -383,7 +384,7 @@ def _mm_fwd(x, w2d, out_dtype, strides):
     n, ho, wo, c = x.shape
     o = w2d.shape[-1]
     r = n * ho * wo
-    br = _resolve_rows(r, c + o, 2 * 2 + 4, "mm", x.dtype)
+    br = _resolve_rows(r, c + o, _ROW_UNIT["mm"], "mm", x.dtype)
     y, s, ss = pl.pallas_call(
         _mm_stats_kernel,
         grid=(r // br,),
@@ -394,6 +395,7 @@ def _mm_fwd(x, w2d, out_dtype, strides):
             jax.ShapeDtypeStruct((1, o), jnp.float32),
             jax.ShapeDtypeStruct((1, o), jnp.float32),
         ],
+        name="conv_bn_mm_stats",
         interpret=_interpret(),
     )(x.reshape(r, c), w2d)
     return y, (n, ho, wo, o), s, ss
@@ -438,13 +440,12 @@ def _s2d_weights(w):
 
 
 def _elementwise_rows(r, o, dtype=jnp.float32):
-    # y + out + grad all <=4B, double-buffered
-    br = _resolve_rows(r, o, 3 * 4, "apply", dtype)
+    br = _resolve_rows(r, o, _ROW_UNIT["apply"], "apply", dtype)
     if br is None:
         raise _feas.NoFeasibleConfig(
             "conv_bn", {"kind": "apply", "r": r, "w": o},
             [({"block_rows": c},
-              _feas.conv_bn_rows_ok(r, o, c, 3 * 4)[1])
+              _feas.conv_bn_rows_ok(r, o, c, _ROW_UNIT["apply"])[1])
              for c in _ROW_CANDIDATES])
     return br
 
@@ -481,6 +482,7 @@ def _pallas_fwd(x, w, scale, bias, *, strides, pads, eps, with_relu,
         in_specs=[_row_specs(br, o), _const_spec(4, o)],
         out_specs=_row_specs(br, o),
         out_shape=jax.ShapeDtypeStruct((r, o), x.dtype),
+        name="conv_bn_apply",
         interpret=_interpret(),
     )(z2d, stat)
     return y2d.reshape(oshape), z2d, stat, m, v
@@ -502,6 +504,7 @@ def _pallas_bwd(x, w, z2d, stat, g, *, strides, pads, with_relu):
             jax.ShapeDtypeStruct((nb, 1, o), jnp.float32),
             jax.ShapeDtypeStruct((nb, 1, o), jnp.float32),
         ],
+        name="conv_bn_bwd_reduce",
         interpret=_interpret(),
     )(z2d, g2d, stat)
     dgamma = dg.sum(axis=(0, 1))
@@ -515,6 +518,7 @@ def _pallas_bwd(x, w, z2d, stat, g, *, strides, pads, with_relu):
                   _const_spec(2, o)],
         out_specs=_row_specs(br, o),
         out_shape=jax.ShapeDtypeStruct((r, o), x.dtype),
+        name="conv_bn_bwd_dz",
         interpret=_interpret(),
     )(z2d, g2d, stat, tot)
     # dX / dW on XLA's native conv schedules (the measured best — see the

@@ -68,7 +68,14 @@ def _ref_paged_attention(q, k_pages, v_pages, page_table, lengths,
 
 
 def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page, sm_scale, maxp):
+                  m_ref, l_ref, acc_ref, *, page, heads, sm_scale, maxp):
+    """One (slot, page) grid cell. k/v arrive as [page, H*D] — the pool
+    row as stored, heads side by side on the lane axis — so each head is
+    a STATIC lane slice (the flash BSH idiom) and the page's positions
+    lie on the sublane axis: scores are a lane reduce, the online
+    softmax state one [1, 1] cell of the [H, 1] scratch per head, the
+    p @ v contraction a sublane reduce. All of it is VPU work on
+    vectors: decode streams pages from HBM, its arithmetic is noise."""
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -78,36 +85,26 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[b]
-    pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    q = q_ref[0].astype(jnp.float32)          # [H, D]
-    k = k_ref[0].astype(jnp.float32)          # [page, H, D]
-    v = v_ref[0].astype(jnp.float32)
-    h = q.shape[0]
-
-    def head(i, _):
-        qh = jax.lax.dynamic_slice_in_dim(q, i, 1, axis=0)   # [1, D]
-        kh = jax.lax.dynamic_slice_in_dim(k, i, 1, axis=1)[:, 0, :]
-        vh = jax.lax.dynamic_slice_in_dim(v, i, 1, axis=1)[:, 0, :]
-        s = jax.lax.dot_general(
-            qh, kh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [1, page]
-        s = jnp.where(pos < length, s, _NEG_INF)
-        m_prev = m_ref[i, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
+    d = q_ref.shape[-1]
+    pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
+    live = pos < len_ref[b]                                  # [page, 1]
+    for h in range(heads):
+        qh = q_ref[0, h:h + 1, :].astype(jnp.float32)        # [1, D]
+        kh = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+        vh = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+        s = jnp.sum(kh * qh, axis=-1, keepdims=True) * sm_scale
+        s = jnp.where(live, s, _NEG_INF)                     # [page, 1]
+        m_prev = m_ref[h:h + 1, :]                           # [1, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         # all-masked page: keep state unchanged (exp(-inf - -inf) trap)
-        alpha = jnp.where(jnp.isfinite(m_new),
-                          jnp.exp(m_prev - m_new), 1.0)
-        pw = jnp.where(jnp.isfinite(m_new), jnp.exp(s - m_new), 0.0)
-        m_ref[i, 0] = m_new
-        l_ref[i, 0] = l_ref[i, 0] * alpha + jnp.sum(pw)
-        pv = jax.lax.dot_general(
-            pw, vh, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [1, D]
-        acc_ref[i, :] = acc_ref[i, :] * alpha + pv[0]
-        return 0
-
-    jax.lax.fori_loop(0, h, head, 0)
+        seen = jnp.isfinite(m_new)
+        alpha = jnp.where(seen, jnp.exp(m_prev - m_new), 1.0)
+        pw = jnp.where(seen, jnp.exp(s - m_new), 0.0)        # [page, 1]
+        m_ref[h:h + 1, :] = m_new
+        l_ref[h:h + 1, :] = (l_ref[h:h + 1, :] * alpha
+                             + jnp.sum(pw, axis=0, keepdims=True))
+        acc_ref[h:h + 1, :] = (acc_ref[h:h + 1, :] * alpha
+                               + jnp.sum(pw * vh, axis=0, keepdims=True))
 
     @pl.when(p == maxp - 1)
     def _emit():
@@ -121,17 +118,17 @@ def _pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
     n_pages, page, kh, _ = k_pages.shape
     assert kh == h, "pallas path is MHA-only; GQA uses the jnp path"
     maxp = page_table.shape[1]
-    kernel = functools.partial(_paged_kernel, page=page,
+    kernel = functools.partial(_paged_kernel, page=page, heads=h,
                                sm_scale=sm_scale, maxp=maxp)
+    kv_spec = pl.BlockSpec((1, page, h * d),
+                           lambda bi, pi, tbl, lens: (tbl[bi, pi], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, maxp),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda bi, pi, tbl, lens: (bi, 0, 0)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda bi, pi, tbl, lens: (tbl[bi, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page, h, d),
-                         lambda bi, pi, tbl, lens: (tbl[bi, pi], 0, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, h, d),
                                lambda bi, pi, tbl, lens: (bi, 0, 0)),
@@ -141,12 +138,16 @@ def _pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
             pltpu.VMEM((h, d), jnp.float32),
         ],
     )
+    # [P, page, H, D] -> [P, page, H*D]: the same bytes, heads on lanes
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        name="paged_attention",
         interpret=_interpret(),
-    )(page_table, lengths, q, k_pages, v_pages)
+    )(page_table, lengths, q,
+      k_pages.reshape(n_pages, page, h * d),
+      v_pages.reshape(n_pages, page, h * d))
 
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths,

@@ -71,10 +71,7 @@ def init_parallel_env() -> None:
         # jax.default_backend(): that would initialize backends BEFORE
         # the coordination service, which breaks multi-process startup.
         if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001 — older jaxlib without gloo
-                pass
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         eps = get_endpoints()
         coordinator = eps[0] if eps else None
         jax.distributed.initialize(

@@ -194,9 +194,8 @@ def ring_attention_global(q, k, v, mesh, axis: str = "sp", bias=None,
     dim (and `batch_axis` on batch if present in the mesh), run the ring
     body per shard. Usable under jit — GSPMD handles everything outside,
     the ring handles attention's cross-shard dependency inside."""
+    import jax
     from jax.sharding import PartitionSpec as P
-
-    from ..compat import shard_map
 
     ba = batch_axis if (batch_axis and batch_axis in mesh.axis_names) else None
     qkv_spec = P(ba, None, axis, None)
@@ -207,18 +206,18 @@ def ring_attention_global(q, k, v, mesh, axis: str = "sp", bias=None,
             return ring_attention(ql, kl, vl, axis, None, sm_scale, causal,
                                   dropout_prob, dropout_key)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(qkv_spec,) * 3, out_specs=qkv_spec,
-            check=False,
+            check_vma=False,
         )(q, k, v)
 
     def body_b(ql, kl, vl, bl):
         return ring_attention(ql, kl, vl, axis, bl, sm_scale, causal,
                               dropout_prob, dropout_key)
 
-    return shard_map(
+    return jax.shard_map(
         body_b, mesh=mesh, in_specs=(qkv_spec,) * 3 + (bias_spec,),
-        out_specs=qkv_spec, check=False,
+        out_specs=qkv_spec, check_vma=False,
     )(q, k, v, bias)
 
 

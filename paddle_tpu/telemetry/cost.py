@@ -489,7 +489,9 @@ def profile_executor_run(exe, program, feed, fetch_list, scope=None,
                                 scope=scope)
         hlo_text = compiled.as_text()
         measured = _cost_analysis_flops(compiled)
-        if peak_flops is None:
+        if peak_flops is None and jax.default_backend() == "tpu":
+            # off the chip there is no peak to divide by: the report's
+            # measured_mfu / formula_mfu stay unset
             peak_flops = peak_flops_per_chip()
         return build_cost_report(
             prof.xplane_op_events(trace_dir), hlo_text,
@@ -508,39 +510,40 @@ def profile_executor_run(exe, program, feed, fetch_list, scope=None,
 
 
 def _cost_analysis_flops(compiled) -> Optional[float]:
-    """Per-execution flops from XLA's cost model; None when the backend
-    cannot report it. jax returns a dict or a one-element list of dicts
-    depending on version."""
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = float(ca.get("flops", 0.0))
-        return flops or None
-    except Exception:  # noqa: BLE001 — diagnostics never fail the profile
-        return None
+    """Per-execution flops from XLA's cost model; None when the model
+    counted none."""
+    return float(compiled.cost_analysis().get("flops", 0.0)) or None
+
+
+# bf16 peak FLOP/s per chip, keyed by a substring of the lowercased
+# jax device_kind (vendor datasheets; v5e: Google Cloud "TPU v5e" page)
+PEAK_BF16_FLOPS = {
+    "v5 lite": 197e12,  # v5e
+    "v5e": 197e12,
+    "v5p": 459e12,
+    "v4": 275e12,
+    "v6": 918e12,  # trillium
+    "v3": 123e12,
+    "v2": 45e12,
+}
 
 
 def peak_flops_per_chip() -> float:
-    """bf16 peak FLOP/s for the local chip (best-effort detect). THE
-    table — bench.py delegates here so the MFU denominators of the bench
-    rows and the measured gauge can never drift apart."""
+    """bf16 peak FLOP/s for the local chip. THE table — bench.py
+    delegates here so the MFU denominators of the bench rows and the
+    measured gauge can never drift apart. A device that is not in the
+    table (the CPU included) raises: a utilization against a guessed
+    peak is not a measurement."""
     import jax
 
-    kind = jax.devices()[0].device_kind.lower()
-    table = {
-        "v5 lite": 197e12,  # v5e
-        "v5e": 197e12,
-        "v5p": 459e12,
-        "v4": 275e12,
-        "v6": 918e12,  # trillium
-        "v3": 123e12,
-        "v2": 45e12,
-    }
-    for k, v in table.items():
-        if k in kind:
+    kind = jax.devices()[0].device_kind
+    for k, v in PEAK_BF16_FLOPS.items():
+        if k in kind.lower():
             return v
-    return 197e12  # conservative default
+    raise ValueError(
+        f"peak_flops_per_chip: device_kind {kind!r} is not in the peak "
+        f"table {sorted(PEAK_BF16_FLOPS)}; add its datasheet peak or pass "
+        f"peak_flops= explicitly")
 
 
 def report_to_json_line(report: CostReport, topk: Optional[int] = None) -> str:

@@ -20,7 +20,7 @@ from . import feasible
 # block-size menu shared by the flash axes (the kernels' tiling minimum
 # is 128; 1024 is the largest tile the s4096 hand measurements reached)
 _FLASH_BLOCKS = (1024, 512, 256, 128)
-_LN_ROWS = (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
+_LN_ROWS = (2048, 1024, 512, 256, 128)
 _CONV_ROWS = (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
 # paged-attention page sizes: fewer grid steps (large pages) first; the
 # tuned page doubles as the KV pool page granularity, so small pages
@@ -67,11 +67,9 @@ def add_ln_candidates(r: int, h: int, dtype: str = "float32",
     return ok, rejects
 
 
-# bytes-per-row-unit by pass kind, exactly as ops/pallas/conv_bn.py
-# sizes its row blocks: the 1x1 matmul holds x+y double-buffered + the
-# f32 accumulator over width c+o; the elementwise sweeps hold three
-# <=4B tensors over width o
-CONV_BN_ROW_UNIT = {"mm": 2 * 2 + 4, "apply": 3 * 4}
+# bytes-per-row-unit by pass kind, the table ops/pallas/conv_bn.py sizes
+# its row blocks with
+CONV_BN_ROW_UNIT = feasible.CONV_BN_ROW_UNIT
 
 
 def conv_bn_candidates(kind: str, r: int, width: int,

@@ -22,6 +22,9 @@ from typing import Any, Dict, List, Tuple
 # the design); the row-blocked kernels stay under the default ~16MB.
 BSH_VMEM_LIMIT = 112 * 1024 * 1024
 LN_VMEM_BUDGET = 10 * 1024 * 1024
+# add_ln's [1, R] f32 row statistics are blocked (1, rows): Mosaic takes
+# a lane block only in multiples of 128
+LN_ROW_ALIGN = 128
 CONV_BN_VMEM_BUDGET = 12 * 1024 * 1024
 PAGED_ATTN_VMEM_BUDGET = 8 * 1024 * 1024
 
@@ -116,6 +119,8 @@ def ln_rows_ok(r: int, h: int, rows: int,
                *, budget: int = LN_VMEM_BUDGET) -> Tuple[bool, str]:
     if rows < 1 or r % rows:
         return False, f"row block {rows} does not tile r={r}"
+    if rows % LN_ROW_ALIGN:
+        return False, f"row block {rows} is not a multiple of {LN_ROW_ALIGN}"
     est = ln_vmem_bytes(rows, h)
     if est > budget:
         return False, f"VMEM estimate {est} > {budget}"
@@ -125,6 +130,16 @@ def ln_rows_ok(r: int, h: int, rows: int,
 # ---------------------------------------------------------------------------
 # fused conv + batch-norm
 # ---------------------------------------------------------------------------
+
+
+# bytes per row*width unit of conv_bn's row-blocked passes. 'mm' (the 1x1
+# matmul): x + y blocks double-buffered bf16 + the f32 accumulator.
+# 'apply' (normalize and the two backward sweeps): three <=2B blocks
+# double-buffered + ~4 f32 temporaries, add_ln's model. The temporaries
+# count: inside the ResNet-50 b128 step Mosaic allocated 19.94M scoped
+# for conv_bn_bwd_dz at [100352, 512] with 2048-row blocks, against the
+# 16M limit (v5e, PR 21)
+CONV_BN_ROW_UNIT = {"mm": 2 * 2 + 4, "apply": 3 * 2 * 2 + 4 * 4}
 
 
 def conv_bn_row_bytes(rows: int, width: int, bytes_per_row_unit: int) -> int:

@@ -198,8 +198,8 @@ def test_flash_candidates_feasibility():
 
 def test_ln_and_conv_candidates():
     ok, _ = configs.add_ln_candidates(256, 128)
-    assert {"block_rows": 256} in ok and {"block_rows": 8} in ok
-    assert all(256 % c["block_rows"] == 0 for c in ok)
+    # the [1, R] stat rows are blocked (1, rows): multiples of 128 only
+    assert ok == [{"block_rows": 256}, {"block_rows": 128}]
     ok, rej = configs.conv_bn_candidates("apply", 25, 8)
     assert ok == [{"block_rows": 1}]  # 25 has no larger divisor in menu
     assert rej  # and the audit trail names the non-divisors
@@ -258,13 +258,15 @@ def test_resolvers_use_cache_entry_and_validate(autotune_on):
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     lnkey = canonical_key({"r": 256, "h": 128, "dtype": "float32"})
-    with tuning.override({"add_ln": {lnkey: {"block_rows": 64}}}):
-        assert add_ln._resolve_ln_rows(256, 128, "float32") == 64
+    with tuning.override({"add_ln": {lnkey: {"block_rows": 128}}}):
+        assert add_ln._resolve_ln_rows(256, 128, "float32") == 128
         assert any(v["source"] == "cache"
                    for v in tuning.chosen_configs().values())
-    # a non-dividing row block is REJECTED -> hand-picked fallback
-    with tuning.override({"add_ln": {lnkey: {"block_rows": 100}}}):
-        assert add_ln._resolve_ln_rows(256, 128, "float32") == 256
+    # a non-dividing row block, or one the TPU cannot tile the stat
+    # rows with, is REJECTED -> hand-picked fallback
+    for bad in (100, 64):
+        with tuning.override({"add_ln": {lnkey: {"block_rows": bad}}}):
+            assert add_ln._resolve_ln_rows(256, 128, "float32") == 256
     fkey = canonical_key({"sq": 512, "skv": 512, "h": 256,
                           "dtype": "float32"})
     with tuning.override({"flash_bsh": {fkey: {"bq": 256, "bk": 128}}}):
@@ -337,7 +339,7 @@ def test_flag_off_emitted_hlo_bit_identical():
     baseline = _lowered_ln_text()
     # flag OFF + a cache entry that WOULD change the block size: the
     # lowered computation must be byte-identical to the no-cache build
-    with tuning.override({"add_ln": {key: {"block_rows": 64}}}):
+    with tuning.override({"add_ln": {key: {"block_rows": 128}}}):
         assert _lowered_ln_text() == baseline
     # flag ON + empty cache: still byte-identical (no behavior cliff)
     fluid.flags.set_flags({"FLAGS_kernel_autotune": True})
@@ -345,7 +347,7 @@ def test_flag_off_emitted_hlo_bit_identical():
         with tuning.override({}):
             assert _lowered_ln_text() == baseline
         # flag ON + a real entry: the block size actually moves
-        with tuning.override({"add_ln": {key: {"block_rows": 64}}}):
+        with tuning.override({"add_ln": {key: {"block_rows": 128}}}):
             assert _lowered_ln_text() != baseline
     finally:
         fluid.flags.set_flags({"FLAGS_kernel_autotune": False})
@@ -508,7 +510,7 @@ def test_autotune_cli_mock_search_cache_reuse(tmp_path, monkeypatch):
 
     cache_path = str(tmp_path / "cpu.json")
     monkeypatch.setenv("PADDLE_AUTOTUNE_CHIP", "cpu")
-    argv = ["search", "--ln", "64:128", "--measure", "mock",
+    argv = ["search", "--ln", "256:128", "--measure", "mock",
             "--cache", cache_path, "--json"]
     assert at.main(argv) == 0
     first = open(cache_path).read()

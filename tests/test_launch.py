@@ -287,3 +287,19 @@ def test_launch_trace_dir_merges_per_rank_timeline(tmp_path):
              if e.get("ph") == "M" and e["name"] == "process_name"}
     assert any(n.startswith("rank 0") for n in names)
     assert any(n.startswith("rank 1") for n in names)
+
+
+def test_refuses_several_processes_on_a_chip_host(monkeypatch):
+    """One process per chip host: on the v5e machine the second of two
+    --serve replicas died at backend init and was respawned until the
+    budget ran out (PR 21 chip run), so the launcher refuses up front."""
+    from paddle_tpu.distributed import launch
+
+    monkeypatch.setattr(launch, "_local_tpu_chips", lambda: ["/dev/vfio/2"])
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    launch._check_one_process_per_chip_host(1)
+    with pytest.raises(launch.ChipOwnershipError, match="ONE process per host"):
+        launch.launch(["--serve", "--nproc_per_node", "2", "model_dir"])
+    # a CPU fleet on the same host never opens a chip
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    launch._check_one_process_per_chip_host(2)

@@ -267,12 +267,12 @@ def test_proftop_cli_resnet18(capsys):
     for row in rep["rows"]:
         assert row["op_index"] >= 0
         assert row["layer"], f"row {row['scope']} lost its callstack"
-    # measured-MFU gauge vs bench.py's model formula: same time base, so
-    # the ratio compares flop accounting — documented tolerance 2x
-    assert rep["measured_mfu"] is not None and rep["formula_mfu"] is not None
-    ratio = rep["measured_mfu"] / rep["formula_mfu"]
+    # XLA's measured flops vs bench.py's model formula — documented
+    # tolerance 2x. The MFU gauges divide both by a chip peak; the CPU
+    # has none in the table, so they stay unset here
+    ratio = rep["measured_flops_per_step"] / rep["formula_flops_per_step"]
     assert 0.5 <= ratio <= 2.0, ratio
-    assert get_registry().gauge("measured_mfu").value == rep["measured_mfu"]
+    assert rep["measured_mfu"] is None and rep["formula_mfu"] is None
 
 
 def test_proftop_trace_dir_mode(tmp_path, capsys):
