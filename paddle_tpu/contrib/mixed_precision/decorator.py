@@ -97,7 +97,12 @@ class OptimizerWithMixedPrecision:
         return scaled_loss, params_grads
 
     def apply_gradients(self, params_grads):
-        return self._apply(params_grads)
+        # the float32 casts of the gradients, the unscale, the finite
+        # check and the loss-scale update are the optimizer's part of the
+        # step (framework.Operator.role)
+        program = params_grads[0][0].block.program
+        with program._optimized_guard():
+            return self._apply(params_grads)
 
     def _apply(self, params_grads):
         if self._dest_dtype == "bfloat16" and not self._use_dynamic_loss_scaling:

@@ -446,45 +446,48 @@ class _DCNGradSyncOptimizer:
             step_var = _create_persistable_var(
                 unique_name.generate("dcn_dgc_step"), [1], "float32", 0.0
             )
-        synced = []
-        for p, g in params_grads:
-            if g is None:
-                synced.append((p, g))
-                continue
-            inputs = {"X": [g]}
-            outputs = {}
-            if use_dgc:
-                # [n_dcn, *shape], SHARDED over "dcn": each slice owns its
-                # error-feedback residual (replicating it would collapse
-                # the per-slice state on any metadata-trusting reshard)
-                ef = _create_persistable_var(
-                    p.name + "@DGCErrorFeedback",
-                    (n_dcn,) + tuple(p.shape), "float32", 0.0,
+        # gradient synchronisation is the optimizer's part of the step
+        with block.program._optimized_guard():
+            synced = []
+            for p, g in params_grads:
+                if g is None:
+                    synced.append((p, g))
+                    continue
+                inputs = {"X": [g]}
+                outputs = {}
+                if use_dgc:
+                    # [n_dcn, *shape], SHARDED over "dcn": each slice owns
+                    # its error-feedback residual (replicating it would
+                    # collapse the per-slice state on any metadata-trusting
+                    # reshard)
+                    ef = _create_persistable_var(
+                        p.name + "@DGCErrorFeedback",
+                        (n_dcn,) + tuple(p.shape), "float32", 0.0,
+                    )
+                    set_var_sharding(
+                        ef, ("dcn",) + (None,) * len(tuple(p.shape))
+                    )
+                    inputs["ErrorFeedback"] = [ef]
+                    outputs["ErrorFeedback"] = [ef]
+                    if step_var is not None:
+                        inputs["Step"] = [step_var]
+                out_name = unique_name.generate(g.name + "@DCNSync")
+                block.append_op(
+                    type="c_dcn_grad_sync",
+                    inputs=inputs,
+                    outputs={"Out": [out_name], **outputs},
+                    attrs={"use_dgc": use_dgc, "sparsity": sparsity,
+                           "rampup_begin_step": rampup, "dcn_axis": "dcn",
+                           "wire_dtype": wire},
                 )
-                set_var_sharding(
-                    ef, ("dcn",) + (None,) * len(tuple(p.shape))
+                synced.append((p, block.var(out_name)))
+            if step_var is not None:
+                block.append_op(
+                    type="scale",
+                    inputs={"X": [step_var]},
+                    outputs={"Out": [step_var]},
+                    attrs={"scale": 1.0, "bias": 1.0},
                 )
-                inputs["ErrorFeedback"] = [ef]
-                outputs["ErrorFeedback"] = [ef]
-                if step_var is not None:
-                    inputs["Step"] = [step_var]
-            out_name = unique_name.generate(g.name + "@DCNSync")
-            block.append_op(
-                type="c_dcn_grad_sync",
-                inputs=inputs,
-                outputs={"Out": [out_name], **outputs},
-                attrs={"use_dgc": use_dgc, "sparsity": sparsity,
-                       "rampup_begin_step": rampup, "dcn_axis": "dcn",
-                       "wire_dtype": wire},
-            )
-            synced.append((p, block.var(out_name)))
-        if step_var is not None:
-            block.append_op(
-                type="scale",
-                inputs={"X": [step_var]},
-                outputs={"Out": [step_var]},
-                attrs={"scale": 1.0, "bias": 1.0},
-            )
         opt_ops = self.inner_opt.apply_optimize(
             loss, startup_program, synced
         )
@@ -524,45 +527,47 @@ class _DCNLocalSGDOptimizer:
             no_grad_set)
         program = loss.block.program
         block = program.global_block()
-        synced = []
-        for p, g in params_grads:
-            if g is None:
-                synced.append((p, g))
-                continue
-            out_name = unique_name.generate(g.name + "@DPSync")
-            block.append_op(
-                type="c_dcn_grad_sync",
-                inputs={"X": [g]},
-                outputs={"Out": [out_name]},
-                attrs={"intra_only": True, "dcn_axis": "dcn"},
-            )
-            synced.append((p, block.var(out_name)))
-        opt_ops = self.inner_opt.apply_optimize(loss, startup_program, synced)
+        with program._optimized_guard():
+            synced = []
+            for p, g in params_grads:
+                if g is None:
+                    synced.append((p, g))
+                    continue
+                out_name = unique_name.generate(g.name + "@DPSync")
+                block.append_op(
+                    type="c_dcn_grad_sync",
+                    inputs={"X": [g]},
+                    outputs={"Out": [out_name]},
+                    attrs={"intra_only": True, "dcn_axis": "dcn"},
+                )
+                synced.append((p, block.var(out_name)))
+            opt_ops = self.inner_opt.apply_optimize(
+                loss, startup_program, synced)
 
-        # replicated in-graph step counter, incremented AFTER the sync
-        # ops: step i reads value i, so `i % k == k-1` fires the first
-        # consensus after exactly k local updates
-        # int32: a float32 counter saturates at 2^24 (x+1 == x), which
-        # would freeze step%k on very long runs
-        step_var = _create_persistable_var(
-            unique_name.generate("localsgd_step"), [1], "int32", 0.0)
-        divergent = set(getattr(program, "_dcn_divergent_names", ()))
-        for p, g in params_grads:
-            if g is None:
-                continue
+            # replicated in-graph step counter, incremented AFTER the sync
+            # ops: step i reads value i, so `i % k == k-1` fires the first
+            # consensus after exactly k local updates
+            # int32: a float32 counter saturates at 2^24 (x+1 == x), which
+            # would freeze step%k on very long runs
+            step_var = _create_persistable_var(
+                unique_name.generate("localsgd_step"), [1], "int32", 0.0)
+            divergent = set(getattr(program, "_dcn_divergent_names", ()))
+            for p, g in params_grads:
+                if g is None:
+                    continue
+                block.append_op(
+                    type="c_dcn_localsgd_sync",
+                    inputs={"X": [p], "Step": [step_var]},
+                    outputs={"Out": [p]},
+                    attrs={"k_steps": k_steps, "dcn_axis": "dcn"},
+                )
+                divergent.add(p.name)
+                _parallel.set_var_sharding(
+                    p, ("dcn",) + (None,) * len(tuple(p.shape)))
             block.append_op(
-                type="c_dcn_localsgd_sync",
-                inputs={"X": [p], "Step": [step_var]},
-                outputs={"Out": [p]},
-                attrs={"k_steps": k_steps, "dcn_axis": "dcn"},
+                type="increment", inputs={"X": [step_var]},
+                outputs={"Out": [step_var]}, attrs={"step": 1},
             )
-            divergent.add(p.name)
-            _parallel.set_var_sharding(
-                p, ("dcn",) + (None,) * len(tuple(p.shape)))
-        block.append_op(
-            type="increment", inputs={"X": [step_var]},
-            outputs={"Out": [step_var]}, attrs={"step": 1},
-        )
         # accumulators diverge with their slice's gradients
         for slot in getattr(self.inner_opt, "_accumulators", {}).values():
             for acc_var in slot.values():

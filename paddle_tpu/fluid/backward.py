@@ -70,8 +70,11 @@ def append_backward(
     """
     from .analysis import pass_sandwich
 
-    with pass_sandwich(loss.block.program, "append_backward",
-                       live_out=(loss.name,)):
+    program = loss.block.program
+    # every op from the loss-gradient fill to the last gradient sum is
+    # stamped `backward` (framework.Operator.role)
+    with pass_sandwich(program, "append_backward", live_out=(loss.name,)), \
+            program._backward_role_guard():
         return _append_backward_impl(
             loss, parameter_list, no_grad_set, callbacks, checkpoints)
 

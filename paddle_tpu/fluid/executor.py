@@ -591,7 +591,12 @@ class Executor:
             return (jax.named_scope(f"fwk:{name}") if op_profile
                     else contextlib.nullcontext())
 
-        def fn(feed_vals, donated_vals, kept_vals, rng_key):
+        # Its name is the compiled module's (`jit_step`), and that is part
+        # of the key of JAX's persistent compilation cache, which op_name
+        # metadata is not: under the old name (`fn`) a cache filled before
+        # emit_ops wrote role scopes would go on serving executables
+        # without them, identical in code and blind in a trace.
+        def step(feed_vals, donated_vals, kept_vals, rng_key):
             ctx = registry.EmitContext(rng_key=rng_key, mesh=mesh,
                                        op_scopes=op_profile)
             env: Dict[str, Any] = {}
@@ -764,7 +769,7 @@ class Executor:
                 repl,
             )
             jit_fn = jax.jit(
-                fn,
+                step,
                 donate_argnums=(1,) if donate else (),
                 in_shardings=in_shardings,
                 out_shardings=out_shardings,
@@ -776,7 +781,7 @@ class Executor:
             cb.feed_shardings = {n: sh(n) for n in feed_names}
             cb.repl_sharding = repl
             return cb
-        jit_fn = jax.jit(fn, donate_argnums=(1,) if donate else ())
+        jit_fn = jax.jit(step, donate_argnums=(1,) if donate else ())
         return _CompiledBlock(
             jit_fn, list(feed_names), donate_names, keep_names, state_out, fetch_names
         )

@@ -35,6 +35,13 @@ _dummy_batch_probes = (3, 5)
 # grad path ignore it — pure diagnostics, never semantics.
 OP_CALLSTACK_ATTR = "__op_callstack__"
 
+# an op's role in the training step (the reference's OpRole, as far as the
+# compiled step is split by it); also the names of the scopes in the step
+ROLE_FORWARD = "forward"
+ROLE_BACKWARD = "backward"
+ROLE_OPTIMIZER = "optimizer"
+ROLES = (ROLE_FORWARD, ROLE_BACKWARD, ROLE_OPTIMIZER)
+
 
 def _capture_callstack(skip: int = 2, limit: int = 32):
     """Cheap (file, line, fn) stack walk for op attribution — no source
@@ -198,6 +205,13 @@ class Operator:
     Reference: framework.py:1821 (wrapping C++ OpDesc,
     paddle/fluid/framework/op_desc.h). Inputs/outputs map slot name ->
     list of variable names (strings).
+
+    `role` is the reference's `op_role` (forward / backward / optimizer):
+    which part of the training step the op belongs to. It is stamped from
+    the program's current role when the op is made, lives beside `attrs`
+    and not in them (attribute comparisons, saved programs and the
+    program's hash see nothing of it), and names the `jax.named_scope`
+    that `ops/registry.emit_ops` lowers the op under.
     """
 
     def __init__(
@@ -210,6 +224,7 @@ class Operator:
     ):
         self.block = block
         self.type = type
+        self.role = block.program._op_role
         self.inputs = {k: list(v) for k, v in (inputs or {}).items()}
         self.outputs = {k: list(v) for k, v in (outputs or {}).items()}
         self.attrs = dict(attrs or {})
@@ -387,9 +402,32 @@ class Program:
         # set by AMP / fleet passes; consumed by the Executor
         self._amp_enabled = False
         self._mesh = None  # paddle_tpu.parallel mesh attached by fleet
+        # the role every op made from here on is stamped with
+        self._op_role = ROLE_FORWARD
 
     def _bump_version(self):
         self._version += 1
+
+    @contextlib.contextmanager
+    def _role_guard(self, role: str):
+        prev, self._op_role = self._op_role, role
+        try:
+            yield
+        finally:
+            self._op_role = prev
+
+    def _backward_role_guard(self):
+        """Ops appended inside are gradient ops (reference framework.py
+        `_backward_role_guard`): the loss-gradient fill, the `*_grad`
+        ops, the sums that accumulate partial gradients."""
+        return self._role_guard(ROLE_BACKWARD)
+
+    def _optimized_guard(self):
+        """Ops appended inside run between the gradients and the
+        parameter write (reference framework.py `_optimized_guard`):
+        clipping, regularisation, AMP's unscale and loss-scale update,
+        gradient synchronisation, the update itself."""
+        return self._role_guard(ROLE_OPTIMIZER)
 
     def global_block(self) -> Block:
         return self.blocks[0]
@@ -436,6 +474,7 @@ class Program:
         p._serial = next(_program_serial_counter)  # own compile-cache identity
         p._amp_enabled = self._amp_enabled
         p._mesh = self._mesh
+        p._op_role = ROLE_FORWARD
         for b in self.blocks:
             nb = Block(p, b.idx, b.parent_idx)
             p.blocks.append(nb)
@@ -465,6 +504,7 @@ class Program:
                             outputs=copy.deepcopy(sop.outputs),
                             attrs=dict(sop.attrs),
                         )
+                        nsop.role = sop.role
                         if for_test and "is_test" in nsop.attrs:
                             nsop.attrs["is_test"] = True
                         subs.append(nsop)
@@ -476,6 +516,7 @@ class Program:
                     outputs=copy.deepcopy(op.outputs),
                     attrs=attrs,
                 )
+                nop.role = op.role
                 if for_test and "is_test" in nop.attrs:
                     nop.attrs["is_test"] = True
                 nb.ops.append(nop)

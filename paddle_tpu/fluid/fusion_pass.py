@@ -126,6 +126,7 @@ def _try_fuse_at(block, i) -> bool:
         },
         attrs=attrs,
     )
+    fused.role = conv.role  # a rewrite takes the role of what it replaces
     for idx in sorted(filter(lambda k: k is not None, (i, j, relu_idx)),
                       reverse=True):
         del block.ops[idx]

@@ -121,6 +121,12 @@ class Optimizer:
         )
 
     def apply_gradients(self, params_grads):
+        # clip, regularisation, the numerics guards and the update are
+        # all stamped `optimizer` (framework.Operator.role)
+        with framework.default_main_program()._optimized_guard():
+            return self._apply_gradients(params_grads)
+
+    def _apply_gradients(self, params_grads):
         params_grads = sorted(params_grads, key=lambda x: x[0].name)
         grad_clip = self._grad_clip
         if grad_clip is None and params_grads:
@@ -828,7 +834,7 @@ class GradientMergeOptimizer:
             if startup_program is not None
             else framework.default_startup_program()
         )
-        with program_guard(main, startup):
+        with program_guard(main, startup), main._optimized_guard():
             block = main.global_block()
             cond = _append_step_cond(
                 block, unique_name.generate("gradient_merge_step"), self.k_steps
@@ -905,7 +911,7 @@ class LookaheadOptimizer:
             if startup_program is not None
             else framework.default_startup_program()
         )
-        with program_guard(main, startup):
+        with program_guard(main, startup), main._optimized_guard():
             block = main.global_block()
             cond = _append_step_cond(
                 block, unique_name.generate("lookahead_step"), self.k
@@ -1243,6 +1249,10 @@ class ExponentialMovingAverage:
         return name
 
     def update(self):
+        with framework.default_main_program()._optimized_guard():
+            self._append_update_ops()
+
+    def _append_update_ops(self):
         main = framework.default_main_program()
         block = main.global_block()
         step = _create_persistable_var(self._step_name, (1,), "int32", 0.0)
@@ -1338,6 +1348,10 @@ class ModelAverage:
         self.max_average_window = int(max_average_window)
         self._pairs = []  # (param, sum_name, num_name)
         self._backup = {}
+        with framework.default_main_program()._optimized_guard():
+            self._append_average_ops()
+
+    def _append_average_ops(self):
         main = framework.default_main_program()
         block = main.global_block()
 

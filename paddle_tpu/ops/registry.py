@@ -257,6 +257,15 @@ def _fwd_key_from_grad(op):
     )), _attrs_sig(op.attrs))
 
 
+def role_scope(role: str):
+    """The scope an op of this role is lowered under. A function of its
+    own so that a test can put a null context in its place and compare
+    the two executables."""
+    import jax
+
+    return jax.named_scope(role)
+
+
 def emit_ops(ctx: EmitContext, ops, env: Dict[str, Any],
              on_op=None) -> Dict[str, Any]:
     """Trace a list of framework Operators into JAX values. `env` maps var
@@ -278,15 +287,31 @@ def emit_ops(ctx: EmitContext, ops, env: Dict[str, Any],
     the op that lowered it — the join key telemetry/cost.py aggregates
     xplane device events by. Grad-op backward compute (the cached vjp_fn
     call) is tagged at the GRAD op's index; sub-block emitters recursing
-    through emit_ops nest their scopes under the parent op's."""
+    through emit_ops nest their scopes under the parent op's.
+
+    Role scopes (always on): every op is lowered under
+    jax.named_scope(op.role) — "forward", "backward" or "optimizer" — so
+    the first component of an instruction's op_name after the jit(...)
+    wrappers says which part of the step it belongs to; a grad op's
+    cached vjp_fn call runs under the grad op's scope, so transposes and
+    rematerialised computation read "backward". A named scope writes
+    op_name metadata and nothing else: the executable is the same with
+    and without it (tests/test_op_role.py), which is why there is no flag
+    here and nothing in the executor's cache key. The op<idx>:<type>
+    scope nests inside; a sub-block's ops nest a second role, and only
+    the outermost counts."""
     import contextlib
 
     import jax
 
     def _scope(idx, op):
+        role = role_scope(op.role)
         if not ctx.op_scopes:
-            return contextlib.nullcontext()
-        return jax.named_scope(f"op{idx}:{op.type}")
+            return role
+        stack = contextlib.ExitStack()
+        stack.enter_context(role)
+        stack.enter_context(jax.named_scope(f"op{idx}:{op.type}"))
+        return stack
 
     wanted: Dict[tuple, int] = {}
     for op in ops:
