@@ -29,8 +29,12 @@ Capabilities:
     attention can causal-mask blocks whose global positions are shifted
     relative to the local shard
   - attention-probs dropout folded into the kernel: on TPU the mask is
-    regenerated from the hardware PRNG (pltpu.prng_*) per (bh, q-block,
-    k-block) in both forward and backward — zero HBM traffic for masks.
+    regenerated in both forward and backward — zero HBM traffic for
+    masks. The BHSD kernels draw it from the hardware PRNG (pltpu.prng_*)
+    per (bh, q-block, k-block), and so do the BSH whole-tile kernels
+    (S < 1024); the BSH stream kernels draw it from a counter-based
+    hash of the absolute (bh, key, query) position, so their two passes
+    may tile differently (_dropout_keep_t).
     Masking only the numerator accumulator and never the normalizer is
     exactly post-softmax dropout (same scheme as parallel/ring_attention).
     In interpret mode (CPU tests) the TPU PRNG is unavailable, so the
@@ -40,7 +44,9 @@ Capabilities:
     dp, heads on tp (megatron split); dropout seeds are decorrelated per
     shard and per-key dbias is psum'd over tp.
 
-Block sizes cap at 512 to match VMEM; S must be a multiple of 128.
+S must be a multiple of 128. The BHSD kernels' blocks, which are grid
+tiles and compute tiles at once, cap at 512 to match VMEM; the BSH
+kernels' DMA tiles go to 1024 and hold compute tiles of their own.
 """
 from __future__ import annotations
 
@@ -66,8 +72,10 @@ _VMEM_BUDGET = 9 * 1024 * 1024 + 512 * 1024
 
 def _pick_block(s):
     """Largest block that tiles s, capped at 512: the whole score tile
-    fits VMEM and bigger dots keep the MXU busy (128-blocks are
-    latency-bound: profiled 4x slower at S=512). PADDLE_FLASH_BLOCK
+    fits VMEM and bigger dots keep the MXU busy (as GRID tiles of the
+    BHSD kernels, whose body is one block, 128-blocks are latency-bound:
+    profiled 4x slower at S=512; the BSH kernels take this as a DMA tile
+    only and compute in tiles of their own). PADDLE_FLASH_BLOCK
     overrides for tuning sweeps (must divide s)."""
     import os
 
@@ -1280,10 +1288,12 @@ def flash_block_with_lse(q, k, v, key_bias=None, sm_scale=None,
 # shape) falls out for free because q and k/v carry separate lengths.
 #
 # Capabilities: per-key additive bias [B, 1, S_kv] (no dbias — padding
-# masks), causal with (q_offset, k_offset), in-kernel PRNG dropout (same
-# quantized-byte scheme and seed mixing as the BHSD kernels, bh = b*nh+h,
-# so masks are reproducible across fwd/bwd). Full [.., S, S] bias and
-# dbias stay on the BHSD path.
+# masks), causal with (q_offset, k_offset), in-kernel dropout (the BHSD
+# kernels' quantized-byte scheme, keep 230/256 at p = 0.1, bh = b*nh+h,
+# reproducible across fwd/bwd: the whole-tile kernels seed the hardware
+# PRNG per block and tile both passes alike, the stream kernels hash
+# the seed and the absolute position, whatever tiles each pass runs).
+# Full [.., S, S] bias and dbias stay on the BHSD path.
 
 
 def _prescale_ok(sm_scale) -> bool:
@@ -1298,7 +1308,13 @@ def _prescale_ok(sm_scale) -> bool:
     return math.frexp(float(sm_scale))[0] == 0.5
 
 
-def _make_fwd_bsh_kernel(*, sm_scale, causal, dropout_prob, has_bias,
+# Two bodies share these names. Up to S = 512 a head's whole score tile
+# is one step of work, and the WHOLE-TILE kernels below do it as one
+# straight-line block (DMA tile = compute tile); from S = 1024 the
+# STREAM kernels further down run compute tiles inside the DMA tile in
+# a rolled, software-pipelined loop. _bsh_streams says which.
+
+def _make_fwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
                          use_prng, has_mask, has_offsets, nh, d, bq, bk,
                          prescale=False):
     def kernel(*refs):
@@ -1378,92 +1394,13 @@ def _make_fwd_bsh_kernel(*, sm_scale, causal, dropout_prob, has_bias,
     return kernel
 
 
-def default_bsh_block(s, skv, h, bwd=False, sync_bwd=False):
-    """THE hand-picked BSH tile chooser (the autotune cache-miss
-    fallback — tuning/search.py replaces it per shape when a measured
-    winner exists; see _resolve_bsh_blocks).
-
-    BSH kernels tolerate bigger tiles than the streamed BHSD path
-    (whole-sequence VMEM residency is already the design): at S>=4096 a
-    1024 tile measured 0.4266 vs 0.4240 MFU (BERT-base s4096/b8, v5e) —
-    fewer block iterations amortize the per-block softmax epilogue.
-    Footprint gates (v5e-calibrated): the fwd holds k/v resident —
-    skv-sized, ~8 B/elem double-buffered — plus ~40MB of 1024-tile
-    temporaries; the bwd's q/do/dq residency measured 124MB at
-    (s8192, bq1024) vs the 112MB limit, so it escalates only at
-    s==4096 (fits; the full s4096/b8 bench runs it).
-
-    sync_bwd: in-kernel PRNG dropout seeds per (bh, q-block, k-block)
-    and draws [bq, bk] masks, so the keep pattern DEPENDS on the block
-    partition — when the fwd applied PRNG dropout, the bwd must
-    regenerate the identical mask, which means identical tiles. Callers
-    set sync_bwd on the fwd pick whenever use_prng, forcing the fwd
-    down to whatever the bwd can afford. Without dropout (or with a
-    materialized mask), mixed fwd/bwd tiles are fine — lse and delta
-    ride as full [B, nh, S] arrays."""
-    import os
-
-    forced = int(os.environ.get("PADDLE_FLASH_BLOCK", "0"))
-    if forced >= MIN_BLOCK and s % forced == 0:
-        return forced
-    if s >= 4096 and s % 1024 == 0:
-        if bwd or sync_bwd:
-            if s == 4096 and skv == 4096:
-                return 1024
-        elif 8 * skv * h + 40 * 2**20 <= _BSH_VMEM_LIMIT:
-            return 1024
-    return _pick_block(s)
-
-
-_pick_block_bsh = default_bsh_block  # historical name (round-5 sweeps)
-
-
-def _resolve_bsh_blocks(sq, skv, h, dtype, *, bwd=False, sync_bwd=False):
-    """(bq, bk, vmem_limit_bytes) for one BSH kernel launch.
-
-    Precedence: PADDLE_FLASH_BLOCK env override (hand sweeps) >
-    FLAGS_kernel_autotune cache entry > default_bsh_block heuristic.
-    One cache entry serves fwd AND bwd (in-kernel PRNG dropout must
-    regenerate identical per-block masks, which requires identical
-    tiles), so a cached config is validated against BOTH footprint
-    models before it is trusted; an invalid or missing entry falls back
-    to the hand-picked chooser — no behavior cliff."""
-    import os
-
-    key = {"sq": sq, "skv": skv, "h": h, "dtype": str(dtype)}
-    if not int(os.environ.get("PADDLE_FLASH_BLOCK", "0")):
-        from ... import tuning
-
-        cfg = tuning.maybe_lookup("flash_bsh", key)
-        if cfg:
-            try:
-                bq = int(cfg.get("bq", 0))
-                bk = int(cfg.get("bk", 0))
-                limit = (int(cfg["vmem_limit_mb"]) * 2**20
-                         if cfg.get("vmem_limit_mb") else _BSH_VMEM_LIMIT)
-            except (TypeError, ValueError):
-                bq = bk = 0
-                limit = _BSH_VMEM_LIMIT
-            ok, _why = _feas.flash_bsh_ok(sq, skv, h, bq, bk, limit=limit)
-            if ok:
-                return bq, bk, limit
-            # bad entry (edited by hand / stale shape): hand-picked path
-            tuning.note_choice("flash_bsh", key, None, "default")
-    return (
-        default_bsh_block(sq, skv, h, bwd=bwd, sync_bwd=sync_bwd),
-        default_bsh_block(skv, skv, h, bwd=bwd, sync_bwd=sync_bwd),
-        _BSH_VMEM_LIMIT,
-    )
-
-
-def _flash_fwd_bsh(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
+def _flash_fwd_bsh_tile(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
                    causal, dropout_prob):
     b, sq, hdim = q.shape
     skv = k.shape[1]
     d = hdim // nh
     use_prng = dropout_prob > 0.0 and mask is None
-    bq, bk, vmem_limit = _resolve_bsh_blocks(
-        sq, skv, hdim, q.dtype, sync_bwd=use_prng)
+    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim, q.dtype)
     has_mask = mask is not None and dropout_prob > 0.0
     has_offsets = offsets is not None
     has_bias = bias is not None
@@ -1494,7 +1431,7 @@ def _flash_fwd_bsh(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(offsets)
 
-    kernel = _make_fwd_bsh_kernel(
+    kernel = _make_fwd_bsh_tile_kernel(
         sm_scale=sm_scale, causal=causal, dropout_prob=dropout_prob,
         has_bias=has_bias, use_prng=use_prng, has_mask=has_mask,
         has_offsets=has_offsets, nh=nh, d=d, bq=bq, bk=bk,
@@ -1522,7 +1459,7 @@ def _flash_fwd_bsh(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
     return o, lse
 
 
-def _make_bwd_bsh_kernel(*, sm_scale, causal, dropout_prob, has_bias,
+def _make_bwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
                          use_prng, has_mask, has_offsets, nh, d, bq, bk,
                          prescale=False):
     """Single-pass BSH backward: grid (B, NKv) with NKv innermost per
@@ -1652,14 +1589,14 @@ def _make_bwd_bsh_kernel(*, sm_scale, causal, dropout_prob, has_bias,
     return kernel
 
 
-def _flash_bwd_bsh(res, g, *, sm_scale, nh, causal, dropout_prob):
+def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob):
     q, k, v, bias, mask, seed, offsets, o, lse = res
     b, sq, hdim = q.shape
     skv = k.shape[1]
     d = hdim // nh
     use_prng = dropout_prob > 0.0 and mask is None
     bq, bk, vmem_limit = _resolve_bsh_blocks(
-        sq, skv, hdim, q.dtype, bwd=True, sync_bwd=use_prng)
+        sq, skv, hdim, q.dtype, bwd=True)
     has_mask = mask is not None and dropout_prob > 0.0
     has_offsets = offsets is not None
     has_bias = bias is not None
@@ -1699,7 +1636,7 @@ def _flash_bwd_bsh(res, g, *, sm_scale, nh, causal, dropout_prob):
     args += [g, lse, delta]
 
     dq, dk, dv = pl.pallas_call(
-        _make_bwd_bsh_kernel(
+        _make_bwd_bsh_tile_kernel(
             sm_scale=sm_scale, causal=causal, dropout_prob=dropout_prob,
             has_bias=has_bias, use_prng=use_prng, has_mask=has_mask,
             has_offsets=has_offsets, nh=nh, d=d, bq=bq, bk=bk,
@@ -1721,9 +1658,1041 @@ def _flash_bwd_bsh(res, g, *, sm_scale, nh, causal, dropout_prob):
     return dq.astype(q.dtype), dk, dv
 
 
-# the BSH kernels keep whole sequences resident (k/v in fwd, q/do/dq in
-# bwd): ~40MB at s=4096/H=768, ~102MB at s=8192 (Mosaic's scoped-vmem
-# report). v5e has 128MB of VMEM; the default ~16MB scoped limit is far
+
+# Two-level tiling (the stream kernels). The DMA tile (bq rows of q / o /
+# lse in the forward, bk rows of k / v / dk / dv in the backward) is what
+# _resolve_bsh_blocks picks; inside it the arithmetic runs over compute
+# tiles in ONE rolled loop a grid cell, so the traced body and Mosaic's
+# compile time are those of a trip of that loop whatever the DMA tile is
+# (the whole-tile body at bq = bk = 1024 keeps 4 MB intermediates where
+# the core has 64 vregs: nine in ten of its vector stores are spills,
+# and it takes 27 s to compile).
+#
+# A compute tile is held TRANSPOSED, ck <= 512 keys on the sublanes and
+# cq <= 512 queries on the lanes: s^T = k q^T is [ck, cq]. The per-query
+# statistics (m, l, lse, delta) are then [1, cq] lane-major rows, cq / 128
+# vregs each and the layout lse / delta have in HBM, instead of [cq, 1]
+# columns of cq / 8 vregs; the softmax reductions run down the sublanes,
+# elementwise across vregs; acc^T [d, cq] fills its vregs at d = 64. The
+# four 128-lane blocks of a 512-query tile are the four weight tiles of
+# a product, one an MXU, with no weight pushed twice.
+#
+# Heads are taken in lane groups of 128 // d (two at d = 64): a group's
+# q / k / v / o slab is a 128-aligned lane window, which a rolled loop
+# can index, so the traced body is one group's. A head contracts the
+# whole slab against an operand zeroed outside its own d rows or lanes —
+# the MXU's contraction is 128 deep whatever d is — and no 64-lane
+# slice, rotate or masked store is left in the loop.
+#
+# What the tiles need transposed (V, q, do, k going in; o, dk, dv, dq
+# coming out) is transposed a grid cell or a batch row at a time, on the
+# MXU (_mxu_t).
+_CQ = 512
+_CK = 512
+# steps of the stream traced into one trip of its rolled loop. Their
+# scores / probabilities cross from step to step in two VMEM slots that
+# must alternate statically, so two; four measured no faster (17.91
+# against 17.57 ms a layer at s4096, my chip run, PR 27)
+_STEPS = 2
+
+
+def _compute_tile(nq, nk, d):
+    """(cq, ck) of a compute tile: cq queries on the lanes, ck keys on
+    the sublanes, each the largest of 512 / 256 / 128 that tiles the nq
+    queries and nk keys a grid cell holds (the forward: its bq block
+    and all of Skv; the backward: all of Sq and its bk block)."""
+    del d
+
+    def largest(n, cap):
+        return next(c for c in (512, 256, 128) if c <= cap and n % c == 0)
+
+    return largest(nq, _CQ), largest(nk, _CK)
+
+
+def _head_groups(nh, d):
+    """(heads a lane group, groups). Groups are 128 lanes wide where
+    heads pair up into that; otherwise every head is its own group at
+    a static lane offset."""
+    hp = MIN_BLOCK // d if d < MIN_BLOCK else 1
+    if hp > 1 and nh % hp:
+        hp = 1
+    return hp, nh // hp
+
+
+def _own_lanes(x, hh, hp, d):
+    """x [n, hp * d] with every lane outside head hh's d lanes zeroed."""
+    if hp == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    own = (lane >= hh * d) & (lane < (hh + 1) * d)
+    return jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _eye(n, dtype, hh=0, hp=1):
+    """[n, n] identity; with hp > 1 only head hh's n / hp rows of it."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    own = r == c
+    if hp > 1:
+        d = n // hp
+        own = own & (r >= hh * d) & (r < (hh + 1) * d)
+    return own.astype(dtype)
+
+
+def _mxu_t(x, eye=None):
+    """x^T of x [m, n], 128 lanes of x at a time, as eye @ x^T on the
+    MXU (the transposed weight push q k^T uses): exact for the 16-bit
+    operands and results it is used on. The XLU's transpose holds the
+    core ~150 cycles a 128 x 128 block, which at S = 512, where a grid
+    cell is short, was a third of a kernel; this is a pass of an idle
+    MXU. eye: what _eye(min(n, 128)) gives, for a caller that has it."""
+    m, n = x.shape
+    w = min(n, MIN_BLOCK)
+    if eye is None:
+        eye = _eye(w, x.dtype)
+    blocks = [
+        jax.lax.dot_general(
+            eye, x[:, j:j + w], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(x.dtype)
+        for j in range(0, n, w)]
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=0)
+
+
+_MIX_A = 0x7FEB352D
+_MIX_B = 0x846CA68B - (1 << 32)
+
+
+def _mix32(x):
+    """lowbias32 (Wellons): a bijection of int32 with full avalanche;
+    on the scalar core here (two native u32 multiplies)."""
+    srl = jax.lax.shift_right_logical
+    x = x ^ srl(x, jnp.int32(16))
+    x = x * jnp.int32(_MIX_A)
+    x = x ^ srl(x, jnp.int32(15))
+    x = x * jnp.int32(_MIX_B)
+    return x ^ srl(x, jnp.int32(16))
+
+
+def _dropout_keep_t(seed, bh, k0, q0, keep_prob, ck, cq):
+    """[ck, cq] keep mask (keys on sublanes) of the tile whose first key
+    and query sit at ABSOLUTE positions k0, q0 of head-row bh.
+
+    A counter-based draw: the mask of (bh, key, query) is a pure function
+    of the seed and of that position in the whole [Sq, Skv] matrix, never
+    of the DMA partition, of the compute tile or of the order tiles are
+    visited in, so forward and backward regenerate the same mask whatever
+    tiles each runs. (Seeding the hardware PRNG costs ~150 dependent
+    vector operations a seed; this costs 7 a word and keeps no state.)
+    One 32-bit word feeds FOUR mask bytes: word (a, query) of the
+    128-key group g covers keys g*128 + j*32 + a, j the byte. Byte j is
+    moved to the top of its word and compared, signed, with
+    t * 2**24 - 2**31: true for exactly t of the 256 byte values, with
+    no mask-to-[0, 255] step."""
+    t = _dropout_quantized_thresh(keep_prob)
+    if t >= 256:
+        return jnp.ones((ck, cq), jnp.bool_)
+    thresh = jnp.int32(t * 2**24 - 2**31)
+    srl = jax.lax.shift_right_logical
+    a = jax.lax.broadcasted_iota(jnp.int32, (MIN_BLOCK // 4, cq), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (MIN_BLOCK // 4, cq), 1)
+    n = (q0 + lane) * jnp.int32(MIN_BLOCK // 4) + a
+    parts = []
+    for gi in range(ck // MIN_BLOCK):
+        g = k0 // MIN_BLOCK + gi
+        base = seed + bh * jnp.int32(_SEED_BH) + g * jnp.int32(_SEED_KI)
+        key1 = _mix32(base)
+        key2 = _mix32(base ^ jnp.int32(_SEED_QI))
+        x = (n ^ key1) * jnp.int32(_MIX_A)
+        x = x ^ srl(x, jnp.int32(15))
+        x = (x + key2) * jnp.int32(_MIX_B)
+        x = x ^ srl(x, jnp.int32(16))
+        parts += [
+            (x << jnp.int32(24 - 8 * j) if j < 3 else x) < thresh
+            for j in range(4)
+        ]
+    return jnp.concatenate(parts, axis=0)
+
+
+def _causal_mask_t(s, qglob, kglob):
+    """_causal_mask for a transposed [ck, cq] tile."""
+    kpos = kglob + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    qpos = qglob + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(qpos >= kpos, s, NEG_INF)
+
+
+def _bias_columns(bias_ref, bcol_ref, k0, nk):
+    """Per-key bias, lane-major in bias_ref[0, 0, k0 + ...], into
+    bcol_ref [nk * 128, cq]: key on the sublane, replicated along the
+    lanes — the form a transposed score tile adds. An exact XLU
+    transpose of the sublane-broadcast row, once a grid cell."""
+    n = MIN_BLOCK
+    reps = bcol_ref.shape[1] // n
+
+    def body(j, carry):
+        row = bias_ref[0, :, pl.ds(pl.multiple_of(k0 + j * n, n), n)]
+        col = jnp.broadcast_to(row, (n, n)).T
+        for i in range(reps):
+            bcol_ref[pl.ds(pl.multiple_of(j * n, n), n),
+                     i * n:(i + 1) * n] = col
+        return carry
+
+    jax.lax.fori_loop(0, nk, body, 0)
+
+
+def _window(i, n):
+    if isinstance(i, int):
+        return slice(i * n, (i + 1) * n)
+    return pl.ds(pl.multiple_of(i * n, n), n)
+
+
+class _LaneGroups:
+    """The lane groups of a stream kernel's heads (_head_groups). Groups
+    that are 128-aligned lane windows are indexed dynamically, the group
+    a value of the loop that walks them; otherwise (an odd head count at
+    d = 64) every loop over groups is a static one and `lanes` answers
+    for the group being traced."""
+
+    def __init__(self, nh, d):
+        self.hp, self.ng = _head_groups(nh, d)
+        self.gw = self.hp * d
+        self.dynamic = self.gw % MIN_BLOCK == 0
+        self._static = None
+
+    def lanes(self, g):
+        return _window(g if self.dynamic else self._static, self.gw)
+
+    def each(self, fn, n):
+        """fn(g, i) for every group g and i < n."""
+        if self.dynamic:
+            def body(j, carry):
+                fn(j // n, j % n)
+                return carry
+
+            jax.lax.fori_loop(0, self.ng * n, body, 0)
+            return
+        for g in range(self.ng):
+            self._static = g
+
+            def body(i, carry, g=g):
+                fn(g, i)
+                return carry
+
+            jax.lax.fori_loop(0, n, body, 0)
+
+    def streams(self, run):
+        """run(first group, groups): one stream over all the groups, or
+        one a group at its static lanes."""
+        if self.dynamic:
+            run(0, self.ng)
+            return
+        for g in range(self.ng):
+            self._static = g
+            run(g, 1)
+
+
+def _tile_stream(g0, ng, hp, n_outer, inner_range, step, finish, init):
+    """Run step(j, cur, prv, nxt, flags, carry) -> carry over every
+    compute step of lane groups g0 .. g0 + ng - 1 as ONE rolled loop of
+    _STEPS steps a trip: groups outermost, then the n_outer windows o of
+    the kernel's DMA block, then the hp heads of a group, then the
+    windows inner_range(o) = (lo, hi) of the resident side. A step's
+    index is (g, o, hh, i); prv / nxt are its neighbours' (of another
+    head, window or group at a segment's ends) and j its static place in
+    the trip. flags = (live, live_nxt, fresh, fresh_prv): the count is
+    rounded up to whole trips and a step past the last is not live and
+    must change nothing; fresh says the step opens a segment (g, o, hh).
+    finish(j, seg) runs after the trip whose step j opened a new segment
+    and so closed seg, the one before. Returns (last step's index,
+    whether it opened its segment, carry)."""
+    zero = jnp.int32(0)
+
+    def succ(idx):
+        g, o, hh, i = idx
+        i = i + 1
+        wrap = i >= inner_range(o)[1]
+        hh = jnp.where(wrap, hh + 1, hh)
+        wrap_h = hh >= hp
+        hh = jnp.where(wrap_h, 0, hh)
+        o = jnp.where(wrap_h, o + 1, o)
+        wrap_o = o >= n_outer
+        o = jnp.where(wrap_o, 0, o)
+        g = jnp.where(wrap_o, g + 1, g)
+        return g, o, hh, jnp.where(wrap, inner_range(o)[0], i)
+
+    def count_steps(o, n):
+        lo, hi = inner_range(o)
+        return n + (hi - lo)
+
+    total = jax.lax.fori_loop(0, n_outer, count_steps, zero) * (hp * ng)
+    start = (jnp.int32(g0), zero, zero, inner_range(zero)[0])
+
+    def trip(i, state):
+        prv, cur, fresh_prv, carry = state
+        closed = []
+        for j in range(_STEPS):
+            t = i * _STEPS + j
+            live = t < total
+            live_nxt = t + 1 < total
+            # past the end the index stays on the last step
+            nxt = tuple(jnp.where(live_nxt, a, b)
+                        for a, b in zip(succ(cur), cur))
+            fresh = live & (
+                (t == 0) | (cur[0] != prv[0]) | (cur[1] != prv[1])
+                | (cur[2] != prv[2]))
+            carry = step(j, cur, prv, nxt,
+                         (live, live_nxt, fresh, fresh_prv), carry)
+            closed.append((fresh & (t > 0), prv))
+            # a dead step repeats the last live one's index, whose
+            # segment it continues (with nothing)
+            prv = tuple(jnp.where(live, a, b) for a, b in zip(cur, prv))
+            fresh_prv = fresh
+            cur = nxt
+        for j, (closes, seg) in enumerate(closed):
+            @pl.when(closes)
+            def _close(j=j, seg=seg):
+                finish(j, seg)
+        return prv, cur, fresh_prv, carry
+
+    trips = (total + _STEPS - 1) // _STEPS
+    last, _, fresh_last, carry = jax.lax.fori_loop(
+        0, trips, trip, (start, start, jnp.bool_(True), init))
+    return last, fresh_last, carry
+
+
+def _make_fwd_bsh_stream_kernel(*, sm_scale, causal, dropout_prob, has_bias,
+                         use_prng, has_mask, has_offsets, nh, d, bq, cq, ck,
+                         prescale=False):
+    groups = _LaneGroups(nh, d)
+    hp, gw, lanes_of = groups.hp, groups.gw, groups.lanes
+
+    def kernel(*refs):
+        it = iter(refs)
+        q_ref = next(it)          # [1, BQ, H]
+        k_ref = next(it)          # [1, Skv, H]
+        v_ref = next(it)          # [1, Skv, H]
+        bias_ref = next(it) if has_bias else None   # [1, 1, Skv]
+        mask_ref = next(it) if has_mask else None   # [1, nh, Skv, BQ]
+        seed_ref = next(it) if use_prng else None
+        off_ref = next(it) if has_offsets else None
+        o_ref = next(it)          # [1, BQ, H]
+        lse_ref = next(it)        # [1, nh, BQ]
+        bcol_ref = next(it) if has_bias else None   # [Skv, CQ] f32
+        vt_ref = next(it)         # [H, Skv]: V^T
+        wq_ref = next(it)         # [nh * GW, BQ]: each head's padded q^T
+        s_bufs = (next(it), next(it))   # [CK, CQ] f32 each
+        p_bufs = (next(it), next(it))   # [CK, CQ], v's dtype
+        acc_ref = next(it)        # [_STEPS, D, CQ] f32
+        stat_ref = next(it)       # [_STEPS, 2, CQ] f32: closed m, l
+        ot_ref = next(it)         # [GW, CQ] f32: a group's o^T
+
+        b = pl.program_id(0)
+        qi = pl.program_id(1)
+        nkc = k_ref.shape[1] // ck
+        nr = bq // cq
+        keep_prob = 1.0 - dropout_prob
+        keep_div = (
+            _dropout_quantized_keep(keep_prob) if use_prng else keep_prob
+        )
+        q_off = off_ref[0] if has_offsets else 0
+        k_off = off_ref[1] if has_offsets else 0
+
+        def visible(r):
+            """Key windows the query window r sees."""
+            return _hi_blocks(
+                causal, qi * nr + r, cq, ck, nkc, q_off, k_off)
+
+        def keys_of(r):
+            # at least one step a segment: a window that sees no key
+            # (ring offsets) runs one fully masked step, see finish
+            return 0, (jnp.maximum(visible(r), 1) if causal else nkc)
+
+        if has_bias:
+            _bias_columns(bias_ref, bcol_ref, 0, k_ref.shape[1] // MIN_BLOCK)
+
+        def transpose_v(g, n):
+            vt_ref[_window(g, gw), _window(n, ck)] = _mxu_t(
+                v_ref[0, _window(n, ck), lanes_of(g)])
+
+        def transpose_q(g, r):
+            """A head's q^T, zero outside its own d rows of the group's
+            slab (the MXU contracts the whole 128-lane slab of K), once
+            a grid cell: wq_ref[head * GW ..., queries]."""
+            q = q_ref[0, _window(r, cq), lanes_of(g)]     # [CQ, GW]
+            if prescale:
+                q = q * jnp.asarray(sm_scale, q.dtype)
+            for hh in range(hp):
+                wq_ref[_window(g * hp + hh, gw), _window(r, cq)] = _mxu_t(
+                    q, _eye(gw, q.dtype, hh, hp))
+
+        groups.each(transpose_v, nkc)
+        groups.each(transpose_q, nr)
+
+        def scores(idx, live=None):
+            """s^T [CK, CQ] of head hh of group g, queries r, keys c,
+            bias and causal mask on; NEG_INF throughout where not live (a
+            step past the end of the stream: p = 0, alpha = 1)."""
+            g, r, hh, c = idx
+            s = jnp.dot(
+                k_ref[0, _window(c, ck), lanes_of(g)],
+                wq_ref[_window(g * hp + hh, gw), _window(r, cq)],
+                preferred_element_type=jnp.float32,
+            )
+            if not prescale:
+                s = s * sm_scale
+            if has_bias:
+                s = s + bcol_ref[_window(c, ck), :]
+            if causal:
+                s = _causal_mask_t(
+                    s, q_off + (qi * nr + r) * cq, k_off + c * ck)
+            if live is not None:
+                # as a [CK, CQ] select here, in the shadow of the softmax
+                # before it; done on m_new / alpha in the step itself it
+                # cost 0.55 ms a layer at s4096 (my chip run, PR 27)
+                s = jnp.where(live, s, NEG_INF)
+            return s
+
+        def weighted_values(idx, p):
+            """(p v)^T [D, CQ] of head hh of group g, keys c."""
+            g, _, hh, c = idx
+            return jnp.dot(
+                vt_ref[_window(g * hp + hh, d), _window(c, ck)],
+                p, preferred_element_type=jnp.float32,
+            )
+
+        def write_segment(seg, m, l, acc):
+            """Normalize and write a finished (g, r, hh) segment."""
+            g, r, hh = seg
+            rows = _window(r, cq)
+            if has_offsets:
+                # a window that sees no key ran one masked step:
+                # exp(0) everywhere, which must not reach o
+                none = visible(r) <= 0
+                l = jnp.where(none, 0.0, l)
+                m = jnp.where(none, NEG_INF, m)
+                acc = jnp.where(none, 0.0, acc)
+            l_safe = jnp.maximum(l, 1e-30)
+            # the dropout rescale 1 / keep_div waited for here: one
+            # [D, CQ] pass a segment instead of a [CK, CQ] one a step
+            denom = l_safe * keep_div if dropout_prob > 0.0 else l_safe
+            lse_ref[0, pl.ds(g * hp + hh, 1), rows] = m + jnp.log(l_safe)
+            if hp == 1:
+                o_ref[0, rows, lanes_of(g)] = _mxu_t(
+                    (acc / denom).astype(o_ref.dtype))
+                return
+            ot_ref[_window(hh, d), :] = acc / denom
+
+            @pl.when(hh == hp - 1)
+            def _store_group():
+                o_ref[0, rows, lanes_of(g)] = _mxu_t(
+                    ot_ref[...].astype(o_ref.dtype))
+
+        # One stream of steps a grid cell, software-pipelined by hand,
+        # because the MXU runs in program order: step t asks for the
+        # scores of step t + 1 and for the p v product of step t - 1
+        # FIRST, and only then does the vector work of its own softmax,
+        # which hides both; neither waits at the end of a head. Scores,
+        # probabilities and acc cross from step to step through VMEM, in
+        # slots that alternate STATICALLY with the step's place in the
+        # trip: dynamic slots the compiler cannot tell apart, and it
+        # would hold every store behind the other slot's loads.
+        def step(j, cur, prv, nxt, flags, carry):
+            _, live_nxt, fresh, _ = flags
+            g, r, hh, c = cur
+            m, l, alpha_prev = carry
+            slot, other = j % 2, 1 - j % 2
+            p_prev = p_bufs[other][...]
+            s_bufs[other][...] = scores(nxt, live_nxt)
+            acc_ref[j] = (
+                acc_ref[(j - 1) % _STEPS] * alpha_prev
+                + weighted_values(prv, p_prev))
+            s = s_bufs[slot][...]
+            # a segment that closes leaves its m, l for finish; the
+            # fresh one's alpha = exp(NEG_INF - m_new) = 0 then drops
+            # the closed l and acc by itself
+            stat_ref[j, 0:1, :] = m
+            stat_ref[j, 1:2, :] = l
+            m = jnp.where(fresh, NEG_INF, m)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+            if dropout_prob > 0.0:
+                if use_prng:
+                    keep = _dropout_keep_t(
+                        seed_ref[0], b * nh + g * hp + hh, c * ck,
+                        qi * bq + r * cq, keep_prob, ck, cq)
+                else:
+                    keep = mask_ref[0, g * hp + hh, _window(c, ck),
+                                    _window(r, cq)] != 0
+                p = jnp.where(keep, p, 0.0)
+            p_bufs[slot][...] = p.astype(p_bufs[slot].dtype)
+            return m_new, l, alpha
+
+        def finish(j, seg):
+            write_segment(seg[:3], stat_ref[j, 0:1, :],
+                          stat_ref[j, 1:2, :], acc_ref[j])
+
+        def stream(g0, groups):
+            zero = jnp.int32(0)
+            s_bufs[0][...] = scores((jnp.int32(g0), zero, zero, zero))
+            last_p = p_bufs[(_STEPS - 1) % 2]
+            last_p[...] = jnp.zeros((ck, cq), last_p.dtype)
+            acc_ref[_STEPS - 1] = jnp.zeros((d, cq), jnp.float32)
+            init = (jnp.full((1, cq), NEG_INF, jnp.float32),
+                    jnp.zeros((1, cq), jnp.float32),
+                    jnp.ones((1, cq), jnp.float32))
+            last, _, (m, l, alpha) = _tile_stream(
+                g0, groups, hp, nr, keys_of, step, finish, init)
+            acc = acc_ref[_STEPS - 1] * alpha + weighted_values(
+                last, last_p[...])
+            write_segment(last[:3], m, l, acc)
+
+        groups.streams(stream)
+
+    return kernel
+
+
+def default_bsh_block(s, skv, h, bwd=False):
+    """THE hand-picked BSH DMA-tile chooser (the autotune cache-miss
+    fallback — tuning/search.py replaces it per shape when a measured
+    winner exists; see _resolve_bsh_blocks).
+
+    Below _STREAM_FROM the whole-tile kernels run and the tile is
+    _pick_block's, for both passes alike (their in-kernel PRNG seeds a
+    [bq, bk] block, so the backward must tile as the forward did).
+    From it on the tile is the rows of q / o / lse a forward grid cell
+    holds (with all of K / V), the rows of k / v / dk / dv a backward
+    one does (with q^T / do^T / dq^T of the whole batch row); the
+    arithmetic runs over compute tiles inside it (_compute_tile), so the
+    tile decides only how often a cell's set-up is paid and how long one
+    stream of steps runs: the largest that fits (tuning/feasible.py).
+    At s4096 / h768, 1024 against 512: 17.62 against 18.17 ms a layer
+    (my chip run, PR 27; 0.4266 against 0.4240 MFU before compute
+    tiles). The two passes may tile differently there: the dropout mask
+    is a function of absolute positions (_dropout_keep_t), lse and
+    delta ride as full [B, nh, S] arrays."""
+    import os
+
+    forced = int(os.environ.get("PADDLE_FLASH_BLOCK", "0"))
+    if forced >= MIN_BLOCK and s % forced == 0:
+        return forced
+    if not _bsh_streams(s, skv):
+        return _pick_block(s)
+    need = (_feas.flash_bsh_bwd_vmem_bytes if bwd
+            else _feas.flash_bsh_fwd_vmem_bytes)
+    for cand in ((1024,) if s >= 4096 else ()) + (512, 256):
+        if s % cand == 0 and need(s, skv, h, cand, cand) <= _BSH_VMEM_LIMIT:
+            return cand
+    return _pick_block(s)
+
+
+_pick_block_bsh = default_bsh_block  # historical name (round-5 sweeps)
+
+
+def _resolve_bsh_blocks(sq, skv, h, dtype, *, bwd=False):
+    """(bq, bk, vmem_limit_bytes) for one BSH kernel launch: the forward
+    uses bq, the backward bk.
+
+    Precedence: PADDLE_FLASH_BLOCK env override (hand sweeps) >
+    FLAGS_kernel_autotune cache entry > default_bsh_block heuristic.
+    One cache entry serves fwd AND bwd, so a cached config is validated
+    against BOTH footprint models before it is trusted; an invalid or
+    missing entry falls back to the hand-picked chooser — no behavior
+    cliff."""
+    import os
+
+    key = {"sq": sq, "skv": skv, "h": h, "dtype": str(dtype)}
+    if not int(os.environ.get("PADDLE_FLASH_BLOCK", "0")):
+        from ... import tuning
+
+        cfg = tuning.maybe_lookup("flash_bsh", key)
+        if cfg:
+            try:
+                bq = int(cfg.get("bq", 0))
+                bk = int(cfg.get("bk", 0))
+                limit = (int(cfg["vmem_limit_mb"]) * 2**20
+                         if cfg.get("vmem_limit_mb") else _BSH_VMEM_LIMIT)
+            except (TypeError, ValueError):
+                bq = bk = 0
+                limit = _BSH_VMEM_LIMIT
+            ok, _why = _feas.flash_bsh_ok(sq, skv, h, bq, bk, limit=limit)
+            if ok:
+                return bq, bk, limit
+            # bad entry (edited by hand / stale shape): hand-picked path
+            tuning.note_choice("flash_bsh", key, None, "default")
+    return (
+        default_bsh_block(sq, skv, h, bwd=bwd),
+        default_bsh_block(skv, skv, h, bwd=bwd),
+        _BSH_VMEM_LIMIT,
+    )
+
+
+def _flash_fwd_bsh_stream(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
+                   causal, dropout_prob):
+    b, sq, hdim = q.shape
+    skv = k.shape[1]
+    d = hdim // nh
+    use_prng = dropout_prob > 0.0 and mask is None
+    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim, q.dtype)
+    has_mask = mask is not None and dropout_prob > 0.0
+    has_offsets = offsets is not None
+    has_bias = bias is not None
+
+    # K and V change with the batch row only: where a row has several
+    # grid cells, one buffer each (a second would hold the next row's
+    # 2 * Skv * H bytes for the whole of this one)
+    once_a_row = (
+        {"pipeline_mode": pl.Buffered(1)} if sq // bq > 1 else {})
+    in_specs = [
+        pl.BlockSpec((1, bq, hdim), lambda b_, i: (b_, i, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, skv, hdim), lambda b_, i: (b_, 0, 0),
+                     memory_space=pltpu.VMEM, **once_a_row),
+        pl.BlockSpec((1, skv, hdim), lambda b_, i: (b_, 0, 0),
+                     memory_space=pltpu.VMEM, **once_a_row),
+    ]
+    args = [q, k, v]
+    if has_bias:
+        in_specs.append(
+            pl.BlockSpec((1, 1, skv), lambda b_, i: (b_, 0, 0),
+                         memory_space=pltpu.VMEM))
+        args.append(bias)
+    if has_mask:
+        in_specs.append(
+            pl.BlockSpec((1, nh, skv, bq), lambda b_, i: (b_, 0, 0, i),
+                         memory_space=pltpu.VMEM))
+        args.append(mask)
+    if use_prng:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(seed)
+    if has_offsets:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(offsets)
+
+    cq, ck = _compute_tile(bq, skv, d)
+    gw = _head_groups(nh, d)[0] * d
+    kernel = _make_fwd_bsh_stream_kernel(
+        sm_scale=sm_scale, causal=causal, dropout_prob=dropout_prob,
+        has_bias=has_bias, use_prng=use_prng, has_mask=has_mask,
+        has_offsets=has_offsets, nh=nh, d=d, bq=bq, cq=cq, ck=ck,
+        prescale=_prescale_ok(sm_scale),
+    )
+    o, lse = pl.pallas_call(
+        kernel,
+        grid=(b, sq // bq),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, bq, hdim), lambda b_, i: (b_, i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, nh, bq), lambda b_, i: (b_, 0, i),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, sq, hdim), q.dtype),
+            jax.ShapeDtypeStruct((b, nh, sq), jnp.float32),
+        ],
+        scratch_shapes=(
+            [pltpu.VMEM((skv, cq), jnp.float32)] if has_bias else []
+        ) + [pltpu.VMEM((hdim, skv), v.dtype),
+             pltpu.VMEM((nh * gw, bq), q.dtype),
+             pltpu.VMEM((ck, cq), jnp.float32),
+             pltpu.VMEM((ck, cq), jnp.float32),
+             pltpu.VMEM((ck, cq), v.dtype),
+             pltpu.VMEM((ck, cq), v.dtype),
+             pltpu.VMEM((_STEPS, d, cq), jnp.float32),
+             pltpu.VMEM((_STEPS, 2, cq), jnp.float32),
+             pltpu.VMEM((gw, cq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit),
+        name="flash_bsh_fwd",
+        interpret=_interpret(),
+    )(*args)
+    return o, lse
+
+
+def _make_bwd_bsh_stream_kernel(*, sm_scale, causal, dropout_prob, has_bias,
+                         use_prng, has_mask, has_offsets, nh, d, bk, cq, ck,
+                         prescale=False):
+    """Single-pass BSH backward: grid (B, NKv) with NKv innermost per
+    batch row. Computes dk / dv for this k block and accumulates dq^T
+    over the blocks of a batch row in a resident f32 scratch, written
+    out (transposed and scaled) with the last block.
+
+    The same stream of transposed [ck, cq] tiles as the forward, over
+    (group, key window of the block, head, query window): s^T = k q^T
+    and dp^T = v do^T take the block's k / v rows as they lie against
+    q^T / do^T (transposed once a batch row); dv^T = do^T p, dk^T = q^T
+    ds and dq^T = k^T ds^T come out [d, .] with 64 rows to stream, p and
+    ds^T being the weights (pushed transposed for the first two), so no
+    product transposes a [ck, cq] matrix on the XLU and the accumulators
+    fill their vregs at d = 64."""
+    groups = _LaneGroups(nh, d)
+    hp, gw, lanes_of = groups.hp, groups.gw, groups.lanes
+    keep_prob = 1.0 - dropout_prob
+    keep_div = 1.0
+    if dropout_prob > 0.0:
+        keep_div = (
+            _dropout_quantized_keep(keep_prob) if use_prng else keep_prob)
+    # ds is formed as keep_div * ds, unscaled by sm_scale: both wait for
+    # the end of a segment (dk, dv) or of the batch row (dq). A power of
+    # two sm_scale is already in q^T, so dk = ds^T q has it.
+    dk_scale = (1.0 if prescale else sm_scale) / keep_div
+    dv_scale = 1.0 / keep_div
+    dq_scale = sm_scale / keep_div
+    nt = (((1,), (1,)), ((), ()))   # a @ b^T
+
+    def kernel(*refs):
+        it = iter(refs)
+        q_hbm = next(it)          # [B, Sq, H], left in HBM
+        k_ref = next(it)          # [1, BK, H]
+        v_ref = next(it)          # [1, BK, H]
+        bias_ref = next(it) if has_bias else None   # [1, 1, Skv]
+        mask_ref = next(it) if has_mask else None   # [1, nh, BK, Sq]
+        seed_ref = next(it) if use_prng else None
+        off_ref = next(it) if has_offsets else None
+        do_hbm = next(it)         # [B, Sq, H], left in HBM
+        lse_ref = next(it)        # [1, nh, Sq]
+        delta_ref = next(it)      # [1, nh, Sq]
+        dq_hbm = next(it)         # [B, Sq, H] f32, left in HBM
+        dk_ref = next(it)         # [1, BK, H]
+        dv_ref = next(it)         # [1, BK, H]
+        bcol_ref = next(it) if has_bias else None   # [BK, CQ] f32
+        rows_ref = next(it)       # [2, CQ, H]: q and do rows on their way
+        dq_rows_ref = next(it)    # [128, H] f32: dq rows on their way
+        sems = next(it)           # 3 DMA semaphores
+        qt_ref = next(it)         # [H, Sq]: q^T (prescaled), a batch row
+        dot_ref = next(it)        # [H, Sq]: do^T, a batch row
+        dqt_ref = next(it)        # [H, Sq] f32: dq^T, a batch row
+        kt_ref = next(it)         # [H, BK]: k^T of the block
+        kp_ref = next(it) if hp > 1 else None   # [nh, BK, GW]: k, v with
+        vp_ref = next(it) if hp > 1 else None   # the other heads' lanes 0
+        s_bufs = (next(it), next(it))    # [CK, CQ] f32 each
+        dp_bufs = (next(it), next(it))   # [CK, CQ] f32 each
+        p_bufs = (next(it), next(it))    # [CK, CQ], q's dtype
+        ds_bufs = (next(it), next(it))   # [CK, CQ], q's dtype
+        dkt_ref = next(it)        # [_STEPS, D, CK] f32
+        dvt_ref = next(it)        # [_STEPS, D, CK] f32
+        okt_ref = next(it)        # [GW, CK] f32: a group's dk^T
+        ovt_ref = next(it)        # [GW, CK] f32: a group's dv^T
+
+        b = pl.program_id(0)
+        ki = pl.program_id(1)
+        sq = q_hbm.shape[1]
+        nrq = sq // cq
+        nck = bk // ck
+        q_off = off_ref[0] if has_offsets else 0
+        k_off = off_ref[1] if has_offsets else 0
+
+        # q, do and dq are wanted transposed, and only that: they stay in
+        # HBM, q / do come in a row window at a time when a batch row
+        # starts and dq leaves 128 rows at a time when it ends (a block
+        # each would hold 8 B/elem of the row for nothing)
+        @pl.when(ki == 0)
+        def _new_batch_row():
+            def window_in(r, carry):
+                copies = [
+                    pltpu.make_async_copy(
+                        hbm.at[b, _window(r, cq), :], rows_ref.at[i],
+                        sems.at[i])
+                    for i, hbm in enumerate((q_hbm, do_hbm))]
+                for copy in copies:
+                    copy.start()
+                for copy in copies:
+                    copy.wait()
+
+                def transpose(g, _):
+                    q = rows_ref[0, :, lanes_of(g)]
+                    if prescale:
+                        # exact pow2 shift; dk = ds^T q is then
+                        # chain-rule scaled already
+                        q = q * jnp.asarray(sm_scale, q.dtype)
+                    qt_ref[_window(g, gw), _window(r, cq)] = _mxu_t(q)
+                    dot_ref[_window(g, gw), _window(r, cq)] = _mxu_t(
+                        rows_ref[1, :, lanes_of(g)])
+
+                groups.each(transpose, 1)
+                return carry
+
+            jax.lax.fori_loop(0, nrq, window_in, 0)
+            dqt_ref[...] = jnp.zeros_like(dqt_ref)
+
+        def prepare_k(g, c):
+            k = k_ref[0, _window(c, ck), lanes_of(g)]      # [CK, GW]
+            kt_ref[_window(g, gw), _window(c, ck)] = _mxu_t(k)
+            if hp > 1:
+                v = v_ref[0, _window(c, ck), lanes_of(g)]
+                for hh in range(hp):
+                    kp_ref[g * hp + hh, _window(c, ck), :] = (
+                        _own_lanes(k, hh, hp, d))
+                    vp_ref[g * hp + hh, _window(c, ck), :] = (
+                        _own_lanes(v, hh, hp, d))
+
+        groups.each(prepare_k, nck)
+        if has_bias:
+            _bias_columns(bias_ref, bcol_ref, ki * bk, bk // MIN_BLOCK)
+
+        def queries_of(c):
+            """Query windows that see the block's key window c: at least
+            one (whose causal mask then leaves nothing)."""
+            if not causal:
+                return 0, nrq
+            lo = _lo_blocks(causal, ki * nck + c, cq, ck, nrq, q_off, k_off)
+            return jnp.minimum(lo, nrq - 1), nrq
+
+        def kv_rows(ref, pad_ref, idx):
+            g, c, hh, _ = idx
+            if hp > 1:
+                return pad_ref[g * hp + hh, _window(c, ck), :]
+            return ref[0, _window(c, ck), lanes_of(g)]
+
+        def t_slab(ref, idx):
+            g, _, _, r = idx
+            return ref[_window(g, gw), _window(r, cq)]        # [GW, CQ]
+
+        def head_rows(idx):
+            return _window(idx[0] * hp + idx[2], d)
+
+        def t_head(ref, idx, cols):
+            return ref[head_rows(idx), cols]                  # [D, .]
+
+        def step(j, cur, prv, nxt, flags, carry):
+            live, _, _, fresh_prv = flags
+            g, c, hh, r = cur
+            head = g * hp + hh
+            slot, other = j % 2, 1 - j % 2
+            # 1. the MXU first, in program order: the two products of
+            # the next step, the three of the last
+            p_prev = p_bufs[other][...]
+            ds_prev = ds_bufs[other][...]
+            s_bufs[other][...] = jnp.dot(
+                kv_rows(k_ref, kp_ref, nxt), t_slab(qt_ref, nxt),
+                preferred_element_type=jnp.float32)
+            dp_bufs[other][...] = jnp.dot(
+                kv_rows(v_ref, vp_ref, nxt), t_slab(dot_ref, nxt),
+                preferred_element_type=jnp.float32)
+            rows_prv = _window(prv[3], cq)
+            keys_prv = _window(prv[1], ck)
+            keep_acc = jnp.where(fresh_prv, 0.0, 1.0)
+            dvt_ref[j] = dvt_ref[(j - 1) % _STEPS] * keep_acc + (
+                jax.lax.dot_general(
+                    t_head(dot_ref, prv, rows_prv), p_prev, nt,
+                    preferred_element_type=jnp.float32))
+            dkt_ref[j] = dkt_ref[(j - 1) % _STEPS] * keep_acc + (
+                jax.lax.dot_general(
+                    t_head(qt_ref, prv, rows_prv), ds_prev, nt,
+                    preferred_element_type=jnp.float32))
+            dqt_ref[head_rows(prv), rows_prv] += jnp.dot(
+                t_head(kt_ref, prv, keys_prv), ds_prev,
+                preferred_element_type=jnp.float32)
+            # 2. the vector work of this step's tile
+            rows = _window(r, cq)
+            s = s_bufs[slot][...]
+            if not prescale:
+                s = s * sm_scale
+            if has_bias:
+                s = s + bcol_ref[_window(c, ck), :]
+            if causal:
+                s = _causal_mask_t(
+                    s, q_off + r * cq, k_off + (ki * nck + c) * ck)
+            lse = lse_ref[0, pl.ds(head, 1), rows]
+            delta = delta_ref[0, pl.ds(head, 1), rows]
+            # a step past the end of the stream leaves p = ds = 0
+            p = jnp.exp(s - jnp.where(live, lse, -NEG_INF))
+            dp = dp_bufs[slot][...]
+            if dropout_prob > 0.0:
+                if use_prng:
+                    keep = _dropout_keep_t(
+                        seed_ref[0], b * nh + head, ki * bk + c * ck,
+                        r * cq, keep_prob, ck, cq)
+                else:
+                    keep = mask_ref[0, head, _window(c, ck), rows] != 0
+                dp = jnp.where(keep, dp, 0.0)
+                delta = delta * keep_div
+                p_bufs[slot][...] = jnp.where(keep, p, 0.0).astype(
+                    p_bufs[slot].dtype)
+            else:
+                p_bufs[slot][...] = p.astype(p_bufs[slot].dtype)
+            ds_bufs[slot][...] = (p * (dp - delta)).astype(
+                ds_bufs[slot].dtype)
+            return carry
+
+        def write_segment(seg, dkt, dvt):
+            g, c, hh = seg
+            keys = _window(c, ck)
+            if hp == 1:
+                dk_ref[0, keys, lanes_of(g)] = _mxu_t(
+                    (dkt * dk_scale).astype(dk_ref.dtype))
+                dv_ref[0, keys, lanes_of(g)] = _mxu_t(
+                    (dvt * dv_scale).astype(dv_ref.dtype))
+                return
+            okt_ref[_window(hh, d), :] = dkt * dk_scale
+            ovt_ref[_window(hh, d), :] = dvt * dv_scale
+
+            @pl.when(hh == hp - 1)
+            def _store_group():
+                dk_ref[0, keys, lanes_of(g)] = _mxu_t(
+                    okt_ref[...].astype(dk_ref.dtype))
+                dv_ref[0, keys, lanes_of(g)] = _mxu_t(
+                    ovt_ref[...].astype(dv_ref.dtype))
+
+        def finish(j, seg):
+            write_segment(seg[:3], dkt_ref[j], dvt_ref[j])
+
+        def stream(g0, groups):
+            zero = jnp.int32(0)
+            first = (jnp.int32(g0), zero, zero, queries_of(zero)[0])
+            s_bufs[0][...] = jnp.dot(
+                kv_rows(k_ref, kp_ref, first), t_slab(qt_ref, first),
+                preferred_element_type=jnp.float32)
+            dp_bufs[0][...] = jnp.dot(
+                kv_rows(v_ref, vp_ref, first), t_slab(dot_ref, first),
+                preferred_element_type=jnp.float32)
+            last_slot = (_STEPS - 1) % 2
+            p_bufs[last_slot][...] = jnp.zeros(
+                (ck, cq), p_bufs[last_slot].dtype)
+            ds_bufs[last_slot][...] = jnp.zeros(
+                (ck, cq), ds_bufs[last_slot].dtype)
+            dkt_ref[_STEPS - 1] = jnp.zeros((d, ck), jnp.float32)
+            dvt_ref[_STEPS - 1] = jnp.zeros((d, ck), jnp.float32)
+            last, fresh_last, _ = _tile_stream(
+                g0, groups, hp, nck, queries_of, step, finish, 0)
+            # the last step's three products
+            rows_l = _window(last[3], cq)
+            p_l = p_bufs[last_slot][...]
+            ds_l = ds_bufs[last_slot][...]
+            keep_acc = jnp.where(fresh_last, 0.0, 1.0)
+            dvt = dvt_ref[_STEPS - 1] * keep_acc + jax.lax.dot_general(
+                t_head(dot_ref, last, rows_l), p_l, nt,
+                preferred_element_type=jnp.float32)
+            dkt = dkt_ref[_STEPS - 1] * keep_acc + jax.lax.dot_general(
+                t_head(qt_ref, last, rows_l), ds_l, nt,
+                preferred_element_type=jnp.float32)
+            dqt_ref[head_rows(last), rows_l] += jnp.dot(
+                t_head(kt_ref, last, _window(last[1], ck)), ds_l,
+                preferred_element_type=jnp.float32)
+            write_segment(last[:3], dkt, dvt)
+
+        groups.streams(stream)
+
+        @pl.when(ki == pl.num_programs(1) - 1)
+        def _write_dq():
+            n = MIN_BLOCK
+
+            def rows_out(r, carry):
+                # dq leaves as f32 and is cast to q's dtype outside:
+                # rounded here, once, it transposes exactly
+                for lo in range(0, nh * d, min(gw, n)):
+                    hi = lo + min(gw, n)
+                    dq_rows_ref[:, lo:hi] = _mxu_t(
+                        (dqt_ref[lo:hi, _window(r, n)] * dq_scale).astype(
+                            kt_ref.dtype)).astype(jnp.float32)
+                copy = pltpu.make_async_copy(
+                    dq_rows_ref, dq_hbm.at[b, _window(r, n), :], sems.at[2])
+                copy.start()
+                copy.wait()
+                return carry
+
+            jax.lax.fori_loop(0, sq // n, rows_out, 0)
+
+    return kernel
+
+
+def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob):
+    q, k, v, bias, mask, seed, offsets, o, lse = res
+    b, sq, hdim = q.shape
+    skv = k.shape[1]
+    d = hdim // nh
+    use_prng = dropout_prob > 0.0 and mask is None
+    _, bk, vmem_limit = _resolve_bsh_blocks(
+        sq, skv, hdim, q.dtype, bwd=True)
+    has_mask = mask is not None and dropout_prob > 0.0
+    has_offsets = offsets is not None
+    has_bias = bias is not None
+    cq, ck = _compute_tile(sq, bk, d)
+    hp = _head_groups(nh, d)[0]
+    gw = hp * d
+
+    # delta[b, h, s] = sum_d o*g per head, from the BSH layout
+    delta = (
+        (o.astype(jnp.float32) * g.astype(jnp.float32))
+        .reshape(b, sq, nh, d).sum(axis=-1).transpose(0, 2, 1)
+    )
+
+    # q, do and dq stay in HBM: the kernel holds them transposed and
+    # moves their rows itself. lse and delta change with the batch row
+    # only: one buffer each where a row has several grid cells
+    fullq = pl.BlockSpec(memory_space=pl.ANY)
+    kspec = pl.BlockSpec((1, bk, hdim), lambda b_, i: (b_, i, 0),
+                         memory_space=pltpu.VMEM)
+    statspec = pl.BlockSpec(
+        (1, nh, sq), lambda b_, i: (b_, 0, 0), memory_space=pltpu.VMEM,
+        **({"pipeline_mode": pl.Buffered(1)} if skv // bk > 1 else {}))
+
+    args = [q, k, v]
+    in_specs = [fullq, kspec, kspec]
+    if has_bias:
+        in_specs.append(
+            pl.BlockSpec((1, 1, skv), lambda b_, i: (b_, 0, 0),
+                         memory_space=pltpu.VMEM))
+        args.append(bias)
+    if has_mask:
+        in_specs.append(
+            pl.BlockSpec((1, nh, bk, sq), lambda b_, i: (b_, 0, i, 0),
+                         memory_space=pltpu.VMEM))
+        args.append(mask)
+    if use_prng:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(seed)
+    if has_offsets:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(offsets)
+    in_specs += [fullq, statspec, statspec]
+    args += [g, lse, delta]
+
+    f32 = jnp.float32
+    tile = [pltpu.VMEM((ck, cq), f32)] * 4 + [pltpu.VMEM((ck, cq), q.dtype)] * 4
+    dq, dk, dv = pl.pallas_call(
+        _make_bwd_bsh_stream_kernel(
+            sm_scale=sm_scale, causal=causal, dropout_prob=dropout_prob,
+            has_bias=has_bias, use_prng=use_prng, has_mask=has_mask,
+            has_offsets=has_offsets, nh=nh, d=d, bk=bk, cq=cq, ck=ck,
+            prescale=_prescale_ok(sm_scale),
+        ),
+        grid=(b, skv // bk),
+        in_specs=in_specs,
+        out_specs=[fullq, kspec, kspec],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, sq, hdim), jnp.float32),
+            jax.ShapeDtypeStruct((b, skv, hdim), k.dtype),
+            jax.ShapeDtypeStruct((b, skv, hdim), v.dtype),
+        ],
+        scratch_shapes=(
+            [pltpu.VMEM((bk, cq), f32)] if has_bias else []
+        ) + [pltpu.VMEM((2, cq, hdim), q.dtype),
+             pltpu.VMEM((MIN_BLOCK, hdim), f32),
+             pltpu.SemaphoreType.DMA((3,)),
+             pltpu.VMEM((hdim, sq), q.dtype),
+             pltpu.VMEM((hdim, sq), q.dtype),
+             pltpu.VMEM((hdim, sq), f32),
+             pltpu.VMEM((hdim, bk), k.dtype)] + (
+            [pltpu.VMEM((nh, bk, gw), k.dtype)] * 2 if hp > 1 else []
+        ) + tile + [
+            pltpu.VMEM((_STEPS, d, ck), f32),
+            pltpu.VMEM((_STEPS, d, ck), f32),
+            pltpu.VMEM((gw, ck), f32),
+            pltpu.VMEM((gw, ck), f32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit),
+        name="flash_bsh_bwd",
+        interpret=_interpret(),
+    )(*args)
+    return dq.astype(q.dtype), dk, dv
+
+
+# the BSH kernels keep whole sequences resident (k / v and V^T in the
+# fwd; q^T / do^T / dq^T in the bwd): 34 MB and 56 MB at s=4096/H=768,
+# 69 MB at s=8192 (Mosaic's scoped-vmem allocation, bisected on the
+# limit). v5e has 128MB of VMEM; the default ~16MB scoped limit is far
 # below what the hardware allows, so raise it for these calls. Past the
 # estimate below, dispatch falls back to the BHSD kernels (streamed
 # blocks, head-transposed layout) — and beyond single-chip HBM, shard
@@ -1734,11 +2703,12 @@ _BSH_VMEM_LIMIT = _feas.BSH_VMEM_LIMIT
 
 
 def bsh_shapes_ok(sq, skv, h) -> bool:
-    """Will the BSH kernels' whole-sequence VMEM residency fit? The 13
-    B/elem slope + fixed blocks/temps term is calibrated against
-    Mosaic's scoped-vmem report (s8192/h768 allocates 102M)."""
-    est = 13 * max(sq, skv) * h + 24 * 1024 * 1024
-    return est <= _BSH_VMEM_LIMIT
+    """Will the BSH kernels' whole-sequence VMEM residency fit, at the
+    smallest tiles the chooser falls back to? tuning/feasible.py's
+    models of both passes, calibrated against Mosaic's allocation."""
+    fwd = _feas.flash_bsh_fwd_vmem_bytes(sq, skv, h, MIN_BLOCK, MIN_BLOCK)
+    bwd = _feas.flash_bsh_bwd_vmem_bytes(sq, skv, h, MIN_BLOCK, MIN_BLOCK)
+    return max(fwd, bwd) <= _BSH_VMEM_LIMIT
 
 
 def bsh_dispatch_ok(sq, skv, h, num_heads, bias=None, batch=None,
@@ -1779,6 +2749,32 @@ def _bsh_mask_materialize(sq, skv, h, dtype) -> bool:
     cfg = tuning.maybe_lookup(
         "flash_bsh", {"sq": sq, "skv": skv, "h": h, "dtype": str(dtype)})
     return bool(cfg) and cfg.get("mask") == "materialize"
+
+
+# the S from which the stream kernels run (my chip run, PR 27, BERT-base
+# heads, ms a call fwd+bwd, whole-tile -> stream: S 256 2.73 -> 3.85,
+# S 512 3.24 -> 4.35, S 1024 6.77 -> 6.40, S 2048 12.05 -> 10.26,
+# S 4096 22.37 -> 17.62): a stream pays a grid cell's set-up (K^T / V^T
+# / q^T, bias columns) and a pipeline's fill, which S = 512 cannot
+# amortize over the 16 tiles a head has there
+_STREAM_FROM = _feas.FLASH_BSH_STREAM_FROM
+
+
+def _bsh_streams(sq, skv) -> bool:
+    return max(sq, skv) >= _STREAM_FROM
+
+
+def _flash_fwd_bsh(q, k, v, *rest, **statics):
+    fwd = (_flash_fwd_bsh_stream if _bsh_streams(q.shape[1], k.shape[1])
+           else _flash_fwd_bsh_tile)
+    return fwd(q, k, v, *rest, **statics)
+
+
+def _flash_bwd_bsh(res, g, **statics):
+    bwd = (_flash_bwd_bsh_stream
+           if _bsh_streams(res[0].shape[1], res[1].shape[1])
+           else _flash_bwd_bsh_tile)
+    return bwd(res, g, **statics)
 
 
 @functools.lru_cache(maxsize=256)
@@ -1845,10 +2841,11 @@ def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
                 dtype=jnp.int32)
         else:
             raise ValueError("dropout needs dropout_key or dropout_seed")
-        # mask source is a tuned axis: interpret mode (no hardware PRNG)
-        # and a cache entry saying {'mask': 'materialize'} both
-        # precompute the keep mask outside the kernel; the default
-        # regenerates it from the in-kernel PRNG with zero HBM traffic
+        # mask source is a tuned axis: interpret mode (the CPU tests'
+        # oracle draws it with jax.random) and a cache entry saying
+        # {'mask': 'materialize'} both precompute the keep mask outside
+        # the kernel; the default regenerates it in the kernel with
+        # zero HBM traffic
         if _interpret() or _bsh_mask_materialize(sq, k.shape[1], hdim,
                                                  q.dtype):
             mkey = dropout_key if dropout_key is not None else (
@@ -1857,6 +2854,9 @@ def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
                 jax.random.fold_in(mkey, 7), 1.0 - dropout_prob,
                 (b, num_heads, sq, k.shape[1]),
             ).astype(jnp.uint8)
+            if _bsh_streams(sq, k.shape[1]):
+                # the stream kernels hold score tiles keys-major
+                mask = mask.swapaxes(2, 3)
 
     def local(ql, kl, vl, bl, ml, sl, nh_local):
         core = _make_flash_core_bsh(

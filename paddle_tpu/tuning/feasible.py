@@ -67,30 +67,64 @@ class NoFeasibleConfig(ValueError):
 # ---------------------------------------------------------------------------
 
 
+# ops/pallas/flash_attention.py runs its stream kernels from this S on
+# and the whole-tile ones below it
+FLASH_BSH_STREAM_FROM = 1024
+
+
+def _flash_bsh_compute_tile(n: int) -> int:
+    """The stream kernels' compute-tile extent along n rows a grid cell
+    holds (ops/pallas/flash_attention.py:_compute_tile)."""
+    return next(c for c in (512, 256, 128) if n % c == 0)
+
+
 def flash_bsh_fwd_vmem_bytes(sq: int, skv: int, h: int, bq: int,
                              bk: int) -> int:
-    """Forward kernel footprint: k/v whole-sequence resident
-    (double-buffered, <=2B elems -> 8 B/elem), q/o blocks, plus the
-    per-tile f32 score temporaries (~40 B per bq*bk tile element — the
-    calibration that reproduces the '~40MB of 1024-tile temporaries'
-    v5e measurement in ops/pallas/flash_attention.py)."""
-    return 8 * skv * h + 8 * bq * h + 40 * bq * bk
+    """Forward kernel footprint (bf16 operands). Whole-tile kernel
+    (S < 1024): k/v whole-sequence resident, double-buffered, q/o
+    blocks, and ~40 B per bq*bk tile element of f32 score temporaries.
+    Stream kernel: k / v resident in ONE buffer each where a batch row
+    has several cells (4 B/elem), V^T beside them (2 B/elem), the
+    per-key bias as f32 columns a compute tile wide, the q / o blocks
+    double-buffered, a zero-padded q^T per head (two heads share a
+    128-lane slab at d = 64, so 4 B per block element), and two slots
+    each of f32 scores and bf16 probabilities a compute tile in size.
+    Mosaic allocated 34.1 MiB at (s4096, h768, bq1024) where this counts
+    39.0 (described-v5e compiles bisected on the limit, PR 27)."""
+    if max(sq, skv) < FLASH_BSH_STREAM_FROM:
+        return 8 * skv * h + 8 * bq * h + 40 * bq * bk
+    cq, ck = _flash_bsh_compute_tile(bq), _flash_bsh_compute_tile(skv)
+    return (6 * skv * h + 4 * skv * cq + 12 * bq * h + 12 * ck * cq
+            + 2 ** 20)
 
 
 def flash_bsh_bwd_vmem_bytes(sq: int, skv: int, h: int, bq: int,
                              bk: int) -> int:
-    """Backward kernel footprint: q/do double-buffered bf16 + the dq
-    f32 revisited accumulator (~12 B/elem of the full sq*h residency
-    — reproduces the measured 124MB at (s8192, h768, bq1024) vs the
-    112MB limit), k/v/dk/dv blocks, score temporaries."""
-    return 12 * sq * h + 8 * bk * h + 40 * bq * bk
+    """Backward kernel footprint. Whole-tile kernel (S < 1024): q/do
+    double-buffered bf16 + the dq f32 revisited accumulator (~12 B/elem
+    of the full sq*h residency), k/v/dk/dv blocks, score temporaries.
+    Stream kernel: a batch row's q^T / do^T (bf16) and the f32 dq^T
+    accumulator, 8 B/elem of sq*h (q, do and dq themselves stay in HBM
+    and pass through two row windows and 128 rows of staging); the
+    k / v / dk / dv blocks, k^T and the per-head zero-padded k / v of a
+    block (~22 B/elem of bk*h); two slots each of f32 s^T, dp^T and bf16
+    p, ds^T a compute tile in size, and the bias columns. Mosaic
+    allocated 55.8 MiB at (s4096, bk1024, h768) where this counts 56.4,
+    and at most 69.4 MiB for either pass at s8192 (80.4 here)
+    (described-v5e compiles bisected on the limit, PR 27; the
+    whole-tile kernel measured 124 MB at (s8192, bq1024))."""
+    if max(sq, skv) < FLASH_BSH_STREAM_FROM:
+        return 12 * sq * h + 8 * bk * h + 40 * bq * bk
+    cq, ck = _flash_bsh_compute_tile(sq), _flash_bsh_compute_tile(bk)
+    return (8 * sq * h + 22 * bk * h + 24 * ck * cq + 4 * bk * cq
+            + 4 * cq * h + 512 * h + 6 * 2 ** 20)
 
 
 def flash_bsh_ok(sq: int, skv: int, h: int, bq: int, bk: int,
                  *, limit: int = BSH_VMEM_LIMIT) -> Tuple[bool, str]:
-    """(feasible, reason). A config serves BOTH passes (PRNG dropout
-    must regenerate identical per-block masks in fwd and bwd), so both
-    footprints must fit."""
+    """(feasible, reason). A config serves BOTH passes (bq is the
+    forward's DMA tile, bk the backward's), so both footprints must
+    fit."""
     if bq < 128 or bk < 128:
         return False, "block below the 128 tiling minimum"
     if sq % bq or skv % bk:

@@ -185,10 +185,15 @@ def test_flash_candidates_feasibility():
         feas, _ = feasible.flash_bsh_ok(4096, 4096, 768,
                                         cfg["bq"], cfg["bk"])
         assert feas
-    # bwd residency kills every tile at s8192/h768 sq-side... but the
-    # model must reproduce the measured 124MB > 112MB rejection
+    # the bwd's residency is q^T / do^T / dq^T, 8 B/elem of sq*h: the
+    # model must admit what Mosaic was seen to allocate (69.4 MiB at
+    # s8192/h768) and reject a batch row that cannot fit
     assert feasible.flash_bsh_bwd_vmem_bytes(
-        8192, 8192, 768, 1024, 1024) > feasible.BSH_VMEM_LIMIT
+        8192, 8192, 768, 1024, 1024) <= feasible.BSH_VMEM_LIMIT
+    assert feasible.flash_bsh_bwd_vmem_bytes(
+        8192, 8192, 768, 1024, 1024) >= 69.4 * 2**20
+    assert feasible.flash_bsh_bwd_vmem_bytes(
+        32768, 32768, 768, 128, 128) > feasible.BSH_VMEM_LIMIT
     # dropout doubles the space with the mask axis
     okd, _ = configs.flash_bsh_candidates(512, 512, 768, "bfloat16",
                                           dropout=True)

@@ -182,25 +182,184 @@ def test_bsh_matches_bhsd_kernel():
                                rtol=1e-6, atol=1e-6)
 
 
-def test_bsh_block_picker_syncs_fwd_bwd_under_prng_dropout():
-    """In-kernel PRNG dropout seeds per (bh, q-block, k-block): the keep
-    mask depends on the tile partition, so whenever the fwd uses PRNG
-    dropout its tiles must equal the bwd's (round-5 review finding —
-    desynced tiles at s8192 silently corrupted gradients)."""
-    from paddle_tpu.ops.pallas.flash_attention import _pick_block_bsh
+def _grads(loss, *args):
+    return jax.grad(loss, argnums=(0, 1, 2))(*args)
 
-    h = 768
-    for s in (4096, 8192, 5120):
-        fwd_synced = _pick_block_bsh(s, s, h, sync_bwd=True)
-        bwd = _pick_block_bsh(s, s, h, bwd=True)
-        assert fwd_synced == bwd, (s, fwd_synced, bwd)
-    # without dropout the fwd may take bigger tiles than the bwd
-    assert _pick_block_bsh(8192, 8192, h) == 1024
-    assert _pick_block_bsh(8192, 8192, h, bwd=True) == 512
-    # rectangular: the k/v residency gate uses skv, not sq
-    assert _pick_block_bsh(4096, 16384, h) == _pick_block_bsh(4096, 16384, h)
-    big_kv = _pick_block_bsh(4096, 65536, h)
-    assert big_kv == 512  # 8*skv*h alone exceeds the VMEM limit
+
+# The stream kernels (the BSH kernels from S = 1024 on), steered down to
+# small S here. (sq, skv, causal, bias, DMA tile, compute tile): S 512
+# under DMA tiles of 256 and 512 with 128 x 128 compute tiles has two and
+# four of them in both directions of a DMA tile, and more key windows
+# than one trip of the kernels' stream holds; the unpatched tiles (512)
+# run the same shapes as one step a head
+TILED = [
+    (512, 512, False, False, 256, 128),
+    (512, 512, False, True, 512, 128),
+    (512, 512, True, False, 256, 128),
+    (512, 512, True, True, 512, 128),
+    (256, 128, False, True, 128, 128),
+    (128, 384, False, True, 128, 128),
+    (512, 512, False, True, 512, 512),
+    (512, 512, True, False, 256, 512),
+    (384, 384, True, True, 128, 512),
+]
+
+
+@pytest.mark.parametrize("sq,skv,causal,with_bias,block,tile", TILED)
+def test_bsh_compute_tiles_forward_and_grads(monkeypatch, sq, skv, causal,
+                                             with_bias, block, tile):
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setenv("PADDLE_FLASH_BLOCK", str(block))
+    monkeypatch.setattr(fa, "_STREAM_FROM", 128)
+    monkeypatch.setattr(fa, "_CQ", tile)
+    monkeypatch.setattr(fa, "_CK", tile)
+    q, k, v = _mk(sq, skv, seed=9)
+    rng = np.random.RandomState(10)
+    bias = None
+    if with_bias:
+        bias = jnp.asarray((rng.rand(B, 1, 1, skv) <= 0.2) * -1e4,
+                           dtype=jnp.float32)
+    w = jnp.asarray(rng.randn(B, sq, H).astype(np.float32))
+
+    def loss_bsh(q_, k_, v_):
+        return jnp.sum(w * fa.flash_attention_bsh(
+            q_, k_, v_, bias=bias, num_heads=NH, causal=causal))
+
+    def loss_ref(q_, k_, v_):
+        return jnp.sum(w * _oracle(q_, k_, v_, bias=bias, causal=causal))
+
+    np.testing.assert_allclose(float(loss_bsh(q, k, v)),
+                               float(loss_ref(q, k, v)), rtol=1e-5)
+    for a, b_ in zip(_grads(loss_bsh, q, k, v), _grads(loss_ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_bsh_streams_from_s1024():
+    """S = 1024 takes the stream kernels by itself: two compute tiles in
+    both directions of its DMA tile, per-key bias, gradients."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    s = 1024
+    assert fa._bsh_streams(s, s) and not fa._bsh_streams(512, 512)
+    q, k, v = _mk(s, s, seed=15)
+    rng = np.random.RandomState(16)
+    bias = jnp.asarray((rng.rand(B, 1, 1, s) <= 0.2) * -1e4,
+                       dtype=jnp.float32)
+    w = jnp.asarray(rng.randn(B, s, H).astype(np.float32))
+
+    def loss_bsh(q_, k_, v_):
+        return jnp.sum(w * fa.flash_attention_bsh(
+            q_, k_, v_, bias=bias, num_heads=NH))
+
+    def loss_ref(q_, k_, v_):
+        return jnp.sum(w * _oracle(q_, k_, v_, bias=bias))
+
+    for a, b_ in zip(_grads(loss_bsh, q, k, v), _grads(loss_ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def _position_mask(fa, seed, sq, skv, prob):
+    """The in-kernel dropout mask of the whole [B, NH, sq, skv] matrix,
+    from the kernels' own draw a 128-key group at a time."""
+    rows = []
+    for bh in range(B * NH):
+        rows.append(jnp.concatenate([
+            fa._dropout_keep_t(seed[0], jnp.int32(bh), jnp.int32(k0),
+                               jnp.int32(0), 1.0 - prob, 128, sq)
+            for k0 in range(0, skv, 128)], axis=0).T)
+    return jnp.stack(rows).reshape(B, NH, sq, skv)
+
+
+@pytest.mark.parametrize("fwd_block,bwd_block,tile", [
+    (256, 128, 128), (128, 512, 128), (512, 256, 512)])
+def test_bsh_dropout_mask_is_the_same_under_any_tiles(
+        monkeypatch, fwd_block, bwd_block, tile):
+    """The forward under one DMA tile and the backward under another
+    (and both whatever their compute tiles are) draw the same mask: it
+    is a function of the seed and of (head-row, key, query) alone. The
+    kernels run their in-kernel draw here, in interpret mode."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    sq = skv = 512
+    prob = 0.1
+    monkeypatch.setattr(fa, "_STREAM_FROM", 128)
+    monkeypatch.setattr(fa, "_CQ", tile)
+    monkeypatch.setattr(fa, "_CK", tile)
+    blocks = fa._resolve_bsh_blocks
+
+    def resolve(sq_, skv_, h, dtype, *, bwd=False):
+        block = bwd_block if bwd else fwd_block
+        return block, block, blocks(sq_, skv_, h, dtype, bwd=bwd)[2]
+
+    monkeypatch.setattr(fa, "_resolve_bsh_blocks", resolve)
+    q, k, v = _mk(sq, skv, seed=12)
+    rng = np.random.RandomState(13)
+    bias = jnp.asarray((rng.rand(B, 1, skv) <= 0.2) * -1e4,
+                       dtype=jnp.float32)
+    w = jnp.asarray(rng.randn(B, sq, H).astype(np.float32))
+    seed = jnp.asarray([1234567], jnp.int32)
+    core = fa._make_flash_core_bsh.__wrapped__(
+        sm_scale=1.0 / math.sqrt(D), nh=NH, causal=False,
+        dropout_prob=prob)
+
+    keep = fa._dropout_quantized_keep(1.0 - prob)
+    mask = _position_mask(fa, seed, sq, skv, prob)
+    # the quantized keep probability, 230 / 256, within 4 sigma
+    sigma = math.sqrt(keep * (1 - keep) / mask.size)
+    assert abs(float(mask.mean()) - keep) < 4 * sigma
+
+    def loss_bsh(q_, k_, v_):
+        return jnp.sum(w * core(q_, k_, v_, bias, None, seed, None))
+
+    def loss_ref(q_, k_, v_):
+        return jnp.sum(w * _oracle(q_, k_, v_, bias=bias, mask=mask,
+                                   keep=keep))
+
+    np.testing.assert_allclose(
+        np.asarray(core(q, k, v, bias, None, seed, None)),
+        np.asarray(_oracle(q, k, v, bias=bias, mask=mask, keep=keep)),
+        rtol=2e-5, atol=2e-5)
+    for a, b_ in zip(_grads(loss_bsh, q, k, v), _grads(loss_ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_bsh_odd_head_count_runs_static_lane_groups(monkeypatch):
+    """Three heads of 64 do not pair up into 128-lane groups: every head
+    is then its own group of the stream kernels, at a static lane
+    offset."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bsh
+
+    monkeypatch.setattr(fa, "_STREAM_FROM", 128)
+    nh, s = 3, 256
+    rng = np.random.RandomState(14)
+    q, k, v = (jnp.asarray(rng.randn(B, s, nh * D).astype(np.float32) * 0.3)
+               for _ in range(3))
+
+    def oracle(q_, k_, v_):
+        def heads(t):
+            return t.reshape(B, s, nh, D).transpose(0, 2, 1, 3)
+
+        sc = jnp.einsum("bnqd,bnkd->bnqk", heads(q_), heads(k_)) / math.sqrt(D)
+        sc = jnp.where(jnp.arange(s)[:, None] >= jnp.arange(s)[None, :],
+                       sc, -1e30)
+        o = jnp.einsum("bnqk,bnkd->bnqd", jax.nn.softmax(sc, -1), heads(v_))
+        return o.transpose(0, 2, 1, 3).reshape(B, s, nh * D)
+
+    def loss_bsh(q_, k_, v_):
+        return jnp.sum(jnp.square(flash_attention_bsh(
+            q_, k_, v_, num_heads=nh, causal=True)))
+
+    def loss_ref(q_, k_, v_):
+        return jnp.sum(jnp.square(oracle(q_, k_, v_)))
+
+    for a, b_ in zip(_grads(loss_bsh, q, k, v), _grads(loss_ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4)
 
 
 def test_bsh_s8192_dropout_grads_match_interpret_oracle():
