@@ -41,6 +41,14 @@ white_list = {
     # fused conv+BN(+relu): conv on the MXU in bf16, statistics and the
     # normalize chain in f32 inside the kernel (ops/pallas/conv_bn.py)
     "fused_conv_bn",
+    # decoder blocks (ops/decoder_ops.py, ops/moe_ops.py): products in
+    # bf16; norm statistics, conv taps, gates, router scores and the
+    # SwiGLU activation in f32 inside the emitters. `rope` is gray: it
+    # rotates in f32 and returns its input's dtype
+    "rms_norm",
+    "short_conv",
+    "swiglu_ffn",
+    "moe_swiglu",
 }
 
 black_list = {

@@ -62,6 +62,11 @@ _KEEP_F32_SLOTS = {
     "batch_norm": {"Mean", "Variance", "Scale", "Bias"},
     "fused_conv_bn": {"Mean", "Variance", "Scale", "Bias"},
     "layer_norm": {"Scale", "Bias"},
+    "rms_norm": {"Scale"},
+    "short_conv": {"Filter"},
+    # the router scores in f32 over its own f32 weights; the selection
+    # bias is a buffer that is compared, never multiplied
+    "moe_swiglu": {"GateW", "ExpertBias"},
 }
 
 

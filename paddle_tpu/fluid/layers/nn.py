@@ -1195,6 +1195,192 @@ def moe_ffn(
     return out, aux
 
 
+def _named_param(helper, param_attr, name, suffix, shape, dtype,
+                 default_initializer=None, trainable=True):
+    """A parameter `<name>.<suffix>` that takes the caller's initializer
+    (one `param_attr` serves all the matrices of a block)."""
+    attr = ParamAttr._to_attr(param_attr)
+    attr = ParamAttr(name=f"{name}.{suffix}",
+                     initializer=attr.initializer if attr else None,
+                     trainable=trainable)
+    return helper.create_parameter(
+        attr, shape=shape, dtype=dtype,
+        default_initializer=default_initializer)
+
+
+def rms_norm(input, epsilon=1e-5, group_size=None, param_attr=None, name=None):
+    """Root-mean-square norm with a learned weight and no bias
+    (ops/decoder_ops.py):
+
+        y = x / sqrt(mean(x^2) + epsilon) * w
+
+    over the last axis, or with `group_size` over every consecutive group
+    of that many columns by itself under one shared `w` of that length:
+    the per-head norm of queries and keys on a head-interleaved
+    [B, S, heads * head_dim] tensor (`group_size=head_dim`). Statistics
+    in float32 whatever the input dtype."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    width = int(group_size or input.shape[-1])
+    scale = helper.create_parameter(
+        helper.param_attr, shape=[width], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="rms_norm", inputs={"X": [input], "Scale": [scale]},
+        outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rope(input, head_dim, theta=10000.0, name=None):
+    """Rotary position embedding on a head-interleaved [B, S, heads *
+    head_dim] tensor (ops/decoder_ops.py), rotate-half pairing: inside
+    every head, columns i and i + head_dim/2 are one pair,
+
+        y_i        = x_i cos(t f_i) - x_{i+d/2} sin(t f_i)
+        y_{i+d/2}  = x_{i+d/2} cos(t f_i) + x_i sin(t f_i),  f_i = theta^(-2i/d)
+
+    with t the index on axis 1. No parameter; rotation in float32."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="rope", inputs={"X": [input]}, outputs={"Out": [out]},
+        attrs={"head_dim": int(head_dim), "theta": float(theta)})
+    return out
+
+
+def short_conv(input, kernel_size=3, param_attr=None, name=None):
+    """Gated short convolution, the operator of the `conv` layers of the
+    LFM2 family (ops/decoder_ops.py). For x [B, S, H]:
+
+        [Bg, Cg, u] = split3(x W_in)                    W_in  [H, 3H]
+        c_t = sum_j w_j * (Bg * u)_{t-(L-1)+j}          w [L, H], depthwise,
+                                                        causal, zeros before t = 0
+        y   = (Cg * c) W_out                            W_out [H, H]
+
+    No bias and no activation anywhere. Parameters `<name>.in_proj`,
+    `<name>.conv` and `<name>.out_proj`."""
+    helper = LayerHelper("short_conv", param_attr=param_attr, name=name)
+    name = name or helper.name
+    h = input.shape[-1]
+    w_in = _named_param(helper, param_attr, name, "in_proj", [h, 3 * h],
+                        "float32")
+    taps = _named_param(helper, param_attr, name, "conv",
+                        [int(kernel_size), h], "float32")
+    w_out = _named_param(helper, param_attr, name, "out_proj", [h, h],
+                         "float32")
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="short_conv",
+        inputs={"X": [input], "InW": [w_in], "Filter": [taps],
+                "OutW": [w_out]},
+        outputs={"Out": [out]})
+    return out
+
+
+def swiglu_ffn(input, size, remat=False, param_attr=None, name=None):
+    """Dense SwiGLU feed-forward (ops/decoder_ops.py):
+
+        y = W2 (silu(W1 x) * W3 x)        W1, W3 [H, size], W2 [size, H]
+
+    without biases. `remat=True` keeps only x for the backward pass and
+    computes the [.., size] intermediates again there. Parameters
+    `<name>.w1`, `<name>.w3`, `<name>.w2`."""
+    helper = LayerHelper("swiglu_ffn", param_attr=param_attr, name=name)
+    name = name or helper.name
+    h = input.shape[-1]
+    w1 = _named_param(helper, param_attr, name, "w1", [h, size], "float32")
+    w3 = _named_param(helper, param_attr, name, "w3", [h, size], "float32")
+    w2 = _named_param(helper, param_attr, name, "w2", [size, h], "float32")
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="swiglu_ffn",
+        inputs={"X": [input], "W1": [w1], "W3": [w3], "W2": [w2]},
+        outputs={"Out": [out]}, attrs={"remat": bool(remat)})
+    return out
+
+
+def moe_swiglu(
+    input,
+    num_experts,
+    expert_hidden,
+    experts_held=None,
+    first_expert=0,
+    top_k=4,
+    norm_topk_prob=True,
+    routed_scaling_factor=1.0,
+    remat=False,
+    bias_update_rate=0.0,
+    param_attr=None,
+    bias_attr=None,
+    name=None,
+):
+    """Dropless, bias-routed mixture of SwiGLU experts that is told which
+    experts it holds (ops/moe_ops.py). For a token z, over a router of
+    `num_experts` outputs:
+
+        s = sigmoid(W_g z)                         float32
+        I = top-k of (s + b)                       b: a buffer, not trained
+        g_i = s_i / (sum_{j in I} s_j + 1e-6) * routed_scaling_factor
+                                                   (without norm_topk_prob: g_i = s_i)
+        y = sum_{i in I, i held here} g_i W2_i (silu(W1_i z) * W3_i z)
+
+    This layer holds experts `first_expert .. first_expert + experts_held
+    - 1` (all of them by default): its weights are [experts_held, ...], the
+    router keeps its published width, and the result is the held experts'
+    part of the layer's output; the shares of all holders add up to the
+    whole layer. No capacity and no dropped token at any imbalance: the
+    (token, pick) pairs on held experts are sorted by expert and each
+    projection is one grouped product over the rows present.
+
+    `bias_update_rate` u > 0 makes the layer keep its experts balanced as a
+    trainer of such models does, without an auxiliary loss: after each
+    step's picks, b_e += u * sign(mean load - load_e) for every expert of
+    the router, written back into the buffer for the next step (0: the
+    buffer is left alone, as at inference).
+
+    input: [B, S, H]. Returns (out [B, S, H], tokens_per_expert
+    [experts_held] int32: the rows each held expert received this step,
+    fetchable like any variable). Parameters `<name>.gate`,
+    `<name>.expert_bias` (persistable, no gradient), `<name>.w1`,
+    `<name>.w3`, `<name>.w2`."""
+    helper = LayerHelper("moe_swiglu", param_attr=param_attr, name=name)
+    name = name or helper.name
+    h = input.shape[-1]
+    held = int(num_experts if experts_held is None else experts_held)
+    f = int(expert_hidden)
+    gate_w = _named_param(helper, param_attr, name, "gate",
+                          [h, int(num_experts)], "float32")
+    bias = _named_param(helper, bias_attr, name, "expert_bias",
+                        [int(num_experts)], "float32",
+                        default_initializer=ConstantInitializer(0.0),
+                        trainable=False)
+    bias.stop_gradient = True
+    w1 = _named_param(helper, param_attr, name, "w1", [held, h, f], "float32")
+    w3 = _named_param(helper, param_attr, name, "w3", [held, h, f], "float32")
+    w2 = _named_param(helper, param_attr, name, "w2", [held, f, h], "float32")
+    out = helper.create_variable_for_type_inference(input.dtype)
+    counts = helper.create_variable_for_type_inference("int32",
+                                                       stop_gradient=True)
+    helper.append_op(
+        type="moe_swiglu",
+        inputs={"X": [input], "GateW": [gate_w], "ExpertBias": [bias],
+                "W1": [w1], "W3": [w3], "W2": [w2]},
+        # the buffer is read by the selection and written by the balancing
+        # rule: one persistable variable, updated in place every step
+        outputs={"Out": [out], "TokensPerExpert": [counts],
+                 "ExpertBiasOut": [bias]},
+        attrs={
+            "top_k": int(top_k),
+            "bias_update_rate": float(bias_update_rate),
+            "norm_topk_prob": bool(norm_topk_prob),
+            "routed_scaling_factor": float(routed_scaling_factor),
+            "first_expert": int(first_expert),
+            "remat": bool(remat),
+        },
+    )
+    return out, counts
+
+
 def unique_name_layer():  # pragma: no cover - placeholder parity stub
     raise NotImplementedError
 

@@ -10,6 +10,7 @@ from . import (  # noqa: F401
     compare_ops,
     control_flow_ops,
     creation,
+    decoder_ops,
     detection2_ops,
     detection3_ops,
     detection_ops,

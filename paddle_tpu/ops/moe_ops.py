@@ -1,10 +1,33 @@
-"""Mixture-of-Experts FFN op with expert parallelism.
+"""Mixture-of-Experts feed-forward ops: `moe_ffn` and `moe_swiglu`.
 
-New TPU-era capability (the 2020 reference predates MoE): a fused
-`moe_ffn` op — top-k router + capacity-bounded dispatch + per-expert FFN —
-expressed entirely as dense einsums over a one-hot dispatch tensor
-(Switch-Transformer / GShard formulation). That formulation is the
-TPU-idiomatic one: every FLOP-carrying contraction is a large static-shape
+Two ops, two formulations, and which model takes which:
+
+- `moe_ffn` is the capacity-bounded GShard / Switch layer: softmax router,
+  every expert a static `capacity` of slots, dispatch and combine as dense
+  `[T, E, C]` one-hot einsums, two-matrix experts with biases, tokens over
+  capacity get zero, and a load-balancing auxiliary loss. It holds all E
+  experts; sharding the expert axis over an "ep" mesh axis
+  (`fleet.apply_expert_parallel`) lets GSPMD insert the all-to-all pair.
+  `BertConfig.moe_num_experts` builds it; nothing about it changed when
+  `moe_swiglu` arrived.
+- `moe_swiglu` is the dropless layer of today's open sparse decoders:
+  sigmoid scores, a selection bias that picks the top-k but does not weigh
+  them, renormalised gates, three-matrix SwiGLU experts without biases. It
+  is **told which experts it holds** (`first_expert`, and `E_held` from the
+  weights' leading axis) while its router keeps the published width: it
+  sorts the (token, pick) pairs that fall on its own experts by expert,
+  runs one grouped product a projection over those groups
+  (`jax.lax.ragged_dot`: on the TPU XLA lowers it to its own grouped-matmul
+  kernel whose grid follows the rows present) and returns the held
+  experts' part of the layer's result. Nothing is dropped at any
+  imbalance, there is no `[T, E, C]` tensor and no capacity; on one chip
+  there is no exchange and nothing stands in for the absent chips.
+  `models/lfm2_moe.py` builds it.
+
+`moe_ffn`, in detail: a fused top-k router + capacity-bounded dispatch +
+per-expert FFN, expressed entirely as dense einsums over a one-hot dispatch
+tensor. That formulation is the TPU-idiomatic one for a capacity-bounded
+layer: every FLOP-carrying contraction is a large static-shape
 einsum the MXU can tile, and when the expert dimension of W1/W2 is sharded
 over an "ep" mesh axis (fleet.apply_expert_parallel) while tokens are
 sharded over "dp", XLA's SPMD partitioner inserts the all-to-all pair
@@ -14,9 +37,10 @@ from GSPMD rather than a transpiler pass.
 
 Exposed through the same surfaces as every other capability:
   fluid.layers.moe_ffn(...)            (layer DSL)
+  fluid.layers.moe_swiglu(...)
   DistributedStrategy.expert_parallel  (fleet strategy -> "ep" axis)
 
-Semantics:
+`moe_ffn` semantics:
   X      [B, S, H]   tokens
   GateW  [H, E]      router weights
   W1     [E, H, F]   expert up-projection
@@ -130,3 +154,149 @@ def moe_ffn(ctx, ins, attrs):
     aux = e * jnp.sum(frac * mean_prob)
 
     return {"Out": [out2.reshape(b, s, h)], "AuxLoss": [aux.astype(jnp.float32)]}
+
+
+# ---------------------------------------------------------------------------
+# moe_swiglu: dropless, bias-routed, a held share of the experts
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid_topk(x2, gate_w, expert_bias, top_k, norm_topk_prob,
+                       routed_scaling_factor):
+    """Scores, selection and gates, all float32: s = sigmoid(x W_g) over
+    the router's whole width; the top-k of s + bias are the picks (the
+    bias selects, it does not weigh and takes no gradient); the gates are
+    the picks' own s, renormalised over the k picks where asked.
+    Returns (picks [T, k] int32, gates [T, k] float32)."""
+    logits = jnp.dot(x2.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    biased = s + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+    _, picks = jax.lax.top_k(biased, top_k)
+    gates = jnp.take_along_axis(s, picks, axis=-1)
+    if norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-6)
+    return picks.astype(jnp.int32), gates * routed_scaling_factor
+
+
+def expert_load(picks, num_experts: int):
+    """Picks each expert of the router received, [E] int32."""
+    return jnp.sum(
+        picks.reshape(-1)[:, None] == jnp.arange(num_experts)[None, :],
+        axis=0, dtype=jnp.int32)
+
+
+def balance_bias(expert_bias, load, rate: float):
+    """Auxiliary-loss-free balancing (Wang et al. 2024, arXiv:2408.15664,
+    the rule DeepSeek-V3 trains with): after a step's picks, every
+    expert's selection bias moves by `rate` towards the mean load,
+    b_e += rate * sign(mean load - load_e), over the router's whole width
+    and the tokens at hand. The bias is what the next step selects by; it
+    never weighs an output and takes no gradient. rate 0: unchanged."""
+    bias = jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+    if rate == 0.0:
+        return bias
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load)
+
+
+@jax.custom_vjp
+def _gather_rows(src, index, back_index):
+    """src[index] whose transpose is a gather too: the caller knows the
+    inverse map, d_src[r] = sum over the last axis of
+    d_out.reshape(-1, H)[back_index[r, :]], so the backward pass makes no
+    scatter-add (serial on the TPU) out of a permutation."""
+    return jnp.take(src, index, axis=0, mode="clip")
+
+
+def _gather_rows_fwd(src, index, back_index):
+    return _gather_rows(src, index, back_index), back_index
+
+
+def _gather_rows_bwd(back_index, d_out):
+    rows = jnp.take(d_out.reshape(-1, d_out.shape[-1]), back_index, axis=0,
+                    mode="clip")
+    d_src = jnp.sum(rows.astype(jnp.float32), axis=-2).astype(d_out.dtype)
+    return d_src, None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _grouped_swiglu(xs, w1, w3, w2, group_sizes):
+    """The three grouped products and the activation: rows of `xs` lie
+    sorted by expert, `group_sizes[e]` of them for expert e, and what lies
+    behind the last group is computed by nobody."""
+    a = jax.lax.ragged_dot(xs, w1.astype(xs.dtype), group_sizes)
+    g = jax.lax.ragged_dot(xs, w3.astype(xs.dtype), group_sizes)
+    inter = (jax.nn.silu(a.astype(jnp.float32))
+             * g.astype(jnp.float32)).astype(xs.dtype)
+    return jax.lax.ragged_dot(inter, w2.astype(xs.dtype), group_sizes)
+
+
+@register("moe_swiglu")
+def moe_swiglu(ctx, ins, attrs):
+    """X [B, S, H], GateW [H, E], ExpertBias [E], W1 / W3 [E_held, H, F],
+    W2 [E_held, F, H] -> Out [B, S, H], the part of the layer's result that
+    experts first_expert .. first_expert + E_held - 1 give,
+    TokensPerExpert [E_held] int32, the rows each of them received, and
+    ExpertBiasOut [E], the selection bias after the balancing rule
+    (`balance_bias`), which a training program binds to ExpertBias itself."""
+    x = ins["X"][0]
+    gate_w, expert_bias = ins["GateW"][0], ins["ExpertBias"][0]
+    w1, w3, w2 = ins["W1"][0], ins["W3"][0], ins["W2"][0]
+    top_k = int(attrs.get("top_k", 4))
+    first = int(attrs.get("first_expert", 0))
+    held = w1.shape[0]
+    if not 0 <= first <= gate_w.shape[1] - held:
+        raise ValueError(
+            f"moe_swiglu: experts {first}..{first + held - 1} of a router "
+            f"that is {gate_w.shape[1]} wide")
+    b, s, h = x.shape
+    t = b * s
+    x2 = x.reshape(t, h)
+
+    with jax.named_scope("moe_route"):
+        picks, gates = route_sigmoid_topk(
+            x2, gate_w, expert_bias, top_k,
+            bool(attrs.get("norm_topk_prob", True)),
+            float(attrs.get("routed_scaling_factor", 1.0)))
+        load = expert_load(picks, gate_w.shape[1])
+        new_bias = balance_bias(expert_bias, load,
+                                float(attrs.get("bias_update_rate", 0.0)))
+
+    with jax.named_scope("moe_dispatch"):
+        # (token, pick) pairs sorted by held expert; pairs that fall on
+        # experts held elsewhere sort behind the last group
+        local = picks - first
+        mine = (local >= 0) & (local < held)  # [T, k]
+        key = jnp.where(mine, local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        slot = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
+        group_sizes = load[first:first + held]
+
+    def held_part(x2, gates, w1, w3, w2):
+        with jax.named_scope("moe_dispatch"):
+            # a row behind the last group belongs to nobody: the grouped
+            # products neither read nor write it, so it is zero going in
+            # and masked coming out
+            present = jnp.arange(t * top_k) < jnp.sum(group_sizes)
+            xs = jnp.where(present[:, None],
+                           _gather_rows(x2, order // top_k, slot), 0)
+        with jax.named_scope("moe_experts"):
+            ys = _grouped_swiglu(xs, w1, w3, w2, group_sizes)
+        with jax.named_scope("moe_combine"):
+            mine_y = _gather_rows(ys, slot, order[:, None])  # [T, k, H]
+            weighted = jnp.where(mine[..., None],
+                                 mine_y.astype(jnp.float32), 0.0)
+            return jnp.sum(weighted * gates[..., None], axis=1).astype(x.dtype)
+
+    # remat keeps the tokens and the gates for the backward pass and
+    # gathers and multiplies again there: the [T*k, .] row buffers of a
+    # layer are then alive in one layer at a time
+    if attrs.get("remat", False):
+        held_part = jax.checkpoint(held_part)
+    out = held_part(x2, gates, w1, w3, w2)
+
+    return {"Out": [out.reshape(b, s, h)], "TokensPerExpert": [group_sizes],
+            "ExpertBiasOut": [new_bias]}

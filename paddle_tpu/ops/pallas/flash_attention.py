@@ -1308,6 +1308,16 @@ def _prescale_ok(sm_scale) -> bool:
     return math.frexp(float(sm_scale))[0] == 0.5
 
 
+def _bsh_kernel_name(direction: str, causal: bool) -> str:
+    """`pallas_call(name=)` of a BSH call: `flash_bsh_fwd` / `flash_bsh_bwd`
+    over the full score square, `flash_bsh_causal_fwd` / `_bwd` where the
+    kernel skips the blocks above the diagonal. The benchmark finds a call
+    by this name and counts its work by it (`benchmark/kernels/<name>.py`):
+    the square's count over a causal call's time would read up to twice
+    its true share of the roofline."""
+    return f"flash_bsh_{'causal_' if causal else ''}{direction}"
+
+
 # Two bodies share these names. Up to S = 512 a head's whole score tile
 # is one step of work, and the WHOLE-TILE kernels below do it as one
 # straight-line block (DMA tile = compute tile); from S = 1024 the
@@ -1453,7 +1463,7 @@ def _flash_fwd_bsh_tile(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name="flash_bsh_fwd",
+        name=_bsh_kernel_name("fwd", causal),
         interpret=_interpret(),
     )(*args)
     return o, lse
@@ -1652,7 +1662,7 @@ def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob):
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name="flash_bsh_bwd",
+        name=_bsh_kernel_name("bwd", causal),
         interpret=_interpret(),
     )(*args)
     return dq.astype(q.dtype), dk, dv
@@ -2304,7 +2314,7 @@ def _flash_fwd_bsh_stream(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
              pltpu.VMEM((gw, cq), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name="flash_bsh_fwd",
+        name=_bsh_kernel_name("fwd", causal),
         interpret=_interpret(),
     )(*args)
     return o, lse
@@ -2683,7 +2693,7 @@ def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob):
             pltpu.VMEM((gw, ck), f32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name="flash_bsh_bwd",
+        name=_bsh_kernel_name("bwd", causal),
         interpret=_interpret(),
     )(*args)
     return dq.astype(q.dtype), dk, dv

@@ -2976,6 +2976,75 @@ def _nonzero_static():
                   outputs={"Out": 1, "Count": 1})
 
 
+# ---- decoder blocks (ops/decoder_ops.py) ----------------------------------
+@case("rms_norm")
+def _rms_norm():
+    rng = R(461)
+    x, scale = _mix(rng, 3, 8), _pos(rng, 4)  # two groups ("heads") of 4
+
+    def oracle(ins, a):
+        xx = ins["X"][0].reshape(3, 2, 4)
+        y = xx / np.sqrt((xx * xx).mean(-1, keepdims=True) + 1e-5) * ins["Scale"][0]
+        return {"Y": [f32(y.reshape(3, 8))]}
+
+    return OpTest("rms_norm", {"X": x, "Scale": scale}, oracle,
+                  attrs={"epsilon": 1e-5}, outputs={"Y": 1},
+                  grad=("X", "Scale"), tol=1e-5, grad_tol=2e-2)
+
+
+@case("rope")
+def _rope():
+    x = _mix(R(463), 2, 5, 8)
+
+    def oracle(ins, a):
+        xx = ins["X"][0].reshape(2, 5, 2, 2, 2)  # heads of 4: pairs (i, i+2)
+        ang = np.arange(5)[:, None] * 100.0 ** (-np.arange(2) * 2.0 / 4)
+        c, s = np.cos(ang)[None, :, None, :], np.sin(ang)[None, :, None, :]
+        y = np.stack([xx[..., 0, :] * c - xx[..., 1, :] * s,
+                      xx[..., 1, :] * c + xx[..., 0, :] * s], axis=-2)
+        return {"Out": [f32(y.reshape(2, 5, 8))]}
+
+    return OpTest("rope", {"X": x}, oracle,
+                  attrs={"head_dim": 4, "theta": 100.0}, grad=("X",),
+                  tol=1e-5)
+
+
+@case("short_conv")
+def _short_conv():
+    rng = R(467)
+    x = _mix(rng, 2, 6, 4)
+    w_in, taps, w_out = _mix(rng, 4, 12), _mix(rng, 3, 4), _mix(rng, 4, 4)
+
+    def oracle(ins, a):
+        proj = ins["X"][0] @ ins["InW"][0]
+        bg, cg, u = proj[..., :4], proj[..., 4:8], proj[..., 8:]
+        bu = np.pad(bg * u, ((0, 0), (2, 0), (0, 0)))  # zeros before t = 0
+        t = ins["Filter"][0]
+        c = sum(t[j] * bu[:, j:j + 6] for j in range(3))
+        return {"Out": [f32((cg * c) @ ins["OutW"][0])]}
+
+    return OpTest("short_conv",
+                  {"X": x, "InW": w_in, "Filter": taps, "OutW": w_out},
+                  oracle, grad=("X", "InW", "Filter", "OutW"), tol=1e-4,
+                  grad_tol=2e-2)
+
+
+@case("swiglu_ffn")
+def _swiglu_ffn():
+    rng = R(479)
+    x, w1, w3, w2 = _mix(rng, 3, 4), _mix(rng, 4, 6), _mix(rng, 4, 6), _mix(rng, 6, 4)
+
+    def oracle(ins, a):
+        xx = ins["X"][0]
+        a1 = xx @ ins["W1"][0]
+        return {"Out": [f32((a1 / (1 + np.exp(-a1)) * (xx @ ins["W3"][0]))
+                            @ ins["W2"][0])]}
+
+    return OpTest("swiglu_ffn", {"X": x, "W1": w1, "W3": w3, "W2": w2}, oracle,
+                  attrs={"remat": True}, grad=("X", "W1", "W3", "W2"),
+                  tol=1e-4, grad_tol=2e-2)
+
+
 # ---------------------------------------------------------------------------
 # exemptions: ops whose contract is verified elsewhere or is stochastic
 # ---------------------------------------------------------------------------
@@ -3015,6 +3084,8 @@ EXEMPT = {
     "select_input": "test_control_flow.py",
     # fused mega-ops have dedicated oracle suites
     "moe_ffn": "test_moe.py (numpy routing oracle, capacity, ep parity)",
+    "moe_swiglu": "test_lfm2_ops.py (dense-loop oracle, shares add up, "
+                  "nothing dropped, bias selects only)",
     "fused_encoder_stack": "test_bert.py (vs per-layer composition)",
     "fused_decoder_stack": "test_sequence_models.py (fused NMT stack "
                            "trains + stays causal)",
