@@ -24,6 +24,25 @@ Two ops, two formulations, and which model takes which:
   there is no exchange and nothing stands in for the absent chips.
   `models/lfm2_moe.py` builds it.
 
+  **The sorted block's rows.** XLA's kernel follows the rows present;
+  every gather, mask and element-wise pass beside it follows the buffer.
+  So the block is not T * k rows, the worst imbalance, but
+  `sorted_rows`: twice what uniform routing sends this share
+  (`2 * T * k * E_held / E`, up to a multiple of 512), read from the
+  op's own shapes, no attribute and no flag. A share of a quarter of the
+  router works on half the rows, a share of an eighth on a quarter; a
+  share of half or all of it has T * k rows and lowers as it always did.
+  Where the bound is below T * k the block is lowered twice, at the
+  bound and at T * k under the scope `moe_full_width`, and a device
+  scalar (do the held experts' pairs fit?) picks one each step through
+  `jax.lax.cond`, once in the forward and once in the backward pass: no
+  host sync, no recompilation, nothing dropped. A step that falls back
+  costs what a T * k-row step costs; the second lowering costs every
+  process its tracing and, in a cold cache, its compile. The combine's
+  transpose is written by hand (`_combine`): in sorted order it reads
+  d_out by token, one gather of the block's rows, where autodiff wrote a
+  [T, k, H] cotangent out and gathered it back.
+
 `moe_ffn`, in detail: a fused top-k router + capacity-bounded dispatch +
 per-expert FFN, expressed entirely as dense einsums over a one-hot dispatch
 tensor. That formulation is the TPU-idiomatic one for a capacity-bounded
@@ -59,6 +78,7 @@ applies to the FLOP-heavy path only.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -200,27 +220,77 @@ def balance_bias(expert_bias, load, rate: float):
     return bias + rate * jnp.sign(jnp.mean(load) - load)
 
 
+# The sorted block of a held share has room for this many times the rows
+# uniform routing sends it. 2: under the balancing rule every sparse trainer
+# runs (`balance_bias`) a share stays within a few per cent of its
+# expectation (1.00-1.06 x a layer in the LFM2 cell, from random weights),
+# and a router left to the loss alone drifts by tens of per cent; twice the
+# expectation holds both, and still halves the rows of a share of a quarter.
+# Beyond it nothing is dropped: the block is then T * k rows (`_held_part`).
+_ROW_HEADROOM = 2
+_ROW_MULTIPLE = 512  # the bound is rounded up to whole blocks of rows
+
+
+def sorted_rows(pairs: int, held: int, experts: int) -> int:
+    """Static rows of the sorted block of a layer that holds `held` of a
+    router's `experts`, over `pairs` = T * k (token, pick) pairs."""
+    expected = -(-_ROW_HEADROOM * pairs * held // experts)
+    return min(pairs, -(-expected // _ROW_MULTIPLE) * _ROW_MULTIPLE)
+
+
 @jax.custom_vjp
-def _gather_rows(src, index, back_index):
+def _gather_rows(src, index, back_index, back_valid):
     """src[index] whose transpose is a gather too: the caller knows the
     inverse map, d_src[r] = sum over the last axis of
-    d_out.reshape(-1, H)[back_index[r, :]], so the backward pass makes no
-    scatter-add (serial on the TPU) out of a permutation."""
+    d_out[back_index[r, :]] where `back_valid`, so the backward pass makes
+    no scatter-add (serial on the TPU) out of a permutation."""
     return jnp.take(src, index, axis=0, mode="clip")
 
 
-def _gather_rows_fwd(src, index, back_index):
-    return _gather_rows(src, index, back_index), back_index
+def _gather_rows_fwd(*operands):
+    return _gather_rows(*operands), operands[2:]
 
 
-def _gather_rows_bwd(back_index, d_out):
-    rows = jnp.take(d_out.reshape(-1, d_out.shape[-1]), back_index, axis=0,
-                    mode="clip")
-    d_src = jnp.sum(rows.astype(jnp.float32), axis=-2).astype(d_out.dtype)
-    return d_src, None, None
+def _gather_rows_bwd(saved, d_out):
+    back_index, back_valid = saved
+    rows = jnp.take(d_out, back_index, axis=0, mode="clip")
+    rows = jnp.where(back_valid[..., None], rows.astype(jnp.float32), 0.0)
+    return jnp.sum(rows, axis=-2).astype(d_out.dtype), None, None, None
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, slot, placed, head, present):
+    """out[t] = sum over the placed picks of gates[t, j] * ys[slot[t, j]],
+    float32 sums, in `ys`' dtype. Its transpose works in sorted order, on
+    the rows that exist: d_ys[r] = gate of pair head[r] x d_out[its token],
+    one gather of len(head) rows out of the T-row d_out, where autodiff
+    would write the [T, k, H] cotangent out and gather it back by `head`."""
+    picked = jnp.take(ys, slot, axis=0, mode="clip")  # [T, k, H]
+    weighted = jnp.where(placed[..., None], picked.astype(jnp.float32), 0.0)
+    return jnp.sum(weighted * gates[..., None], axis=1).astype(ys.dtype)
+
+
+def _combine_fwd(*operands):
+    return _combine(*operands), operands
+
+
+def _combine_bwd(saved, d_out):
+    ys, gates, slot, placed, head, present = saved
+    top_k = gates.shape[1]
+    d_row = jnp.take(d_out, head // top_k, axis=0,
+                     mode="clip").astype(jnp.float32)
+    d_row = jnp.where(present[:, None], d_row, 0.0)  # [rows, H]
+    gate_row = jnp.take(gates.reshape(-1), head, axis=0, mode="clip")
+    d_ys = (d_row * gate_row[:, None]).astype(ys.dtype)
+    d_gate_row = jnp.sum(d_row * ys.astype(jnp.float32), axis=-1)
+    d_gates = jnp.where(placed, jnp.take(d_gate_row, slot, mode="clip"), 0.0)
+    return d_ys, d_gates.astype(gates.dtype), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _grouped_swiglu(xs, w1, w3, w2, group_sizes):
@@ -234,6 +304,75 @@ def _grouped_swiglu(xs, w1, w3, w2, group_sizes):
     return jax.lax.ragged_dot(inter, w2.astype(xs.dtype), group_sizes)
 
 
+def _sorted_block(rows, x2, gates, w1, w3, w2, order, slot, mine,
+                  group_sizes):
+    """The held experts' part of the layer out of a sorted block of `rows`
+    rows, which has to hold every pair on a held expert: gather the pairs'
+    tokens in sorted order, three grouped products, weigh and sum back by
+    token. `order` sorts the T * k pairs by held expert (the others
+    behind), `slot` [T, k] is its inverse, `mine` [T, k] the pairs on held
+    experts. A pair whose slot lies behind `rows` is on nobody's expert
+    here and is masked as `mine` masks."""
+    top_k = gates.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        head = order[:rows]
+        placed = mine & (slot < rows)
+        # a row behind the last group belongs to nobody: the grouped
+        # products neither read nor write it, so it is zero going in and
+        # masked coming out
+        present = jnp.arange(rows) < jnp.sum(group_sizes)
+        xs = jnp.where(present[:, None],
+                       _gather_rows(x2, head // top_k, slot, placed), 0)
+    with jax.named_scope("moe_experts"):
+        ys = _grouped_swiglu(xs, w1, w3, w2, group_sizes)
+    with jax.named_scope("moe_combine"):
+        return _combine(ys, gates, slot, placed, head, present)
+
+
+def _full_width(x2, gates, *operands):
+    """The dropless fallback: the same block at T * k rows."""
+    with jax.named_scope("moe_full_width"):
+        return _sorted_block(gates.size, x2, gates, *operands)
+
+
+def _fits(rows, group_sizes):
+    return jnp.sum(group_sizes) <= rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_part(rows, x2, gates, w1, w3, w2, order, slot, mine, group_sizes):
+    """`_sorted_block` at `rows` rows where the held experts' pairs fit,
+    else at T * k: a conditional on a device scalar, no host sync and
+    nothing dropped. The conditional stands outside differentiation: under
+    `jax.vjp` a `cond` hands back the union of its branches' residuals,
+    zero-filled for the branch not taken, so the bounded branch would write
+    the other's T * k-row buffers. Here the forward keeps its operands
+    alone and the backward is a second conditional whose branches each run
+    and transpose their own block."""
+    return jax.lax.cond(_fits(rows, group_sizes),
+                        functools.partial(_sorted_block, rows), _full_width,
+                        x2, gates, w1, w3, w2, order, slot, mine, group_sizes)
+
+
+def _held_part_fwd(rows, *operands):
+    return _held_part(rows, *operands), operands
+
+
+def _held_part_bwd(rows, operands, d_out):
+    def transposed(block, *operands):
+        trained, indices = operands[:5], operands[5:]
+        return jax.vjp(lambda *t: block(*t, *indices), *trained)[1](d_out)
+
+    d_trained = jax.lax.cond(
+        _fits(rows, operands[-1]),
+        functools.partial(transposed, functools.partial(_sorted_block, rows)),
+        functools.partial(transposed, _full_width), *operands)
+    return (*d_trained, None, None, None, None)
+
+
+_held_part.defvjp(_held_part_fwd, _held_part_bwd)
+
+
 @register("moe_swiglu")
 def moe_swiglu(ctx, ins, attrs):
     """X [B, S, H], GateW [H, E], ExpertBias [E], W1 / W3 [E_held, H, F],
@@ -241,7 +380,17 @@ def moe_swiglu(ctx, ins, attrs):
     experts first_expert .. first_expert + E_held - 1 give,
     TokensPerExpert [E_held] int32, the rows each of them received, and
     ExpertBiasOut [E], the selection bias after the balancing rule
-    (`balance_bias`), which a training program binds to ExpertBias itself."""
+    (`balance_bias`), which a training program binds to ExpertBias itself.
+
+    The sorted block has `sorted_rows(T * k, E_held, E)` rows. A layer that
+    holds half of its router or more has T * k of them and lowers without a
+    conditional; `remat` then decides whether the block's buffers are kept
+    for the backward pass (False) or the block is run again there (True).
+    A smaller share lowers the block twice, bounded and, under the scope
+    `moe_full_width`, at T * k rows, and a device scalar picks one each
+    step, forward and backward; such a layer always keeps its operands
+    alone and runs the block again in the backward pass, whatever `remat`
+    says (buffers kept across a conditional would be both branches')."""
     x = ins["X"][0]
     gate_w, expert_bias = ins["GateW"][0], ins["ExpertBias"][0]
     w1, w3, w2 = ins["W1"][0], ins["W3"][0], ins["W2"][0]
@@ -275,28 +424,17 @@ def moe_swiglu(ctx, ins, attrs):
         slot = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
         group_sizes = load[first:first + held]
 
-    def held_part(x2, gates, w1, w3, w2):
-        with jax.named_scope("moe_dispatch"):
-            # a row behind the last group belongs to nobody: the grouped
-            # products neither read nor write it, so it is zero going in
-            # and masked coming out
-            present = jnp.arange(t * top_k) < jnp.sum(group_sizes)
-            xs = jnp.where(present[:, None],
-                           _gather_rows(x2, order // top_k, slot), 0)
-        with jax.named_scope("moe_experts"):
-            ys = _grouped_swiglu(xs, w1, w3, w2, group_sizes)
-        with jax.named_scope("moe_combine"):
-            mine_y = _gather_rows(ys, slot, order[:, None])  # [T, k, H]
-            weighted = jnp.where(mine[..., None],
-                                 mine_y.astype(jnp.float32), 0.0)
-            return jnp.sum(weighted * gates[..., None], axis=1).astype(x.dtype)
-
-    # remat keeps the tokens and the gates for the backward pass and
-    # gathers and multiplies again there: the [T*k, .] row buffers of a
-    # layer are then alive in one layer at a time
-    if attrs.get("remat", False):
-        held_part = jax.checkpoint(held_part)
-    out = held_part(x2, gates, w1, w3, w2)
+    rows = sorted_rows(t * top_k, held, gate_w.shape[1])
+    if rows < t * top_k:
+        held_part = functools.partial(_held_part, rows)
+    else:
+        held_part = functools.partial(_sorted_block, rows)
+        # remat keeps the tokens and the gates for the backward pass and
+        # gathers and multiplies again there: the [T*k, .] row buffers of
+        # a layer are then alive in one layer at a time
+        if attrs.get("remat", False):
+            held_part = jax.checkpoint(held_part)
+    out = held_part(x2, gates, w1, w3, w2, order, slot, mine, group_sizes)
 
     return {"Out": [out.reshape(b, s, h)], "TokensPerExpert": [group_sizes],
             "ExpertBiasOut": [new_bias]}

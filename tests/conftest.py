@@ -5,6 +5,7 @@ fake device; the 8 virtual devices let distributed tests exercise real mesh
 sharding + collectives without TPU hardware (the driver separately dry-runs
 the multi-chip path). Must run before jax initializes.
 """
+import gc
 import os
 import sys
 
@@ -25,6 +26,20 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import pytest
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_compiled_step_outlives_its_file():
+    """The inference layer's process-wide executor keeps every step it
+    compiled, and the profiler lists every live executable in a trace's
+    metadata: a test file that reads a trace's modules by name would find
+    the `jit_step`s of whichever serving file its worker ran before it.
+    Dropped after each file; `shared_executor()` makes the next one."""
+    yield
+    predictor = sys.modules.get("paddle_tpu.inference.predictor")
+    if predictor is not None and predictor._shared_executor is not None:
+        predictor._shared_executor = None
+        gc.collect()
 
 
 @pytest.fixture(autouse=True)

@@ -25,10 +25,11 @@ def _rel(a, b):
     return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
 
 
-def _run(build, feed, amp=False):
+def _run(build, feed, amp=False, lowered=None):
     """Build `out = build(x)` over a fed x, loss = sum(out * w) for a fixed
     random w, minimize with SGD(0) so that nothing moves, and return (out,
-    {parameter or 'x': gradient}, parameters, w, extras)."""
+    {parameter or 'x': gradient}, parameters, w, extras). A list given as
+    `lowered` receives the lowered step's text."""
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 11
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
@@ -56,6 +57,10 @@ def _run(build, feed, amp=False):
     grads = {p.name: g.name for p, g in pgs if g is not None}
     names = sorted(grads)
     fetch = [out.name] + [grads[n] for n in names] + [e.name for e in extras]
+    if lowered is not None:
+        lowered.append(exe._lower_step(
+            main, feed={"x": feed["x"], "w": wv}, fetch_list=fetch,
+            scope=scope).as_text(debug_info=True))
     got = exe.run(main, feed={"x": feed["x"], "w": wv}, fetch_list=fetch,
                   scope=scope)
     g = dict(zip(names, got[1:1 + len(names)]))
@@ -243,12 +248,12 @@ def _moe_ref(held):
     return fn
 
 
-def _moe_layer(held, remat=False):
+def _moe_layer(held, remat=False, experts=E, bias=INIT):
     first, count = held
     return lambda v: (lambda r: (r[0], [r[1]]))(layers.moe_swiglu(
-        v, E, F, experts_held=count, first_expert=first, top_k=K, remat=remat,
-        param_attr=fluid.ParamAttr(initializer=INIT),
-        bias_attr=fluid.ParamAttr(initializer=INIT), name="m"))
+        v, experts, F, experts_held=count, first_expert=first, top_k=K,
+        remat=remat, param_attr=fluid.ParamAttr(initializer=INIT),
+        bias_attr=fluid.ParamAttr(initializer=bias), name="m"))
 
 
 @pytest.mark.parametrize("held, remat", [((0, 8), False), ((8, 8), True),
@@ -282,10 +287,14 @@ def test_moe_swiglu_under_bf16_amp():
     _assert_close(got[0], got[1], want_out, want, tol=8e-2)
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("seq", [24, 128])
+def test_the_four_shares_add_up_to_the_uncut_layer(seq):
     """first_expert 0, 8, 16, 24 of one layer: the shares' outputs sum to
-    what the reference gives for the whole layer, and so do the loads."""
-    x = np.random.RandomState(8).randn(2, 24, H).astype(np.float32)
+    what the reference gives for the whole layer, and so do the loads. At
+    256 tokens each share's sorted block is bounded (512 rows of 1,024)."""
+    assert (moe_ops.sorted_rows(2 * seq * K, 8, E) < 2 * seq * K) == (
+        seq == 128)
+    x = np.random.RandomState(8).randn(2, seq, H).astype(np.float32)
     whole = _run(_moe_layer((0, 32)), {"x": x})
     params = whole[2]
     with jax.default_matmul_precision("highest"):
@@ -308,6 +317,12 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert loads == whole[4][0].tolist()
 
 
+def _ref_params(ins):
+    """An op's inputs under the names `_moe_ref` reads."""
+    return {"m.gate": ins["GateW"][0], "m.expert_bias": ins["ExpertBias"][0],
+            "m.w1": ins["W1"][0], "m.w3": ins["W3"][0], "m.w2": ins["W2"][0]}
+
+
 def _moe_ins(rng, tokens, bias):
     return {"X": [jnp.asarray(rng.randn(1, tokens, H), jnp.float32)],
             "GateW": [jnp.asarray(rng.randn(H, E) * 0.3, jnp.float32)],
@@ -328,9 +343,7 @@ def test_nothing_is_dropped_when_every_token_picks_the_same_expert():
     outs = moe_ops.moe_swiglu(None, ins, {"top_k": K, "first_expert": 0})
     counts = outs["TokensPerExpert"][0]
     assert int(counts[3]) == tokens
-    want = _moe_ref((0, 8))(ins["X"][0], {
-        "m.gate": ins["GateW"][0], "m.expert_bias": ins["ExpertBias"][0],
-        "m.w1": ins["W1"][0], "m.w3": ins["W3"][0], "m.w2": ins["W2"][0]})
+    want = _moe_ref((0, 8))(ins["X"][0], _ref_params(ins))
     np.testing.assert_allclose(outs["Out"][0], want, rtol=2e-5, atol=2e-6)
     # ... and when every pick of every token falls on held experts, the
     # row buffer is full to its last row: T * k rows, none lost
@@ -339,6 +352,153 @@ def test_nothing_is_dropped_when_every_token_picks_the_same_expert():
     ins = _moe_ins(np.random.RandomState(10), tokens, bias)
     outs = moe_ops.moe_swiglu(None, ins, {"top_k": K, "first_expert": 0})
     assert outs["TokensPerExpert"][0].tolist() == [tokens] * 4 + [0] * 4
+
+
+# the sorted block's row bound: a share of 2 of 16 experts over 1,024 tokens
+# expects 512 of the 4,096 pairs and has room for 1,024
+
+NARROW, SHARE, TOKENS = 16, (0, 2), 1024
+BOUND = 1024
+
+
+@pytest.mark.parametrize("pairs, held, experts, rows", [
+    (65536, 8, 32, 32768),  # the LFM2 cell
+    (16384, 8, 32, 8192),  # its check program
+    (4096, 2, 16, BOUND), (4096, 1, 16, 512), (4096, 3, 32, 1024),
+    (192, 8, 32, 192),  # too few pairs for a multiple of 512 to bound
+    (4096, 8, 16, 4096), (4096, 16, 16, 4096)])  # half, whole: no bound
+def test_the_sorted_block_has_twice_the_expected_rows(pairs, held, experts,
+                                                      rows):
+    assert moe_ops.sorted_rows(pairs, held, experts) == rows
+
+
+def _share_against_the_dense_loop(bias, lowered=None):
+    x = np.random.RandomState(14).randn(1, TOKENS, H).astype(np.float32)
+    got = _run(_moe_layer(SHARE, remat=True, experts=NARROW,
+                          bias=fluid.initializer.NumpyArrayInitializer(bias)),
+               {"x": x}, lowered=lowered)
+    want_out, want = _ref_grads(_moe_ref(SHARE), x, got[2], got[3])
+    want.pop("m.expert_bias")
+    _assert_close(got[0], got[1], want_out, want, tol=2e-5)
+    return got[4][0]
+
+
+def test_a_share_that_receives_more_than_its_bound_falls_back_and_drops_nothing():
+    """+10 on both held experts puts them into every token's top-k: 2,048
+    rows against a bound of 1,024, four times the expectation. Output,
+    every gradient and the counter are the dense loop's."""
+    bias = np.zeros(NARROW, np.float32)
+    bias[:2] = 10.0
+    counts = _share_against_the_dense_loop(bias)
+    assert counts.tolist() == [TOKENS, TOKENS]
+    assert counts.sum() > BOUND == moe_ops.sorted_rows(TOKENS * K, 2, NARROW)
+
+
+def test_a_balanced_share_runs_the_bounded_block_and_lowers_both():
+    lowered = []
+    counts = _share_against_the_dense_loop(
+        np.random.RandomState(15).randn(NARROW).astype(np.float32) * 0.05,
+        lowered)
+    assert 0.5 * 512 < counts.sum() < BOUND
+    (text,) = lowered
+    # the block's [rows, F] buffers at the bound and, under the fallback's
+    # scope, at T * k rows
+    assert f"tensor<{BOUND}x{F}xf32>" in text
+    assert f"tensor<{TOKENS * K}x{F}xf32>" in text
+    assert "stablehlo.case" in text
+    names = set(re.findall(r'"(jit\(step\)[^"]*)"', text))
+    for part in ("moe_dispatch", "moe_experts", "moe_combine"):
+        for role in ("forward", "backward"):
+            assert any(n.startswith(f"jit(step)/{role}/") and part in n
+                       and "moe_full_width" not in n for n in names)
+            # the scope stands outside the part's, so the part is still
+            # the first that `benchmark/scopes.py` meets
+            assert any(n.startswith(f"jit(step)/{role}/")
+                       and "moe_full_width" in n
+                       and n.index("moe_full_width") < n.index(part)
+                       for n in names if part in n)
+
+
+def _typed_tokens(rng, both, one):
+    """Tokens whose picks are known: `both` of them pick held experts 0 and
+    1 (and 4, 5), `one` picks held expert 0 alone (and 4, 5, 6), the rest
+    pick 4-7; small noise keeps every output and gradient alive."""
+    kinds = np.asarray([0] * both + [1] * one
+                       + [2] * (TOKENS - both - one))
+    rng.shuffle(kinds)
+    x = rng.randn(TOKENS, H).astype(np.float32) * 0.05
+    x[np.arange(TOKENS), kinds] += 4.0
+    gate = rng.randn(H, NARROW).astype(np.float32) * 0.05
+    for kind, picked in enumerate(([0, 1, 4, 5], [0, 4, 5, 6], [4, 5, 6, 7])):
+        gate[kind, picked] += 2.0
+    return {"X": [jnp.asarray(x[None])], "GateW": [jnp.asarray(gate)],
+            "ExpertBias": [jnp.zeros((NARROW,), jnp.float32)],
+            "W1": [jnp.asarray(rng.randn(2, H, F) * 0.3, jnp.float32)],
+            "W3": [jnp.asarray(rng.randn(2, H, F) * 0.3, jnp.float32)],
+            "W2": [jnp.asarray(rng.randn(2, F, H) * 0.3, jnp.float32)]}
+
+
+def _through_the_op(ins):
+    names = ("X", "GateW", "W1", "W3", "W2")
+
+    def loss(*trained):
+        outs = moe_ops.moe_swiglu(
+            None, dict(ins, **{n: [v] for n, v in zip(names, trained)}),
+            {"top_k": K, "first_expert": 0, "remat": True})
+        return (outs["Out"][0] ** 2).sum(), outs
+
+    (_, outs), grads = jax.value_and_grad(loss, argnums=range(5),
+                                          has_aux=True)(
+        *(ins[n][0] for n in names))
+    return outs["Out"][0], grads, outs["TokensPerExpert"][0]
+
+
+@pytest.mark.parametrize("present", [BOUND, BOUND + 1])
+def test_both_sides_of_the_bound(present):
+    """Exactly `rows` pairs on held experts fill the bounded block to its
+    last row; one more and the layer runs at full width. With the fallback
+    made to answer zero, the first still gives the dense loop's result and
+    the second gives zero: that is which block ran."""
+    ins = _typed_tokens(np.random.RandomState(16), BOUND // 2,
+                        present - BOUND)
+    out, grads, counts = _through_the_op(ins)
+    assert int(counts.sum()) == present
+    params = _ref_params(ins)
+    with jax.default_matmul_precision("highest"):
+        want_out = _moe_ref(SHARE)(ins["X"][0], params)
+        want = jax.grad(lambda x, p: (_moe_ref(SHARE)(x, p) ** 2).sum(),
+                        argnums=(0, 1))(ins["X"][0], params)
+    assert _rel(out, want_out) < 2e-5
+    for got, ref in zip(grads, (want[0], *(want[1][n] for n in (
+            "m.gate", "m.w1", "m.w3", "m.w2")))):
+        assert _rel(got, ref) < 2e-5
+    with mock.patch.object(moe_ops, "_full_width",
+                           lambda *operands: jnp.zeros_like(operands[0])):
+        zeroed = _through_the_op(ins)[0]
+    if present <= BOUND:
+        np.testing.assert_array_equal(zeroed, out)
+    else:
+        assert not np.asarray(zeroed).any() and np.asarray(out).any()
+
+
+@pytest.mark.parametrize("held, conditional", [(8, False), (16, False),
+                                               (2, True)])
+def test_half_or_all_of_the_router_lowers_without_a_conditional(
+        held, conditional):
+    rng = np.random.RandomState(17)
+    ins = _typed_tokens(rng, 8, 0)
+    for name in ("W1", "W3", "W2"):
+        ins[name] = [jnp.concatenate([ins[name][0]] * (held // 2))]
+
+    def loss(x, w1):
+        return moe_ops.moe_swiglu(
+            None, dict(ins, X=[x], W1=[w1]),
+            {"top_k": K, "first_expert": 0, "remat": True})["Out"][0].sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        ins["X"][0], ins["W1"][0]).as_text(debug_info=True)
+    assert ("stablehlo.case" in text or "stablehlo.if" in text) == conditional
+    assert ("moe_full_width" in text) == conditional
 
 
 def test_the_expert_bias_changes_the_selection_and_not_the_gates():
