@@ -148,27 +148,6 @@ def _persist_cost_report(rep, model) -> None:
         print(f"# proftop report persist failed: {e}", file=sys.stderr)
 
 
-def _autotune_fields():
-    """BENCH_r06+ rows record the ACTIVE autotune cache hash and the
-    per-kernel configs chosen while tracing (ISSUE 13), next to the
-    peak_hbm_bytes/hbm_model_bytes fields — a perf row is reproducible
-    only if it names the kernel configs that produced it. {} with
-    FLAGS_kernel_autotune off (rows bit-identical to before);
-    BENCH_KERNEL_AUTOTUNE=1 arms the flag for a bench run."""
-    import paddle_tpu.fluid as fluid
-
-    if not fluid.flags.get_flags(
-            "FLAGS_kernel_autotune")["FLAGS_kernel_autotune"]:
-        return {}
-    from paddle_tpu import tuning
-
-    return {
-        "kernel_autotune": True,
-        "autotune_cache_hash": tuning.cache_fingerprint(),
-        "autotune_configs": tuning.chosen_configs(),
-    }
-
-
 def _goodput_snapshot():
     """Stash the goodput ledger's per-bucket totals before the timed
     loop; None when PADDLE_GOODPUT is off (the default — rows stay
@@ -309,7 +288,6 @@ def bench_resnet(depth=50):
         "amp_bf16": use_amp,
         "conv_bn_fusion": use_fusion,
         **_memory_fields(exe, m, data, loss),
-        **_autotune_fields(),
         **_goodput_fields(gp0),
         **_maybe_op_profile(exe, m, data, loss, formula_flops,
                             f"resnet{depth}"),
@@ -432,12 +410,6 @@ def _apply_smoke_defaults():
 def main():
     if "--smoke" in sys.argv:
         _apply_smoke_defaults()
-    if os.environ.get("BENCH_KERNEL_AUTOTUNE", "0") == "1":
-        # route the Pallas kernels through the per-chip tuning cache
-        # (the BENCH_r06 protocol knob; bench_artifacts/autotune.md)
-        import paddle_tpu.fluid as fluid
-
-        fluid.flags.set_flags({"FLAGS_kernel_autotune": True})
     model = os.environ.get("BENCH_MODEL", "bert")
     if model.startswith("resnet"):
         return bench_resnet(int(model[len("resnet"):] or 50))
@@ -467,8 +439,7 @@ def main():
         "peak_hbm_gb": out["peak_hbm_gb"],
     }
     for k in ("measured_mfu", "op_profile_coverage", "peak_hbm_bytes",
-              "hbm_model_bytes", "kernel_autotune", "autotune_cache_hash",
-              "autotune_configs"):
+              "hbm_model_bytes"):
         if k in out:
             result[k] = out[k]
     # long-context guard row (VERDICT r3: the s4096 config regressed with
@@ -571,7 +542,6 @@ def _run_bert(batch, seq, max_preds, steps, use_amp):
         "peak_hbm_gb": peak_gb if peak_gb is not None
         else _peak_hbm_gb(exe, m, data, loss),
         **mem_fields,
-        **_autotune_fields(),
         **_goodput_fields(gp0),
         **_maybe_op_profile(exe, m, data, loss, formula_flops, "bert"),
     }

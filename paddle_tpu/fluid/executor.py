@@ -481,16 +481,10 @@ class Executor:
         # recompile, not silently hit the pre-toggle cache entry
         # (FLAGS_op_profile changes the traced computation's metadata, so
         # toggling it back off must return to the scope-free executable)
-        # cache_signature() is None with FLAGS_kernel_autotune off (key
-        # unchanged vs a build without the tuning layer) and the active
-        # tuning-cache fingerprint with it on, so an edited cache — or a
-        # search-harness override — retraces with the new kernel configs
-        from .. import tuning
-
         return (program._serial, program._version, feed_sig, fetch_names,
                 no_donate, flag("FLAGS_enable_unused_var_check"),
                 flag("FLAGS_program_verify"), flag("FLAGS_op_profile"),
-                flag("FLAGS_tensor_stats"), tuning.cache_signature())
+                flag("FLAGS_tensor_stats"))
 
     def _prepare_feed(self, block, feed):
         import jax

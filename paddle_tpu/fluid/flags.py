@@ -141,20 +141,6 @@ _DEFS: Dict[str, tuple] = {
                "bit-identical. The OOM doctor and the "
                "PADDLE_HBM_BUDGET_BYTES gate work independently of "
                "this flag; tools/memtop.py is the CLI"),
-    "FLAGS_kernel_autotune": (
-        False, "Pallas kernel autotuner (paddle_tpu/tuning): the three "
-               "Pallas kernels (flash attention BSH, fused add+LN, "
-               "fused conv+BN) consult the per-chip tuning cache "
-               "(~/.cache/paddle_tpu/autotune/<chip>.json overlaid on "
-               "the checked-in paddle_tpu/tuning/defaults, "
-               "$PADDLE_AUTOTUNE_CACHE pins an explicit file) for their "
-               "tile/block configs at trace time; a missing entry falls "
-               "back to the hand-picked chooser (no behavior cliff). "
-               "The active cache fingerprint rides the Executor "
-               "compile-cache key so editing the cache retraces. Off = "
-               "no lookup runs and emitted programs are bit-identical "
-               "to a build without the tuning layer. Search/inspect: "
-               "tools/autotune.py"),
     "FLAGS_dataloader_require_spawn": (
         False, "fluid/dataloader: raise instead of warning when worker "
                "args are unpicklable and the loader would fall back to "

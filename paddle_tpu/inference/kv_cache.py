@@ -124,25 +124,6 @@ class PagedKVPool:
         given.  ``memtop --budget`` remains the fit gate: the pool's
         standing allocation shows up in the live allocator stats it
         renders, and /memz carries the pool section."""
-        if page_size is None and not os.environ.get(ENV_KV_PAGE_SIZE):
-            # No explicit choice anywhere: let the paged-attention
-            # autotuner pick (r22). The kernel streams one KV page per
-            # grid step, so its tuned page size IS the pool's page size
-            # — a mismatch would force a re-layout at attention time.
-            # Silent no-op when tuning is off or the cache has no entry.
-            try:
-                from .. import tuning as _tuning
-
-                if _tuning.enabled():
-                    cfg = _tuning.maybe_lookup("paged_attention", {
-                        "kv_heads": int(kv_heads),
-                        "head_dim": int(head_dim),
-                        "dtype": str(np.dtype(dtype).name),
-                    })
-                    if cfg and cfg.get("page_size"):
-                        page_size = int(cfg["page_size"])
-            except Exception:  # noqa: BLE001 — tuning is best-effort
-                pass
         page_size = int(page_size or os.environ.get(
             ENV_KV_PAGE_SIZE, _DEFAULT_PAGE_SIZE))
         if n_pages is None and os.environ.get(ENV_KV_PAGES):

@@ -34,11 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...tuning import feasible as _feas
+from . import feasible as _feas
 from .flash_attention import _identity, _interpret, _to_lanes, _to_sublanes
-
-# single source shared with the autotuner's feasibility gate
-_LN_VMEM_BUDGET = _feas.LN_VMEM_BUDGET
 
 _ROW_ALIGN = _feas.LN_ROW_ALIGN
 _ROW_CANDIDATES = (1024, 512, 256, 128)
@@ -49,39 +46,15 @@ def _padded_rows(r):
 
 
 def default_ln_rows(r, h):
-    """THE hand-picked row-block chooser (the autotune cache-miss
-    fallback): largest row block that tiles r (a multiple of 128 — see
-    _padded_rows) under the VMEM budget (x, y, out blocks
-    double-buffered bf16 + ~4 f32 temporaries per row block). None when
-    nothing tiles."""
+    """THE row-block chooser, for the forward and the backward alike
+    (the saved [1, R] stats re-block as they were written): largest row
+    block that tiles r (a multiple of 128 — see _padded_rows) under the
+    VMEM budget (x, y, out blocks double-buffered bf16 + ~4 f32
+    temporaries per row block). None when nothing tiles."""
     for cand in _ROW_CANDIDATES:
-        if r % cand == 0 and _feas.ln_vmem_bytes(cand, h) <= _LN_VMEM_BUDGET:
+        if _feas.ln_rows_ok(r, h, cand)[0]:
             return cand
     return None
-
-
-_pick_rows = default_ln_rows  # historical name
-
-
-def _resolve_ln_rows(r, h, dtype):
-    """Row block for one kernel launch: FLAGS_kernel_autotune cache
-    entry (validated against divisibility + the VMEM budget) or the
-    hand-picked default. fwd and bwd resolve through the same entry, so
-    the saved [1, R] stats always re-block consistently."""
-    from ... import tuning
-
-    key = {"r": r, "h": h, "dtype": str(dtype)}
-    cfg = tuning.maybe_lookup("add_ln", key)
-    if cfg:
-        try:
-            rows = int(cfg.get("block_rows", 0))
-        except (TypeError, ValueError):
-            rows = 0
-        ok, _why = _feas.ln_rows_ok(r, h, rows)
-        if ok:
-            return rows
-        tuning.note_choice("add_ln", key, None, "default")
-    return default_ln_rows(r, h)
 
 
 def ln_shapes_ok(r, h) -> bool:
@@ -144,7 +117,7 @@ def _bwd_kernel(*refs, has_y, br):
 
 def _ln_fwd(x, y, scale, shift, *, eps):
     r, h = x.shape
-    br = _resolve_ln_rows(r, h, x.dtype)
+    br = default_ln_rows(r, h)
     has_y = y is not None
     row_spec = pl.BlockSpec((br, h), lambda i: (i, 0), memory_space=pltpu.VMEM)
     vec_spec = pl.BlockSpec((1, h), lambda i: (0, 0), memory_space=pltpu.VMEM)
@@ -169,7 +142,7 @@ def _ln_fwd(x, y, scale, shift, *, eps):
 
 def _ln_bwd(x, y, scale, mean, rstd, g, *, eps):
     r, h = x.shape
-    br = _resolve_ln_rows(r, h, x.dtype)
+    br = default_ln_rows(r, h)
     has_y = y is not None
     row_spec = pl.BlockSpec((br, h), lambda i: (i, 0), memory_space=pltpu.VMEM)
     vec_spec = pl.BlockSpec((1, h), lambda i: (0, 0), memory_space=pltpu.VMEM)

@@ -46,13 +46,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...tuning import feasible as _feas
+from . import feasible as _feas
 from .flash_attention import _interpret
 
 # per-grid-step VMEM budget: in/out blocks double-buffered + the f32
 # accumulator; leaves headroom of the ~16MB/core for Mosaic's own use.
-# The byte value lives in tuning/feasible.py so the autotuner's
-# feasibility gate and the kernel can never disagree about it.
 _CONV_BN_VMEM_BUDGET = _feas.CONV_BN_VMEM_BUDGET
 _ROW_UNIT = _feas.CONV_BN_ROW_UNIT
 
@@ -60,37 +58,13 @@ _ROW_CANDIDATES = (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
 
 
 def default_conv_bn_rows(r, width, bytes_per_row_unit):
-    """THE hand-picked row-block chooser (the autotune cache-miss
-    fallback): largest row block dividing r whose working set fits the
-    budget."""
+    """THE row-block chooser of the row-blocked passes (the 1x1 matmul,
+    the normalize and backward sweeps): largest row block dividing r
+    whose working set fits the budget. None when nothing tiles."""
     for cand in _ROW_CANDIDATES:
-        if r % cand == 0 and cand * width * bytes_per_row_unit <= _CONV_BN_VMEM_BUDGET:
+        if _feas.conv_bn_rows_ok(r, width, cand, bytes_per_row_unit)[0]:
             return cand
     return None
-
-
-_pick_rows = default_conv_bn_rows  # historical name
-
-
-def _resolve_rows(r, width, bytes_per_row_unit, kind, dtype):
-    """Row block for one row-blocked pass (kind 'mm' = the 1x1 matmul
-    pass, 'apply' = the normalize/backward elementwise sweeps):
-    FLAGS_kernel_autotune cache entry validated against divisibility +
-    the VMEM budget, else the hand-picked default."""
-    from ... import tuning
-
-    key = {"kind": kind, "r": r, "w": width, "dtype": str(dtype)}
-    cfg = tuning.maybe_lookup("conv_bn", key)
-    if cfg:
-        try:
-            rows = int(cfg.get("block_rows", 0))
-        except (TypeError, ValueError):
-            rows = 0
-        ok, _why = _feas.conv_bn_rows_ok(r, width, rows, bytes_per_row_unit)
-        if ok:
-            return rows
-        tuning.note_choice("conv_bn", key, None, "default")
-    return default_conv_bn_rows(r, width, bytes_per_row_unit)
 
 
 def _resolve_pads(pad, h, w, kh, kw, strides):
@@ -119,7 +93,7 @@ def conv_bn_shapes_ok(x_shape, w_shape, strides, pads, dilations=(1, 1),
         ho = -(-h // strides[0])
         wo = -(-w // strides[1])
         r = n * ho * wo
-        return _pick_rows(r, c + o, _ROW_UNIT["mm"]) is not None
+        return default_conv_bn_rows(r, c + o, _ROW_UNIT["mm"]) is not None
     if tuple(strides) != (1, 1):
         return False
     hp = h + pads[0][0] + pads[0][1]
@@ -147,59 +121,6 @@ def conv_bn_dispatch_ok(x_shape, w_shape, strides, pads, dilations=(1, 1),
     if FORCE_PALLAS:
         return ok
     return ok and not _interpret()
-
-
-def conv_bn_s2d_ok(x_shape, w_shape, strides, pads) -> bool:
-    """Structural + VMEM gate for the space-to-depth lowering of a kxk
-    STRIDE-2 conv (pads already explicit): the 2x2 input phases stack
-    into 4C channels, the filter splits into ceil(k/2)^2 taps, and the
-    conv becomes stride-1 — servable by the per-image Pallas kernel
-    that conv_bn_shapes_ok otherwise rejects for k>1 strided cases.
-
-    Exactness conditions: each padded extent must be even OR the kernel
-    odd along that dim (otherwise the evening pad row would enter the
-    last window and change the output size)."""
-    n, h, w, c = x_shape
-    o, cg, kh, kw = w_shape
-    if tuple(strides) != (2, 2) or cg != c or (kh, kw) == (1, 1):
-        return False
-    hp = h + pads[0][0] + pads[0][1]
-    wp = w + pads[1][0] + pads[1][1]
-    for ext, k in ((hp, kh), (wp, kw)):
-        if ext % 2 and k % 2 == 0:
-            return False
-    ho = (hp - kh) // 2 + 1
-    wo = (wp - kw) // 2 + 1
-    if ho <= 0 or wo <= 0:
-        return False
-    # the normalize/backward sweeps must tile too
-    if default_conv_bn_rows(n * ho * wo, o, _ROW_UNIT["apply"]) is None:
-        return False
-    return (_feas.conv_bn_s2d_per_image_bytes(hp, wp, c, o, kh, kw)
-            <= _CONV_BN_VMEM_BUDGET)
-
-
-def _s2d_wanted(x_shape, w_shape, strides, pads, dtype) -> bool:
-    """The tuned space-to-depth axis (ISSUE 13): a kxk stride-2 conv is
-    routed through the s2d lowering only when the autotune cache holds
-    {'space_to_depth': 1} for this conv signature AND the structural
-    gate passes — with the flag off or the cache empty, these convs
-    take exactly the path they take today (the jnp reference)."""
-    if not conv_bn_s2d_ok(x_shape, w_shape, strides, pads):
-        return False
-    from ..attention import FORCE_PALLAS
-
-    if _interpret() and not FORCE_PALLAS:
-        return False
-    from ... import tuning
-
-    n, h, w_sp, c = x_shape
-    o, _cg, kh, kw = w_shape
-    cfg = tuning.maybe_lookup("conv_bn_s2d", {
-        "n": n, "h": h, "w": w_sp, "c": c, "o": o, "kh": kh, "kw": kw,
-        "sh": int(strides[0]), "sw": int(strides[1]),
-        "dtype": str(dtype)})
-    return bool(cfg) and bool(cfg.get("space_to_depth"))
 
 
 # ---------------------------------------------------------------------------
@@ -384,7 +305,7 @@ def _mm_fwd(x, w2d, out_dtype, strides):
     n, ho, wo, c = x.shape
     o = w2d.shape[-1]
     r = n * ho * wo
-    br = _resolve_rows(r, c + o, _ROW_UNIT["mm"], "mm", x.dtype)
+    br = default_conv_bn_rows(r, c + o, _ROW_UNIT["mm"])
     y, s, ss = pl.pallas_call(
         _mm_stats_kernel,
         grid=(r // br,),
@@ -401,46 +322,8 @@ def _mm_fwd(x, w2d, out_dtype, strides):
     return y, (n, ho, wo, o), s, ss
 
 
-def _space_to_depth_x(x, pads):
-    """Exact stride-2 -> stride-1 input rearrangement: pad, even the
-    extents (the extra zero row/col is provably outside every valid
-    window under conv_bn_s2d_ok's parity condition), then stack the 2x2
-    phase grid into channels: [N, Hp/2, Wp/2, 4C] with phase (a, b) at
-    channels [(a*2+b)*C, (a*2+b+1)*C)."""
-    xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, 0)))
-    hp, wp = xp.shape[1], xp.shape[2]
-    if hp % 2 or wp % 2:
-        xp = jnp.pad(xp, ((0, 0), (0, hp % 2), (0, wp % 2), (0, 0)))
-    n, hp, wp, c = xp.shape
-    x4 = xp.reshape(n, hp // 2, 2, wp // 2, 2, c)
-    return x4.transpose(0, 1, 3, 2, 4, 5).reshape(n, hp // 2, wp // 2, 4 * c)
-
-
-def _s2d_weights(w):
-    """OIHW [O, C, kh, kw] -> [O, 4C, ceil(kh/2), ceil(kw/2)]: tap
-    (du, dv) of phase (a, b) is original tap (2du+a, 2dv+b); taps past
-    the original kernel extent stay zero (the sparse rearrangement that
-    makes stride-2 kxk EXACTLY a stride-1 conv over the phase image)."""
-    o, c, kh, kw = w.shape
-    k2h, k2w = (kh + 1) // 2, (kw + 1) // 2
-    w4 = jnp.zeros((o, 4 * c, k2h, k2w), w.dtype)
-    for a in (0, 1):
-        for b in (0, 1):
-            lo = (a * 2 + b) * c
-            for du in range(k2h):
-                ki = 2 * du + a
-                if ki >= kh:
-                    continue
-                for dv in range(k2w):
-                    kj = 2 * dv + b
-                    if kj >= kw:
-                        continue
-                    w4 = w4.at[:, lo:lo + c, du, dv].set(w[:, :, ki, kj])
-    return w4
-
-
-def _elementwise_rows(r, o, dtype=jnp.float32):
-    br = _resolve_rows(r, o, _ROW_UNIT["apply"], "apply", dtype)
+def _elementwise_rows(r, o):
+    br = default_conv_bn_rows(r, o, _ROW_UNIT["apply"])
     if br is None:
         raise _feas.NoFeasibleConfig(
             "conv_bn", {"kind": "apply", "r": r, "w": o},
@@ -450,23 +333,12 @@ def _elementwise_rows(r, o, dtype=jnp.float32):
     return br
 
 
-def _pallas_fwd(x, w, scale, bias, *, strides, pads, eps, with_relu,
-                s2d=False):
+def _pallas_fwd(x, w, scale, bias, *, strides, pads, eps, with_relu):
     o, c, kh, kw = w.shape
-    if s2d:
-        # kxk stride-2 via space-to-depth: stride-1 per-image kernel
-        # over the phase image with the sparsely rearranged filter
-        x4 = _space_to_depth_x(x, pads)
-        w4 = _s2d_weights(w)
-        k2h, k2w = w4.shape[2], w4.shape[3]
-        w2d = jnp.transpose(w4, (2, 3, 1, 0)).reshape(k2h * k2w * 4 * c, o)
-        z2d, oshape, s, ss = _conv_fwd(x4, w2d, x.dtype, k2h, k2w,
-                                       ((0, 0), (0, 0)))
-    elif (kh, kw) == (1, 1):
-        w2d = jnp.transpose(w, (2, 3, 1, 0)).reshape(kh * kw * c, o)
+    w2d = jnp.transpose(w, (2, 3, 1, 0)).reshape(kh * kw * c, o)
+    if (kh, kw) == (1, 1):
         z2d, oshape, s, ss = _mm_fwd(x, w2d, x.dtype, strides)
     else:
-        w2d = jnp.transpose(w, (2, 3, 1, 0)).reshape(kh * kw * c, o)
         z2d, oshape, s, ss = _conv_fwd(x, w2d, x.dtype, kh, kw, pads)
     r = z2d.shape[0]
     m = s[0] / r
@@ -475,7 +347,7 @@ def _pallas_fwd(x, w, scale, bias, *, strides, pads, eps, with_relu,
     stat = jnp.stack(
         [m, inv, scale.astype(jnp.float32), bias.astype(jnp.float32)]
     )
-    br = _elementwise_rows(r, o, x.dtype)
+    br = _elementwise_rows(r, o)
     y2d = pl.pallas_call(
         functools.partial(_apply_kernel, with_relu=with_relu),
         grid=(r // br,),
@@ -490,7 +362,7 @@ def _pallas_fwd(x, w, scale, bias, *, strides, pads, eps, with_relu,
 
 def _pallas_bwd(x, w, z2d, stat, g, *, strides, pads, with_relu):
     r, o = z2d.shape
-    br = _elementwise_rows(r, o, x.dtype)
+    br = _elementwise_rows(r, o)
     nb = r // br
     g2d = g.reshape(r, o)
     part_spec = pl.BlockSpec((1, 1, o), lambda i: (i, 0, 0),
@@ -539,19 +411,19 @@ def _pallas_bwd(x, w, z2d, stat, g, *, strides, pads, with_relu):
 
 
 @functools.lru_cache(maxsize=64)
-def _make_core(kh, kw, strides, pads, eps, with_relu, s2d=False):
+def _make_core(kh, kw, strides, pads, eps, with_relu):
     @jax.custom_vjp
     def core(x, w, scale, bias):
         y, _, _, m, v = _pallas_fwd(
             x, w, scale, bias, strides=strides, pads=pads, eps=eps,
-            with_relu=with_relu, s2d=s2d,
+            with_relu=with_relu,
         )
         return y, m, v
 
     def core_fwd(x, w, scale, bias):
         y, z2d, stat, m, v = _pallas_fwd(
             x, w, scale, bias, strides=strides, pads=pads, eps=eps,
-            with_relu=with_relu, s2d=s2d,
+            with_relu=with_relu,
         )
         return (y, m, v), (x, w, scale, z2d, stat)
 
@@ -581,12 +453,6 @@ def fused_conv_bn(x, w, scale, bias, *, strides=(1, 1), pads="SAME",
     pads = _resolve_pads(pads, x.shape[1], x.shape[2], kh, kw, strides)
     if conv_bn_dispatch_ok(x.shape, w.shape, strides, pads):
         core = _make_core(kh, kw, strides, pads, float(eps), bool(with_relu))
-        return core(x, w, scale, bias)
-    if _s2d_wanted(x.shape, w.shape, strides, pads, x.dtype):
-        # tuned kxk stride-2 space-to-depth lowering (autotune cache
-        # opt-in; exact — see _s2d_weights)
-        core = _make_core(kh, kw, strides, pads, float(eps),
-                          bool(with_relu), s2d=True)
         return core(x, w, scale, bias)
     return conv_bn_reference(
         x, w, scale, bias, strides=strides, pads=pads, eps=eps,

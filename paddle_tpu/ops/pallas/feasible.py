@@ -1,17 +1,9 @@
-"""VMEM-footprint models — the feasibility gate candidates must pass
-BEFORE they are ever timed (a config that OOMs scoped VMEM wastes a
-compile + a device fault; rejecting it up front is free).
-
-These are the SAME models the kernels' hand-picked fallback choosers
-use (the kernel modules import the budgets and estimators from here so
-the two can never drift): calibrated on v5e against Mosaic's
+"""VMEM-footprint models of the Pallas kernels in this package: what
+their tile and row-block choosers (flash_attention.default_bsh_block,
+add_ln.default_ln_rows, conv_bn.default_conv_bn_rows) and their
+dispatch gates hold a candidate to. Calibrated on v5e against Mosaic's
 scoped-vmem report — see the per-function notes. All pure stdlib math;
 nothing here imports jax.
-
-The HBM side of the gate is tools/memtop.py --budget (the static
-live-range peak over the whole program); tuning/search.py applies it
-through the `hbm_gate` hook for candidates that add HBM-resident
-tensors (e.g. a materialized dropout mask).
 """
 from __future__ import annotations
 
@@ -26,16 +18,6 @@ LN_VMEM_BUDGET = 10 * 1024 * 1024
 # a lane block only in multiples of 128
 LN_ROW_ALIGN = 128
 CONV_BN_VMEM_BUDGET = 12 * 1024 * 1024
-PAGED_ATTN_VMEM_BUDGET = 8 * 1024 * 1024
-
-_DTYPE_BYTES = {
-    "float32": 4, "f32": 4, "bfloat16": 2, "bf16": 2, "float16": 2,
-    "f16": 2, "float64": 8,
-}
-
-
-def dtype_bytes(dtype: Any) -> int:
-    return _DTYPE_BYTES.get(str(dtype), 4)
 
 
 class NoFeasibleConfig(ValueError):
@@ -122,9 +104,8 @@ def flash_bsh_bwd_vmem_bytes(sq: int, skv: int, h: int, bq: int,
 
 def flash_bsh_ok(sq: int, skv: int, h: int, bq: int, bk: int,
                  *, limit: int = BSH_VMEM_LIMIT) -> Tuple[bool, str]:
-    """(feasible, reason). A config serves BOTH passes (bq is the
-    forward's DMA tile, bk the backward's), so both footprints must
-    fit."""
+    """(feasible, reason) for a pair of DMA tiles (bq is the forward's,
+    bk the backward's): both passes' footprints must fit."""
     if bq < 128 or bk < 128:
         return False, "block below the 128 tiling minimum"
     if sq % bq or skv % bk:
@@ -176,72 +157,14 @@ def ln_rows_ok(r: int, h: int, rows: int,
 CONV_BN_ROW_UNIT = {"mm": 2 * 2 + 4, "apply": 3 * 2 * 2 + 4 * 4}
 
 
-def conv_bn_row_bytes(rows: int, width: int, bytes_per_row_unit: int) -> int:
-    """Row-blocked passes (1x1 matmul / normalize / backward sweeps):
-    in+out blocks double-buffered + the f32 accumulator, expressed as
-    bytes per row*width unit exactly as ops/pallas/conv_bn.py sizes
-    them."""
-    return rows * width * bytes_per_row_unit
-
-
 def conv_bn_rows_ok(r: int, width: int, rows: int, bytes_per_row_unit: int,
                     *, budget: int = CONV_BN_VMEM_BUDGET) -> Tuple[bool, str]:
+    """Row-blocked passes (1x1 matmul / normalize / backward sweeps):
+    in+out blocks double-buffered + the f32 accumulator, as bytes per
+    row*width unit (CONV_BN_ROW_UNIT)."""
     if rows < 1 or r % rows:
         return False, f"row block {rows} does not tile r={r}"
-    est = conv_bn_row_bytes(rows, width, bytes_per_row_unit)
+    est = rows * width * bytes_per_row_unit
     if est > budget:
         return False, f"VMEM estimate {est} > {budget}"
     return True, "ok"
-
-
-def paged_attention_vmem_bytes(page: int, kv_heads: int, head_dim: int,
-                               dtype: Any = "float32") -> int:
-    """Per-grid-step footprint of the serving paged-attention kernel
-    (ops/pallas/paged_attention.py): one KV page streamed per step —
-    k+v page blocks double-buffered — plus the q/o head blocks and the
-    f32 online-softmax scratch (running max, running denominator, and
-    the [h, d] weighted-value accumulator). MHA-only kernel, so the q/o
-    head count equals kv_heads."""
-    b = dtype_bytes(dtype)
-    kv_pages = 2 * 2 * page * kv_heads * head_dim * b
-    q_out = 2 * 2 * kv_heads * head_dim * b
-    scratch = 4 * (kv_heads + kv_heads + kv_heads * head_dim)
-    return kv_pages + q_out + scratch
-
-
-def paged_page_ok(page: int, kv_heads: int, head_dim: int,
-                  dtype: Any = "float32", max_seq: int = 0,
-                  *, budget: int = PAGED_ATTN_VMEM_BUDGET
-                  ) -> Tuple[bool, str]:
-    """(feasible, reason) for a paged-attention page size. The tuned
-    page size doubles as the KV pool's page granularity (the kernel
-    streams pool pages directly), so a page longer than the model's
-    max sequence can never fill and only wastes pool bytes."""
-    if page < 1:
-        return False, "page size must be >= 1"
-    if max_seq and page > max_seq:
-        return False, f"page {page} exceeds max_seq {max_seq}"
-    est = paged_attention_vmem_bytes(page, kv_heads, head_dim, dtype)
-    if est > budget:
-        return False, f"VMEM estimate {est} > {budget}"
-    return True, "ok"
-
-
-def conv_bn_s2d_per_image_bytes(hp: int, wp: int, c: int, o: int,
-                                kh: int, kw: int) -> int:
-    """Per-image footprint of the space-to-depth lowering of a stride-2
-    kxk conv: the phase image is [hp/2, wp/2, 4c], the filter becomes
-    ceil(k/2)^2 taps over 4c channels, outputs shrink to the strided
-    grid. Same cost model as conv_bn_shapes_ok's k>1 path, on the
-    transformed dims."""
-    hp2, wp2 = (hp + 1) // 2, (wp + 1) // 2
-    k2h, k2w = (kh + 1) // 2, (kw + 1) // 2
-    ho, wo = hp2 - k2h + 1, wp2 - k2w + 1
-    if ho <= 0 or wo <= 0:
-        return 1 << 62
-    return (
-        2 * 2 * hp2 * wp2 * 4 * c      # phase image block, double-buffered
-        + 2 * 2 * ho * wo * o          # y block
-        + 4 * ho * wo * o              # f32 accumulator
-        + 2 * k2h * k2w * 4 * c * o    # rearranged weights (resident)
-    )

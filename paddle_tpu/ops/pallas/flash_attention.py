@@ -56,10 +56,11 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ...tuning import feasible as _feas
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import feasible as _feas
 
 MIN_BLOCK = 128
 NEG_INF = -1e30
@@ -75,13 +76,7 @@ def _pick_block(s):
     fits VMEM and bigger dots keep the MXU busy (as GRID tiles of the
     BHSD kernels, whose body is one block, 128-blocks are latency-bound:
     profiled 4x slower at S=512; the BSH kernels take this as a DMA tile
-    only and compute in tiles of their own). PADDLE_FLASH_BLOCK
-    overrides for tuning sweeps (must divide s)."""
-    import os
-
-    forced = int(os.environ.get("PADDLE_FLASH_BLOCK", "0"))
-    if forced >= MIN_BLOCK and s % forced == 0:
-        return forced
+    only and compute in tiles of their own)."""
     for cand in (512, 256, 128):
         if s % cand == 0:
             return cand
@@ -91,15 +86,9 @@ def _pick_block(s):
         detail=f"seq must be a multiple of {MIN_BLOCK}")
 
 
-def _scan_groups(bh, env_var, fits):
-    """Shared group-size scan: honor a (divisibility-checked) env
-    override, else take the largest divisor of bh whose footprint
+def _scan_groups(bh, fits):
+    """Shared group-size scan: the largest divisor of bh whose footprint
     estimate fits."""
-    import os
-
-    forced = int(os.environ.get(env_var, "0"))
-    if forced > 0 and bh % forced == 0:
-        return forced
     for g in (8, 6, 4, 3, 2, 1):
         if bh % g == 0 and fits(g):
             return g
@@ -120,7 +109,7 @@ def _pick_group(bh, s, bq, d, full_bias):
         sc = 3 * bq * min(s, 512) * 4    # per-head f32 score temporaries
         return kv + qo + sc <= _VMEM_BUDGET
 
-    return _scan_groups(bh, "PADDLE_FLASH_GROUP", fits)
+    return _scan_groups(bh, fits)
 
 
 # lse, delta, the pre-broadcast key bias and its gradient all ride as
@@ -831,7 +820,7 @@ def _pick_group_bwd(bh, s, bq, d, full_bias):
         blocks = 16 * g * min(s, bq) * d
         return fulls + blocks + 7 * 1024 * 1024 <= 14 * 1024 * 1024
 
-    return _scan_groups(bh, "PADDLE_FLASH_GROUP_BWD", fits)
+    return _scan_groups(bh, fits)
 
 
 def _flash_bwd(res, g, *, sm_scale, num_heads, causal, dropout_prob,
@@ -1410,7 +1399,7 @@ def _flash_fwd_bsh_tile(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
     skv = k.shape[1]
     d = hdim // nh
     use_prng = dropout_prob > 0.0 and mask is None
-    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim, q.dtype)
+    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim)
     has_mask = mask is not None and dropout_prob > 0.0
     has_offsets = offsets is not None
     has_bias = bias is not None
@@ -1605,8 +1594,7 @@ def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob):
     skv = k.shape[1]
     d = hdim // nh
     use_prng = dropout_prob > 0.0 and mask is None
-    bq, bk, vmem_limit = _resolve_bsh_blocks(
-        sq, skv, hdim, q.dtype, bwd=True)
+    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim, bwd=True)
     has_mask = mask is not None and dropout_prob > 0.0
     has_offsets = offsets is not None
     has_bias = bias is not None
@@ -2163,9 +2151,8 @@ def _make_fwd_bsh_stream_kernel(*, sm_scale, causal, dropout_prob, has_bias,
 
 
 def default_bsh_block(s, skv, h, bwd=False):
-    """THE hand-picked BSH DMA-tile chooser (the autotune cache-miss
-    fallback — tuning/search.py replaces it per shape when a measured
-    winner exists; see _resolve_bsh_blocks).
+    """THE BSH DMA-tile chooser: a function of the operands' shapes and
+    of nothing else.
 
     Below _STREAM_FROM the whole-tile kernels run and the tile is
     _pick_block's, for both passes alike (their in-kernel PRNG seeds a
@@ -2175,17 +2162,12 @@ def default_bsh_block(s, skv, h, bwd=False):
     one does (with q^T / do^T / dq^T of the whole batch row); the
     arithmetic runs over compute tiles inside it (_compute_tile), so the
     tile decides only how often a cell's set-up is paid and how long one
-    stream of steps runs: the largest that fits (tuning/feasible.py).
+    stream of steps runs: the largest that fits (feasible.py).
     At s4096 / h768, 1024 against 512: 17.62 against 18.17 ms a layer
     (my chip run, PR 27; 0.4266 against 0.4240 MFU before compute
     tiles). The two passes may tile differently there: the dropout mask
     is a function of absolute positions (_dropout_keep_t), lse and
     delta ride as full [B, nh, S] arrays."""
-    import os
-
-    forced = int(os.environ.get("PADDLE_FLASH_BLOCK", "0"))
-    if forced >= MIN_BLOCK and s % forced == 0:
-        return forced
     if not _bsh_streams(s, skv):
         return _pick_block(s)
     need = (_feas.flash_bsh_bwd_vmem_bytes if bwd
@@ -2196,40 +2178,9 @@ def default_bsh_block(s, skv, h, bwd=False):
     return _pick_block(s)
 
 
-_pick_block_bsh = default_bsh_block  # historical name (round-5 sweeps)
-
-
-def _resolve_bsh_blocks(sq, skv, h, dtype, *, bwd=False):
+def _resolve_bsh_blocks(sq, skv, h, *, bwd=False):
     """(bq, bk, vmem_limit_bytes) for one BSH kernel launch: the forward
-    uses bq, the backward bk.
-
-    Precedence: PADDLE_FLASH_BLOCK env override (hand sweeps) >
-    FLAGS_kernel_autotune cache entry > default_bsh_block heuristic.
-    One cache entry serves fwd AND bwd, so a cached config is validated
-    against BOTH footprint models before it is trusted; an invalid or
-    missing entry falls back to the hand-picked chooser — no behavior
-    cliff."""
-    import os
-
-    key = {"sq": sq, "skv": skv, "h": h, "dtype": str(dtype)}
-    if not int(os.environ.get("PADDLE_FLASH_BLOCK", "0")):
-        from ... import tuning
-
-        cfg = tuning.maybe_lookup("flash_bsh", key)
-        if cfg:
-            try:
-                bq = int(cfg.get("bq", 0))
-                bk = int(cfg.get("bk", 0))
-                limit = (int(cfg["vmem_limit_mb"]) * 2**20
-                         if cfg.get("vmem_limit_mb") else _BSH_VMEM_LIMIT)
-            except (TypeError, ValueError):
-                bq = bk = 0
-                limit = _BSH_VMEM_LIMIT
-            ok, _why = _feas.flash_bsh_ok(sq, skv, h, bq, bk, limit=limit)
-            if ok:
-                return bq, bk, limit
-            # bad entry (edited by hand / stale shape): hand-picked path
-            tuning.note_choice("flash_bsh", key, None, "default")
+    uses bq, the backward bk."""
     return (
         default_bsh_block(sq, skv, h, bwd=bwd),
         default_bsh_block(skv, skv, h, bwd=bwd),
@@ -2243,7 +2194,7 @@ def _flash_fwd_bsh_stream(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
     skv = k.shape[1]
     d = hdim // nh
     use_prng = dropout_prob > 0.0 and mask is None
-    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim, q.dtype)
+    bq, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim)
     has_mask = mask is not None and dropout_prob > 0.0
     has_offsets = offsets is not None
     has_bias = bias is not None
@@ -2613,8 +2564,7 @@ def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob):
     skv = k.shape[1]
     d = hdim // nh
     use_prng = dropout_prob > 0.0 and mask is None
-    _, bk, vmem_limit = _resolve_bsh_blocks(
-        sq, skv, hdim, q.dtype, bwd=True)
+    _, bk, vmem_limit = _resolve_bsh_blocks(sq, skv, hdim, bwd=True)
     has_mask = mask is not None and dropout_prob > 0.0
     has_offsets = offsets is not None
     has_bias = bias is not None
@@ -2706,19 +2656,15 @@ def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob):
 # below what the hardware allows, so raise it for these calls. Past the
 # estimate below, dispatch falls back to the BHSD kernels (streamed
 # blocks, head-transposed layout) — and beyond single-chip HBM, shard
-# the sequence (ring attention over "sp") instead. The byte value lives
-# in tuning/feasible.py so the autotuner's feasibility gate and the
-# kernel can never disagree about the budget.
+# the sequence (ring attention over "sp") instead.
 _BSH_VMEM_LIMIT = _feas.BSH_VMEM_LIMIT
 
 
 def bsh_shapes_ok(sq, skv, h) -> bool:
     """Will the BSH kernels' whole-sequence VMEM residency fit, at the
-    smallest tiles the chooser falls back to? tuning/feasible.py's
-    models of both passes, calibrated against Mosaic's allocation."""
-    fwd = _feas.flash_bsh_fwd_vmem_bytes(sq, skv, h, MIN_BLOCK, MIN_BLOCK)
-    bwd = _feas.flash_bsh_bwd_vmem_bytes(sq, skv, h, MIN_BLOCK, MIN_BLOCK)
-    return max(fwd, bwd) <= _BSH_VMEM_LIMIT
+    smallest tiles the chooser falls back to? feasible.py's models of
+    both passes, calibrated against Mosaic's allocation."""
+    return _feas.flash_bsh_ok(sq, skv, h, MIN_BLOCK, MIN_BLOCK)[0]
 
 
 def bsh_dispatch_ok(sq, skv, h, num_heads, bias=None, batch=None,
@@ -2745,20 +2691,6 @@ def bsh_dispatch_ok(sq, skv, h, num_heads, bias=None, batch=None,
         return False
     return (bn == 1 and bq_ == 1 and bk_ == skv
             and (batch is None or bb == batch))
-
-
-def _bsh_mask_materialize(sq, skv, h, dtype) -> bool:
-    """The tuned dropout-mask axis (ISSUE 13): an autotune cache entry
-    with {'mask': 'materialize'} precomputes the [B, nh, Sq, Skv] keep
-    mask with the traced PRNG (one HBM-resident tensor read by both
-    passes; the search harness's HBM gate rejects it where it cannot
-    fit) instead of regenerating it from the in-kernel hardware PRNG.
-    Identical dropout MATH either way — only the mask's source moves."""
-    from ... import tuning
-
-    cfg = tuning.maybe_lookup(
-        "flash_bsh", {"sq": sq, "skv": skv, "h": h, "dtype": str(dtype)})
-    return bool(cfg) and cfg.get("mask") == "materialize"
 
 
 # the S from which the stream kernels run (my chip run, PR 27, BERT-base
@@ -2851,13 +2783,10 @@ def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
                 dtype=jnp.int32)
         else:
             raise ValueError("dropout needs dropout_key or dropout_seed")
-        # mask source is a tuned axis: interpret mode (the CPU tests'
-        # oracle draws it with jax.random) and a cache entry saying
-        # {'mask': 'materialize'} both precompute the keep mask outside
-        # the kernel; the default regenerates it in the kernel with
-        # zero HBM traffic
-        if _interpret() or _bsh_mask_materialize(sq, k.shape[1], hdim,
-                                                 q.dtype):
+        # interpret mode (the CPU tests' oracle) draws the keep mask
+        # with jax.random outside the kernel; on the chip the kernel
+        # regenerates it with zero HBM traffic
+        if _interpret():
             mkey = dropout_key if dropout_key is not None else (
                 jax.random.PRNGKey(seed[0]))
             mask = jax.random.bernoulli(

@@ -223,8 +223,8 @@ class CostReport:
     def top(self, k: int = 20) -> List[CostRow]:
         return sorted(self.rows, key=lambda r: -r.device_ms)[:k]
 
-    # -- programmatic per-op queries (ISSUE 13: the autotuner ranks a
-    # candidate by ITS OWN measured device time, not the whole step's) --
+    # -- programmatic per-op queries (tools/op_bench.py reports an op's
+    # OWN measured device time, not the whole step's) --
     def rows_for(self, op_type: Optional[str] = None,
                  op_index: Optional[int] = None) -> List[CostRow]:
         """Attributed rows filtered by op type and/or Program IR op

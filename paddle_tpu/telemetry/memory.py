@@ -2,8 +2,8 @@
 
 The observability stack answers "where did the TIME go" (cost.py /
 proftop); this module answers "where did the MEMORY go" — the question
-behind every OOM, every remat decision, and the SPMD/autotuner items
-(both must rank candidates by fit before ranking them by speed):
+behind every OOM, every remat decision, and the SPMD items (which
+must rank candidates by fit before ranking them by speed):
 
   1. The static side: fluid/analysis/liverange.py computes first-def/
      last-use, byte size and category (params / optimizer_state /

@@ -19,8 +19,6 @@ setup(
     packages=find_packages(include=["paddle_tpu", "paddle_tpu.*"]),
     package_data={
         "paddle_tpu.native": ["*.cc", "*.h"],
-        # checked-in per-chip autotune winners (tuning/cache.py layer 1)
-        "paddle_tpu.tuning": ["defaults/*.json"],
     },
     python_requires=">=3.10",
     install_requires=[

@@ -210,7 +210,8 @@ def test_bsh_compute_tiles_forward_and_grads(monkeypatch, sq, skv, causal,
                                              with_bias, block, tile):
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    monkeypatch.setenv("PADDLE_FLASH_BLOCK", str(block))
+    monkeypatch.setattr(fa, "default_bsh_block",
+                        lambda s, skv_, h, bwd=False: block)
     monkeypatch.setattr(fa, "_STREAM_FROM", 128)
     monkeypatch.setattr(fa, "_CQ", tile)
     monkeypatch.setattr(fa, "_CK", tile)
@@ -290,9 +291,9 @@ def test_bsh_dropout_mask_is_the_same_under_any_tiles(
     monkeypatch.setattr(fa, "_CK", tile)
     blocks = fa._resolve_bsh_blocks
 
-    def resolve(sq_, skv_, h, dtype, *, bwd=False):
+    def resolve(sq_, skv_, h, *, bwd=False):
         block = bwd_block if bwd else fwd_block
-        return block, block, blocks(sq_, skv_, h, dtype, bwd=bwd)[2]
+        return block, block, blocks(sq_, skv_, h, bwd=bwd)[2]
 
     monkeypatch.setattr(fa, "_resolve_bsh_blocks", resolve)
     q, k, v = _mk(sq, skv, seed=12)

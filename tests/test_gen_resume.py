@@ -27,8 +27,7 @@ for (one extra decode_step shape for the small "pressure" pool):
     DeadlineExceededError, ResumedOnNewWeightsError (with the partial
     tokens attached across a failover)
   * servetop RESUME/PREEMPT columns
-  * paged_attention autotune target: candidate enumeration + VMEM
-    gate, searcher round-trip, kv_cache.from_budget page-size lookup
+  * kv_cache.from_budget's page size: argument, serving env, default
   * bench.py goodput-delta row fields
 
 Slow lane (tools/ci.sh serving drills):
@@ -740,86 +739,19 @@ def test_servetop_resume_preempt_columns():
 
 
 # ---------------------------------------------------------------------------
-# paged_attention autotune target
+# the pool's page size: argument, then the serving env, then the default
 # ---------------------------------------------------------------------------
 
 
-def test_paged_attention_candidates_and_vmem_gate():
-    from paddle_tpu.tuning import configs, feasible
-
-    ok, rejects = configs.paged_attention_candidates(2, 8, "float32",
-                                                     max_seq=32)
-    # largest page first (fewest grid steps) — the deterministic
-    # tie-break order; 64 can never fill a 32-position sequence
-    assert [c["page_size"] for c in ok] == [32, 16, 8]
-    assert rejects and rejects[0][0] == {"page_size": 64}
-    assert "max_seq" in rejects[0][1]
-    # the footprint model is monotone in the page size, and the budget
-    # gate turns an oversized page into a reject with the estimate
-    small = feasible.paged_attention_vmem_bytes(8, 2, 8)
-    big = feasible.paged_attention_vmem_bytes(64, 2, 8)
-    assert small < big
-    feas, why = feasible.paged_page_ok(64, 2, 8, budget=1024)
-    assert not feas and "VMEM" in why
-    assert feasible.paged_page_ok(1, 2, 8)[0]
-    assert not feasible.paged_page_ok(0, 2, 8)[0]
-
-
-def test_paged_autotune_target_search_round_trip():
-    from paddle_tpu.tuning.cache import TuningCache, canonical_key
-    from paddle_tpu.tuning.search import Searcher, mock_measure
-
-    sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
-    try:
-        import autotune
-    finally:
-        sys.path.pop(0)
-    (t,) = autotune._paged_targets("2:32:2:8", "float32")
-    assert t.kernel == "paged_attention"
-    assert t.spec["kind"] == "paged_attention"
-    # the cache key deliberately omits batch/seq: the winner is a pool
-    # geometry property kv_cache.from_budget looks up by model shape
-    assert t.canonical == canonical_key(
-        {"kv_heads": 2, "head_dim": 8, "dtype": "float32"})
-    cache = TuningCache("cpu")
-    s = Searcher(cache, mock_measure, log=lambda m: None)
-    res = s.search(t)
-    assert res.winner["page_size"] in (32, 16, 8)
-    entry = cache.get("paged_attention", t.canonical)
-    assert entry["config"] == res.winner
-    # the smoke lane exercises the target end to end in CI
-    assert any(x.kernel == "paged_attention"
-               for x in autotune._smoke_targets())
-
-
-def test_kv_pool_from_budget_consults_tuned_page_size(monkeypatch):
-    from paddle_tpu import tuning
-    from paddle_tpu.tuning.cache import canonical_key
-
-    key = canonical_key({"kv_heads": 2, "head_dim": 8,
-                         "dtype": "float32"})
+def test_kv_pool_from_budget_page_size_precedence(monkeypatch):
     mk = dict(n_layers=1, kv_heads=2, head_dim=8, n_pages=4,
               allocate=False)
-    fl.set_flags({"FLAGS_kernel_autotune": True})
-    try:
-        with tuning.override({"paged_attention": {key: {"page_size": 8}}}):
-            assert PagedKVPool.from_budget(**mk).page_size == 8
-            # an explicit argument or env pin always beats the cache
-            assert PagedKVPool.from_budget(page_size=4,
-                                           **mk).page_size == 4
-            monkeypatch.setenv(kvmod.ENV_KV_PAGE_SIZE, "32")
-            assert PagedKVPool.from_budget(**mk).page_size == 32
-            monkeypatch.delenv(kvmod.ENV_KV_PAGE_SIZE)
-        # no cache entry for this shape: silent fall-through
-        with tuning.override({}):
-            assert PagedKVPool.from_budget(**mk).page_size \
-                == kvmod._DEFAULT_PAGE_SIZE
-    finally:
-        fl.set_flags({"FLAGS_kernel_autotune": False})
-    # flag off: the lookup never runs even with a populated cache
-    with tuning.override({"paged_attention": {key: {"page_size": 8}}}):
-        assert PagedKVPool.from_budget(**mk).page_size \
-            == kvmod._DEFAULT_PAGE_SIZE
+    monkeypatch.delenv(kvmod.ENV_KV_PAGE_SIZE, raising=False)
+    assert PagedKVPool.from_budget(**mk).page_size \
+        == kvmod._DEFAULT_PAGE_SIZE
+    monkeypatch.setenv(kvmod.ENV_KV_PAGE_SIZE, "32")
+    assert PagedKVPool.from_budget(**mk).page_size == 32
+    assert PagedKVPool.from_budget(page_size=4, **mk).page_size == 4
 
 
 # ---------------------------------------------------------------------------

@@ -222,11 +222,22 @@ def test_launch_straggler_drill_logs_structured_event(tmp_path):
         step = [0]
         heartbeat.set_step_provider(lambda: (step[0], None))
         hb = heartbeat.start_heartbeat(interval=0.1)
-        per_step = 0.02 if rank == 0 else 0.25  # rank 1 drags >10x
-        for _ in range(24):
-            time.sleep(per_step)
-            step[0] += 1
-        time.sleep(0.3)  # one more beat with the final count
+        done = os.environ["DRILL_SLOW_RANK_DONE"]
+        if rank == 0:
+            # the fast rank steps for as long as the slow one lives (not
+            # for a fixed 24 x 0.02 s, which a loaded host's launcher can
+            # miss between two polls): the detector needs two samples of
+            # a peer's progress before it can call anyone slow
+            deadline = time.time() + 90
+            while not os.path.exists(done) and time.time() < deadline:
+                time.sleep(0.02)
+                step[0] += 1
+        else:
+            for _ in range(24):
+                time.sleep(0.25)  # drags >10x
+                step[0] += 1
+            time.sleep(0.3)  # one more beat with the final count
+            open(done, "w").close()
         hb.stop()
         """
     ))
@@ -236,7 +247,8 @@ def test_launch_straggler_drill_logs_structured_event(tmp_path):
         str(script),
     ]
     env = dict(os.environ, PYTHONPATH=REPO, REPO=REPO,
-               PADDLE_HEARTBEAT_DIR=str(hb_dir))
+               PADDLE_HEARTBEAT_DIR=str(hb_dir),
+               DRILL_SLOW_RANK_DONE=str(tmp_path / "slow_rank_done"))
     r = subprocess.run(cmd, env=env, capture_output=True, text=True,
                        timeout=120)
     assert r.returncode == 0, (r.returncode, r.stderr)

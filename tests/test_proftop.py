@@ -544,3 +544,52 @@ def test_sink_placeholder_falls_back_to_pid(monkeypatch):
     monkeypatch.setenv("PADDLE_TRAINER_ID", "2")
     assert _expand("/tmp/m.{rank}.jsonl", 2) == "/tmp/m.2.jsonl"
     assert _expand("/tmp/m.jsonl", 2) == "/tmp/m.rank2.jsonl"
+
+
+# ---------------------------------------------------------------------------
+# tools/op_bench.py (the one-op measurement path) and the cost report's
+# per-op query it reads
+# ---------------------------------------------------------------------------
+
+
+def test_op_bench_run_case_schema_and_sweep():
+    op_bench = _load_tool("op_bench")
+
+    row = op_bench.run_case("matmul", {"X": (8, 8), "Y": (8, 8)}, {},
+                            repeat=2, op_profile=False)
+    assert row["op"] == "matmul" and row["fenced"] is True
+    assert row["latency_us"] > 0 and row["repeat"] == 2
+    combos = list(op_bench.sweep_cases(
+        [("X", [(8, 8), (16, 16)]), ("Y", [(8, 8)])]))
+    assert combos == [{"X": (8, 8), "Y": (8, 8)},
+                      {"X": (16, 16), "Y": (8, 8)}]
+
+
+def test_op_bench_op_profile_objective():
+    op_bench = _load_tool("op_bench")
+
+    row = op_bench.run_case("matmul", {"X": (32, 32), "Y": (32, 32)}, {},
+                            repeat=2, op_profile=True, op_profile_steps=2)
+    # the op's OWN attributed device time, not the whole step's
+    assert row["op_device_us"] > 0
+    assert 0 < row["op_profile_coverage"] <= 1.0
+
+
+def test_cost_report_per_op_query():
+    from paddle_tpu.telemetry.cost import CostReport, CostRow
+
+    rows = [
+        CostRow(scope="op0:matmul", op_index=0, op_type="matmul",
+                device_ms=6.0, share=0.6, count=2, fused=False),
+        CostRow(scope="op1:softmax", op_index=1, op_type="softmax",
+                device_ms=4.0, share=0.4, count=2, fused=False),
+    ]
+    rep = CostReport(rows=rows, by_op_type={}, by_layer={}, framework={},
+                     unattributed={}, steps=2, total_op_ms=10.0,
+                     attributed_ms=10.0, coverage=1.0,
+                     device_ms_per_step=5.0)
+    assert rep.device_ms_for(op_type="matmul") == 3.0  # per step
+    assert rep.device_ms_for(op_type="matmul", per_step=False) == 6.0
+    assert rep.device_ms_for(op_index=1) == 2.0
+    assert rep.device_ms_for(op_type="missing") == 0.0
+    assert len(rep.rows_for(op_type="softmax")) == 1

@@ -10,9 +10,8 @@ the per-category breakdown (params / optimizer_state / gradients /
 feeds / activations), attribution coverage, and the what-if levers.
 
 `--budget <bytes>` turns memtop into a gate: exit 2 when the static
-peak estimate exceeds the budget — the hook CI and the autotuner's
-feasibility pre-check both consume this (a candidate that cannot fit
-VMEM/HBM must be rejected before it is ever timed).
+peak estimate exceeds the budget — the hook CI consumes (a program
+that cannot fit HBM is rejected before it is ever run).
 
 Examples:
 
@@ -97,7 +96,7 @@ def main(argv=None) -> int:
     ap.add_argument("--budget", type=int, default=None,
                     help="HBM budget in BYTES: exit "
                     f"{EXIT_OVER_BUDGET} when the static peak estimate "
-                    "exceeds it (the CI / autotuner feasibility gate)")
+                    "exceeds it (the CI feasibility gate)")
     ap.add_argument("--static-only", action="store_true",
                     help="skip the measured join (no compile, no "
                     "backend needed): live-range pass only")

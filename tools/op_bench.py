@@ -27,14 +27,9 @@ timed loop runs under FLAGS_benchmark (the sync fence — every
 dispatch blocks until the device finishes, so per-iteration latency is
 honest); --no-fence restores the async-dispatch loop. --op-profile
 additionally traces a few steps under FLAGS_op_profile and reports the
-op's OWN attributed device time (telemetry/cost.py) — the objective
-the kernel autotuner ranks candidates by. --flag sets FLAGS_* before
-the run (flag-gated kernels: FLAGS_conv_dw_im2col, FLAGS_use_fused_ln,
-FLAGS_kernel_autotune, ...).
-
-This module is also the LIBRARY the autotuner and CI share
-(tools/autotune.py imports run_case) so there is exactly one
-measurement path.
+op's OWN attributed device time (telemetry/cost.py). --flag sets
+FLAGS_* before the run (flag-gated kernels: FLAGS_conv_dw_im2col,
+FLAGS_use_fused_ln, ...).
 """
 import argparse
 import itertools
@@ -107,9 +102,9 @@ def run_case(op_type, shapes, attrs, out_slot="Out", repeat=100, warmup=1,
     fence=True wraps the timed loop in FLAGS_benchmark so each run()
     blocks until the device finishes. op_profile=True re-runs a few
     steps under FLAGS_op_profile and adds `op_device_us` — the op's own
-    attributed per-step device time from telemetry/cost.py, the
-    autotuner's ranking objective (0.0 when the backend produced no
-    attributable device events; callers fall back to latency_us)."""
+    attributed per-step device time from telemetry/cost.py (0.0 when
+    the backend produced no attributable device events; callers fall
+    back to latency_us)."""
     import jax
 
     import paddle_tpu.fluid as fluid
@@ -189,7 +184,7 @@ def main():
     ap.add_argument("--op-profile", action="store_true",
                     help="also report the op's own attributed device "
                     "time per step (FLAGS_op_profile + "
-                    "telemetry/cost.py) — the autotuner objective")
+                    "telemetry/cost.py)")
     ap.add_argument("--flag", action="append", default=[],
                     help="FLAGS_name=value set before the run")
     args = ap.parse_args()
