@@ -195,6 +195,18 @@ def record_cache_hit() -> None:
     _counter("executor_cache_hits_total", "compile-cache hits").inc()
 
 
+def record_grouped_product_lowering(impl: str, form: str) -> None:
+    """Called by ops/pallas/grouped_matmul.py each time a grouped product
+    of `moe_swiglu` is traced into a step: `impl` is what was lowered
+    (`pallas`, the repo's kernel, or `ragged_dot`, XLA's own), `form` the
+    product (`nn`) or its transpose (`nt` input gradient, `tn` weight
+    gradient). A lowering-time counter: it moves when a step is built,
+    never while one runs."""
+    _reg.counter("moe_grouped_product_lowerings_total",
+                 help="grouped expert products traced, by implementation "
+                      "and form", impl=impl, form=form).inc()
+
+
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

@@ -17,15 +17,31 @@ Two ops, two formulations, and which model takes which:
   weights' leading axis) while its router keeps the published width: it
   sorts the (token, pick) pairs that fall on its own experts by expert,
   runs one grouped product a projection over those groups
-  (`jax.lax.ragged_dot`: on the TPU XLA lowers it to its own grouped-matmul
-  kernel whose grid follows the rows present) and returns the held
+  (`ops/pallas/grouped_matmul.py`, below) and returns the held
   experts' part of the layer's result. Nothing is dropped at any
   imbalance, there is no `[T, E, C]` tensor and no capacity; on one chip
   there is no exchange and nothing stands in for the absent chips.
   `models/lfm2_moe.py` builds it.
 
-  **The sorted block's rows.** XLA's kernel follows the rows present;
-  every gather, mask and element-wise pass beside it follows the buffer.
+  **Who multiplies.** The three products of a block and their transposes
+  (input and weight gradients) go through `grouped_matmul`, one
+  `jax.custom_vjp` a product. On the TPU, for bf16 or float32 rows whose
+  lane dimensions are multiples of 128 and whose row count a row tile
+  divides, each of the three forms runs a Pallas kernel written for the
+  chip (`moe_gmm_nn`, `moe_gmm_nt`, `moe_gmm_tn`; tiles from the operands'
+  shapes alone). On every other backend, and at every other shape, the
+  CPU tests and the benchmark's `--rehearse` included, each form is
+  `jax.lax.ragged_dot` or its transpose as autodiff writes it:
+  `ragged_dot` stays in the tree as the kernels' `jnp` composition, as
+  what XLA lowers to a grouped-matmul kernel of its own on the TPU where
+  the gate refuses, and as two of the three forms of the dropless
+  fallback below (`_FULL_WIDTH_KERNELS` says which and why). At the LFM2
+  cell's operands XLA's kernel took 1.27-1.60 ms a call and the Pallas
+  ones 0.80-0.91 (PERF.md, PR 31). Both follow the rows present, not the
+  buffer, and neither reads nor writes a row behind the last group.
+
+  **The sorted block's rows.** The products follow the rows present;
+  every gather, mask and element-wise pass beside them follows the buffer.
   So the block is not T * k rows, the worst imbalance, but
   `sorted_rows`: twice what uniform routing sends this share
   (`2 * T * k * E_held / E`, up to a multiple of 512), read from the
@@ -293,26 +309,34 @@ def _combine_bwd(saved, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _grouped_swiglu(xs, w1, w3, w2, group_sizes):
+def _grouped_swiglu(xs, w1, w3, w2, group_sizes, kernels=None):
     """The three grouped products and the activation: rows of `xs` lie
     sorted by expert, `group_sizes[e]` of them for expert e, and what lies
-    behind the last group is computed by nobody."""
-    a = jax.lax.ragged_dot(xs, w1.astype(xs.dtype), group_sizes)
-    g = jax.lax.ragged_dot(xs, w3.astype(xs.dtype), group_sizes)
+    behind the last group is computed by nobody. `grouped_matmul` is the
+    Pallas kernel where its gate serves the operands (the TPU, lane
+    dimensions in multiples of 128) and `jax.lax.ragged_dot` elsewhere,
+    forward and transposed alike; `kernels` names the forms that may take
+    the kernel (None: all three)."""
+    # imported here: a program without this op never loads Pallas
+    from .pallas.grouped_matmul import FORMS, grouped_matmul
+
+    product = functools.partial(grouped_matmul, kernels=kernels or FORMS)
+    a = product(xs, w1.astype(xs.dtype), group_sizes)
+    g = product(xs, w3.astype(xs.dtype), group_sizes)
     inter = (jax.nn.silu(a.astype(jnp.float32))
              * g.astype(jnp.float32)).astype(xs.dtype)
-    return jax.lax.ragged_dot(inter, w2.astype(xs.dtype), group_sizes)
+    return product(inter, w2.astype(xs.dtype), group_sizes)
 
 
 def _sorted_block(rows, x2, gates, w1, w3, w2, order, slot, mine,
-                  group_sizes):
+                  group_sizes, kernels=None):
     """The held experts' part of the layer out of a sorted block of `rows`
     rows, which has to hold every pair on a held expert: gather the pairs'
     tokens in sorted order, three grouped products, weigh and sum back by
     token. `order` sorts the T * k pairs by held expert (the others
     behind), `slot` [T, k] is its inverse, `mine` [T, k] the pairs on held
     experts. A pair whose slot lies behind `rows` is on nobody's expert
-    here and is masked as `mine` masks."""
+    here and is masked as `mine` masks. `kernels`: `_grouped_swiglu`'s."""
     top_k = gates.shape[1]
     with jax.named_scope("moe_dispatch"):
         head = order[:rows]
@@ -324,15 +348,29 @@ def _sorted_block(rows, x2, gates, w1, w3, w2, order, slot, mine,
         xs = jnp.where(present[:, None],
                        _gather_rows(x2, head // top_k, slot, placed), 0)
     with jax.named_scope("moe_experts"):
-        ys = _grouped_swiglu(xs, w1, w3, w2, group_sizes)
+        ys = _grouped_swiglu(xs, w1, w3, w2, group_sizes, kernels)
     with jax.named_scope("moe_combine"):
         return _combine(ys, gates, slot, placed, head, present)
+
+
+# The forms of the grouped product that run the Pallas kernel in the
+# dropless fallback. A form lowered at a second row count is two more
+# kernel bodies for every process to trace and lower (~0.1 s each on the
+# benchmark's host, in the step and again in the check program), for a
+# block that runs only when routing has left the rails; the product and
+# the weight gradient therefore keep XLA's `ragged_dot` there (it follows
+# the rows present too). The input gradient keeps the kernel: XLA's own
+# wants the weights in a second layout beside the one the bounded block's
+# kernels read, and the step's peak, which the fallback's T * k-row
+# buffers set, grows by 0.29 GB (PERF.md, PR 31).
+_FULL_WIDTH_KERNELS = ("nt",)
 
 
 def _full_width(x2, gates, *operands):
     """The dropless fallback: the same block at T * k rows."""
     with jax.named_scope("moe_full_width"):
-        return _sorted_block(gates.size, x2, gates, *operands)
+        return _sorted_block(gates.size, x2, gates, *operands,
+                             kernels=_FULL_WIDTH_KERNELS)
 
 
 def _fits(rows, group_sizes):
@@ -381,6 +419,10 @@ def moe_swiglu(ctx, ins, attrs):
     TokensPerExpert [E_held] int32, the rows each of them received, and
     ExpertBiasOut [E], the selection bias after the balancing rule
     (`balance_bias`), which a training program binds to ExpertBias itself.
+
+    The expert products are `ops/pallas/grouped_matmul.py`'s: Pallas
+    kernels on the TPU at shapes their gate serves, `jax.lax.ragged_dot`
+    everywhere else (the module docstring says why both stay).
 
     The sorted block has `sorted_rows(T * k, E_held, E)` rows. A layer that
     holds half of its router or more has T * k of them and lowers without a

@@ -168,3 +168,39 @@ def conv_bn_rows_ok(r: int, width: int, rows: int, bytes_per_row_unit: int,
     if est > budget:
         return False, f"VMEM estimate {est} > {budget}"
     return True, "ok"
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (ops/pallas/grouped_matmul.py)
+# ---------------------------------------------------------------------------
+
+
+# the chooser keeps a cell's estimate under this budget, and a call asks
+# Mosaic for its estimate and this slack, not for the core's 128 MiB: what
+# a call reserves, XLA cannot give to the buffers it keeps on the chip
+# around it
+GMM_VMEM_BUDGET = 64 * 1024 * 1024
+GMM_VMEM_SLACK = 8 * 1024 * 1024
+
+
+def gmm_vmem_bytes(form: str, tm: int, tk: int, tn: int, k: int, n: int,
+                   itemsize: int) -> int:
+    """Footprint of one grid cell of ops/pallas/grouped_matmul.py over
+    [G, k, n] matrices. nn: a [tm, k] block of rows, [k, tn] of the
+    group's matrix and the [tm, tn] result, each double-buffered, and the
+    float32 product before its cast; nt the same with [tk, n] of the
+    matrix and a [tm, tk] result. tn: [tm, tk] and [tm, tn] row blocks
+    and the [tk, tn] result double-buffered, the float32 [tk, tn]
+    accumulator, and the masked copies of the row blocks. Mosaic
+    allocated, in MiB, with the block in one product (described-v5e
+    compiles bisected on the limit, PR 31; this model in brackets): nn
+    bf16 (256, 2048, 1792) 19.1 (20.5), (512, 2048, 1792) 24.2 (26.0),
+    float32 (256, 2048, 1792) 35.9 (38.2); tn bf16 (256, 2048, 1792) 33.1
+    (36.5), (256, 2048, 896) 18.2 (20.8), float32 (256, 2048, 1792) 50.7
+    (58.0)."""
+    if form == "tn":
+        return (2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
+                + 2 * itemsize * tm * (tk + tn) + 2 ** 20)
+    rows_in, out = (k, tn) if form == "nn" else (n, tk)
+    return (2 * itemsize * (tm * rows_in + rows_in * out + tm * out)
+            + 4 * tm * out + 2 ** 20)

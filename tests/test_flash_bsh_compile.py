@@ -124,3 +124,36 @@ def test_traced_size_does_not_go_with_the_tile(traced, kernel):
     # below S 1024 the whole-tile kernels run, as they were
     assert _pallas_calls(
         traced["s512"].jaxpr.jaxpr, {})[kernel] == PARENT_EQNS[kernel]
+
+
+# ---------------------------------------------------------------------------
+# the grouped-matmul kernels of moe_swiglu (ops/pallas/grouped_matmul.py),
+# in this file because one process of a test run may describe the topology
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("k, n", [(2048, 1792), (1792, 2048)],
+                         ids=["w1_w3", "w2"])
+@pytest.mark.parametrize("form", ["nn", "nt", "tn"])
+def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
+                                                 form, k, n):
+    """The LFM2 cell's operands: 32,768 sorted rows against eight
+    [2048, 1792] (W1, W3) or [1792, 2048] (W2) matrices in bf16, at the
+    chooser's tiles: Mosaic takes the blocks, the transposed contractions
+    and the scoped-VMEM limit."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    rows, groups = 32768, 8
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    a = shaped(rows, n if form == "nt" else k)
+    b = shaped(rows, n) if form == "tn" else shaped(groups, k, n)
+    with mock.patch.object(gm, "_interpret", lambda: False):
+        assert gm.gmm_tiles(form, a, (groups, k, n)) == (256, k, n)
+        compiled = jax.jit(
+            lambda a, b, sizes: gm._run(form, a, b, sizes, (groups, k, n))
+        ).lower(a, b, shaped(groups, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert f"moe_gmm_{form}" in text and "tpu_custom_call" in text

@@ -15,7 +15,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.executor import Scope
-from paddle_tpu.ops import decoder_ops, moe_ops
+from paddle_tpu.ops import attention, decoder_ops, moe_ops
 
 INIT = fluid.initializer.TruncatedNormalInitializer(scale=0.3)
 
@@ -248,10 +248,10 @@ def _moe_ref(held):
     return fn
 
 
-def _moe_layer(held, remat=False, experts=E, bias=INIT):
+def _moe_layer(held, remat=False, experts=E, bias=INIT, inter=F):
     first, count = held
     return lambda v: (lambda r: (r[0], [r[1]]))(layers.moe_swiglu(
-        v, experts, F, experts_held=count, first_expert=first, top_k=K,
+        v, experts, inter, experts_held=count, first_expert=first, top_k=K,
         remat=remat, param_attr=fluid.ParamAttr(initializer=INIT),
         bias_attr=fluid.ParamAttr(initializer=bias), name="m"))
 
@@ -417,6 +417,33 @@ def test_a_balanced_share_runs_the_bounded_block_and_lowers_both():
                        and "moe_full_width" in n
                        and n.index("moe_full_width") < n.index(part)
                        for n in names if part in n)
+
+
+@pytest.mark.parametrize("fallen_back", [False, True])
+def test_the_grouped_matmul_kernels_agree_with_ragged_dot_through_the_op(
+        fallen_back):
+    """`moe_swiglu` through Program -> Executor at widths the kernels'
+    gate serves (128 lanes), the Pallas kernels pinned on (interpreted)
+    against the `ragged_dot` path: output, every gradient and
+    `TokensPerExpert`, in the bounded block and in the fallback."""
+    wide, inter = 128, 256
+    bias = np.random.RandomState(16).randn(NARROW).astype(np.float32) * 0.05
+    if fallen_back:
+        bias[:2] = 10.0
+    x = np.random.RandomState(17).randn(1, TOKENS, wide).astype(np.float32)
+    layer = _moe_layer(SHARE, remat=True, experts=NARROW, inter=inter,
+                       bias=fluid.initializer.NumpyArrayInitializer(bias))
+    lowered = []
+    want = _run(layer, {"x": x})
+    with mock.patch.object(attention, "FORCE_PALLAS", True):
+        got = _run(layer, {"x": x}, lowered=lowered)
+    assert {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} <= set(
+        re.findall(r"moe_gmm_[a-z]+", lowered[0]))
+    assert (got[4][0].sum() > BOUND) == fallen_back
+    assert got[4][0].tolist() == want[4][0].tolist()
+    assert set(got[1]) == set(want[1]) == {"m.gate", "m.w1", "m.w2", "m.w3",
+                                           "x"}
+    _assert_close(got[0], got[1], want[0], want[1], tol=1e-5)
 
 
 def _typed_tokens(rng, both, one):
