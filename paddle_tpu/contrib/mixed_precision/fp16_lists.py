@@ -49,6 +49,15 @@ white_list = {
     "short_conv",
     "swiglu_ffn",
     "moe_swiglu",
+    # latent attention and the hyper-connection ops (ops/latent_ops.py):
+    # products and the residual streams in bf16; the latent norms, the
+    # rotation, the three mappings with Sinkhorn and the mixing in f32
+    # inside the emitters
+    "shared_expert",
+    "mla",
+    "mhc_map",
+    "mhc_pre",
+    "mhc_post",
 }
 
 black_list = {

@@ -67,6 +67,12 @@ _KEEP_F32_SLOTS = {
     # the router scores in f32 over its own f32 weights; the selection
     # bias is a buffer that is compared, never multiplied
     "moe_swiglu": {"GateW", "ExpertBias"},
+    "mla": {"QANorm", "KVANorm"},
+    # the mappings are float32 end to end: their weights in, the three H
+    # out, and the H into the mixing
+    "mhc_map": {"Phi", "Bias", "Alpha"},
+    "mhc_pre": {"HPre"},
+    "mhc_post": {"HRes", "HPost"},
 }
 
 

@@ -799,11 +799,16 @@ class Executor:
         return self._lower_step(program, feed, fetch_list, scope).compile()
 
     def _lower_step(self, program=None, feed=None, fetch_list=None,
-                    scope=None, platforms=None):
+                    scope=None, platforms=None, sharding=None):
         """jax Lowered of the step aot_step() compiles. platforms (e.g.
         ("tpu",)) lowers for another backend than the process's own: the
         Pallas TPU lowering and its block-shape checks then run on a CPU
-        host, which is what tests/test_tpu_lowering.py relies on."""
+        host, which is what tests/test_tpu_lowering.py relies on.
+        sharding (a SingleDeviceSharding on a described device) is given
+        to every argument that has none, so that `.compile()` compiles
+        for a chip that is not attached; the scope may then hold
+        `jax.ShapeDtypeStruct`s in place of arrays, and nothing of the
+        program's size is allocated."""
         import jax
 
         if program is None:
@@ -849,12 +854,16 @@ class Executor:
         # over a mesh the state's avals carry their sharding, as the
         # arrays run() passes do: the trace — and with it the compiled
         # executable — is then the one run() already holds
-        sh = compiled.state_shardings.get
+        def sh(n):
+            return compiled.state_shardings.get(n) or sharding
+
         donated = {n: _abstract(states[n], sh(n))
                    for n in compiled.donate_names}
         kept = {n: _abstract(states[n], sh(n)) for n in compiled.keep_names}
-        feeds_abs = {n: _abstract(a) for n, a in feed_arrays.items()}
-        rng_abs = _abstract(rng, getattr(compiled, "repl_sharding", None))
+        feeds_abs = {n: _abstract(a, sharding)
+                     for n, a in feed_arrays.items()}
+        rng_abs = _abstract(
+            rng, getattr(compiled, "repl_sharding", None) or sharding)
         traced = compiled.fn.trace(feeds_abs, donated, kept, rng_abs)
         if platforms is None:
             return traced.lower()

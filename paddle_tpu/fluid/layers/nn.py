@@ -1111,30 +1111,39 @@ _rng_salt_counter = [0]
 
 def fused_multihead_attention(
     q, k, v, attn_bias=None, num_heads=1, dropout_prob=0.0, is_test=False,
-    causal=False, name=None
+    causal=False, softmax_scale=None, name=None
 ):
     """Fused scaled-dot-product attention over head-interleaved [B,S,H]
     tensors (TPU: Pallas flash attention; see ops/attention.py). The
     reference gets this via graph fusion passes (multihead_matmul_fuse_pass);
     here it is a first-class op. causal=True masks future positions
-    inside the kernel (block-level skipping of upper-triangular work)."""
+    inside the kernel (block-level skipping of upper-triangular work).
+
+    The scores are scaled by 1/sqrt(q's head width) unless `softmax_scale`
+    gives the factor. `v` may have heads of another width than `q` and `k`
+    ([B, S, num_heads * dv]); the result then has v's width. Either takes
+    the latent form of the op (`ops/attention.py:latent_attention`), which
+    has no bias and no dropout."""
     helper = LayerHelper("fused_multihead_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     _rng_salt_counter[0] += 1
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         inputs["BiasQK"] = [attn_bias]
+    attrs = {
+        "num_heads": num_heads,
+        "dropout_prob": dropout_prob,
+        "is_test": is_test,
+        "causal": bool(causal),
+        "rng_salt": _rng_salt_counter[0],
+    }
+    if softmax_scale is not None:
+        attrs["softmax_scale"] = float(softmax_scale)
     helper.append_op(
         type="fused_multihead_attention",
         inputs=inputs,
         outputs={"Out": [out]},
-        attrs={
-            "num_heads": num_heads,
-            "dropout_prob": dropout_prob,
-            "is_test": is_test,
-            "causal": bool(causal),
-            "rng_salt": _rng_salt_counter[0],
-        },
+        attrs=attrs,
     )
     return out
 
@@ -1231,7 +1240,7 @@ def rms_norm(input, epsilon=1e-5, group_size=None, param_attr=None, name=None):
     return out
 
 
-def rope(input, head_dim, theta=10000.0, name=None):
+def rope(input, head_dim, theta=10000.0, inv_freq=None, name=None):
     """Rotary position embedding on a head-interleaved [B, S, heads *
     head_dim] tensor (ops/decoder_ops.py), rotate-half pairing: inside
     every head, columns i and i + head_dim/2 are one pair,
@@ -1239,12 +1248,17 @@ def rope(input, head_dim, theta=10000.0, name=None):
         y_i        = x_i cos(t f_i) - x_{i+d/2} sin(t f_i)
         y_{i+d/2}  = x_{i+d/2} cos(t f_i) + x_i sin(t f_i),  f_i = theta^(-2i/d)
 
-    with t the index on axis 1. No parameter; rotation in float32."""
+    with t the index on axis 1. `inv_freq`, head_dim/2 numbers, replaces
+    the f_i where the model brings its own table (YaRN's blend, computed
+    once on the host). No parameter; rotation in float32."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"head_dim": int(head_dim), "theta": float(theta)}
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
     helper.append_op(
         type="rope", inputs={"X": [input]}, outputs={"Out": [out]},
-        attrs={"head_dim": int(head_dim), "theta": float(theta)})
+        attrs=attrs)
     return out
 
 
@@ -1285,7 +1299,11 @@ def swiglu_ffn(input, size, remat=False, param_attr=None, name=None):
     without biases. `remat=True` keeps only x for the backward pass and
     computes the [.., size] intermediates again there. Parameters
     `<name>.w1`, `<name>.w3`, `<name>.w2`."""
-    helper = LayerHelper("swiglu_ffn", param_attr=param_attr, name=name)
+    return _swiglu("swiglu_ffn", input, size, remat, param_attr, name)
+
+
+def _swiglu(op_type, input, size, remat, param_attr, name):
+    helper = LayerHelper(op_type, param_attr=param_attr, name=name)
     name = name or helper.name
     h = input.shape[-1]
     w1 = _named_param(helper, param_attr, name, "w1", [h, size], "float32")
@@ -1293,9 +1311,146 @@ def swiglu_ffn(input, size, remat=False, param_attr=None, name=None):
     w2 = _named_param(helper, param_attr, name, "w2", [size, h], "float32")
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(
-        type="swiglu_ffn",
+        type=op_type,
         inputs={"X": [input], "W1": [w1], "W3": [w3], "W2": [w2]},
         outputs={"Out": [out]}, attrs={"remat": bool(remat)})
+    return out
+
+
+def shared_expert(input, size, remat=False, param_attr=None, name=None):
+    """The expert every token passes, beside the routed ones of
+    `moe_swiglu`: a SwiGLU feed-forward of the experts' width, as
+    `swiglu_ffn`, lowered under the part scope `shared_expert`. In a
+    deployment that splits the routed experts over chips every chip
+    computes it alike, so it is counted once when shares are added up."""
+    return _swiglu("shared_expert", input, size, remat, param_attr, name)
+
+
+def mla(input, num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+        qk_rope_head_dim, v_head_dim, softmax_scale, epsilon=1e-6,
+        theta=10000.0, inv_freq=None, param_attr=None, name=None):
+    """Causal multi-head latent attention (ops/latent_ops.py), the whole
+    sublayer, for x [B, S, C], as DeepSeek-V2/V3 publish it:
+
+        c_q = RMSNorm(x W_qa)                       [q_lora_rank]
+        [q_nope_h, q_rope_h] = c_q W_qb             a head: 128 + 64
+        [c_kv, k_rope] = x W_kva                    [kv_lora_rank], [64]
+        [k_nope_h, v_h] = RMSNorm(c_kv) W_kvb       a head: 128 + 128
+        q_h = [q_nope_h, R(q_rope_h)],  k_h = [k_nope_h, R(k_rope)]
+        out = concat_h(softmax(q_h . k_h * softmax_scale, causal) v_h) W_o
+
+    R is the rotate-half rotary embedding at theta^(-2i/d) or at the
+    caller's `inv_freq` table; the one k_rope serves every head.
+    `num_heads` is the number of heads held here: W_qb and W_kvb have
+    their columns, W_o their rows, and the result is their part of the sum
+    over heads. Parameters `<name>.q_a_proj`, `.q_a_layernorm`, `.q_b_proj`,
+    `.kv_a_proj`, `.kv_a_layernorm`, `.kv_b_proj`, `.o_proj`."""
+    helper = LayerHelper("mla", param_attr=param_attr, name=name)
+    name = name or helper.name
+    c = input.shape[-1]
+    nh, nope, rot, dv = (int(num_heads), int(qk_nope_head_dim),
+                         int(qk_rope_head_dim), int(v_head_dim))
+
+    def matrix(suffix, shape):
+        return _named_param(helper, param_attr, name, suffix, shape, "float32")
+
+    def norm(suffix, width):
+        return helper.create_parameter(
+            ParamAttr(name=f"{name}.{suffix}"), shape=[width],
+            dtype="float32", default_initializer=ConstantInitializer(1.0))
+
+    inputs = {
+        "X": [input],
+        "QA": [matrix("q_a_proj", [c, int(q_lora_rank)])],
+        "QANorm": [norm("q_a_layernorm", int(q_lora_rank))],
+        "QB": [matrix("q_b_proj", [int(q_lora_rank), nh * (nope + rot)])],
+        "KVA": [matrix("kv_a_proj", [c, int(kv_lora_rank) + rot])],
+        "KVANorm": [norm("kv_a_layernorm", int(kv_lora_rank))],
+        "KVB": [matrix("kv_b_proj", [int(kv_lora_rank), nh * (nope + dv)])],
+        "O": [matrix("o_proj", [nh * dv, c])],
+    }
+    attrs = {"num_heads": nh, "qk_nope_head_dim": nope,
+             "qk_rope_head_dim": rot, "v_head_dim": dv,
+             "softmax_scale": float(softmax_scale),
+             "epsilon": float(epsilon), "theta": float(theta)}
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="mla", inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs)
+    return out
+
+
+def mhc_map(streams_in, streams, epsilon=1e-6, sinkhorn_iters=20,
+            clamp_min=-30.0, clamp_max=30.0, alpha_init=0.01,
+            param_attr=None, bias_attr=None, name=None):
+    """The three mappings of one sublayer under manifold-constrained
+    hyper-connections (ops/latent_ops.py), from the n = `streams` residual
+    streams X [B, S, n*C] (stream j the columns j*C .. (j+1)*C), a token
+    at a time and in float32:
+
+        xbar   = vec(X) / sqrt(mean(vec(X)^2) + epsilon)
+        H_pre  = sigmoid(a_pre xbar phi_pre + b_pre)            [n]
+        H_post = 2 sigmoid(a_post xbar phi_post + b_post)       [n]
+        H_res  = Sinkhorn(exp(clamp(a_res mat(xbar phi_res) + b_res)))  [n, n]
+
+    Sinkhorn: `sinkhorn_iters` rounds of rows over their sums, then columns
+    over theirs. Returns (H_pre [B, S, n], H_post [B, S, n], H_res [B, S,
+    n*n] row-major, gap [n]: the worst distance of row i's or column i's
+    sum from 1 over the tokens, fetchable to see whether the rounds
+    sufficed). Parameters `<name>.phi` [n*C, 2n + n*n], `<name>.b`
+    [2n + n*n] (both in the order pre, post, res) and `<name>.alpha` [3]."""
+    helper = LayerHelper("mhc_map", param_attr=param_attr, name=name)
+    name = name or helper.name
+    n = int(streams)
+    width = 2 * n + n * n
+    phi = _named_param(helper, param_attr, name, "phi",
+                       [streams_in.shape[-1], width], "float32")
+    bias = _named_param(helper, bias_attr, name, "b", [width], "float32",
+                        default_initializer=ConstantInitializer(0.0))
+    alpha = helper.create_parameter(
+        ParamAttr(name=f"{name}.alpha"), shape=[3], dtype="float32",
+        default_initializer=ConstantInitializer(float(alpha_init)))
+    outs = {k: helper.create_variable_for_type_inference("float32")
+            for k in ("HPre", "HPost", "HRes")}
+    gap = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
+    helper.append_op(
+        type="mhc_map",
+        inputs={"X": [streams_in], "Phi": [phi], "Bias": [bias],
+                "Alpha": [alpha]},
+        outputs={**{k: [v] for k, v in outs.items()}, "SinkhornGap": [gap]},
+        attrs={"streams": n, "epsilon": float(epsilon),
+               "sinkhorn_iters": int(sinkhorn_iters),
+               "clamp_min": float(clamp_min), "clamp_max": float(clamp_max)})
+    return outs["HPre"], outs["HPost"], outs["HRes"], gap
+
+
+def mhc_pre(streams_in, h_pre=None, streams=None, name=None):
+    """u = H_pre X: a sublayer's input [B, S, C] out of the n residual
+    streams [B, S, n*C]. Without `h_pre`, the plain sum of the `streams`
+    streams (the model's readout)."""
+    helper = LayerHelper("mhc_pre", name=name)
+    out = helper.create_variable_for_type_inference(streams_in.dtype)
+    inputs = {"X": [streams_in]}
+    if h_pre is not None:
+        inputs["HPre"] = [h_pre]
+        streams = h_pre.shape[-1]
+    helper.append_op(type="mhc_pre", inputs=inputs, outputs={"Out": [out]},
+                     attrs={"streams": int(streams)})
+    return out
+
+
+def mhc_post(streams_in, y, h_res, h_post, name=None):
+    """X' = H_res X + H_post^T y: the streams after a sublayer whose output
+    is y [B, S, C]."""
+    helper = LayerHelper("mhc_post", name=name)
+    out = helper.create_variable_for_type_inference(streams_in.dtype)
+    helper.append_op(
+        type="mhc_post",
+        inputs={"X": [streams_in], "Y": [y], "HRes": [h_res],
+                "HPost": [h_post]},
+        outputs={"Out": [out]})
     return out
 
 

@@ -207,6 +207,18 @@ def record_grouped_product_lowering(impl: str, form: str) -> None:
                       "and form", impl=impl, form=form).inc()
 
 
+def record_attention_lowering(impl: str, form: str) -> None:
+    """Called by ops/attention.py each time the attention op (or `mla`,
+    which shares its code) is traced into a step: `impl` is what was
+    lowered (`pallas`, a flash kernel, or `jnp`, the composition), `form`
+    `mha` (one head width, the default scale) or `mla` (value heads of
+    another width than the query / key heads, or a given scale). A
+    lowering-time counter, like the grouped products'."""
+    _reg.counter("attention_lowerings_total",
+                 help="attention calls traced, by implementation and form",
+                 impl=impl, form=form).inc()
+
+
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

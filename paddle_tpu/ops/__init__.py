@@ -15,6 +15,7 @@ from . import (  # noqa: F401
     detection3_ops,
     detection_ops,
     encoder_stack,
+    latent_ops,
     manipulation,
     math_ops,
     misc_ops,

@@ -31,12 +31,14 @@ def _merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, s, nh * dh)
 
 
-def _reference_attention(q, k, v, bias, dropout_prob, deterministic, rng_key):
-    """jnp composition: [B,nh,S,dh] in, [B,nh,S,dh] out."""
+def _reference_attention(q, k, v, bias, dropout_prob, deterministic, rng_key,
+                         sm_scale=None):
+    """jnp composition: [B,nh,S,dh] in, [B,nh,S,dv] out; scores scaled by
+    1/sqrt(dh) unless `sm_scale` says otherwise."""
     dh = q.shape[-1]
     scores = jnp.einsum(
         "bnqd,bnkd->bnqk", q, k, preferred_element_type=jnp.float32
-    ) * (1.0 / math.sqrt(dh))
+    ) * (1.0 / math.sqrt(dh) if sm_scale is None else sm_scale)
     if bias is not None:
         scores = scores + bias.astype(scores.dtype)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -56,8 +58,68 @@ def _use_pallas(q):
     return flash_shapes_ok(q.shape[2], q.shape[-1])
 
 
+def _causal_bias(s):
+    import numpy as _np
+
+    return jnp.where(
+        _np.tril(_np.ones((s, s), bool)), 0.0, -1e30)[None, None, :, :]
+
+
+def _pad_heads(x3, num_heads, width):
+    """[B, S, nh * d] -> [B, S, nh * width], zeros behind every head."""
+    b, s, h = x3.shape
+    d = h // num_heads
+    if d == width:
+        return x3
+    x4 = x3.reshape(b, s, num_heads, d)
+    x4 = jnp.pad(x4, ((0, 0), (0, 0), (0, 0), (0, width - d)))
+    return x4.reshape(b, s, num_heads * width)
+
+
+def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
+                     mesh=None):
+    """Attention whose value heads need not be as wide as its query / key
+    heads and whose softmax scale is the caller's: the form latent
+    attention (MLA) takes, 192-wide q / k against 128-wide v under a scale
+    that YaRN multiplies. q3, k3 [B, S, nh * dqk], v3 [B, S, nh * dv] ->
+    [B, S, nh * dv]; no bias, no dropout.
+
+    Where the flash gates pass, every head is zero-padded to the next width
+    the BSH kernels run (192 and 128 -> 256) and the result sliced: a zero
+    column adds nothing to a score and a zero value column gives a zero
+    output column, so the result is that of the unpadded heads. The calls
+    carry names of their own (`flash_mla_causal_fwd` / `_bwd`). Elsewhere
+    the jnp composition."""
+    from ..fluid.monitor import record_attention_lowering
+    from .pallas.flash_attention import (HEAD_WIDTHS, bsh_dispatch_ok,
+                                         flash_attention_bsh)
+
+    b, sq, hq = q3.shape
+    skv = k3.shape[1]
+    dqk, dv = hq // num_heads, v3.shape[2] // num_heads
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(dqk)
+    width = next((w for w in HEAD_WIDTHS if w >= max(dqk, dv)), None)
+    if width is not None and bsh_dispatch_ok(
+            sq, skv, num_heads * width, num_heads, batch=b, causal=causal):
+        record_attention_lowering("pallas", "mla")
+        out = flash_attention_bsh(
+            _pad_heads(q3, num_heads, width), _pad_heads(k3, num_heads, width),
+            _pad_heads(v3, num_heads, width), None, num_heads=num_heads,
+            sm_scale=sm_scale, causal=causal, mesh=mesh, form="mla")
+        out = out.reshape(b, sq, num_heads, width)[..., :dv]
+        return out.reshape(b, sq, num_heads * dv)
+    record_attention_lowering("jnp", "mla")
+    out = _reference_attention(
+        _split_heads(q3, num_heads), _split_heads(k3, num_heads),
+        _split_heads(v3, num_heads), _causal_bias(sq) if causal else None,
+        0.0, True, None, sm_scale=sm_scale)
+    return _merge_heads(out)
+
+
 @register("fused_multihead_attention")
 def fused_multihead_attention(ctx, ins, attrs):
+    from ..fluid.monitor import record_attention_lowering
     from ..parallel.ring_attention import (
         key_bias_from_attn_bias,
         ring_attention_global,
@@ -70,6 +132,16 @@ def fused_multihead_attention(ctx, ins, attrs):
     dropout_prob = float(attrs.get("dropout_prob", 0.0))
     is_test = bool(attrs.get("is_test", False))
     causal = bool(attrs.get("causal", False))
+    sm_scale = float(attrs.get("softmax_scale", 0.0)) or None
+
+    if sm_scale is not None or v3.shape[2] != q3.shape[2]:
+        # a given scale or value heads of another width: the latent form
+        if bias is not None or (not is_test and dropout_prob > 0.0):
+            raise ValueError(
+                "fused_multihead_attention: a softmax_scale or a V of "
+                "another width than Q takes neither BiasQK nor dropout")
+        return {"Out": [latent_attention(q3, k3, v3, nh, sm_scale, causal,
+                                         mesh=ctx.mesh)]}
 
     if use_ring(ctx, attrs):
         # sequence-parallel ring attention over the "sp" mesh axis; probs
@@ -99,6 +171,7 @@ def fused_multihead_attention(ctx, ins, attrs):
                        causal=causal):
         from .pallas.flash_attention import flash_attention_bsh
 
+        record_attention_lowering("pallas", "mha")
         dkey = None
         if not is_test and dropout_prob > 0.0:
             dkey = ctx.salted_rng(int(attrs.get("rng_salt", 0)))
@@ -118,6 +191,7 @@ def fused_multihead_attention(ctx, ins, attrs):
     if _use_pallas(q) and q.shape[2] == k.shape[2]:
         from .pallas.flash_attention import flash_attention
 
+        record_attention_lowering("pallas", "mha")
         dkey = None
         if not is_test and dropout_prob > 0.0:
             dkey = ctx.salted_rng(int(attrs.get("rng_salt", 0)))
@@ -127,13 +201,9 @@ def fused_multihead_attention(ctx, ins, attrs):
             dropout_key=dkey, mesh=ctx.mesh,
         )
     else:
+        record_attention_lowering("jnp", "mha")
         if causal:
-            import numpy as _np
-
-            s = q.shape[2]
-            cmask = jnp.where(
-                _np.tril(_np.ones((s, s), bool)), 0.0, -1e30
-            )[None, None, :, :]
+            cmask = _causal_bias(q.shape[2])
             bias = cmask if bias is None else bias + cmask
         rng = None
         if not is_test and dropout_prob > 0.0:

@@ -40,12 +40,19 @@ def rms_norm(ctx, ins, attrs):
         return {"Y": [y.reshape(x.shape).astype(x.dtype)]}
 
 
-def rope_tables(seq_len: int, head_dim: int, theta: float):
+def rope_tables(seq_len: int, head_dim: int, theta: float, inv_freq=None):
     """cos and sin of position * theta^(-2i/d), [S, d/2], float32 from
-    float64: the table is exact to float32 rounding at any position."""
+    float64: the table is exact to float32 rounding at any position.
+    `inv_freq`, d/2 frequencies, stands in for theta^(-2i/d) where the
+    model brings its own (YaRN's blend)."""
     half = head_dim // 2
-    inv_freq = float(theta) ** (-np.arange(half, dtype=np.float64) * 2.0
-                                / head_dim)
+    if inv_freq is None:
+        inv_freq = float(theta) ** (-np.arange(half, dtype=np.float64) * 2.0
+                                    / head_dim)
+    inv_freq = np.asarray(inv_freq, np.float64)
+    if inv_freq.shape != (half,):
+        raise ValueError(
+            f"rope: {inv_freq.shape} frequencies for a head of {head_dim}")
     angle = np.arange(seq_len, dtype=np.float64)[:, None] * inv_freq
     return (np.cos(angle).astype(np.float32),
             np.sin(angle).astype(np.float32))
@@ -54,20 +61,27 @@ def rope_tables(seq_len: int, head_dim: int, theta: float):
 @register("rope")
 def rope(ctx, ins, attrs):
     """Rotary embedding with the rotate-half pairing (i, i + d/2) inside
-    every head of a [B, S, nh*d] tensor; position = index on axis 1."""
+    every head of a [B, S, nh*d] tensor; position = index on axis 1. The
+    attribute `inv_freq` (d/2 numbers) replaces theta^(-2i/d)."""
     x = ins["X"][0]
     d = int(attrs["head_dim"])
     theta = float(attrs.get("theta", 10000.0))
-    b, s, h = x.shape
+    h = x.shape[2]
     if h % d or d % 2:
         raise ValueError(f"rope: hidden {h} against an even head_dim {d}")
-    cos, sin = rope_tables(s, d, theta)
+    cos, sin = rope_tables(x.shape[1], d, theta, attrs.get("inv_freq"))
     with jax.named_scope("rope"):
-        xf = x.astype(jnp.float32).reshape(b, s, h // d, 2, d // 2)
-        x1, x2 = xf[..., 0, :], xf[..., 1, :]
-        c, sn = cos[None, :, None, :], sin[None, :, None, :]
-        y = jnp.stack([x1 * c - x2 * sn, x2 * c + x1 * sn], axis=-2)
-        return {"Out": [y.reshape(b, s, h).astype(x.dtype)]}
+        return {"Out": [rotate_half(x, d, cos, sin)]}
+
+
+def rotate_half(x, d, cos, sin):
+    """x [B, S, nh*d] rotated by the [S, d/2] tables, in float32."""
+    b, s, h = x.shape
+    xf = x.astype(jnp.float32).reshape(b, s, h // d, 2, d // 2)
+    x1, x2 = xf[..., 0, :], xf[..., 1, :]
+    c, sn = cos[None, :, None, :], sin[None, :, None, :]
+    y = jnp.stack([x1 * c - x2 * sn, x2 * c + x1 * sn], axis=-2)
+    return y.reshape(b, s, h).astype(x.dtype)
 
 
 def causal_depthwise_conv(x, taps):
@@ -107,13 +121,22 @@ def swiglu(x, w1, w3, w2):
     return jnp.einsum("...f,fh->...h", inter, w2.astype(x.dtype))
 
 
-@register("swiglu_ffn")
-def swiglu_ffn(ctx, ins, attrs):
-    """Dense SwiGLU feed-forward. `remat` keeps only the input for the
-    backward pass and computes the two [.., F] intermediates again there
-    (what `remat_ffn` does for the encoder stack)."""
-    x = ins["X"][0]
-    w1, w3, w2 = ins["W1"][0], ins["W3"][0], ins["W2"][0]
-    fn = jax.checkpoint(swiglu) if attrs.get("remat", False) else swiglu
-    with jax.named_scope("swiglu_ffn"):
-        return {"Out": [fn(x, w1, w3, w2)]}
+def _swiglu_op(scope):
+    """Emitter of a SwiGLU feed-forward under the part scope `scope`.
+    `remat` keeps only the input for the backward pass and computes the
+    two [.., F] intermediates again there (what `remat_ffn` does for the
+    encoder stack)."""
+    def emit(ctx, ins, attrs):
+        x = ins["X"][0]
+        w1, w3, w2 = ins["W1"][0], ins["W3"][0], ins["W2"][0]
+        fn = jax.checkpoint(swiglu) if attrs.get("remat", False) else swiglu
+        with jax.named_scope(scope):
+            return {"Out": [fn(x, w1, w3, w2)]}
+    return emit
+
+
+# the dense SwiGLU feed-forward, and the same arithmetic as the expert that
+# every token passes beside the routed ones, under a part scope of its own
+# so that a trace tells the shared expert from a dense layer's MLP
+register("swiglu_ffn")(_swiglu_op("swiglu_ffn"))
+register("shared_expert")(_swiglu_op("shared_expert"))

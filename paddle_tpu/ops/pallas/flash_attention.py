@@ -1297,14 +1297,16 @@ def _prescale_ok(sm_scale) -> bool:
     return math.frexp(float(sm_scale))[0] == 0.5
 
 
-def _bsh_kernel_name(direction: str, causal: bool) -> str:
+def _bsh_kernel_name(direction: str, causal: bool, form: str = "bsh") -> str:
     """`pallas_call(name=)` of a BSH call: `flash_bsh_fwd` / `flash_bsh_bwd`
     over the full score square, `flash_bsh_causal_fwd` / `_bwd` where the
     kernel skips the blocks above the diagonal. The benchmark finds a call
     by this name and counts its work by it (`benchmark/kernels/<name>.py`):
     the square's count over a causal call's time would read up to twice
-    its true share of the roofline."""
-    return f"flash_bsh_{'causal_' if causal else ''}{direction}"
+    its true share of the roofline. `form` "mla" names the calls that
+    latent attention makes on heads padded to a kernel width
+    (`flash_mla_causal_fwd`): their useful work is the unpadded heads'."""
+    return f"flash_{form}_{'causal_' if causal else ''}{direction}"
 
 
 # Two bodies share these names. Up to S = 512 a head's whole score tile
@@ -1394,7 +1396,7 @@ def _make_fwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
 
 
 def _flash_fwd_bsh_tile(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
-                   causal, dropout_prob):
+                   causal, dropout_prob, form="bsh"):
     b, sq, hdim = q.shape
     skv = k.shape[1]
     d = hdim // nh
@@ -1452,7 +1454,7 @@ def _flash_fwd_bsh_tile(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name=_bsh_kernel_name("fwd", causal),
+        name=_bsh_kernel_name("fwd", causal, form),
         interpret=_interpret(),
     )(*args)
     return o, lse
@@ -1588,7 +1590,8 @@ def _make_bwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
     return kernel
 
 
-def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob):
+def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob,
+                        form="bsh"):
     q, k, v, bias, mask, seed, offsets, o, lse = res
     b, sq, hdim = q.shape
     skv = k.shape[1]
@@ -1650,7 +1653,7 @@ def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob):
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name=_bsh_kernel_name("bwd", causal),
+        name=_bsh_kernel_name("bwd", causal, form),
         interpret=_interpret(),
     )(*args)
     return dq.astype(q.dtype), dk, dv
@@ -2019,8 +2022,10 @@ def _make_fwd_bsh_stream_kernel(*, sm_scale, causal, dropout_prob, has_bias,
             if prescale:
                 q = q * jnp.asarray(sm_scale, q.dtype)
             for hh in range(hp):
+                # a head that is its own group transposes whole, 128
+                # lanes at a time (d = 256: two blocks)
                 wq_ref[_window(g * hp + hh, gw), _window(r, cq)] = _mxu_t(
-                    q, _eye(gw, q.dtype, hh, hp))
+                    q, _eye(gw, q.dtype, hh, hp) if hp > 1 else None)
 
         groups.each(transpose_v, nkc)
         groups.each(transpose_q, nr)
@@ -2189,7 +2194,7 @@ def _resolve_bsh_blocks(sq, skv, h, *, bwd=False):
 
 
 def _flash_fwd_bsh_stream(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
-                   causal, dropout_prob):
+                   causal, dropout_prob, form="bsh"):
     b, sq, hdim = q.shape
     skv = k.shape[1]
     d = hdim // nh
@@ -2265,7 +2270,7 @@ def _flash_fwd_bsh_stream(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
              pltpu.VMEM((gw, cq), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name=_bsh_kernel_name("fwd", causal),
+        name=_bsh_kernel_name("fwd", causal, form),
         interpret=_interpret(),
     )(*args)
     return o, lse
@@ -2558,7 +2563,8 @@ def _make_bwd_bsh_stream_kernel(*, sm_scale, causal, dropout_prob, has_bias,
     return kernel
 
 
-def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob):
+def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob,
+                          form="bsh"):
     q, k, v, bias, mask, seed, offsets, o, lse = res
     b, sq, hdim = q.shape
     skv = k.shape[1]
@@ -2643,7 +2649,7 @@ def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob):
             pltpu.VMEM((gw, ck), f32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
-        name=_bsh_kernel_name("bwd", causal),
+        name=_bsh_kernel_name("bwd", causal, form),
         interpret=_interpret(),
     )(*args)
     return dq.astype(q.dtype), dk, dv
@@ -2720,9 +2726,9 @@ def _flash_bwd_bsh(res, g, **statics):
 
 
 @functools.lru_cache(maxsize=256)
-def _make_flash_core_bsh(*, sm_scale, nh, causal, dropout_prob):
+def _make_flash_core_bsh(*, sm_scale, nh, causal, dropout_prob, form="bsh"):
     statics = dict(sm_scale=sm_scale, nh=nh, causal=causal,
-                   dropout_prob=dropout_prob)
+                   dropout_prob=dropout_prob, form=form)
 
     @jax.custom_vjp
     def core(q, k, v, bias, mask, seed, offsets):
@@ -2747,7 +2753,7 @@ def _make_flash_core_bsh(*, sm_scale, nh, causal, dropout_prob):
 def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
                         causal=False, dropout_prob=0.0, dropout_key=None,
                         dropout_seed=None, mesh=None, batch_axis="dp",
-                        head_axis="tp"):
+                        head_axis="tp", form="bsh"):
     """Transpose-free flash attention on projection-layout tensors.
 
     q: [B, S_q, H], k/v: [B, S_kv, H] with H = num_heads * D — exactly
@@ -2800,7 +2806,7 @@ def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
     def local(ql, kl, vl, bl, ml, sl, nh_local):
         core = _make_flash_core_bsh(
             sm_scale=float(sm_scale), nh=nh_local, causal=causal,
-            dropout_prob=dropout_prob)
+            dropout_prob=dropout_prob, form=form)
         return core(ql, kl, vl, bl, ml, sl, None)
 
     axes = [
@@ -2840,6 +2846,10 @@ def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
     )(q, k, v, bias, mask, seed)
 
 
+# head widths the kernels' lane layouts take
+HEAD_WIDTHS = (64, 128, 256)
+
+
 def flash_shapes_ok(s, d) -> bool:
     """THE shape/backend/flag gate for every flash dispatch site (the
     attention op, the encoder stack, and the ring path all call this)."""
@@ -2848,7 +2858,7 @@ def flash_shapes_ok(s, d) -> bool:
 
     if not flag("FLAGS_use_flash_attention"):
         return False
-    shapes_ok = d in (64, 128, 256) and s % MIN_BLOCK == 0
+    shapes_ok = d in HEAD_WIDTHS and s % MIN_BLOCK == 0
     if FORCE_PALLAS:
         return shapes_ok
     return shapes_ok and not _interpret()

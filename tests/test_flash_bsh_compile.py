@@ -157,3 +157,56 @@ def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
         ).lower(a, b, shaped(groups, dtype=jnp.int32)).compile()
     text = compiled.as_text()
     assert f"moe_gmm_{form}" in text and "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# a whole step at published widths, in this file because one process of a
+# test run may describe the topology
+# ---------------------------------------------------------------------------
+
+
+def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
+                                                       no_compile_cache):
+    """The step of `xing4.0-29b-a4b.tp8ep8share.s4096` as the harness
+    builds it, at the cell's batch and the published widths, compiled for
+    the described chip with its state given as shapes (656 M parameters
+    are not allocated here): Mosaic takes the padded latent-attention
+    flash calls and the grouped products at 3584 x 1024, the step holds
+    every call the configuration lists, and XLA's buffer assignment reads
+    the `peak_hbm_gb` the configuration states."""
+    import numpy as np
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import harness, hlo_text, manifest
+    from paddle_tpu.fluid.executor import Scope
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    cell = manifest.load_cell(manifest.load_manifest(),
+                              "xing4.0-29b-a4b.tp8ep8share.s4096")
+    batch = int(cell.traffic["batch"])
+    built = harness.build_program(cell, batch, dropout=True, seed=1)
+    exe, scope = fluid.Executor(), Scope()
+    for program in (built.startup, built.main):
+        for v in program.global_block().vars.values():
+            if v.persistable and v.shape is not None:
+                scope.set_var(v.name, jax.ShapeDtypeStruct(
+                    tuple(v.shape), np.dtype(v.dtype)))
+    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
+                                  harness.batch_rng(1, 1, 0))
+    with mock.patch.object(fa, "_interpret", lambda: False), \
+            mock.patch.object(gm, "_interpret", lambda: False):
+        fa._make_flash_core_bsh.cache_clear()
+        try:
+            compiled = exe._lower_step(
+                built.main, feed=feed, fetch_list=[built.loss], scope=scope,
+                platforms=("tpu",), sharding=one_chip).compile()
+        finally:
+            fa._make_flash_core_bsh.cache_clear()
+    present = hlo_text.read_step(compiled.as_text()).kernels
+    assert set(cell.config["mosaic_calls"]) <= set(present), present
+    mem = compiled.memory_analysis()
+    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
+    stated = cell.config["stated"]["peak_hbm_gb"]
+    assert abs(peak_gb - stated) <= 0.01 * stated, (peak_gb, stated)
+    assert 4.0 < peak_gb < 15.75

@@ -3045,6 +3045,54 @@ def _swiglu_ffn():
                   tol=1e-4, grad_tol=2e-2)
 
 
+@case("shared_expert")
+def _shared_expert():
+    rng = R(487)
+    x, w1, w3, w2 = _mix(rng, 3, 4), _mix(rng, 4, 6), _mix(rng, 4, 6), _mix(rng, 6, 4)
+
+    def oracle(ins, a):
+        xx = ins["X"][0]
+        a1 = xx @ ins["W1"][0]
+        return {"Out": [f32((a1 / (1 + np.exp(-a1)) * (xx @ ins["W3"][0]))
+                            @ ins["W2"][0])]}
+
+    return OpTest("shared_expert", {"X": x, "W1": w1, "W3": w3, "W2": w2},
+                  oracle, attrs={"remat": False},
+                  grad=("X", "W1", "W3", "W2"), tol=1e-4, grad_tol=2e-2)
+
+
+@case("mhc_pre")
+def _mhc_pre():
+    rng = R(491)
+    x, h = _mix(rng, 2, 3, 12), _pos(rng, 2, 3, 3)  # three streams of 4
+
+    def oracle(ins, a):
+        xs = ins["X"][0].reshape(2, 3, 3, 4)
+        return {"Out": [f32(np.einsum("bsn,bsnc->bsc", ins["HPre"][0], xs))]}
+
+    return OpTest("mhc_pre", {"X": x, "HPre": h}, oracle,
+                  attrs={"streams": 3}, grad=("X", "HPre"), tol=1e-5,
+                  grad_tol=2e-2)
+
+
+@case("mhc_post")
+def _mhc_post():
+    rng = R(499)
+    x, y = _mix(rng, 2, 3, 12), _mix(rng, 2, 3, 4)
+    h_res, h_post = _pos(rng, 2, 3, 9), _pos(rng, 2, 3, 3)
+
+    def oracle(ins, a):
+        xs = ins["X"][0].reshape(2, 3, 3, 4)
+        mixed = np.einsum("bsij,bsjc->bsic",
+                          ins["HRes"][0].reshape(2, 3, 3, 3), xs)
+        mixed = mixed + ins["HPost"][0][..., None] * ins["Y"][0][:, :, None]
+        return {"Out": [f32(mixed.reshape(2, 3, 12))]}
+
+    return OpTest("mhc_post",
+                  {"X": x, "Y": y, "HRes": h_res, "HPost": h_post}, oracle,
+                  grad=("X", "Y", "HRes", "HPost"), tol=1e-5, grad_tol=2e-2)
+
+
 # ---------------------------------------------------------------------------
 # exemptions: ops whose contract is verified elsewhere or is stochastic
 # ---------------------------------------------------------------------------
@@ -3086,6 +3134,10 @@ EXEMPT = {
     "moe_ffn": "test_moe.py (numpy routing oracle, capacity, ep parity)",
     "moe_swiglu": "test_lfm2_ops.py (dense-loop oracle, shares add up, "
                   "nothing dropped, bias selects only)",
+    "mla": "test_xing4_ops.py (the reference's sublayer, forward and "
+           "gradients) + test_xing4_model.py (head shares add up)",
+    "mhc_map": "test_xing4_ops.py (float64 numpy mappings, Sinkhorn after "
+               "20 rounds and after 1, gradients against jax)",
     "fused_encoder_stack": "test_bert.py (vs per-layer composition)",
     "fused_decoder_stack": "test_sequence_models.py (fused NMT stack "
                            "trains + stays causal)",
