@@ -219,6 +219,16 @@ def record_attention_lowering(impl: str, form: str) -> None:
                  impl=impl, form=form).inc()
 
 
+def record_mhc_post_lowering(impl: str) -> None:
+    """Called by ops/latent_ops.py each time `mhc_post` is traced into a
+    step: `impl` is what was lowered (`pallas`, the one-pass kernels of
+    ops/pallas/mhc.py, or `jnp`, the composition). A lowering-time
+    counter, like the grouped products'."""
+    _reg.counter("mhc_post_lowerings_total",
+                 help="mhc_post ops traced, by implementation",
+                 impl=impl).inc()
+
+
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

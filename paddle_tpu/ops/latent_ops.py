@@ -175,7 +175,19 @@ def mhc_pre(ctx, ins, attrs):
 @register("mhc_post")
 def mhc_post(ctx, ins, attrs):
     """X' = H_res X + H_post^T y: stream i of the result is
-    sum_j H_res[i, j] X_j + H_post[i] y."""
+    sum_j H_res[i, j] X_j + H_post[i] y. On the TPU, at a width in whole
+    lane tiles and rows a block tiles, the two one-pass kernels of
+    ops/pallas/mhc.py (`mhc.mhc_rows` is the gate); `_mhc_post` everywhere
+    else. Either way only the four inputs are kept for the backward pass."""
+    from ..fluid.monitor import record_mhc_post_lowering
+    from .pallas import mhc
+
+    args = ins["X"][0], ins["Y"][0], ins["HRes"][0], ins["HPost"][0]
+    # XLA cannot partition a Mosaic call: over a mesh, the composition
+    alone = ctx.mesh is None or ctx.mesh.size == 1
+    rows = mhc.mhc_rows(*args) if alone else None
+    record_mhc_post_lowering("jnp" if rows is None else "pallas")
     with jax.named_scope("mhc_mix"):
-        return {"Out": [jax.checkpoint(_mhc_post)(
-            ins["X"][0], ins["Y"][0], ins["HRes"][0], ins["HPost"][0])]}
+        if rows is None:
+            return {"Out": [jax.checkpoint(_mhc_post)(*args)]}
+        return {"Out": [mhc.mhc_post(*args, rows)]}

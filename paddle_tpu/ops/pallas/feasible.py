@@ -204,3 +204,31 @@ def gmm_vmem_bytes(form: str, tm: int, tk: int, tn: int, k: int, n: int,
     rows_in, out = (k, tn) if form == "nn" else (n, tk)
     return (2 * itemsize * (tm * rows_in + rows_in * out + tm * out)
             + 4 * tm * out + 2 ** 20)
+
+
+# ---------------------------------------------------------------------------
+# the hyper-connections' stream mixing (ops/pallas/mhc.py)
+# ---------------------------------------------------------------------------
+
+
+# as the grouped matmul's: the chooser keeps a cell under the budget, and
+# a call asks Mosaic for its estimate and the slack (30 MiB at the Xing4
+# cell's bf16 streams; the budget admits float32 streams of that width)
+MHC_VMEM_BUDGET = 64 * 1024 * 1024
+MHC_VMEM_SLACK = 4 * 1024 * 1024
+
+
+def mhc_vmem_bytes(pass_: str, rows: int, c: int, n: int,
+                   itemsize: int) -> int:
+    """Footprint of one grid cell of ops/pallas/mhc.py over `rows` tokens
+    of n streams of c columns, every block double-buffered. fwd: the
+    [rows, n c] streams in and out and the [rows, c] sublayer output; bwd:
+    the cotangent and the streams in, the streams' cotangent out, y in and
+    its cotangent out. The float32 mappings (and, bwd, their cotangents)
+    are [n n + n, rows] blocks, their turned copies [rows, n n + n] padded
+    to 128 lanes, and the identity that turns them [rows, rows]."""
+    wide, narrow, maps = {"fwd": (2, 1, 1), "bwd": (3, 2, 2)}[pass_]
+    held = -(-(n * n + n) // 8) * 8
+    return (2 * itemsize * rows * c * (wide * n + narrow)
+            + 4 * maps * (2 * held * rows + rows * 128) + 4 * rows * rows
+            + 2 ** 20)

@@ -160,6 +160,51 @@ def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
 
 
 # ---------------------------------------------------------------------------
+# the stream-mixing kernels of mhc_post (ops/pallas/mhc.py), in this file
+# because one process of a test run may describe the topology
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("tokens", [8192, 4096], ids=["step", "check"])
+@pytest.mark.parametrize("kernel", ["mhc_post_fwd", "mhc_post_bwd"])
+def test_the_mhc_post_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
+                                                  kernel, tokens):
+    """The Xing4 cell's operands (two sequences of 4,096 tokens a step, one
+    in its check program, four streams of 3,584 bf16 columns, float32
+    mappings with the tokens on the lanes) at the chooser's row block:
+    Mosaic takes the blocks, the lane offsets of the streams, the products
+    with the identity and the scoped-VMEM limit the byte model asks for.
+    The traced body is one chunk's: its equation count does not go with the
+    rows."""
+    from paddle_tpu.ops.pallas import mhc
+
+    n, c = 4, 3584
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lead = (tokens // 4096, 4096)
+    x, y = shaped(*lead, n * c), shaped(*lead, c)
+    maps = (shaped(*lead, n * n, dtype=jnp.float32),
+            shaped(*lead, n, dtype=jnp.float32))
+    turned = shaped(n * n + n, tokens, dtype=jnp.float32)
+    with mock.patch.object(mhc, "_interpret", lambda: False):
+        rows = mhc.mhc_rows(x, y, *maps)
+        assert rows == 128
+        if kernel == "mhc_post_fwd":
+            traced = jax.jit(lambda *a: mhc._mhc_fwd(
+                *a, br=rows, interpret=False)).trace(x, y, turned)
+        else:
+            traced = jax.jit(lambda *a: mhc._mhc_bwd(
+                *a, br=rows, interpret=False)).trace(x, x, y, turned)
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert kernel in text and "tpu_custom_call" in text
+    assert _pallas_calls(traced.jaxpr.jaxpr, {})[kernel] <= {
+        "mhc_post_fwd": 250, "mhc_post_bwd": 500}[kernel]
+
+
+# ---------------------------------------------------------------------------
 # a whole step at published widths, in this file because one process of a
 # test run may describe the topology
 # ---------------------------------------------------------------------------
@@ -171,15 +216,17 @@ def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
     builds it, at the cell's batch and the published widths, compiled for
     the described chip with its state given as shapes (656 M parameters
     are not allocated here): Mosaic takes the padded latent-attention
-    flash calls and the grouped products at 3584 x 1024, the step holds
-    every call the configuration lists, and XLA's buffer assignment reads
-    the `peak_hbm_gb` the configuration states."""
+    flash calls, the grouped products at 3584 x 1024 and the stream-mixing
+    kernels, the step holds every call the configuration lists, and XLA's
+    buffer assignment reads no more than the `peak_hbm_gb` the configuration
+    states."""
     import numpy as np
 
     import paddle_tpu.fluid as fluid
     from benchmark import harness, hlo_text, manifest
     from paddle_tpu.fluid.executor import Scope
     from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import mhc
 
     cell = manifest.load_cell(manifest.load_manifest(),
                               "xing4.0-29b-a4b.tp8ep8share.s4096")
@@ -194,7 +241,8 @@ def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
     feed = cell.family.make_batch(cell.config, cell.traffic, batch,
                                   harness.batch_rng(1, 1, 0))
     with mock.patch.object(fa, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False):
+            mock.patch.object(gm, "_interpret", lambda: False), \
+            mock.patch.object(mhc, "_interpret", lambda: False):
         fa._make_flash_core_bsh.cache_clear()
         try:
             compiled = exe._lower_step(
@@ -204,9 +252,12 @@ def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
             fa._make_flash_core_bsh.cache_clear()
     present = hlo_text.read_step(compiled.as_text()).kernels
     assert set(cell.config["mosaic_calls"]) <= set(present), present
+    assert {"mhc_post_fwd", "mhc_post_bwd"} <= set(present), present
     mem = compiled.memory_analysis()
     peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
+    # one-sided since PR 33: the stream-mixing kernels took the peak 2 %
+    # under what the configuration (a benchmark file) states
     stated = cell.config["stated"]["peak_hbm_gb"]
-    assert abs(peak_gb - stated) <= 0.01 * stated, (peak_gb, stated)
+    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb < 15.75

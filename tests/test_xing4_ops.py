@@ -252,9 +252,19 @@ def test_mhc_map_against_numpy_and_sinkhorn(iters, worst):
     _assert_close(out, grads, *_ref_grads(fn, x, params, w), tol=2e-5)
 
 
-def test_mhc_mix_against_numpy():
+def _mhc_post_lowerings(impl):
+    return get_registry().counter("mhc_post_lowerings_total",
+                                  impl=impl).value
+
+
+@pytest.mark.parametrize("B, S, C, impl", [
+    (2, 5, 16, "jnp"),      # the composition, as every CPU run has it
+    (1, 16, 128, "pallas"),  # whole lane tiles, the kernels pinned
+], ids=["jnp", "pallas"])
+def test_mhc_mix_against_numpy(B, S, C, impl):
     rng = np.random.RandomState(7)
-    x = rng.randn(2, 5, N * C + C + 2 * N + N * N).astype(np.float32)
+    x = rng.randn(B, S, N * C + C + 2 * N + N * N).astype(np.float32)
+    before = _mhc_post_lowerings(impl)
 
     def build(v):
         streams = layers.slice(v, [2], [0], [N * C])
@@ -269,18 +279,20 @@ def test_mhc_mix_against_numpy():
         return layers.concat([u, readout, mixed], axis=2), []
 
     def fn(v, p):
-        s = v[..., :N * C].reshape(2, 5, N, C)
+        s = v[..., :N * C].reshape(B, S, N, C)
         y = v[..., N * C:N * C + C]
         at = N * C + C
         h_pre, h_post = v[..., at:at + N], v[..., at + N:at + 2 * N]
-        h_res = v[..., at + 2 * N:].reshape(2, 5, N, N)
+        h_res = v[..., at + 2 * N:].reshape(B, S, N, N)
         u = jnp.einsum("bsn,bsnc->bsc", h_pre, s)
         mixed = (jnp.einsum("bsij,bsjc->bsic", h_res, s)
                  + h_post[..., None] * y[:, :, None, :])
         return jnp.concatenate(
-            [u, s.sum(2), mixed.reshape(2, 5, N * C)], -1)
+            [u, s.sum(2), mixed.reshape(B, S, N * C)], -1)
 
-    got = _run(build, {"x": x})
+    with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
+        got = _run(build, {"x": x})
+    assert _mhc_post_lowerings(impl) > before
     # by hand for one token: stream 1 of X' = sum_j H_res[1, j] X_j + H_post[1] y
     s0 = x[0, 0, :N * C].reshape(N, C).astype(np.float64)
     at = N * C + C
@@ -352,10 +364,15 @@ def test_the_shared_expert_is_a_swiglu_under_its_own_scope():
     assert _parts(lowered[0]) == {"shared_expert"}
 
 
-def test_every_new_op_lowers_under_its_part_scope():
+@pytest.mark.parametrize("width, impl", [(64, "jnp"), (128, "pallas")])
+def test_every_new_op_lowers_under_its_part_scope(width, impl):
+    """At 128 columns a stream with the kernels pinned, `mhc_post` is the
+    two kernels of ops/pallas/mhc.py: they lower under `mhc_mix` like the
+    composition, the backward one under the role `backward`."""
     cfg = Xing4Config.tiny(heads_held=4)
-    x = np.random.RandomState(9).randn(1, 8, 64).astype(np.float32)
+    x = np.random.RandomState(9).randn(1, 8, width).astype(np.float32)
     lowered = []
+    before = _mhc_post_lowerings(impl)
 
     def build(v):
         streams = layers.expand(v, [1, 1, 4])
@@ -367,11 +384,24 @@ def test_every_new_op_lowers_under_its_part_scope():
             param_attr=fluid.ParamAttr(initializer=INIT), name="attn")
         return layers.mhc_post(streams, y, res, post), []
 
-    _run(build, {"x": x}, lowered=lowered)
+    with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
+        _run(build, {"x": x}, lowered=lowered)
+    assert _mhc_post_lowerings(impl) > before
     assert _parts(lowered[0]) == {"mla", "mhc_map", "mhc_mix"}
     backward = "\n".join(line for line in lowered[0].splitlines()
                          if "/backward/" in line)
     assert _parts(backward) == {"mla", "mhc_map", "mhc_mix"}
+    from benchmark import part_scopes, roles
+
+    names = set(re.findall(r'"(jit\([a-z_]+\)/[^"]*)"', lowered[0]))
+    # the kernels are inner jits: the call sites carry the scopes, and XLA
+    # prefixes them to the `mhc_post_*/pallas_call` it inlines
+    for entry, role in (("_mhc_fwd", "forward"), ("_mhc_bwd", "backward")):
+        calls = {n for n in names if n.endswith(f"/jit({entry})")}
+        assert bool(calls) == (impl == "pallas"), (entry, calls)
+        for n in calls:
+            assert part_scopes.part_of(n) == "mhc_mix", n
+            assert roles.role_of(n) == role, n
 
 
 # ---------------------------------------------------------------------------
