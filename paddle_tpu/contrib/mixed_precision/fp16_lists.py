@@ -58,6 +58,10 @@ white_list = {
     "mhc_map",
     "mhc_pre",
     "mhc_post",
+    # the Mamba-2 mixer (ops/ssm_ops.py): both projections and the scan's
+    # products in bf16; the convolution, dt, the decays and their running
+    # sums, the carried state and the gated norm in f32 inside the emitter
+    "mamba2",
 }
 
 black_list = {

@@ -73,6 +73,7 @@ _KEEP_F32_SLOTS = {
     "mhc_map": {"Phi", "Bias", "Alpha"},
     "mhc_pre": {"HPre"},
     "mhc_post": {"HRes", "HPost"},
+    "mamba2": {"ConvW", "ConvB", "DtBias", "ALog", "D", "NormW"},
 }
 
 

@@ -6,6 +6,7 @@ the reference's op defs while lowering happens through the JAX emitters.
 """
 from __future__ import annotations
 
+import zlib
 from typing import List, Optional, Sequence, Union
 
 import numpy as np
@@ -13,7 +14,9 @@ import numpy as np
 from .. import framework
 from ..dtypes import convert_dtype
 from ..framework import Variable
-from ..initializer import ConstantInitializer, NormalInitializer, XavierInitializer
+from ..initializer import (ConstantInitializer, NormalInitializer,
+                           NumpyArrayInitializer, UniformInitializer,
+                           XavierInitializer)
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
@@ -1302,28 +1305,120 @@ def swiglu_ffn(input, size, remat=False, param_attr=None, name=None):
     return _swiglu("swiglu_ffn", input, size, remat, param_attr, name)
 
 
-def _swiglu(op_type, input, size, remat, param_attr, name):
+def _expert_form(activation):
+    """True where the experts have the third matrix W3 (SwiGLU), False for
+    the two-matrix squared-ReLU form."""
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError(
+            f"activation {activation!r}: experts are 'swiglu' or 'relu2'")
+    return activation == "swiglu"
+
+
+def _swiglu(op_type, input, size, remat, param_attr, name,
+            activation="swiglu"):
     helper = LayerHelper(op_type, param_attr=param_attr, name=name)
     name = name or helper.name
     h = input.shape[-1]
-    w1 = _named_param(helper, param_attr, name, "w1", [h, size], "float32")
-    w3 = _named_param(helper, param_attr, name, "w3", [h, size], "float32")
-    w2 = _named_param(helper, param_attr, name, "w2", [size, h], "float32")
+    inputs = {"X": [input], "W1": [_named_param(
+        helper, param_attr, name, "w1", [h, size], "float32")]}
+    if _expert_form(activation):
+        inputs["W3"] = [_named_param(helper, param_attr, name, "w3",
+                                     [h, size], "float32")]
+    inputs["W2"] = [_named_param(helper, param_attr, name, "w2", [size, h],
+                                 "float32")]
     out = helper.create_variable_for_type_inference(input.dtype)
-    helper.append_op(
-        type=op_type,
-        inputs={"X": [input], "W1": [w1], "W3": [w3], "W2": [w2]},
-        outputs={"Out": [out]}, attrs={"remat": bool(remat)})
+    helper.append_op(type=op_type, inputs=inputs, outputs={"Out": [out]},
+                     attrs={"remat": bool(remat)})
     return out
 
 
-def shared_expert(input, size, remat=False, param_attr=None, name=None):
+def shared_expert(input, size, remat=False, param_attr=None, name=None,
+                  activation="swiglu"):
     """The expert every token passes, beside the routed ones of
     `moe_swiglu`: a SwiGLU feed-forward of the experts' width, as
-    `swiglu_ffn`, lowered under the part scope `shared_expert`. In a
-    deployment that splits the routed experts over chips every chip
-    computes it alike, so it is counted once when shares are added up."""
-    return _swiglu("shared_expert", input, size, remat, param_attr, name)
+    `swiglu_ffn` (or, with `activation="relu2"`, the two-matrix W2
+    relu(W1 x)^2 without `<name>.w3`), lowered under the part scope
+    `shared_expert`. In a deployment that splits the routed experts over
+    chips every chip computes it alike, so it is counted once when shares
+    are added up."""
+    return _swiglu("shared_expert", input, size, remat, param_attr, name,
+                   activation)
+
+
+def mamba2(input, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
+           chunk_size=128, epsilon=1e-5, dt_min=0.001, dt_max=0.1,
+           dt_floor=1e-4, param_attr=None, out_attr=None, name=None):
+    """The Mamba-2 mixer (ops/ssm_ops.py; Dao and Gu, arXiv:2405.21060),
+    the whole sublayer, for x [B, S, C] with d_in = num_heads * head_dim:
+
+        [z, xBC, dt] = x W_in           d_in, d_in + 2 G N and H columns
+        xBC = silu(conv(xBC) + b_conv)  causal, depthwise, conv_kernel taps
+        [x, B, C] = xBC                 H heads of P; G groups of N
+        dt_h = softplus(dt_h + dt_bias_h),  A_h = -exp(A_log_h)
+        S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_t^T      S_0 = 0, [P, N]
+        y_t = S_t C_t + D_h x_t         head h reads group h // (H / G)
+        out = RMSNorm_{d_in / G}(y * silu(z)) W_out
+
+    The recurrence runs in chunks of `chunk_size` positions as matrix
+    products (`ssd_scan`), decays and the carried state in float32.
+    Returns (out [B, S, C], min_decay [H] float32: each head's smallest
+    exp(dt A) over the step's tokens, fetchable like any variable).
+
+    Parameters `<name>.in_proj`, `.conv1d.weight` [taps, d_in + 2 G N]
+    and `.conv1d.bias` (uniform in +-1/sqrt(taps), what the release's
+    convolution starts at), `.dt_bias` (the inverse softplus of a dt drawn
+    log-uniform in [dt_min, dt_max] and floored at dt_floor), `.A_log`
+    (log of A drawn uniform in [1, 16], the release's), `.D` (1),
+    `.norm.weight` (1) and `.out_proj` (`out_attr`'s initializer where
+    given, else `param_attr`'s). The two drawn per-head vectors follow the program's
+    `random_seed` and the layer's name."""
+    helper = LayerHelper("mamba2", param_attr=param_attr, name=name)
+    name = name or helper.name
+    c = input.shape[-1]
+    h, p, g, n = (int(num_heads), int(head_dim), int(n_groups),
+                  int(state_size))
+    if h % g:
+        raise ValueError(f"mamba2: {h} heads in {g} groups")
+    d_in, conv_dim = h * p, h * p + 2 * g * n
+    rng = np.random.default_rng(
+        [int(helper.main_program.random_seed or 0),
+         zlib.crc32(name.encode())])
+    dt = np.maximum(np.exp(rng.uniform(np.log(dt_min), np.log(dt_max), h)),
+                    dt_floor)
+    bound = 1.0 / np.sqrt(conv_kernel)
+
+    def vector(suffix, shape, init):
+        return helper.create_parameter(
+            ParamAttr(name=f"{name}.{suffix}"), shape=shape, dtype="float32",
+            default_initializer=init)
+
+    inputs = {
+        "X": [input],
+        "InW": [_named_param(helper, param_attr, name, "in_proj",
+                             [c, d_in + conv_dim + h], "float32")],
+        "ConvW": [vector("conv1d.weight", [int(conv_kernel), conv_dim],
+                         UniformInitializer(-bound, bound))],
+        "ConvB": [vector("conv1d.bias", [conv_dim],
+                         UniformInitializer(-bound, bound))],
+        # softplus^-1(dt) = dt + log(1 - exp(-dt))
+        "DtBias": [vector("dt_bias", [h], NumpyArrayInitializer(
+            dt + np.log(-np.expm1(-dt))))],
+        "ALog": [vector("A_log", [h], NumpyArrayInitializer(
+            np.log(rng.uniform(1.0, 16.0, h))))],
+        "D": [vector("D", [h], ConstantInitializer(1.0))],
+        "NormW": [vector("norm.weight", [d_in], ConstantInitializer(1.0))],
+        "OutW": [_named_param(helper, out_attr or param_attr, name,
+                              "out_proj", [d_in, c], "float32")],
+    }
+    out = helper.create_variable_for_type_inference(input.dtype)
+    min_decay = helper.create_variable_for_type_inference(
+        "float32", stop_gradient=True)
+    helper.append_op(
+        type="mamba2", inputs=inputs,
+        outputs={"Out": [out], "MinDecay": [min_decay]},
+        attrs={"num_heads": h, "head_dim": p, "n_groups": g, "state_size": n,
+               "chunk_size": int(chunk_size), "epsilon": float(epsilon)})
+    return out, min_decay
 
 
 def mla(input, num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
@@ -1468,6 +1563,7 @@ def moe_swiglu(
     param_attr=None,
     bias_attr=None,
     name=None,
+    activation="swiglu",
 ):
     """Dropless, bias-routed mixture of SwiGLU experts that is told which
     experts it holds (ops/moe_ops.py). For a token z, over a router of
@@ -1478,6 +1574,10 @@ def moe_swiglu(
         g_i = s_i / (sum_{j in I} s_j + 1e-6) * routed_scaling_factor
                                                    (without norm_topk_prob: g_i = s_i)
         y = sum_{i in I, i held here} g_i W2_i (silu(W1_i z) * W3_i z)
+
+    With `activation="relu2"` an expert is two matrices and not three,
+    W2_i relu(W1_i z)^2, and there is no `<name>.w3`; everything around
+    the experts is the same code.
 
     This layer holds experts `first_expert .. first_expert + experts_held
     - 1` (all of them by default): its weights are [experts_held, ...], the
@@ -1510,16 +1610,20 @@ def moe_swiglu(
                         default_initializer=ConstantInitializer(0.0),
                         trainable=False)
     bias.stop_gradient = True
-    w1 = _named_param(helper, param_attr, name, "w1", [held, h, f], "float32")
-    w3 = _named_param(helper, param_attr, name, "w3", [held, h, f], "float32")
-    w2 = _named_param(helper, param_attr, name, "w2", [held, f, h], "float32")
+    inputs = {"X": [input], "GateW": [gate_w], "ExpertBias": [bias],
+              "W1": [_named_param(helper, param_attr, name, "w1",
+                                  [held, h, f], "float32")]}
+    if _expert_form(activation):
+        inputs["W3"] = [_named_param(helper, param_attr, name, "w3",
+                                     [held, h, f], "float32")]
+    inputs["W2"] = [_named_param(helper, param_attr, name, "w2",
+                                 [held, f, h], "float32")]
     out = helper.create_variable_for_type_inference(input.dtype)
     counts = helper.create_variable_for_type_inference("int32",
                                                        stop_gradient=True)
     helper.append_op(
         type="moe_swiglu",
-        inputs={"X": [input], "GateW": [gate_w], "ExpertBias": [bias],
-                "W1": [w1], "W3": [w3], "W2": [w2]},
+        inputs=inputs,
         # the buffer is read by the selection and written by the balancing
         # rule: one persistable variable, updated in place every step
         outputs={"Out": [out], "TokensPerExpert": [counts],

@@ -229,6 +229,16 @@ def record_mhc_post_lowering(impl: str) -> None:
                  impl=impl).inc()
 
 
+def record_ssd_scan_lowering(impl: str) -> None:
+    """Called by ops/ssm_ops.py each time a `mamba2` op, and the state-space
+    scan inside it, is traced into a step: `impl` is what was lowered
+    (`jnp`, the chunked composition of matrix products; `pallas` is kept
+    for a kernel). A lowering-time counter, like the grouped products'."""
+    _reg.counter("ssd_scan_lowerings_total",
+                 help="state-space scans traced, by implementation",
+                 impl=impl).inc()
+
+
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

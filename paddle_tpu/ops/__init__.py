@@ -27,6 +27,7 @@ from . import (  # noqa: F401
     recompute,
     reduce_ops,
     sequence_ops,
+    ssm_ops,
     vision_ops,
 )
 from .registry import EmitContext, OpSpec, get, register, registered_ops  # noqa: F401

@@ -121,17 +121,27 @@ def swiglu(x, w1, w3, w2):
     return jnp.einsum("...f,fh->...h", inter, w2.astype(x.dtype))
 
 
+def relu2_ffn(x, w1, w2):
+    """W2 relu(W1 x)^2 on the last axis; products in x's dtype, the
+    activation in float32."""
+    a = jnp.einsum("...h,hf->...f", x, w1.astype(x.dtype))
+    inter = jnp.square(jax.nn.relu(a.astype(jnp.float32))).astype(x.dtype)
+    return jnp.einsum("...f,fh->...h", inter, w2.astype(x.dtype))
+
+
 def _swiglu_op(scope):
-    """Emitter of a SwiGLU feed-forward under the part scope `scope`.
-    `remat` keeps only the input for the backward pass and computes the
-    two [.., F] intermediates again there (what `remat_ffn` does for the
-    encoder stack)."""
+    """Emitter of a SwiGLU feed-forward under the part scope `scope`, or,
+    built without W3, of the two-matrix squared-ReLU one. `remat` keeps
+    only the input for the backward pass and computes the [.., F]
+    intermediates again there (what `remat_ffn` does for the encoder
+    stack)."""
     def emit(ctx, ins, attrs):
-        x = ins["X"][0]
-        w1, w3, w2 = ins["W1"][0], ins["W3"][0], ins["W2"][0]
-        fn = jax.checkpoint(swiglu) if attrs.get("remat", False) else swiglu
+        weights = [ins[k][0] for k in ("W1", "W3", "W2") if k in ins]
+        fn = swiglu if len(weights) == 3 else relu2_ffn
+        if attrs.get("remat", False):
+            fn = jax.checkpoint(fn)
         with jax.named_scope(scope):
-            return {"Out": [fn(x, w1, w3, w2)]}
+            return {"Out": [fn(ins["X"][0], *weights)]}
     return emit
 
 

@@ -21,7 +21,10 @@ Two ops, two formulations, and which model takes which:
   experts' part of the layer's result. Nothing is dropped at any
   imbalance, there is no `[T, E, C]` tensor and no capacity; on one chip
   there is no exchange and nothing stands in for the absent chips.
-  `models/lfm2_moe.py` builds it.
+  `models/lfm2_moe.py` builds it. Built without W3 its experts are the
+  two-matrix squared-ReLU ones of the Nemotron line, W2 relu(W1 x)^2
+  (`models/nemotron_h.py`), through the same route, dispatch, sorted
+  block and combine: two grouped products a block and not three.
 
   **Who multiplies.** The three products of a block and their transposes
   (input and weight gradients) go through `grouped_matmul`, one
@@ -309,34 +312,40 @@ def _combine_bwd(saved, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _grouped_swiglu(xs, w1, w3, w2, group_sizes, kernels=None):
-    """The three grouped products and the activation: rows of `xs` lie
-    sorted by expert, `group_sizes[e]` of them for expert e, and what lies
-    behind the last group is computed by nobody. `grouped_matmul` is the
-    Pallas kernel where its gate serves the operands (the TPU, lane
-    dimensions in multiples of 128) and `jax.lax.ragged_dot` elsewhere,
-    forward and transposed alike; `kernels` names the forms that may take
-    the kernel (None: all three)."""
+def _grouped_experts(xs, weights, group_sizes, kernels=None):
+    """The grouped products and the activation: rows of `xs` lie sorted by
+    expert, `group_sizes[e]` of them for expert e, and what lies behind
+    the last group is computed by nobody. `weights` is (W1, W3, W2) for
+    SwiGLU experts, W2 (silu(W1 x) * W3 x), and (W1, W2) for squared-ReLU
+    ones, W2 relu(W1 x)^2. `grouped_matmul` is the Pallas kernel where its
+    gate serves the operands (the TPU, lane dimensions in multiples of
+    128) and `jax.lax.ragged_dot` elsewhere, forward and transposed alike;
+    `kernels` names the forms that may take the kernel (None: all three)."""
     # imported here: a program without this op never loads Pallas
     from .pallas.grouped_matmul import FORMS, grouped_matmul
 
     product = functools.partial(grouped_matmul, kernels=kernels or FORMS)
-    a = product(xs, w1.astype(xs.dtype), group_sizes)
-    g = product(xs, w3.astype(xs.dtype), group_sizes)
-    inter = (jax.nn.silu(a.astype(jnp.float32))
-             * g.astype(jnp.float32)).astype(xs.dtype)
-    return product(inter, w2.astype(xs.dtype), group_sizes)
+    a = product(xs, weights[0].astype(xs.dtype), group_sizes)
+    if len(weights) == 3:
+        g = product(xs, weights[1].astype(xs.dtype), group_sizes)
+        inter = (jax.nn.silu(a.astype(jnp.float32))
+                 * g.astype(jnp.float32)).astype(xs.dtype)
+    else:
+        inter = jnp.square(jax.nn.relu(a.astype(jnp.float32))
+                           ).astype(xs.dtype)
+    return product(inter, weights[-1].astype(xs.dtype), group_sizes)
 
 
-def _sorted_block(rows, x2, gates, w1, w3, w2, order, slot, mine,
+def _sorted_block(rows, x2, gates, weights, order, slot, mine,
                   group_sizes, kernels=None):
     """The held experts' part of the layer out of a sorted block of `rows`
     rows, which has to hold every pair on a held expert: gather the pairs'
-    tokens in sorted order, three grouped products, weigh and sum back by
-    token. `order` sorts the T * k pairs by held expert (the others
-    behind), `slot` [T, k] is its inverse, `mine` [T, k] the pairs on held
-    experts. A pair whose slot lies behind `rows` is on nobody's expert
-    here and is masked as `mine` masks. `kernels`: `_grouped_swiglu`'s."""
+    tokens in sorted order, the grouped products of `_grouped_experts`
+    over `weights`, weigh and sum back by token. `order` sorts the T * k
+    pairs by held expert (the others behind), `slot` [T, k] is its inverse,
+    `mine` [T, k] the pairs on held experts. A pair whose slot lies behind
+    `rows` is on nobody's expert here and is masked as `mine` masks.
+    `kernels`: `_grouped_experts`'."""
     top_k = gates.shape[1]
     with jax.named_scope("moe_dispatch"):
         head = order[:rows]
@@ -348,7 +357,7 @@ def _sorted_block(rows, x2, gates, w1, w3, w2, order, slot, mine,
         xs = jnp.where(present[:, None],
                        _gather_rows(x2, head // top_k, slot, placed), 0)
     with jax.named_scope("moe_experts"):
-        ys = _grouped_swiglu(xs, w1, w3, w2, group_sizes, kernels)
+        ys = _grouped_experts(xs, weights, group_sizes, kernels)
     with jax.named_scope("moe_combine"):
         return _combine(ys, gates, slot, placed, head, present)
 
@@ -378,7 +387,7 @@ def _fits(rows, group_sizes):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_part(rows, x2, gates, w1, w3, w2, order, slot, mine, group_sizes):
+def _held_part(rows, x2, gates, weights, order, slot, mine, group_sizes):
     """`_sorted_block` at `rows` rows where the held experts' pairs fit,
     else at T * k: a conditional on a device scalar, no host sync and
     nothing dropped. The conditional stands outside differentiation: under
@@ -389,7 +398,7 @@ def _held_part(rows, x2, gates, w1, w3, w2, order, slot, mine, group_sizes):
     and transpose their own block."""
     return jax.lax.cond(_fits(rows, group_sizes),
                         functools.partial(_sorted_block, rows), _full_width,
-                        x2, gates, w1, w3, w2, order, slot, mine, group_sizes)
+                        x2, gates, weights, order, slot, mine, group_sizes)
 
 
 def _held_part_fwd(rows, *operands):
@@ -398,7 +407,7 @@ def _held_part_fwd(rows, *operands):
 
 def _held_part_bwd(rows, operands, d_out):
     def transposed(block, *operands):
-        trained, indices = operands[:5], operands[5:]
+        trained, indices = operands[:3], operands[3:]
         return jax.vjp(lambda *t: block(*t, *indices), *trained)[1](d_out)
 
     d_trained = jax.lax.cond(
@@ -415,7 +424,9 @@ _held_part.defvjp(_held_part_fwd, _held_part_bwd)
 def moe_swiglu(ctx, ins, attrs):
     """X [B, S, H], GateW [H, E], ExpertBias [E], W1 / W3 [E_held, H, F],
     W2 [E_held, F, H] -> Out [B, S, H], the part of the layer's result that
-    experts first_expert .. first_expert + E_held - 1 give,
+    experts first_expert .. first_expert + E_held - 1 give (without W3 the
+    experts are the two-matrix squared-ReLU ones, W2 relu(W1 x)^2: the
+    route, the dispatch, the sorted block and the combine are the same),
     TokensPerExpert [E_held] int32, the rows each of them received, and
     ExpertBiasOut [E], the selection bias after the balancing rule
     (`balance_bias`), which a training program binds to ExpertBias itself.
@@ -435,7 +446,9 @@ def moe_swiglu(ctx, ins, attrs):
     says (buffers kept across a conditional would be both branches')."""
     x = ins["X"][0]
     gate_w, expert_bias = ins["GateW"][0], ins["ExpertBias"][0]
-    w1, w3, w2 = ins["W1"][0], ins["W3"][0], ins["W2"][0]
+    w1 = ins["W1"][0]
+    weights = ((w1, ins["W3"][0], ins["W2"][0]) if "W3" in ins
+               else (w1, ins["W2"][0]))
     top_k = int(attrs.get("top_k", 4))
     first = int(attrs.get("first_expert", 0))
     held = w1.shape[0]
@@ -476,7 +489,7 @@ def moe_swiglu(ctx, ins, attrs):
         # a layer are then alive in one layer at a time
         if attrs.get("remat", False):
             held_part = jax.checkpoint(held_part)
-    out = held_part(x2, gates, w1, w3, w2, order, slot, mine, group_sizes)
+    out = held_part(x2, gates, weights, order, slot, mine, group_sizes)
 
     return {"Out": [out.reshape(b, s, h)], "TokensPerExpert": [group_sizes],
             "ExpertBiasOut": [new_bias]}

@@ -261,3 +261,52 @@ def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
     stated = cell.config["stated"]["peak_hbm_gb"]
     assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb < 15.75
+
+
+def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
+                                                          no_compile_cache):
+    """The step of `nemotron-3-nano-30b-a3b.ep16share.s4096` as the harness
+    builds it, at the cell's batch and the published widths, compiled for
+    the described chip with its state given as shapes (667 M parameters
+    are not allocated here): the chunked state-space scan, the two-matrix
+    experts at 2688 x 1856 and the attention call at H 4096 compile, the
+    step holds every Mosaic call the configuration lists (the BHSD flash
+    kernels: at S 4096 and H 4096 the BSH kernels' whole-sequence residency
+    is over their gate), and XLA's buffer assignment reads the
+    `peak_hbm_gb` the configuration states to 1 %."""
+    import numpy as np
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import harness, hlo_text, manifest
+    from paddle_tpu.fluid.executor import Scope
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    cell = manifest.load_cell(manifest.load_manifest(),
+                              "nemotron-3-nano-30b-a3b.ep16share.s4096")
+    batch = int(cell.traffic["batch"])
+    built = harness.build_program(cell, batch, dropout=True, seed=1)
+    exe, scope = fluid.Executor(), Scope()
+    for program in (built.startup, built.main):
+        for v in program.global_block().vars.values():
+            if v.persistable and v.shape is not None:
+                scope.set_var(v.name, jax.ShapeDtypeStruct(
+                    tuple(v.shape), np.dtype(v.dtype)))
+    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
+                                  harness.batch_rng(1, 1, 0))
+    with mock.patch.object(fa, "_interpret", lambda: False), \
+            mock.patch.object(gm, "_interpret", lambda: False):
+        compiled = exe._lower_step(
+            built.main, feed=feed, fetch_list=[built.loss], scope=scope,
+            platforms=("tpu",), sharding=one_chip).compile()
+    text = compiled.as_text()
+    present = hlo_text.read_step(text).kernels
+    assert set(cell.config["mosaic_calls"]) <= set(present), present
+    # 1856 is no multiple of 128: the experts' products are XLA's own
+    assert not {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} & set(present)
+    assert "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
+    stated = cell.config["stated"]["peak_hbm_gb"]
+    assert abs(peak_gb - stated) <= 0.01 * stated, (peak_gb, stated)
+    assert 4.0 < peak_gb < 15.2

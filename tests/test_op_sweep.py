@@ -3138,6 +3138,9 @@ EXEMPT = {
            "gradients) + test_xing4_model.py (head shares add up)",
     "mhc_map": "test_xing4_ops.py (float64 numpy mappings, Sinkhorn after "
                "20 rounds and after 1, gradients against jax)",
+    "mamba2": "test_nemotron_ops.py (the chunked scan against the float64 "
+              "recurrence, forward and every gradient; the mixer against "
+              "the reference's) + test_nemotron_model.py",
     "fused_encoder_stack": "test_bert.py (vs per-layer composition)",
     "fused_decoder_stack": "test_sequence_models.py (fused NMT stack "
                            "trains + stays causal)",
