@@ -28,8 +28,9 @@ Two ops, two formulations, and which model takes which:
 
   **Who multiplies.** The three products of a block and their transposes
   (input and weight gradients) go through `grouped_matmul`, one
-  `jax.custom_vjp` a product. On the TPU, for bf16 or float32 rows whose
-  lane dimensions are multiples of 128 and whose row count a row tile
+  `jax.custom_vjp` a product. On the TPU, for bf16 or float32 rows, at
+  matrices whose two extents are multiples of 64 (a multiple of 128 is
+  tiled, 1856 is one block of its whole extent) and a row count a row tile
   divides, each of the three forms runs a Pallas kernel written for the
   chip (`moe_gmm_nn`, `moe_gmm_nt`, `moe_gmm_tn`; tiles from the operands'
   shapes alone). On every other backend, and at every other shape, the
@@ -318,8 +319,8 @@ def _grouped_experts(xs, weights, group_sizes, kernels=None):
     the last group is computed by nobody. `weights` is (W1, W3, W2) for
     SwiGLU experts, W2 (silu(W1 x) * W3 x), and (W1, W2) for squared-ReLU
     ones, W2 relu(W1 x)^2. `grouped_matmul` is the Pallas kernel where its
-    gate serves the operands (the TPU, lane dimensions in multiples of
-    128) and `jax.lax.ragged_dot` elsewhere, forward and transposed alike;
+    gate serves the operands (the TPU, the matrices' extents in multiples
+    of 64) and `jax.lax.ragged_dot` elsewhere, forward and transposed alike;
     `kernels` names the forms that may take the kernel (None: all three)."""
     # imported here: a program without this op never loads Pallas
     from .pallas.grouped_matmul import FORMS, grouped_matmul

@@ -197,7 +197,16 @@ def gmm_vmem_bytes(form: str, tm: int, tk: int, tn: int, k: int, n: int,
     bf16 (256, 2048, 1792) 19.1 (20.5), (512, 2048, 1792) 24.2 (26.0),
     float32 (256, 2048, 1792) 35.9 (38.2); tn bf16 (256, 2048, 1792) 33.1
     (36.5), (256, 2048, 896) 18.2 (20.8), float32 (256, 2048, 1792) 50.7
-    (58.0)."""
+    (58.0). An extent that is no multiple of 128 lies in VMEM padded to
+    the next one and is counted so (1856 as 1920). With the rolled loop
+    and its tail, as the kernels run (the same bisection, PR 35): bf16
+    (256, 2688, 1856) nn 21.2 (27.1), nt 22.5 (27.8), tn 44.8 (49.4);
+    (256, 1856, 2688) nn 21.8 (27.8), nt 23.2 (27.1), tn 40.1 (49.4);
+    float32 nn (256, 2688, 1856) 44.9 (51.2), tn (256, 896, 1856) 23.5
+    (31.7). (Bisect at more groups than XLA can hold on the chip: at
+    eight it gave the kernel the 80 MB of weights in VMEM and Mosaic
+    allocated 4.2.)"""
+    tk, tn, k, n = (-(-extent // 128) * 128 for extent in (tk, tn, k, n))
     if form == "tn":
         return (2 * itemsize * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
                 + 2 * itemsize * tm * (tk + tn) + 2 ** 20)

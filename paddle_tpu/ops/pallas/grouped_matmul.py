@@ -40,14 +40,20 @@ rows has one visit that stores the zeros.
 columns go `_chunk` (256) at a time through a rolled loop, so the compiled
 body is one chunk's product whatever the block holds: the whole block in
 one product is 6 % faster a call and five times the code (0.77 against
-0.17 MB a call site, 96 sites a step). The three entry points are inner
+0.17 MB a call site, 96 sites a step). A block that is no whole number of
+chunks (1856 columns) ends in one static tail, a second and narrower
+product in the same body. The three entry points are inner
 `jax.jit`s, `interpret` among their static arguments: the call sites of
 one signature share one traced and one lowered body, so the step's Mosaic
 bodies do not go with its layers (tests/test_moe_gmm_lowering.py). A call
 asks Mosaic for the VMEM its cell needs and a slack, not for the core's.
 
 Tiles come from the operands' shapes alone (`default_gmm_tiles`, held to
-`feasible.gmm_vmem_bytes`): no flag, no environment name, no cache.
+`feasible.gmm_vmem_bytes`): no flag, no environment name, no cache. An
+axis of the matrices is tiled in multiples of 128; one that is a multiple
+of 64 only (1856 = 14.5 x 128) is served as one block of its whole
+extent, which Mosaic takes whatever the extent; any other axis, and rows
+no row tile divides, keep `ragged_dot`.
 """
 from __future__ import annotations
 
@@ -125,11 +131,11 @@ def group_visits(group_sizes, rows: int, tm: int) -> Visits:
 # operator wrappers were most of that (PERF.md, PR 31).
 
 
-def _row_mask(offsets, group, tile, v, shape, tm):
+def _row_mask(offsets, group, tile, v, tm, width):
     """[tm, width] mask of the rows of visit v's tile that are its group's."""
     g = group[v]
     start, end = offsets[g], offsets[lax.add(g, 1)]
-    rows = lax.add(lax.broadcasted_iota(jnp.int32, shape, 0),
+    rows = lax.add(lax.broadcasted_iota(jnp.int32, (tm, width), 0),
                    lax.mul(tile[v], tm))
     return lax.bitwise_and(lax.ge(rows, start), lax.lt(rows, end))
 
@@ -138,18 +144,27 @@ def _keep(mask, x):
     return lax.select(mask, x, lax.full_like(x, 0))
 
 
-def _over_chunks(width: int, chunk: int, body):
-    """body(cols) over `width` columns, `chunk` at a time, as a rolled loop:
-    the compiled body is one chunk's, whatever the block holds."""
-    if chunk == width:
-        body(pl.ds(0, width))
-        return
+def _over_chunks(width: int, chunk: int, mask, body):
+    """body(cols, mask(columns)) over `width` columns, `chunk` at a time, as
+    a rolled loop: the compiled body is one chunk's, whatever the block
+    holds. A width that is no multiple of the chunk (1856 = 7 x 256 + 64)
+    ends in one static tail behind the whole chunks, at a lane-aligned
+    offset, its row mask built at the tail's width: a second, narrower
+    product in the same kernel and not a second `pallas_call`."""
+    whole, tail = divmod(width, chunk)
+    if whole:
+        mine = mask(chunk)  # once, in front of the loop
+        if whole == 1:
+            body(pl.ds(0, chunk), mine)
+        else:
+            def trip(j, carry):
+                body(pl.ds(pl.multiple_of(lax.mul(j, chunk), chunk), chunk),
+                     mine)
+                return carry
 
-    def trip(j, carry):
-        body(pl.ds(pl.multiple_of(lax.mul(j, chunk), chunk), chunk))
-        return carry
-
-    lax.fori_loop(0, width // chunk, trip, 0)
+            lax.fori_loop(0, whole, trip, 0)
+    if tail:
+        body(pl.ds(whole * chunk, tail), mask(tail))
 
 
 def _gmm_kernel(offsets, group, tile, lhs_ref, rhs_ref, out_ref, *, tm,
@@ -157,9 +172,8 @@ def _gmm_kernel(offsets, group, tile, lhs_ref, rhs_ref, out_ref, *, tm,
     # no branch on an empty group: its one visit stores under a mask that
     # holds no row
     v = pl.program_id(1)
-    mine = _row_mask(offsets, group, tile, v, (tm, chunk), tm)
 
-    def columns(cols):
+    def columns(cols, mine):
         product = lax.dot_general(
             lhs_ref[...], rhs_ref[cols, :] if transposed
             else rhs_ref[:, cols],
@@ -169,7 +183,9 @@ def _gmm_kernel(offsets, group, tile, lhs_ref, rhs_ref, out_ref, *, tm,
             mine, lax.convert_element_type(product, out_ref.dtype),
             out_ref[:, cols])
 
-    _over_chunks(out_ref.shape[1], chunk, columns)
+    _over_chunks(out_ref.shape[1], chunk,
+                 functools.partial(_row_mask, offsets, group, tile, v, tm),
+                 columns)
 
 
 def _tgmm_kernel(offsets, group, tile, lhs_ref, rhs_ref, out_ref, acc_ref, *,
@@ -182,11 +198,10 @@ def _tgmm_kernel(offsets, group, tile, lhs_ref, rhs_ref, out_ref, acc_ref, *,
     # rows of other groups, and what lies behind the last one (which may be
     # anything), leave by select and not by a product with zero; a group
     # without rows keeps none, and its one visit stores the zeros
-    lhs = _keep(_row_mask(offsets, group, tile, v, lhs_ref.shape, tm),
-                lhs_ref[...])
-    mine = _row_mask(offsets, group, tile, v, (tm, chunk), tm)
+    mask = functools.partial(_row_mask, offsets, group, tile, v, tm)
+    lhs = _keep(mask(lhs_ref.shape[1]), lhs_ref[...])
 
-    def columns(cols):
+    def columns(cols, mine):
         product = lax.dot_general(
             lhs, _keep(mine, rhs_ref[:, cols]), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -199,7 +214,7 @@ def _tgmm_kernel(offsets, group, tile, lhs_ref, rhs_ref, out_ref, acc_ref, *,
         def _():
             out_ref[:, cols] = lax.convert_element_type(acc, out_ref.dtype)
 
-    _over_chunks(rhs_ref.shape[1], chunk, columns)
+    _over_chunks(rhs_ref.shape[1], chunk, mask, columns)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -279,8 +294,14 @@ _ROW_TILES = (256, 128)
 
 
 def _widths(n: int):
-    """Lane-tile widths of an axis of n: the multiples of 128 that divide
-    it, widest first (1792 = 14 x 128: 1792, 896, 256, 128)."""
+    """Tile widths of an axis of n: the multiples of 128 that divide it,
+    widest first (1792 = 14 x 128: 1792, 896, 256, 128). An axis that is
+    no multiple of 128 but one of 64, the half lane tile (1856 = 14.5 x
+    128), has one, itself: a block whose extent is its array's is legal
+    whatever the extent, and 64 keeps bf16's 16-row packing where the axis
+    is sliced on the sublanes. Any other axis has none."""
+    if n % 128:
+        return [] if n % 64 else [n]
     return [n // d for d in range(1, n // 128 + 1)
             if n % d == 0 and (n // d) % 128 == 0]
 
@@ -309,11 +330,19 @@ def default_gmm_tiles(form: str, m: int, k: int, n: int,
     read 0.797 / 0.825 / 0.840 / 0.909). A narrower tile re-reads the rows
     once a column block; among tn's tiles of one area the wider tn wins.
     (All read with the block in one product; the loop over `_chunk`
-    columns adds 0.05 ms to nn / nt and 0.10 to tn.)"""
-    if k % 128 or n % 128:
-        return None
+    columns adds 0.05 ms to nn / nt and 0.10 to tn.)
+
+    An axis that is a multiple of 64 and not of 128 has one candidate, its
+    whole extent (`_widths`): it is never tiled, and where the block does
+    not fit with it whole the chooser refuses. At the Nemotron cell's operands ([6144, 2688] x [8, 2688, 1856]
+    and [6144, 1856] x [8, 1856, 2688], bf16, 3,072 rows present; my chip
+    run, PR 35) nn / nt / tn read 0.500 / 0.540 / 0.678 ms at the first
+    and 0.379 / 0.356 / 0.508 at the second, the same bits as XLA's
+    `ragged-dot`, which took 1.68-2.22; tm 128 / 256 / 512 read 0.463 /
+    0.506 / 0.593 for nn at the first (at 384 rows a group a 256-row tile
+    is visited three times for every two tiles of rows)."""
     tm = next((t for t in _ROW_TILES if m % t == 0), None)
-    if tm is None:
+    if tm is None or not (_widths(k) and _widths(n)):
         return None
     if form == "nn":
         cands = [(k, tn) for tn in _widths(n)]
@@ -337,8 +366,17 @@ def _chunk(width: int) -> int:
     0.759 with the block unrolled whole; tn 1.10-1.15, 0.89-0.91, 0.82-0.83
     and 0.79-0.80. The whole block is 3.5 ms a step faster than 256 and
     +70 MB of executable, +0.5 s of every process's first step: set-up is
-    an end-to-end metric, so 256."""
-    return next(c for c in (256, 128) if width % c == 0)
+    an end-to-end metric, so 256. A block that neither divides (1856) goes
+    256 at a time and ends in a tail of 64 (`_over_chunks`): nn / nt / tn
+    read 0.518 / 0.377 / 0.741 ms at 128 + 64, 0.503 / 0.354 / 0.682 at
+    256 + 64, 0.493 / 0.348 / 0.668 at 512 + 320 and 0.485 / 0.339 with
+    the block whole (tn then passes the VMEM it asks for) (the Nemotron
+    cell's operands, my chip run, PR 35). The 2688 columns of that cell
+    (21 x 128) keep the 128 that every multiple of 128 without a factor
+    of 256 has had: nn / nt / tn read 0.376 / 0.540 / 0.508 at 128, 0.359
+    / 0.501 / 0.420 at 384, 0.352 / 0.492 / 0.408 at 896: 384 would be
+    0.65 ms a step of 285 and a rule of its own."""
+    return next((c for c in (256, 128) if width % c == 0), 256)
 
 
 def gmm_tiles(form, lhs, rhs_shape) -> Optional[Tuple[int, int, int]]:

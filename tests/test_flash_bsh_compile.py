@@ -132,18 +132,24 @@ def test_traced_size_does_not_go_with_the_tile(traced, kernel):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k, n", [(2048, 1792), (1792, 2048)],
-                         ids=["w1_w3", "w2"])
+@pytest.mark.parametrize("rows, k, n", [
+    (32768, 2048, 1792), (32768, 1792, 2048),
+    (6144, 2688, 1856), (6144, 1856, 2688)],
+    ids=["w1_w3", "w2", "nemotron_w1", "nemotron_w2"])
 @pytest.mark.parametrize("form", ["nn", "nt", "tn"])
 def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
-                                                 form, k, n):
+                                                 form, rows, k, n):
     """The LFM2 cell's operands: 32,768 sorted rows against eight
     [2048, 1792] (W1, W3) or [1792, 2048] (W2) matrices in bf16, at the
     chooser's tiles: Mosaic takes the blocks, the transposed contractions
-    and the scoped-VMEM limit."""
+    and the scoped-VMEM limit. The Nemotron cell's: 6,144 rows against
+    eight [2688, 1856] (W1) or [1856, 2688] (W2), where 1856 = 14.5 x 128
+    is one block of the whole axis, on the result's lanes (a 64-column
+    tail at lane 1792), on the contraction, and on the sublanes of nt's
+    and tn's blocks."""
     from paddle_tpu.ops.pallas import grouped_matmul as gm
 
-    rows, groups = 32768, 8
+    groups = 8
 
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -272,8 +278,9 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     experts at 2688 x 1856 and the attention call at H 4096 compile, the
     step holds every Mosaic call the configuration lists (the BHSD flash
     kernels: at S 4096 and H 4096 the BSH kernels' whole-sequence residency
-    is over their gate), and XLA's buffer assignment reads the
-    `peak_hbm_gb` the configuration states to 1 %."""
+    is over their gate) and, since PR 35, the three grouped-matmul kernels
+    with 1856 as one whole block, and XLA's buffer assignment reads no more
+    than the `peak_hbm_gb` the configuration states."""
     import numpy as np
 
     import paddle_tpu.fluid as fluid
@@ -301,12 +308,17 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     text = compiled.as_text()
     present = hlo_text.read_step(text).kernels
     assert set(cell.config["mosaic_calls"]) <= set(present), present
-    # 1856 is no multiple of 128: the experts' products are XLA's own
-    assert not {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} & set(present)
+    # 1856 = 14.5 x 128 is served as one block (PR 35; before it the gate
+    # refused and every product was XLA's): the bounded block's products
+    # are the kernels, and XLA's `ragged-dot` stays for the dropless
+    # fallback's product and weight gradient
+    assert {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} <= set(present), present
     assert "ragged-dot" in text
     mem = compiled.memory_analysis()
     peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
+    # one-sided since PR 35, as the Xing4 cell's: the configuration (a
+    # benchmark file) states the peak of the step with XLA's products
     stated = cell.config["stated"]["peak_hbm_gb"]
-    assert abs(peak_gb - stated) <= 0.01 * stated, (peak_gb, stated)
+    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb < 15.2

@@ -3,8 +3,10 @@
 forms against a dense loop over the groups, at group layouts that put a
 boundary inside a row tile, leave a group empty, fill the buffer or leave
 rows behind the last group (NaN going in: nobody may read them); the list
-of visits the grid walks; the chooser's tiles at the LFM2 cell's operands
-and its refusals; the gate; the counter."""
+of visits the grid walks; the same forms at matrices one of whose axes is
+a multiple of 64 and not of 128 (one whole block, the inner loop's tail);
+the chooser's tiles at the three decoder cells' operands and its refusals;
+the gate; the counter."""
 from unittest import mock
 
 import jax
@@ -38,7 +40,7 @@ def pinned():
         yield
 
 
-def _operands(sizes, dtype, seed=0):
+def _operands(sizes, dtype, seed=0, k=K, n=N):
     rng = np.random.RandomState(seed)
     present = sum(sizes)
 
@@ -47,7 +49,7 @@ def _operands(sizes, dtype, seed=0):
         a[present:] = np.nan  # behind the last group: nobody's to read
         return jnp.asarray(a, dtype)
 
-    return (rows(K), rows(N), jnp.asarray(rng.randn(len(sizes), K, N), dtype),
+    return (rows(k), rows(n), jnp.asarray(rng.randn(len(sizes), k, n), dtype),
             jnp.asarray(sizes, jnp.int32))
 
 
@@ -59,7 +61,7 @@ def _dense(form, lhs, d_out, rhs, sizes):
     if form == "tn":
         return np.stack([lhs[lo:hi].T @ d_out[lo:hi]
                          for lo, hi in zip(bounds, bounds[1:])])
-    out = np.full((ROWS, N if form == "nn" else K), np.nan)
+    out = np.full((ROWS, rhs.shape[2 if form == "nn" else 1]), np.nan)
     for g, (lo, hi) in enumerate(zip(bounds, bounds[1:])):
         out[lo:hi] = (lhs[lo:hi] @ rhs[g] if form == "nn"
                       else d_out[lo:hi] @ rhs[g].T)
@@ -124,6 +126,66 @@ def test_the_gradients_are_the_two_transposes(pinned, layout):
                                    rtol=1e-5, atol=1e-4)
 
 
+# matrices with one axis that is a multiple of 64 and not of 128, as the
+# Nemotron cell's 1856: on the result's columns and on the contraction of
+# each form; 576 = 2 x 256 + 64 runs the rolled loop and then the tail,
+# 192 the tail alone
+RAGGED = {"k256_n192": (256, 192), "k192_n256": (192, 256),
+          "k384_n576": (384, 576), "k576_n384": (576, 384)}
+
+
+@pytest.mark.parametrize("layout", ["uneven", "an_empty_group"])
+@pytest.mark.parametrize("widths", sorted(RAGGED))
+@pytest.mark.parametrize("form", gm.FORMS)
+def test_a_form_at_an_axis_of_half_lane_tiles(pinned, form, widths, layout):
+    """One whole block at the axis no 128 divides, against the dense loop
+    and against `ragged_dot`; rows behind the last group hold NaN."""
+    k, n = RAGGED[widths]
+    sizes = LAYOUTS[layout]
+    lhs, d_out, rhs, group_sizes = _operands(sizes, jnp.bfloat16, seed=3,
+                                             k=k, n=n)
+    a, b = {"nn": (lhs, rhs), "nt": (d_out, rhs), "tn": (lhs, d_out)}[form]
+    assert gm.gmm_tiles(form, a, rhs.shape) == (256, k, n)
+    got = gm._run(form, a, b, group_sizes, rhs.shape)
+    xla = gm._RAGGED_DOT[form](a, b, group_sizes, rhs.shape)
+    assert got.dtype == xla.dtype and got.shape == xla.shape
+    got, xla = (np.asarray(x, np.float64) for x in (got, xla))
+    want = _dense(form, lhs, d_out, rhs, sizes)
+    if form != "tn":
+        present = sum(sizes)
+        got, xla, want = got[:present], xla[:present], want[:present]
+    assert np.isfinite(got).all()  # no NaN row was read
+    scale = max(np.abs(want).max(initial=0.0), 1.0)
+    np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2 * scale)
+    np.testing.assert_allclose(got, xla, rtol=2e-2, atol=0.25)
+
+
+@pytest.mark.parametrize("layout", ["uneven", "an_empty_group"])
+@pytest.mark.parametrize("widths", ["k256_n192", "k192_n256"])
+def test_the_gradients_at_an_axis_of_half_lane_tiles(pinned, widths, layout):
+    """`jax.grad` through the custom_vjp at such matrices, against autodiff
+    of `jax.lax.ragged_dot`."""
+    k, n = RAGGED[widths]
+    sizes = LAYOUTS[layout]
+    lhs, d_out, rhs, group_sizes = _operands(sizes, jnp.float32, seed=4,
+                                             k=k, n=n)
+    present = sum(sizes)
+    lhs, w = jnp.nan_to_num(lhs), jnp.nan_to_num(d_out)
+
+    def loss(product):
+        return lambda l, r: jnp.sum((product(l, r, group_sizes) * w)[:present])
+
+    text = str(jax.make_jaxpr(jax.grad(loss(gm.grouped_matmul),
+                                       argnums=(0, 1)))(lhs, rhs))
+    assert all(f"moe_gmm_{form}" in text for form in gm.FORMS)
+    got = jax.grad(loss(gm.grouped_matmul), argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(loss(jax.lax.ragged_dot), argnums=(0, 1))(lhs, rhs)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g[:present] if g.ndim == 2 else g,
+                                   r[:present] if r.ndim == 2 else r,
+                                   rtol=1e-5, atol=1e-4)
+
+
 def _visits_by_hand(sizes, rows, tm):
     bounds = np.concatenate([[0], np.cumsum(sizes)])
     tiles = rows // tm
@@ -164,21 +226,47 @@ def test_the_buffer_does_not_add_visits():
         _visits_by_hand(sizes.tolist(), 8192, 256))
 
 
-@pytest.mark.parametrize("form, k, n, want", [
+def _cell_cases(widths, row_counts):
+    return [pytest.param(form, k, n, rows, id=f"{form}-{k}x{n}-{rows}")
+            for k, n in widths for form in gm.FORMS for rows in row_counts]
+
+
+@pytest.mark.parametrize("form, k, n, rows", (
     # the LFM2 cell: [rows, 2048] x [8, 2048, 1792] (W1, W3) and
-    # [rows, 1792] x [8, 1792, 2048] (W2), bf16: the whole matrix
-    ("nn", 2048, 1792, (256, 2048, 1792)),
-    ("nn", 1792, 2048, (256, 1792, 2048)),
-    ("nt", 2048, 1792, (256, 2048, 1792)),
-    ("nt", 1792, 2048, (256, 1792, 2048)),
-    ("tn", 2048, 1792, (256, 2048, 1792)),
-    ("tn", 1792, 2048, (256, 1792, 2048)),
-])
-@pytest.mark.parametrize("rows", [32768, 65536, 8192])
-def test_the_chooser_at_the_cells_operands(form, k, n, want, rows):
+    # [rows, 1792] x [8, 1792, 2048] (W2)
+    _cell_cases([(2048, 1792), (1792, 2048)], [32768, 65536, 8192])
+    # the Xing4 cell: 3584 x 1024 and back, 8,192 rows bounded, 65,536 in
+    # the fallback, 4,096 in the check program
+    + _cell_cases([(3584, 1024), (1024, 3584)], [8192, 65536, 4096])
+    # the Nemotron cell: 2688 x 1856 (W1) and 1856 x 2688 (W2), 1856 =
+    # 14.5 x 128 as one block: 6,144 rows bounded, 49,152 in the fallback,
+    # 3,072 in the check program
+    + _cell_cases([(2688, 1856), (1856, 2688)], [6144, 49152, 3072])))
+def test_the_chooser_at_the_cells_operands(form, k, n, rows):
+    """bf16, every form: the group's whole matrix, at a row tile of 256."""
+    want = (256, k, n)
     assert gm.default_gmm_tiles(form, rows, k, n, 2) == want
     assert feasible.gmm_vmem_bytes(form, *want, k, n, 2) <= (
         feasible.GMM_VMEM_BUDGET)
+
+
+def test_the_vmem_model_counts_a_ragged_lane_extent_padded():
+    """1856 columns lie in 15 lane tiles: the model counts 1920, so that it
+    stays over what Mosaic allocates, and a multiple of 128 as it is."""
+    for form in gm.FORMS:
+        assert feasible.gmm_vmem_bytes(form, 256, 2688, 1856, 2688, 1856, 2) \
+            == feasible.gmm_vmem_bytes(form, 256, 2688, 1920, 2688, 1920, 2)
+        assert feasible.gmm_vmem_bytes(form, 256, 1856, 2688, 1856, 2688, 2) \
+            == feasible.gmm_vmem_bytes(form, 256, 1920, 2688, 1920, 2688, 2)
+    # Mosaic's own, bisected for a described v5e (PR 35), in MiB
+    for form, tiles, mosaic in [("nn", (256, 2688, 1856), 21.2),
+                                ("nt", (256, 2688, 1856), 22.5),
+                                ("tn", (256, 2688, 1856), 44.8),
+                                ("nn", (256, 1856, 2688), 21.8),
+                                ("nt", (256, 1856, 2688), 23.2),
+                                ("tn", (256, 1856, 2688), 40.1)]:
+        model = feasible.gmm_vmem_bytes(form, *tiles, *tiles[1:], 2) / 2 ** 20
+        assert mosaic <= model <= 1.35 * mosaic, (form, tiles, model)
 
 
 @pytest.mark.parametrize("form", gm.FORMS)
@@ -212,10 +300,68 @@ def test_the_widths_of_1792():
     assert gm._widths(2048) == [2048, 1024, 512, 256, 128]
 
 
+@pytest.mark.parametrize("n, want", [
+    (2688, [2688, 896, 384, 128]),  # 21 x 128
+    (1856, [1856]),  # 14.5 x 128: the whole axis or nothing
+    (192, [192]), (64, [64]),
+    (1800, []), (2000, []), (24, []), (96, []),
+])
+def test_the_widths_of_an_axis_no_128_divides(n, want):
+    assert gm._widths(n) == want
+
+
+@pytest.mark.parametrize("form", gm.FORMS)
+def test_a_ragged_axis_is_never_tiled(form):
+    """float32 matrices of 4096 x 4032 do not fit whole: the axis of 4096
+    narrows, the one of 63 x 64 stays whole or the chooser refuses."""
+    for k, n in [(4096, 4032), (4032, 4096)]:
+        tiles = gm.default_gmm_tiles(form, 16384, k, n, 4)
+        if tiles is None:
+            continue
+        tm, tk, tn = tiles
+        assert (tk if k == 4032 else tn) == 4032
+        assert feasible.gmm_vmem_bytes(form, tm, tk, tn, k, n, 4) <= (
+            feasible.GMM_VMEM_BUDGET)
+
+
 @pytest.mark.parametrize("width, chunk", [(1792, 256), (2048, 256),
                                           (896, 128), (384, 128), (128, 128)])
 def test_the_inner_loop_takes_256_columns_where_they_divide(width, chunk):
     assert gm._chunk(width) == chunk
+
+
+@pytest.mark.parametrize("width, chunk, whole, tail", [
+    (1856, 256, 7, 64),   # the tail starts at the lane-aligned 1792
+    (2688, 128, 21, 0),
+    (1792, 256, 7, 0),
+    (576, 256, 2, 64),
+    (256, 256, 1, 0),     # one chunk: no loop
+    (192, 256, 0, 192),   # the tail alone
+])
+def test_the_inner_loop_and_its_tail(width, chunk, whole, tail):
+    """`_over_chunks` hands the body every column once: `whole` chunks
+    through one rolled trip, then one static tail under a mask built at
+    the tail's width."""
+    assert gm._chunk(width) == chunk
+    seen = []
+
+    def body(cols, mask):
+        seen.append((cols.start, cols.size, mask))
+
+    jaxpr = jax.make_jaxpr(lambda: gm._over_chunks(
+        width, chunk, lambda columns: ("mask", columns), body))()
+    loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in ("scan",
+                                                                 "while")]
+    assert len(loops) == (1 if whole > 1 else 0)
+    if whole > 1:
+        assert loops[0].params.get("length", whole) == whole
+    # inside the rolled trip the chunk's start is the loop's counter
+    got = [(start if isinstance(start, int) else "rolled", size, mask)
+           for start, size, mask in seen]
+    want = [("rolled" if whole > 1 else 0, chunk, ("mask", chunk))][:whole]
+    if tail:
+        want.append((whole * chunk, tail, ("mask", tail)))
+    assert got == want
 
 
 def _lowerings():

@@ -10,6 +10,11 @@ equations whatever the tile.
 What this guards is set-up: every process traces and lowers the step in
 front of the compile cache (PR 25 paid 30 s there for an unrolled body;
 PR 31 measured ~0.1 s a distinct kernel body on the benchmark's host).
+The same for the Nemotron family's two-matrix experts at an intermediate
+width that is a multiple of 64 and not of 128 (the published 1856 = 14.5
+x 128; here 192): one whole block, the inner loop's tail in the same
+body.
+
 No wall clock is read; nothing is compiled."""
 import dataclasses
 import re
@@ -32,8 +37,10 @@ KERNELS = {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"}
 BATCH, SEQ = 2, 256  # 512 tokens, 1,024 pairs: a share of 2 of 8 is bounded
 # equations of a kernel's traced body (its nested bodies included) at
 # (256, 2048, 1792): nn and nt 23, tn 54, the loops over 256 columns
-# rolled; the ceiling leaves room for an epilogue, not for a loop unrolled
-# in Python (seven trips of nn's would be ~110)
+# rolled; where a 64-column tail stands behind the loop (the result's
+# 1856 columns) nn and nt 41, tn 79; the ceiling leaves room for an
+# epilogue, not for a loop unrolled in Python (seven trips of nn's would
+# be ~110)
 EQN_CEILING = 100
 
 
@@ -53,10 +60,15 @@ def _step_text(expert_layers):
         num_attention_heads=2, num_key_value_heads=1, experts_held=2,
         num_hidden_layers=len(kinds), layer_types=kinds, remat_ffn=True,
         max_position_embeddings=SEQ)
+    return _lowered_step(build_lfm2_moe_pretrain_program, cfg)
+
+
+def _lowered_step(build, cfg):
+    """The bf16-AMP Adam step of `build(cfg, ...)`, lowered for the TPU."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard():
-        _, _, _, loss = build_lfm2_moe_pretrain_program(
-            cfg, BATCH, SEQ, main_program=main, startup_program=startup)
+        _, _, _, loss = build(cfg, BATCH, SEQ, main_program=main,
+                              startup_program=startup)
         with fluid.program_guard(main, startup):
             mixed_precision.decorate(
                 fluid.optimizer.AdamOptimizer(1e-3), use_bf16=True).minimize(
@@ -133,12 +145,72 @@ def test_the_lowered_bodies_do_not_go_with_the_layers(texts):
                          + ["moe_gmm_tn"] * 2)
 
 
-@pytest.mark.parametrize("rows", [8192, 65536])
+def _nemotron_step_text(expert_layers):
+    """`expert_layers` layers of two-matrix experts behind one Mamba-2
+    layer, 2 of 16 experts held, the experts 192 wide: no multiple of 128."""
+    from paddle_tpu.models.nemotron_h import (
+        NemotronHConfig, build_nemotron_h_pretrain_program)
+
+    cfg = NemotronHConfig.tiny(
+        hidden_size=128, moe_intermediate_size=192,
+        moe_shared_expert_intermediate_size=256, experts_held=2,
+        num_hidden_layers=1 + expert_layers,
+        hybrid_override_pattern="M" + "E" * expert_layers, remat_ffn=True,
+        max_position_embeddings=SEQ)
+    return _lowered_step(build_nemotron_h_pretrain_program, cfg)
+
+
+@pytest.fixture(scope="module")
+def nemotron_texts():
+    with mock.patch.object(flash_attention, "_interpret", lambda: False), \
+            mock.patch.object(gm, "_interpret", lambda: False):
+        return {n: _nemotron_step_text(n) for n in (1, 2)}
+
+
+def test_two_matrix_experts_of_192_columns_take_the_kernels(nemotron_texts):
+    text = nemotron_texts[1]
+    assert KERNELS <= set(_bodies(text))
+    scopes = _scopes(text)
+    assert scopes and all("moe_experts" in s for s in scopes)
+    bounded = [s for s in scopes if "moe_full_width" not in s]
+    for role in ("forward", "backward"):
+        assert any(s.startswith(f"jit(step)/{role}/") for s in bounded), role
+    fallback = [s for s in scopes if "moe_full_width" in s]
+    assert fallback and all(
+        s.startswith("jit(step)/backward/") and s.endswith("jit(_gmm)")
+        for s in fallback)
+    ragged = [m for m in re.findall(r'loc\("(jit\(step\)[^"]*)"', text)
+              if "ragged_dot" in m]
+    assert ragged and all("moe_full_width" in m for m in ragged)
+    # two products a block: two forward, and in the backward pass two
+    # recomputed, two input gradients and two weight gradients; the
+    # fallback: two input gradients
+    sites = _call_sites(text)
+    assert len(sites) == 8 + 2
+    assert sum(s.startswith("_tgmm") for s in sites) == 2
+
+
+def test_the_two_matrix_bodies_do_not_go_with_the_layers(nemotron_texts):
+    one, two = (sorted(b for b in _bodies(nemotron_texts[n]) if b in KERNELS)
+                for n in (1, 2))
+    assert len(_call_sites(nemotron_texts[2])) == 2 * len(
+        _call_sites(nemotron_texts[1]))
+    # one lowered body a signature, the tail inside it and not a second
+    # `pallas_call`: W1 and W2, the input gradient at the bound and at T * k
+    assert one == two == sorted(["moe_gmm_nn"] * 2 + ["moe_gmm_nt"] * 4
+                                + ["moe_gmm_tn"] * 2)
+
+
+@pytest.mark.parametrize("k, n, rows", [
+    (2048, 1792, 8192), (2048, 1792, 65536),  # the LFM2 cell
+    (2688, 1856, 6144), (2688, 1856, 49152),  # the Nemotron cell, W1
+    (1856, 2688, 6144), (1856, 2688, 49152),  # and W2
+])
 @pytest.mark.parametrize("form", gm.FORMS)
-def test_the_traced_body_is_small_and_does_not_go_with_the_rows(form, rows):
+def test_the_traced_body_is_small_and_does_not_go_with_the_rows(form, k, n,
+                                                                rows):
     from test_flash_bsh_compile import _pallas_calls
 
-    k, n = 2048, 1792
     a = jax.ShapeDtypeStruct((rows, n if form == "nt" else k), jnp.bfloat16)
     b = (jax.ShapeDtypeStruct((rows, n), jnp.bfloat16) if form == "tn"
          else jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16))
