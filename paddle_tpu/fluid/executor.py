@@ -87,6 +87,9 @@ class _CompiledBlock:
         # scope arrays produced by an unsharded startup run are resharded
         # on first use (device_put is a no-op when already placed right)
         self.state_shardings: Dict[str, Any] = {}
+        # the first call of `fn` traces, lowers and compiles (jax.jit is
+        # lazy): until one has returned, the call is first_dispatch
+        self.dispatched = False
 
 
 class Executor:
@@ -106,15 +109,36 @@ class Executor:
         return_numpy: bool = True,
         use_program_cache: bool = True,  # parity arg; always cached
     ):
-        # step telemetry (fluid/monitor.py): rec is None unless
-        # PADDLE_METRICS_PATH armed the JSONL sink — the flag-off hot
-        # path pays one attribute read here and nothing below. The step
-        # span (PADDLE_TRACING) is the ROOT of this step's causal trace:
-        # data-wait/compile/device/fetch children below, plus every PS
-        # RPC the step issues from this thread, share its trace_id, and
-        # the kind="step" record carries it (tracetop joins on it).
-        rec = monitor.begin_step()
-        with _tracing.step_span():
+        """One step: feed -> compiled block -> fetches.
+
+        Every phase of the call is one `RecordEvent` (fluid/profiler.py),
+        the only timer here: a `TraceAnnotation` in any profiler session,
+        and the StepRecord's phase field, the profiler's list and the
+        tracing ring while each is armed. `Executor::run` is the CALL (it
+        used to name the dispatch alone): a `StepTraceAnnotation` numbered
+        by the monitor's step, and under PADDLE_TRACING the root of the
+        step's causal trace, whose trace_id every PS RPC the step issues
+        from this thread shares and the kind="step" record carries
+        (tracetop joins on it). Inside it, in order:
+
+          Executor::feed      _prepare_feed                  data_wait_ms
+          Executor::lookup    the cache key and the look-up; on a miss
+            Executor::compile building the jit closure       compile_ms
+          Executor::state     the scope's arrays, the rng key
+          Executor::dispatch  the call of the compiled step: argument
+                              flattening, the copy of a host feed, the
+                              enqueue                        device_ms
+            (Executor::first_dispatch until a call of this block has
+             returned: tracing, lowering and XLA's compile   compile_ms)
+          Executor::commit    rng key and new state into the scope
+          Executor::fetch     np.asarray of the fetches      fetch_ms
+
+        What is left of the call outside these is `Executor::run`'s own
+        time. rec is None unless a consumer armed the monitor
+        (PADDLE_METRICS_PATH, debugz, the goodput ledger): the flag-off
+        hot path builds no record and no dict."""
+        with RecordEvent("Executor::run", step_num=monitor.global_step()):
+            rec = monitor.begin_step()
             try:
                 out = self._run_impl(program, feed, fetch_list, scope,
                                      return_numpy, rec)
@@ -131,13 +155,11 @@ class Executor:
                 except Exception:  # noqa: BLE001 — accounting only
                     pass
                 raise
-        monitor.commit_step(rec)
+            monitor.commit_step(rec)
         return out
 
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
                   rec):
-        import time as _time
-
         if program is None:
             program = framework.default_main_program()
         # CompiledProgram wrapper (compiler.py) delegates here
@@ -152,24 +174,22 @@ class Executor:
         )
         block = program.global_block()
 
-        t_feed = _time.perf_counter() if rec is not None else 0.0
-        with _tracing.span("data_wait"):
+        with RecordEvent("Executor::feed", rec, "data_wait_ms"):
             feed_arrays = self._prepare_feed(block, feed)
-        if rec is not None:
-            rec.data_wait_ms += (_time.perf_counter() - t_feed) * 1e3
         from .flags import flag
 
-        # the nan/inf debugging mode and the bad-step guard both disable
-        # buffer donation (donated buffers are destroyed by the step,
-        # which would make "recover / keep the last good parameters"
-        # impossible), so the compile cache must distinguish the modes
-        check_nan = flag("FLAGS_check_nan_inf")
-        check_numerics = flag("FLAGS_check_numerics")
-        compiled = self._ensure_compiled(
-            program, block, feed_arrays, fetch_names, scope,
-            check_nan or check_numerics,
-        )
-        self._ensure_rng(scope, program)
+        with RecordEvent("Executor::lookup"):
+            # the nan/inf debugging mode and the bad-step guard both
+            # disable buffer donation (donated buffers are destroyed by
+            # the step, which would make "recover / keep the last good
+            # parameters" impossible), so the compile cache must
+            # distinguish the modes
+            check_nan = flag("FLAGS_check_nan_inf")
+            check_numerics = flag("FLAGS_check_numerics")
+            compiled = self._ensure_compiled(
+                program, block, feed_arrays, fetch_names, scope,
+                check_nan or check_numerics,
+            )
 
         def _load(names):
             d = {}
@@ -188,32 +208,41 @@ class Executor:
                 d[n] = v
             return d
 
-        donated = _load(compiled.donate_names)
-        kept = _load(compiled.keep_names)
-        if getattr(compiled, "repl_sharding", None) is not None:
-            import jax
+        with RecordEvent("Executor::state"):
+            self._ensure_rng(scope, program)
+            donated = _load(compiled.donate_names)
+            kept = _load(compiled.keep_names)
+            if getattr(compiled, "repl_sharding", None) is not None:
+                import jax
 
-            if jax.process_count() > 1:
-                # multi-process jit rejects host numpy for sharded params:
-                # build global jax.Arrays from the (identical-per-process)
-                # full batch; each process materializes only its shards
-                feed_arrays = {
-                    n: (
-                        a if isinstance(a, jax.Array)
-                        else jax.make_array_from_callback(
-                            np.shape(a), compiled.feed_shardings[n],
-                            lambda idx, a=a: np.asarray(a)[idx],
+                if jax.process_count() > 1:
+                    # multi-process jit rejects host numpy for sharded
+                    # params: build global jax.Arrays from the
+                    # (identical-per-process) full batch; each process
+                    # materializes only its shards
+                    feed_arrays = {
+                        n: (
+                            a if isinstance(a, jax.Array)
+                            else jax.make_array_from_callback(
+                                np.shape(a), compiled.feed_shardings[n],
+                                lambda idx, a=a: np.asarray(a)[idx],
+                            )
                         )
-                    )
-                    for n, a in feed_arrays.items()
-                }
-                if getattr(scope._rng_key, "sharding", None) != compiled.repl_sharding:
-                    scope._rng_key = jax.device_put(
-                        scope._rng_key, compiled.repl_sharding
-                    )
+                        for n, a in feed_arrays.items()
+                    }
+                    if getattr(scope._rng_key, "sharding", None) != compiled.repl_sharding:
+                        scope._rng_key = jax.device_put(
+                            scope._rng_key, compiled.repl_sharding
+                        )
         bench = flag("FLAGS_benchmark")
-        t_dev = _time.perf_counter() if rec is not None else 0.0
-        with RecordEvent("Executor::run"), _tracing.span("device"):
+        # jax.jit compiles lazily: XLA's compile happens INSIDE the first
+        # call, so that call is another span and belongs to compile_ms —
+        # device_ms would otherwise spike once per signature and poison
+        # step-time stats
+        with (RecordEvent("Executor::dispatch", rec, "device_ms")
+              if compiled.dispatched else
+              monitor.CompileEvent("Executor::first_dispatch", rec,
+                                   "compile_ms")):
             try:
                 from ..distributed.faults import oom_point
 
@@ -233,6 +262,7 @@ class Executor:
                     _memory.raise_oom(program, feed_arrays, phase="run",
                                       error=e)
                 raise
+            compiled.dispatched = True
             if rec is not None and bench:
                 # honest device time needs a fence; gated on the same
                 # FLAGS_benchmark that already syncs below, so telemetry
@@ -241,16 +271,6 @@ class Executor:
 
                 jax.block_until_ready(fetches)
                 rec.fenced = True
-        if rec is not None:
-            dt = (_time.perf_counter() - t_dev) * 1e3
-            if rec.cache_hit:
-                rec.device_ms += dt
-            else:
-                # jax.jit compiles lazily: on a cache-miss step XLA's
-                # compile happens INSIDE this first call, so the window
-                # belongs to compile_ms — device_ms would otherwise
-                # spike once per signature and poison step-time stats
-                rec.compile_ms += dt
         if check_numerics:
             # bad-step guard (FLAGS_check_numerics): refuse to COMMIT a
             # step whose gradients went non-finite — scope (params,
@@ -295,24 +315,21 @@ class Executor:
             # Checked BEFORE committing to the scope, so a handler can
             # checkpoint/retry from the last good parameters
             self._check_nan_inf(fetch_names, fetches, new_state)
-        scope._rng_key = new_key
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        # numerics observability (ISSUE 12): sampled stat-var reads, AMP
-        # scale transitions, SDC fingerprint publishing. Unarmed cost:
-        # two attribute reads (the bit-identity contract)
-        _numerics.on_step_commit(program, new_state)
+        with RecordEvent("Executor::commit"):
+            scope._rng_key = new_key
+            for n, v in new_state.items():
+                scope.set_var(n, v)
+            # numerics observability (ISSUE 12): sampled stat-var reads,
+            # AMP scale transitions, SDC fingerprint publishing. Unarmed
+            # cost: two attribute reads (the bit-identity contract)
+            _numerics.on_step_commit(program, new_state)
         if bench:
             import jax
 
             jax.block_until_ready(fetches)
         if return_numpy:
-            with RecordEvent("Executor::fetch"), _tracing.span("fetch"):
-                t_f = _time.perf_counter() if rec is not None else 0.0
-                out = [np.asarray(f) for f in fetches]
-                if rec is not None:
-                    rec.fetch_ms += (_time.perf_counter() - t_f) * 1e3
-                return out
+            with RecordEvent("Executor::fetch", rec, "fetch_ms"):
+                return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     @staticmethod
@@ -419,13 +436,10 @@ class Executor:
             from ..telemetry import memory as _memory
 
             _memory.on_compile(program, feed_arrays, fetch_names)
-            import time as _time
-
-            t0 = _time.perf_counter()
             try:
-                with RecordEvent("Executor::compile"), \
-                        _tracing.span("compile",
-                                      attrs={"retrace": retrace}):
+                with monitor.CompileEvent(
+                        "Executor::compile",
+                        attrs={"retrace": retrace}) as span:
                     from ..distributed.faults import oom_point
 
                     oom_point("compile")
@@ -443,8 +457,7 @@ class Executor:
                     _memory.raise_oom(program, feed_arrays,
                                       phase="compile", error=e)
                 raise
-            monitor.record_compile((_time.perf_counter() - t0) * 1e3,
-                                   retrace)
+            monitor.record_compile(span.ms, retrace)
             self._cache[key] = compiled
         else:
             monitor.record_cache_hit()
@@ -796,7 +809,9 @@ class Executor:
         the persistent compilation cache (paddle_tpu/__init__.py arms it)
         once the step has run — diagnostics pricing, not per-step
         pricing."""
-        return self._lower_step(program, feed, fetch_list, scope).compile()
+        with monitor.CompileEvent("Executor::aot"):
+            return self._lower_step(program, feed, fetch_list,
+                                    scope).compile()
 
     def _lower_step(self, program=None, feed=None, fetch_list=None,
                     scope=None, platforms=None, sharding=None):

@@ -17,6 +17,8 @@ executor's seams:
                  FLAGS_benchmark fence (block_until_ready inside the
                  timed window); without the fence it measures dispatch,
                  which is what the async hot path actually pays
+                 (span Executor::dispatch; Executor::first_dispatch, the
+                 call that traces, lowers and compiles, is compile_ms)
   fetch_ms       device->host conversion of the fetch list
   ckpt_save_ms   CheckpointManager.save durations (attached to the next
                  committed step record)
@@ -35,6 +37,23 @@ filesystem or fences the device; the always-on residue is a handful of
 counter increments and one deque append per step (the step-rate sample
 the straggler heartbeat rides on), unmeasurable next to any real step.
 
+The four phase fields are filled by the executor's spans
+(profiler.RecordEvent, handed the record), and by nothing else.
+
+What a compile is made of, counted where JAX itself measures it
+(`jax.monitoring`), and only while this thread is inside one of the
+executor's compile spans (CompileEvent: Executor::compile,
+Executor::first_dispatch, Executor::aot), so that no other jit of the
+process counts:
+
+  executor_trace_seconds_total            Program -> jaxpr (emit_ops)
+  executor_lower_seconds_total            jaxpr -> StableHLO module
+  executor_backend_compile_seconds_total  XLA's compile, or the read of
+                                          the executable from JAX's
+                                          persistent cache
+  executor_persistent_cache_hits_total    compile requests that cache
+                                          served
+
 Every number also lands in the process metrics registry
 (telemetry.get_registry()) for the Prometheus exposition.
 """
@@ -46,6 +65,7 @@ import time
 from typing import Optional, Tuple
 
 from ..telemetry import get_registry, goodput, sink
+from .profiler import RecordEvent
 
 _reg = get_registry()
 
@@ -189,6 +209,85 @@ def record_compile(ms: float, retrace: bool) -> None:
     if rec is not None:
         rec.compile_ms += ms
         rec.cache_hit = False
+
+
+# jax.monitoring's name -> the counter and its help. A duration event is
+# announced by a scalar of the same name when it starts, which is how the
+# outermost of nested ones is told: a kernel's inner jit is traced inside
+# the step's trace and reports a duration of its own within it.
+_COMPILE_SECONDS = {
+    "/jax/core/compile/jaxpr_trace_duration": (
+        "executor_trace_seconds_total",
+        "seconds tracing the executor's programs into jaxprs"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": (
+        "executor_lower_seconds_total",
+        "seconds lowering the executor's jaxprs to StableHLO"),
+    "/jax/core/compile/backend_compile_duration": (
+        "executor_backend_compile_seconds_total",
+        "seconds in XLA's compile, or the persistent cache's read, of "
+        "the executor's programs"),
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_listening = False
+
+
+def _compile_depths() -> Optional[dict]:
+    """This thread's open duration events by name, or None outside the
+    executor's compile spans."""
+    return getattr(_tls, "compile_depths", None)
+
+
+def _on_compile_scalar(name, value, **kw):
+    depths = _compile_depths()
+    if depths is not None and name in _COMPILE_SECONDS:
+        depths[name] = depths.get(name, 0) + 1
+
+
+def _on_compile_duration(name, secs, **kw):
+    depths = _compile_depths()
+    if depths is None or name not in _COMPILE_SECONDS:
+        return
+    depths[name] = max(depths.get(name, 1) - 1, 0)
+    if depths[name] == 0:
+        _counter(*_COMPILE_SECONDS[name]).inc(secs)
+
+
+def _on_compile_event(name, **kw):
+    if name == _CACHE_HIT_EVENT and _compile_depths() is not None:
+        _counter("executor_persistent_cache_hits_total",
+                 "compile requests of the executor's programs served by "
+                 "JAX's persistent compilation cache").inc()
+
+
+class CompileEvent(RecordEvent):
+    """The RecordEvent of a phase that holds the program's own compiles
+    (Executor::compile, Executor::first_dispatch, Executor::aot): while
+    one is open in this thread, what `jax.monitoring` reports is added
+    to the executor_* compile counters. The listeners are registered
+    once, by the first of these spans; outside one they return after a
+    thread-local read."""
+
+    __slots__ = ("_outermost",)
+
+    def __enter__(self):
+        global _listening
+        if not _listening:
+            import jax
+
+            _listening = True
+            jax.monitoring.register_scalar_listener(_on_compile_scalar)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_duration)
+            jax.monitoring.register_event_listener(_on_compile_event)
+        self._outermost = _compile_depths() is None
+        if self._outermost:
+            _tls.compile_depths = {}
+        return super().__enter__()
+
+    def __exit__(self, etype, evalue, tb):
+        if self._outermost:
+            _tls.compile_depths = None
+        return super().__exit__(etype, evalue, tb)
 
 
 def record_cache_hit() -> None:

@@ -5,12 +5,17 @@ EnableProfiler/DisableProfiler (:208,211), device_tracer.cc:61 (CUPTI
 capture), python profiler.py:131,198,255 (start_profiler, stop_profiler,
 profiler context manager) and tools/timeline.py (chrome trace export).
 
-TPU-native design: host spans are recorded by a Python RecordEvent (the
-executor wraps each run() in one); device-side timing comes from the JAX
-/ XLA profiler (xplane), the TPU analog of CUPTI. stop_profiler writes
-ONE chrome-trace JSON merging both (host pid 0, device pid 1 — open in
-chrome://tracing or Perfetto), prints the reference-style summary table,
-and leaves the raw xplane file beside it for xprof/tensorboard.
+TPU-native design: RecordEvent is the one host span of the framework (the
+executor and the loader time every phase through it, and nothing else);
+device-side timing comes from the JAX / XLA profiler (xplane), the TPU
+analog of CUPTI. A RecordEvent is a `jax.profiler.TraceAnnotation` first,
+so under any profiler session the host spans lie in the xplane's
+`/host:CPU` plane on the clock of the device lines. stop_profiler writes
+ONE chrome-trace JSON (host pid 0, device pid 1+ — open in
+chrome://tracing or Perfetto): from the xplane where a device trace ran,
+from the recorded host spans alone under state "CPU"; it prints the
+reference-style summary table and leaves the raw xplane file beside the
+JSON for xprof/tensorboard.
 """
 from __future__ import annotations
 
@@ -21,6 +26,10 @@ import tempfile
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+from ..telemetry import tracing as _tracing
 
 _lock = threading.Lock()
 _enabled = False
@@ -34,25 +43,68 @@ def is_profiler_enabled() -> bool:
 
 
 class RecordEvent:
-    """RAII host span (reference platform/profiler.h:126). Usable as a
-    context manager; zero cost when the profiler is off."""
+    """RAII host span (reference platform/profiler.h:126), a context
+    manager. On entry it opens a `jax.profiler.TraceAnnotation(name)`,
+    always: 0.3 us with no profiler session running, and under one the
+    span lies in the xplane beside the device's operations. The same
+    object feeds, each only while its consumer is armed:
 
-    def __init__(self, name: str):
+    - `_events`, the list behind the summary table and the chrome trace,
+      under start_profiler;
+    - `into`, the consumer of the span's milliseconds that the caller
+      hands in where one is armed: a record whose field `key` the span
+      adds to (the monitor's StepRecord), or, with no `key`, a histogram
+      that observes it;
+    - a span in telemetry.tracing's ring under PADDLE_TRACING, a child of
+      the thread's innermost one, with `attrs`.
+
+    With `step_num` the span is the root of a step: a
+    `StepTraceAnnotation`, so that xprof groups the spans of one step
+    under its number, and the ring's `step` root that RPCs and the step
+    record join on. `ms` holds the duration after exit."""
+
+    __slots__ = ("name", "ms", "_into", "_key", "_attrs", "_step_num",
+                 "_note", "_scope", "_start")
+
+    def __init__(self, name: str, into=None, key: Optional[str] = None,
+                 attrs: Optional[dict] = None,
+                 step_num: Optional[int] = None):
         self.name = name
-        self._start = 0
+        self.ms = 0.0
+        self._into = into
+        self._key = key
+        self._attrs = attrs
+        self._step_num = step_num
 
     def __enter__(self):
-        if _enabled:
-            self._start = time.perf_counter_ns()
+        step = self._step_num is not None
+        self._note = (StepTraceAnnotation(self.name, step_num=self._step_num)
+                      if step else TraceAnnotation(self.name))
+        self._note.__enter__()
+        self._scope = None
+        if _tracing.enabled():
+            self._scope = (_tracing.step_span(self._attrs) if step
+                           else _tracing.span(self.name, attrs=self._attrs))
+            self._scope.__enter__()
+        self._start = time.perf_counter_ns()
         return self
 
-    def __exit__(self, *exc):
-        if _enabled and self._start:
+    def __exit__(self, etype, evalue, tb):
+        end = time.perf_counter_ns()
+        self.ms = (end - self._start) / 1e6
+        if self._into is not None:
+            if self._key is None:
+                self._into.observe(self.ms)
+            else:
+                setattr(self._into, self._key,
+                        getattr(self._into, self._key) + self.ms)
+        if _enabled:
             with _lock:
-                _events.append(
-                    (self.name, threading.get_ident(), self._start,
-                     time.perf_counter_ns())
-                )
+                _events.append((self.name, threading.get_ident(),
+                                self._start, end))
+        if self._scope is not None:
+            self._scope.__exit__(etype, evalue, tb)
+        self._note.__exit__(etype, evalue, tb)
         return False
 
 
@@ -98,11 +150,13 @@ def stop_profiler(sorted_key: Optional[str] = "total",
 
     events = list(_events)
     _print_summary(events, sorted_key)
-    # one time base for both pids: host spans use perf_counter_ns and the
-    # xplane uses CLOCK_REALTIME-ish ns, so anchor each side to its own
-    # first timestamp — the two tracks then align at t=0
-    chrome = _host_chrome_events(events)
-    chrome += _device_chrome_events(_trace_dir)
+    # where a device trace ran, the xplane holds both tracks on one clock:
+    # every RecordEvent is a TraceAnnotation in its host plane, beside the
+    # device's operations. Only without one (state "CPU", or an xplane
+    # that cannot be read) is the recorded list the trace's only track.
+    chrome = _xplane_chrome_events(_trace_dir) if _trace_dir else []
+    if not chrome:
+        chrome = _host_chrome_events(events)
     out = profile_path if profile_path.endswith(".json") else profile_path + ".json"
     d = os.path.dirname(out)
     if d:  # dirless paths write to the cwd — nothing to create
@@ -322,8 +376,10 @@ def xplane_op_events(source) -> Dict[str, Dict[str, Any]]:
     return out
 
 
-def _device_chrome_events(trace_dir):
-    """Parse the xplane protobuf into chrome events (device pid 1+)."""
+def _xplane_chrome_events(trace_dir):
+    """The xplane as chrome events on one time base: the host plane (the
+    RecordEvents among its threads' lines) as pid 0, every device plane
+    as pid 1+."""
     xs = load_xplane(trace_dir)
     if xs is None:
         return []
@@ -333,19 +389,23 @@ def _device_chrome_events(trace_dir):
     for plane in xs.planes:
         if "TPU" not in plane.name and "CPU" not in plane.name.upper():
             continue
-        out.append({"name": "process_name", "ph": "M", "pid": pid,
-                    "args": {"name": f"device: {plane.name}"}})
+        host = plane.name == "/host:CPU"
+        p_ = 0 if host else pid
+        out.append({"name": "process_name", "ph": "M", "pid": p_,
+                    "args": {"name": "host (python)" if host
+                             else f"device: {plane.name}"}})
         for li, line in enumerate(plane.lines):
-            out.append({"name": "thread_name", "ph": "M", "pid": pid,
+            out.append({"name": "thread_name", "ph": "M", "pid": p_,
                         "tid": li, "args": {"name": line.name or f"line{li}"}})
             for ev in line.events:
                 meta = plane.event_metadata[ev.metadata_id]
                 start_ns = line.timestamp_ns + ev.offset_ps / 1e3
-                raw.append((meta.name[:120], pid, li, start_ns,
+                raw.append((meta.name[:120], p_, li, start_ns,
                             ev.duration_ps / 1e6))
-        pid += 1
+        if not host:
+            pid += 1
     if not raw:
-        return out
+        return []
     t0 = min(r[3] for r in raw)
     for name, p_, tid, start_ns, dur in raw:
         out.append({"name": name, "ph": "X", "pid": p_, "tid": tid,

@@ -15,30 +15,44 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
 from . import framework
+from .profiler import RecordEvent
 
 # ---------------------------------------------------------------------------
 # data-pipeline instrumentation (ISSUE 15): break the opaque data_wait
-# scalar into stages. Armed only while a telemetry consumer exists
-# (PADDLE_METRICS_PATH sink or the PADDLE_GOODPUT ledger) — flag-off,
-# every loader path below costs one cached bool read per epoch and the
-# produced batches are bit-identical either way.
+# scalar into stages. Every stage is one RecordEvent (fluid/profiler.py),
+# the executor's span: a TraceAnnotation in any profiler session, on the
+# line of the thread that runs it (the consumer's spans on the training
+# loop's, the producer's on its own), and the stage's histogram while a
+# telemetry consumer exists (PADDLE_METRICS_PATH sink or the
+# PADDLE_GOODPUT ledger) — flag-off the histograms are not created and
+# the produced batches are bit-identical either way.
 #
-#   data_fetch_ms    pulling one item/batch from the user's reader or
-#                    indexing the dataset (the producer side)
-#   data_decode_ms   collate_fn over the fetched samples (DataLoader)
-#   data_batch_ms    stacking samples into batch arrays (_stack_samples)
-#   data_h2d_ms      host array materialization of the yielded batch
-#                    (np.asarray before the feed; the device transfer
-#                    itself is charged to the executor's data_wait)
+#   span                     histogram
+#   DataLoader::produce      data_fetch_ms    pulling one item/batch from
+#                                             the user's reader or indexing
+#                                             the dataset (producer side)
+#   DataLoader::collate      data_decode_ms   collate_fn over the fetched
+#                                             samples (DataLoader)
+#   DataLoader::stack        data_batch_ms    stacking samples into batch
+#                                             arrays (_stack_samples)
+#   DataLoader::next                          the consumer's side of one
+#                                             batch: the wait on the
+#                                             prefetch queue, and inside it
+#     DataLoader::materialize data_h2d_ms     host array materialization
+#                                             (np.asarray before the feed;
+#                                             the device transfer itself is
+#                                             the executor's dispatch)
 #   data_queue_depth prefetch queue depth sampled at each consumer get
 #                    (0 = the consumer is starved, the producer is the
 #                    bottleneck; capacity = producer ahead, healthy)
+#
+# No span stays open across a `yield`: a generator's span would otherwise
+# hold whatever its consumer does between two batches.
 # ---------------------------------------------------------------------------
 
 def _pipeline_armed() -> bool:
@@ -47,13 +61,16 @@ def _pipeline_armed() -> bool:
     return sink.enabled() or goodput.enabled()
 
 
-def _stage_obs() -> Optional[dict]:
-    """The per-stage histograms, or None when no consumer is armed.
+_STAGES = ("fetch", "decode", "batch", "h2d")
+
+
+def _stage_obs() -> dict:
+    """The per-stage histograms, each None when no consumer is armed.
     Resolved from the registry per call (get-or-create dict lookups) so
     a registry reset() never strands observations on orphaned metrics;
     callers hold the returned dict for the whole epoch."""
     if not _pipeline_armed():
-        return None
+        return dict.fromkeys(_STAGES)
     from ..telemetry import get_registry
 
     reg = get_registry()
@@ -85,17 +102,22 @@ def _queue_gauge(loader: str):
         loader=loader)
 
 
-def _timed_source(it, hist):
-    """Wrap an iterator so each next() lands in `hist` (fetch stage)."""
+def _produced(it, hist):
+    """Wrap an iterator so each next() is a DataLoader::produce span
+    (fetch stage), closed before the item is handed on."""
     it = iter(it)
     while True:
-        t0 = time.perf_counter()
-        try:
-            item = next(it)
-        except StopIteration:
-            return
-        hist.observe((time.perf_counter() - t0) * 1e3)
+        with RecordEvent("DataLoader::produce", hist):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
         yield item
+
+
+def _materialized(item, hist):
+    with RecordEvent("DataLoader::materialize", hist):
+        return [np.asarray(a) for a in item]
 
 
 def _generator_producer(q, reader):
@@ -194,12 +216,9 @@ class GeneratorLoader:
 
         def worker():
             try:
-                source = self._batch_reader()
-                if obs is not None:
-                    # fetch stage: each batch pulled from the user's
-                    # reader, timed in the producer thread
-                    source = _timed_source(source, obs["fetch"])
-                for batch in source:
+                # fetch stage: each batch pulled from the user's reader,
+                # a span of the producer thread
+                for batch in _produced(self._batch_reader(), obs["fetch"]):
                     if not _put(batch):
                         return
             except BaseException as e:  # noqa: BLE001 — re-raised on consumer
@@ -213,15 +232,13 @@ class GeneratorLoader:
             while True:
                 if depth is not None:
                     depth.set(q.qsize())
-                item = q.get()
-                if item is _END:
-                    if err:
-                        raise err[0]
-                    return
-                t0 = time.perf_counter() if obs is not None else 0.0
-                arrays = [np.asarray(a) for a in item]
-                if obs is not None:
-                    obs["h2d"].observe((time.perf_counter() - t0) * 1e3)
+                with RecordEvent("DataLoader::next"):
+                    item = q.get()
+                    if item is _END:
+                        if err:
+                            raise err[0]
+                        return
+                    arrays = _materialized(item, obs["h2d"])
                 if self._return_list or not self._names:
                     yield arrays
                 else:
@@ -328,14 +345,10 @@ def _buffered_gen(gen, capacity=2, depth_gauge=None):
 
 
 def _stack_samples(samples):
-    obs = _stage_obs()
-    t0 = time.perf_counter() if obs is not None else 0.0
-    ncol = len(samples[0])
-    out = [np.stack([np.asarray(s[i]) for s in samples])
-           for i in range(ncol)]
-    if obs is not None:
-        obs["batch"].observe((time.perf_counter() - t0) * 1e3)
-    return out
+    with RecordEvent("DataLoader::stack", _stage_obs()["batch"]):
+        ncol = len(samples[0])
+        return [np.stack([np.asarray(s[i]) for s in samples])
+                for i in range(ncol)]
 
 
 class DataLoader:
@@ -393,25 +406,19 @@ class DataLoader:
     def _raw_batches(self):
         obs = _stage_obs()
 
-        def _collate_timed(items):
-            if obs is None:
+        def _collated(items):
+            with RecordEvent("DataLoader::collate", obs["decode"]):
                 return self._collate(items)
-            t0 = time.perf_counter()
-            out = self._collate(items)
-            obs["decode"].observe((time.perf_counter() - t0) * 1e3)
-            return out
 
         if self._iterable_ds:
             buf = []
-            source = (self._dataset if obs is None
-                      else _timed_source(self._dataset, obs["fetch"]))
-            for sample in source:
+            for sample in _produced(self._dataset, obs["fetch"]):
                 buf.append(sample)
                 if len(buf) == self._batch_size:
-                    yield _collate_timed(buf)
+                    yield _collated(buf)
                     buf = []
             if buf and not self._drop_last:
-                yield _collate_timed(buf)
+                yield _collated(buf)
             return
         batches = list(self._batch_sampler)
         if self._num_workers > 0:
@@ -424,13 +431,9 @@ class DataLoader:
             )
         else:
             for idx in batches:
-                if obs is None:
-                    yield self._collate([self._dataset[i] for i in idx])
-                    continue
-                t0 = time.perf_counter()
-                items = [self._dataset[i] for i in idx]
-                obs["fetch"].observe((time.perf_counter() - t0) * 1e3)
-                yield _collate_timed(items)
+                with RecordEvent("DataLoader::produce", obs["fetch"]):
+                    items = [self._dataset[i] for i in idx]
+                yield _collated(items)
 
     def __iter__(self):
         obs = _stage_obs()
@@ -438,11 +441,16 @@ class DataLoader:
         if self._use_buffer and self._num_workers == 0:
             gen = _buffered_gen(gen, capacity=2,
                                 depth_gauge=_queue_gauge("dataloader"))
-        for arrays in gen:
-            t0 = time.perf_counter() if obs is not None else 0.0
-            arrays = [np.asarray(a) for a in arrays]
-            if obs is not None:
-                obs["h2d"].observe((time.perf_counter() - t0) * 1e3)
+        gen = iter(gen)
+        while True:
+            # the wait for the batch (the prefetch queue's get, or the
+            # workers') runs inside next(gen), in this thread
+            with RecordEvent("DataLoader::next"):
+                try:
+                    arrays = next(gen)
+                except StopIteration:
+                    return
+                arrays = _materialized(arrays, obs["h2d"])
             yield arrays if self._return_list else dict(zip(self._names, arrays))
 
     @staticmethod

@@ -38,9 +38,55 @@ def test_profiler_records_executor_spans(tmp_path, capsys):
     assert "Executor::run" in names and "Executor::compile" in names
     assert "user_span" in names
     runs = [e for e in trace["traceEvents"] if e["name"] == "Executor::run"]
-    # startup + 3 steps (compile events are separate)
-    assert len(runs) >= 4
+    # startup + 3 steps: Executor::run is the CALL, every phase inside it
+    assert len(runs) == 4
     assert all(e["dur"] >= 0 and "ts" in e for e in runs)
+    calls = [e for e in trace["traceEvents"]
+             if e["name"] in ("Executor::dispatch",
+                              "Executor::first_dispatch")]
+    assert [e["name"] for e in sorted(calls, key=lambda e: e["ts"])] == (
+        ["Executor::first_dispatch"] * 2 + ["Executor::dispatch"] * 2)
+    for call in calls:
+        assert any(r["ts"] <= call["ts"]
+                   and call["ts"] + call["dur"] <= r["ts"] + r["dur"]
+                   for r in runs)
+
+
+def test_a_host_span_encloses_the_device_work_it_waited_for(tmp_path, capsys):
+    """State "All": both tracks of the chrome trace come from the xplane,
+    where a RecordEvent is a TraceAnnotation on the clock of the device's
+    operations (on the CPU backend, XLA's own threads in the host plane).
+    Each side used to be anchored to its own first timestamp, which
+    shifted them against each other by whatever lay between the start of
+    the trace and the first host span: here, a sleep."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def work(x):
+        return jnp.tanh(x @ x).sum()
+
+    x = jnp.ones((256, 256))
+    work(x).block_until_ready()  # compiled before the trace starts
+    path = str(tmp_path / "both")
+    profiler.start_profiler(state="All")
+    time.sleep(0.05)
+    with profiler.RecordEvent("host_span"):
+        work(x).block_until_ready()
+    profiler.stop_profiler(profile_path=path)
+    assert "host_span" in capsys.readouterr().out  # the summary table
+    events = [e for e in json.load(open(path + ".json"))["traceEvents"]
+              if e.get("ph") == "X"]
+    (span,) = [e for e in events if e["name"] == "host_span"]
+    ops = [e for e in events if e["name"].startswith("dot_general")]
+    assert ops, sorted({e["name"] for e in events})[:40]
+    for op in ops:
+        assert span["ts"] <= op["ts"]
+        assert op["ts"] + op["dur"] <= span["ts"] + span["dur"]
+    # one time base: the span starts the sleep after the trace's first event
+    assert span["ts"] >= 50e3
 
 
 def test_record_event_is_noop_when_disabled():

@@ -408,10 +408,19 @@ def test_step_span_children_and_record_join(traced, tmp_path,
         assert r["trace_id"] in trace_ids
     # breakdown children parent under the step root
     root_ids = {s["span"] for s in roots}
-    for name in ("data_wait", "device", "fetch"):
+    # (the executor's phases, one RecordEvent each: fluid/profiler.py)
+    for name in ("Executor::feed", "Executor::lookup", "Executor::state",
+                 "Executor::dispatch", "Executor::commit",
+                 "Executor::fetch"):
         assert by[name], f"missing {name} spans"
         assert all(s["parent"] in root_ids for s in by[name])
-    assert by["compile"], "cache-miss step must record a compile span"
+    # a cache-miss step records the compile under its look-up, and its
+    # first call (tracing, lowering, XLA's compile) under another name
+    assert by["Executor::compile"], "cache-miss step must record a compile span"
+    lookups = {s["span"] for s in by["Executor::lookup"]}
+    assert all(s["parent"] in lookups for s in by["Executor::compile"])
+    assert by["Executor::compile"][0]["attrs"] == {"retrace": False}
+    assert len(by["Executor::first_dispatch"]) == 2  # startup and main
     assert tracing.last_step_trace_id() in trace_ids
 
 
