@@ -328,6 +328,16 @@ def record_mhc_post_lowering(impl: str) -> None:
                  impl=impl).inc()
 
 
+def record_mhc_map_lowering(impl: str) -> None:
+    """Called by ops/latent_ops.py each time `mhc_map` is traced into a
+    step: `impl` is what was lowered (`pallas`, the forward kernel of
+    ops/pallas/mhc.py, or `jnp`, the composition in both passes). A
+    lowering-time counter, like `mhc_post`'s."""
+    _reg.counter("mhc_map_lowerings_total",
+                 help="mhc_map ops traced, by implementation",
+                 impl=impl).inc()
+
+
 def record_ssd_scan_lowering(impl: str) -> None:
     """Called by ops/ssm_ops.py each time a `mamba2` op, and the state-space
     scan inside it, is traced into a step: `impl` is what was lowered

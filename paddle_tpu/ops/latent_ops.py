@@ -24,6 +24,8 @@ Scopes: `mla`, `mhc_map`, `mhc_mix`.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -124,16 +126,32 @@ def mhc_map(ctx, ins, attrs):
     HPost = 2 sigmoid(..) [B, S, n], HRes = Sinkhorn(exp(clamp(a_res
     mat(xbar Phi_res) + b_res))) [B, S, n*n] row-major, doubly stochastic.
     SinkhornGap [n]: for each i the worst |sum - 1| of row i or column i
-    over the tokens, to see whether the rounds sufficed."""
-    n = int(attrs["streams"])
-    fn = jax.checkpoint(lambda x, phi, bias, alpha: _mhc_map(
-        x, phi, bias, alpha, n=n, eps=float(attrs["epsilon"]),
+    over the tokens, to see whether the rounds sufficed. On the TPU, at
+    four streams in whole lane tiles and 128 rows that tile S, the forward
+    pass is the one-pass kernel of ops/pallas/mhc.py (`mhc.map_rows` is
+    the gate; the backward pass is `_mhc_map`'s, see `mhc._map_core_bwd`);
+    `_mhc_map` everywhere else. Either way only the four inputs are kept
+    for the backward pass."""
+    from ..fluid.monitor import record_mhc_map_lowering
+    from .pallas import mhc
+
+    args = ins["X"][0], ins["Phi"][0], ins["Bias"][0], ins["Alpha"][0]
+    settings = dict(
+        n=int(attrs["streams"]), eps=float(attrs["epsilon"]),
         iters=int(attrs["sinkhorn_iters"]),
         clamp_min=float(attrs["clamp_min"]),
-        clamp_max=float(attrs["clamp_max"])))
+        clamp_max=float(attrs["clamp_max"]))
+    # XLA cannot partition a Mosaic call: over a mesh, the composition
+    alone = ctx.mesh is None or ctx.mesh.size == 1
+    rows = (mhc.map_rows(args[0], args[1], settings["n"], settings["iters"])
+            if alone else None)
+    record_mhc_map_lowering("jnp" if rows is None else "pallas")
     with jax.named_scope("mhc_map"):
-        pre, post, res, gap = fn(ins["X"][0], ins["Phi"][0], ins["Bias"][0],
-                                 ins["Alpha"][0])
+        if rows is None:
+            pre, post, res, gap = jax.checkpoint(
+                functools.partial(_mhc_map, **settings))(*args)
+        else:
+            pre, post, res, gap = mhc.mhc_map(*args, rows, **settings)
     return {"HPre": [pre], "HPost": [post], "HRes": [res],
             "SinkhornGap": [gap]}
 

@@ -241,3 +241,21 @@ def mhc_vmem_bytes(pass_: str, rows: int, c: int, n: int,
     return (2 * itemsize * rows * c * (wide * n + narrow)
             + 4 * maps * (2 * held * rows + rows * 128) + 4 * rows * rows
             + 2 ** 20)
+
+
+def mhc_map_vmem_bytes(pass_: str, rows: int, width: int, n: int,
+                       itemsize: int, iters: int) -> int:
+    """Footprint of one grid cell of `mhc_map`'s kernels over `rows` tokens
+    of streams `width` = n c columns wide, every block double-buffered.
+    fwd: the [rows, width] streams in and 80 rows of the bf16 stack of
+    Phi^T's terms; bwd: the streams in and their cotangent out, the
+    stack's 160 rows, dPhi^T [m, width] in float32 and the rounds'
+    inputs, 2 x iters blocks of [n n, rows]. Both: a column chunk's
+    float32 temporaries (512 columns: the block, its square or its
+    cotangent, a product) and the small [m, rows] blocks, counted as 64
+    of [128, rows]."""
+    m = 2 * n + n * n
+    streams, stack, sums = {"fwd": (1, 80, 0), "bwd": (2, 160, 1)}[pass_]
+    return (2 * itemsize * rows * width * streams + 2 * 2 * stack * width
+            + sums * (2 * 4 * m * width + 4 * 2 * iters * n * n * rows)
+            + 4 * 4 * rows * 512 + 64 * 4 * 128 * rows + 2 ** 20)

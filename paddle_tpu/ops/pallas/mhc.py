@@ -1,5 +1,6 @@
 """`mhc_post` of the hyper-connected residual (ops/latent_ops.py) as two
-Pallas kernels for the TPU, each one pass over its operands.
+Pallas kernels for the TPU, each one pass over its operands; below them
+`mhc_map`, the three mappings of a sublayer, the same way.
 
     X'_i = sum_j H_res[i, j] X_j + H_post[i] y        i, j < n streams
 
@@ -40,6 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -287,9 +289,10 @@ def _mhc_bwd(g, x, y, ht, *, br, interpret):
     )(g, x, y, ht)
 
 
-def _stacked(h_res, h_post):
-    """H_res^T over H_post^T, [n n + n, B S]: the tokens on the lanes."""
-    h = jnp.concatenate([h_res, h_post], axis=-1)
+def _stacked(*maps):
+    """The mappings [B, S, k] one over the other with the tokens on the
+    lanes, [sum k, B S]: H_res^T over H_post^T for `mhc_post`'s kernels."""
+    h = jnp.concatenate(maps, axis=-1)
     return h.reshape(-1, h.shape[-1]).T
 
 
@@ -327,3 +330,440 @@ def mhc_post(x, y, h_res, h_post, br: int):
                 sequences(h_res.astype(jnp.float32)),
                 sequences(h_post.astype(jnp.float32)), br)
     return out.reshape(x.shape)
+
+
+# ---------------------------------------------------------------------------
+# mhc_map: the three mappings of a sublayer, one pass over the streams
+# ---------------------------------------------------------------------------
+#
+#   inv = rsqrt(mean(vec(X)^2) + eps),  l = a (inv X Phi) + b            [m, T]
+#   H_pre = sigmoid(l_pre), H_post = 2 sigmoid(l_post),
+#   H_res = Sinkhorn(exp(clip(l_res)))          m = 2 n + n n rows, n = 4
+#
+# Both kernels hold a block of `br` rows of X [B, S, n C] at full width and
+# pass its columns `_map_chunk` at a time through the MXU **with X's tile as
+# the stationary operand**, so that every result has the tokens on the
+# lanes: H^T [m, T] leaves as `mhc_post`'s kernels take it.
+#
+# **The same numbers without a float32 copy of X.** A float32 is three
+# bf16 terms exactly (`_split3`: 8 + 8 + 8 bits), and a bf16 x bf16 product
+# is exact in the MXU's float32 accumulator. Phi^T crosses the call as
+# those terms stacked on the rows of one bf16 matrix [160, n C],
+# `_phi_stack`: hi, mid, lo, hi, hi, mid, sixteen rows of zeros. The
+# projection is the stack's first rows against X's bf16 block, one MXU
+# pass, and the sum of the hi, mid and lo rows: what `Precision.HIGHEST`
+# computes, X having one term. dPhi^T is d(raw)'s three terms against the
+# same block. dX contracts over m, so the terms go on the contraction:
+# d(raw)'s [hi, hi, hi, mid, lo, mid] against the stack's [hi, mid, lo, hi,
+# hi, mid], the six pairs of the highest precision (those left out are
+# 2 ** -24 of the product and less), 144 deep. Float32 streams are split
+# inside the kernel, all their terms against all of the stack's.
+#
+# Sinkhorn's rounds are one rolled loop on [n n, br] (row i n + j of the
+# block is entry (i, j), the tokens on the lanes); the sums over j and over
+# i are sublane rotations. The backward kernel keeps every round's input in
+# VMEM (2 x iters blocks of [n n, br] float32) and walks them back by hand:
+# y = m / sum(m) gives dm = (dy - sum(dy y)) / sum(m).
+
+# rows of the stack of Phi^T's terms: six of m = 24 and a bf16 tile of zeros
+_STACK = 160
+# rows of the stack the projection takes: hi, mid, lo and a bf16 tile's rest
+_PROJ_ROWS = 80
+
+
+def _map_chunk(width: int) -> int:
+    """Columns a trip of the column loops holds."""
+    return next(w for w in (512, 256, _LANES) if width % w == 0)
+
+
+def default_map_rows(batch: int, s: int, width: int, n: int, itemsize: int,
+                     iters: int) -> Optional[int]:
+    """THE row-block chooser of `mhc_map`'s kernels, from the shapes alone:
+    the tokens lie on the lanes of every small block, so a block is whole
+    lane tiles of rows that tile s: 256 where the backward cell (the larger
+    of the two) fits the budget, else 128. At the Xing4 cell's operands
+    128 / 256 read 0.474 / 0.377 ms forward and 1.168 / 0.978 backward (my
+    chip run, PR 37): a cell's epilogue, Sinkhorn's rounds among it, is a
+    chain that no DMA hides, and 256 rows halve its share. None where n is
+    not 4 (H_res has to start on a sublane tile and the terms' groups on
+    bf16 tiles: m = 24), the width is not whole lane tiles a stream or no
+    block serves s; then `_mhc_map` runs."""
+    if n != 4 or width % (n * _LANES):
+        return None
+    for rows in (2 * _ROWS, _ROWS):
+        if s % rows == 0 and _feas.mhc_map_vmem_bytes(
+                "bwd", rows, width, n, itemsize, iters
+        ) <= _feas.MHC_VMEM_BUDGET:
+            return rows
+    return None
+
+
+def map_rows(x, phi, n: int, iters: int) -> Optional[int]:
+    """THE backend / shape gate of `mhc_map`: the chooser's row block on
+    the TPU (or where a test pins the kernels, interpreted) for bf16 or
+    float32 streams [B, S, n C] and Phi [n C, 2 n + n n], else None."""
+    from ..attention import FORCE_PALLAS
+
+    if _interpret() and not FORCE_PALLAS:
+        return None
+    if x.dtype not in (jnp.bfloat16, jnp.float32) or x.ndim != 3:
+        return None
+    if phi.shape != (x.shape[-1], 2 * n + n * n):
+        return None
+    return default_map_rows(x.shape[0], x.shape[1], x.shape[2], n,
+                            x.dtype.itemsize, iters)
+
+
+def _split3(a, rounded):
+    """Float32 a as three float32 terms, each a bf16 value, hi + mid + lo
+    = a exactly; `rounded` rounds a float32 to the nearest bf16 value."""
+    hi = rounded(a)
+    mid = rounded(a - hi)
+    return hi, mid, rounded(a - hi - mid)
+
+
+def _as_bf16(a):
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _phi_stack(phi):
+    """Phi [n C, m] float32 -> the bf16 stack [160, n C] of Phi^T's terms.
+    `reduce_precision`, not a pair of converts: XLA may drop those."""
+    hi, mid, lo = _split3(
+        phi.astype(jnp.float32).T,
+        lambda a: lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7))
+    rows = jnp.concatenate([hi, mid, lo, hi, hi, mid], axis=0)
+    return jnp.pad(rows, ((0, _STACK - rows.shape[0]), (0, 0))).astype(
+        jnp.bfloat16)
+
+
+def _terms(x):
+    """A block of the streams as bf16 terms that sum to it exactly."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    return tuple(t.astype(jnp.bfloat16) for t in _split3(x, _as_bf16))
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, ((contract, ((), ()))),
+                           preferred_element_type=jnp.float32)
+
+
+def _folded(acc, m):
+    """hi + mid + lo of a product's stacked rows, the small terms first."""
+    return acc[2 * m:3 * m] + acc[m:2 * m] + acc[:m]
+
+
+def _project(x_ref, p_ref, m):
+    """(X Phi)^T [m, br] and the rows' sums of squares [1, br], float32,
+    while the held block's columns pass once."""
+    br, width = x_ref.shape
+    kc = _map_chunk(width)
+
+    def columns(k, carry):
+        acc, squares = carry
+        at = pl.ds(pl.multiple_of(k * kc, kc), kc)
+        x = x_ref[:, at]
+        for term in _terms(x):
+            acc = acc + _dot(p_ref[:_PROJ_ROWS, at], term, ((1,), (1,)))
+        xf = x.astype(jnp.float32)
+        xf = xf * xf
+        for tile in range(0, kc, _LANES):
+            squares = squares + xf[:, tile:tile + _LANES]
+        return acc, squares
+
+    acc, squares = lax.fori_loop(
+        0, width // kc, columns,
+        (jnp.zeros((_PROJ_ROWS, br), jnp.float32),
+         jnp.zeros((br, _LANES), jnp.float32)))
+    # the fold over the lanes lands the sums on them: ones [8, 128] against
+    # the partial sums' lanes, exact at the highest precision
+    total = lax.dot_general(
+        jnp.ones((_GROUP, _LANES), jnp.float32), squares,
+        (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return _folded(acc, m), total[:1]
+
+
+def _sum_j(a, n, row):
+    """[n n, br] -> each row's sum over its j (the n rows of its i)."""
+    total, at = a, row % n
+    for k in range(1, n):
+        total = total + jnp.where(at < n - k,
+                                  pltpu.roll(a, n * n - k, 0),
+                                  pltpu.roll(a, n - k, 0))
+    return total
+
+
+def _sum_i(a, n, row):
+    """[n n, br] -> each row's sum over its i (every n-th row)."""
+    total = a
+    for k in range(1, n):
+        total = total + pltpu.roll(a, k * n, 0)
+    return total
+
+
+_SUMS = (_sum_j, _sum_i)  # a round: over j (a row of H_res), then over i
+
+
+def _rounds(e, iters, n, row, kept=None):
+    """Sinkhorn's rounds on e [n n, br]; every division's input goes to
+    kept[2 r], kept[2 r + 1] where the backward pass wants them."""
+    def round_(r, a):
+        for half, total in enumerate(_SUMS):
+            if kept is not None:
+                kept[2 * r + half] = a
+            a = a / total(a, n, row)
+        return a
+
+    return lax.fori_loop(0, iters, round_, e)
+
+
+def _rounds_back(g, y, iters, n, row, kept):
+    """The cotangent of e from g, that of y = `_rounds(e)`."""
+    def round_(r, carry):
+        g, y = carry
+        for half in (1, 0):
+            a = kept[2 * (iters - 1 - r) + half]
+            total = _SUMS[half]
+            g = (g - total(g * y, n, row)) / total(a, n, row)
+            y = a
+        return g, y
+
+    return lax.fori_loop(0, iters, round_, (g, y))[0]
+
+
+def _logits(x_ref, p_ref, coef_ref, *, n, eps):
+    """raw = (X Phi)^T, inv, proj = raw inv and l = a proj + b."""
+    m = 2 * n + n * n
+    raw, squares = _project(x_ref, p_ref, m)
+    inv = lax.rsqrt(squares / x_ref.shape[1] + eps)
+    proj = raw * inv
+    return raw, inv, proj, coef_ref[:, 0:1] * proj + coef_ref[:, 1:2]
+
+
+def _gates(logits, n):
+    """sigmoid of the first 2 n rows and the factor (1, 2) of each."""
+    gate = jax.nn.sigmoid(logits[:2 * n])
+    row = lax.broadcasted_iota(jnp.int32, gate.shape, 0)
+    return gate, jnp.where(row < n, 1.0, 2.0)
+
+
+def _map_fwd_kernel(x_ref, p_ref, coef_ref, ht_ref, *, n, eps, iters, lo, hi):
+    _, _, _, logits = _logits(x_ref, p_ref, coef_ref, n=n, eps=eps)
+    gate, factor = _gates(logits, n)
+    ht_ref[:2 * n, :] = factor * gate
+    e = jnp.exp(jnp.clip(logits[2 * n:], lo, hi))
+    row = lax.broadcasted_iota(jnp.int32, e.shape, 0)
+    ht_ref[2 * n:, :] = _rounds(e, iters, n, row)
+
+
+def _map_bwd_kernel(x_ref, p_ref, coef_ref, dht_ref, dx_ref, dphi_ref,
+                    dcoef_ref, kept, *, n, eps, iters, lo, hi):
+    m = 2 * n + n * n
+    br, width = x_ref.shape
+    kc = _map_chunk(width)
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        dphi_ref[...] = jnp.zeros_like(dphi_ref)
+        dcoef_ref[...] = jnp.zeros_like(dcoef_ref)
+
+    raw, inv, proj, logits = _logits(x_ref, p_ref, coef_ref, n=n, eps=eps)
+    gate, factor = _gates(logits, n)
+    inside = (logits[2 * n:] > lo) & (logits[2 * n:] < hi)
+    e = jnp.exp(jnp.clip(logits[2 * n:], lo, hi))
+    row = lax.broadcasted_iota(jnp.int32, e.shape, 0)
+    res = _rounds(e, iters, n, row, kept)
+    de = _rounds_back(dht_ref[2 * n:, :], res, iters, n, row, kept)
+    dl = jnp.concatenate(
+        [dht_ref[:2 * n, :] * factor * gate * (1.0 - gate),
+         jnp.where(inside, de * e, 0.0)], axis=0)
+    dcoef_ref[:m, :] += dl * proj       # -> dAlpha, a sum over rows too
+    dcoef_ref[m:, :] += dl              # -> dBias
+    dproj = dl * coef_ref[:, 0:1]
+    draw = dproj * inv
+    # inv = (mean(x^2) + eps)^(-1/2): the norm's term of dX is c x
+    c = (jnp.sum(dproj * raw, axis=0, keepdims=True)
+         * inv * inv * inv * (-1.0 / width))
+    d_hi, d_mid, d_lo = _split3(draw, _as_bf16)
+    # d(raw)'s terms, the tokens on the lanes, as dPhi's product takes
+    # them (hi, mid, lo from row 2 m) and, turned, as dX's (six groups
+    # against the stack's; c rides behind them, against the zeros)
+    terms = jnp.concatenate(
+        [d_hi, d_hi, d_hi, d_mid, d_lo, d_mid,
+         jnp.broadcast_to(c, (_STACK - 6 * m, br))], axis=0)
+    turned = terms.T
+    c_rows = turned[:, 6 * m:6 * m + 1]
+    turned = turned.astype(jnp.bfloat16)
+    tail = terms[2 * m:2 * m + _PROJ_ROWS].astype(jnp.bfloat16)
+
+    def columns(k):
+        at = pl.ds(pl.multiple_of(k * kc, kc), kc)
+        x = x_ref[:, at]
+        acc = jnp.zeros((_PROJ_ROWS, kc), jnp.float32)
+        for term in _terms(x):
+            acc = acc + _dot(tail, term, ((1,), (0,)))
+        dphi_ref[:, at] += _folded(acc, m)
+        dx = _dot(turned, p_ref[:, at], ((1,), (0,)))
+        dx_ref[:, at] = (dx + c_rows * x.astype(jnp.float32)).astype(
+            dx_ref.dtype)
+
+    _over(width // kc, columns)
+
+
+def _map_specs(br, s, width, m):
+    """Blocks of [B, S, n C] rows, the stack, the [m, 2] coefficients (a
+    and b of each row) and H^T [m, B S]; the grid is (B, S // br)."""
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda b, i: (0,) * len(shape),
+                            memory_space=pltpu.VMEM)
+
+    return (pl.BlockSpec((None, br, width), lambda b, i: (b, i, 0),
+                         memory_space=pltpu.VMEM),
+            whole, whole(m, 2),
+            pl.BlockSpec((m, br), lambda b, i: (0, b * (s // br) + i),
+                         memory_space=pltpu.VMEM))
+
+
+def _map_params(pass_, semantics, br, width, n, itemsize, iters):
+    return pltpu.CompilerParams(
+        dimension_semantics=(semantics,) * 2,
+        vmem_limit_bytes=(_feas.mhc_map_vmem_bytes(
+            pass_, br, width, n, itemsize, iters) + _feas.MHC_VMEM_SLACK))
+
+
+_MAP_STATICS = ("n", "eps", "iters", "lo", "hi", "br", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_MAP_STATICS)
+def _map_fwd(x, stack, coef, *, n, eps, iters, lo, hi, br, interpret):
+    """x [B, S, n C], `_phi_stack(phi)`, coef [m, 2] -> H^T [m, B S]
+    float32: H_pre^T over H_post^T over H_res^T. An inner jit, like
+    `_mhc_fwd`: a step's sublayers share one traced and lowered body."""
+    b, s, width = x.shape
+    m = 2 * n + n * n
+    streams, whole, coefs, maps = _map_specs(br, s, width, m)
+    return pl.pallas_call(
+        functools.partial(_map_fwd_kernel, n=n, eps=eps, iters=iters, lo=lo,
+                          hi=hi),
+        grid=(b, s // br),
+        in_specs=[streams, whole(_PROJ_ROWS, width), coefs],
+        out_specs=maps,
+        out_shape=jax.ShapeDtypeStruct((m, b * s), jnp.float32),
+        compiler_params=_map_params("fwd", "parallel", br, width, n,
+                                    x.dtype.itemsize, iters),
+        name="mhc_map_fwd",
+        interpret=interpret,
+    )(x, stack, coef)
+
+
+@functools.partial(jax.jit, static_argnames=_MAP_STATICS)
+def _map_bwd(x, stack, coef, dht, *, n, eps, iters, lo, hi, br, interpret):
+    """The cotangents from dht, that of `_map_fwd`'s H^T: dX in x's dtype;
+    dPhi^T [m, n C] and [2 m, br] (rows of sum dl proj over rows of sum dl,
+    the lanes still to be summed), float32 sums over the row blocks."""
+    b, s, width = x.shape
+    m = 2 * n + n * n
+    streams, whole, coefs, maps = _map_specs(br, s, width, m)
+    return pl.pallas_call(
+        functools.partial(_map_bwd_kernel, n=n, eps=eps, iters=iters, lo=lo,
+                          hi=hi),
+        grid=(b, s // br),
+        in_specs=[streams, whole(_STACK, width), coefs, maps],
+        out_specs=[streams, whole(m, width), whole(2 * m, br)],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((m, width), jnp.float32),
+            jax.ShapeDtypeStruct((2 * m, br), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((2 * iters, n * n, br), jnp.float32)],
+        # the sums over the row blocks stay in VMEM from cell to cell
+        compiler_params=_map_params("bwd", "arbitrary", br, width, n,
+                                    x.dtype.itemsize, iters),
+        name="mhc_map_bwd",
+        interpret=interpret,
+    )(x, stack, coef, dht)
+
+
+def _coef(bias, alpha, n):
+    """[m, 2]: each row's a (of its mapping) beside its b."""
+    a = jnp.repeat(alpha.astype(jnp.float32), np.array([n, n, n * n]))
+    return jnp.stack([a, bias.astype(jnp.float32)], axis=1)
+
+
+def _map_kwargs(static):
+    return dict(zip(_MAP_STATICS, static + (_interpret(),)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _map_core(x, phi, bias, alpha, static):
+    return _map_fwd(x, _phi_stack(phi), _coef(bias, alpha, static[0]),
+                    **_map_kwargs(static))
+
+
+def _map_core_fwd(x, phi, bias, alpha, static):
+    # the residuals are the op's inputs, as jax.checkpoint keeps them
+    return _map_core(x, phi, bias, alpha, static), (x, phi, bias, alpha)
+
+
+def map_cotangents(x, phi, bias, alpha, dht, static):
+    """(dX, dPhi, dBias, dAlpha) from dht, the cotangent of H^T, by
+    `mhc_map_bwd`: one pass over the streams."""
+    n = static[0]
+    m = 2 * n + n * n
+    dx, dphi_t, dcoef = _map_bwd(x, _phi_stack(phi), _coef(bias, alpha, n),
+                                 dht, **_map_kwargs(static))
+    dcoef = jnp.sum(dcoef, axis=1)
+    dalpha = jnp.stack([jnp.sum(dcoef[:n]), jnp.sum(dcoef[n:2 * n]),
+                        jnp.sum(dcoef[2 * n:m])])
+    return (dx, dphi_t.T.astype(phi.dtype), dcoef[m:].astype(bias.dtype),
+            dalpha.astype(alpha.dtype))
+
+
+def _composition_t(x, phi, bias, alpha, static):
+    """H^T [m, B S] by `latent_ops._mhc_map`."""
+    from .. import latent_ops
+
+    n, eps, iters, lo, hi, _ = static
+    return _stacked(*latent_ops._mhc_map(
+        x, phi, bias, alpha, n=n, eps=eps, iters=iters, clamp_min=lo,
+        clamp_max=hi)[:3])
+
+
+def _map_core_bwd(static, saved, dht):
+    # NOT `map_cotangents`, which is 3.4 times as fast (0.98 against 3.36
+    # ms a call at the Xing4 cell's operands, and the cell's step 249.4
+    # against 275.3 ms; my chip runs, PR 37): with the backward kernel the
+    # cell's compiled step reads 14.895 GB where the parent's reads 14.312,
+    # four times the benchmark's bound on `peak_hbm_gb`. XLA fits a step
+    # under 15.0 GB and no further: the composition's float32 copy of the
+    # streams, 0.47 GB in every sublayer's backward pass, is what made it
+    # rematerialize a norm's and a projection's output in each layer, and
+    # without the copy it keeps them (PERF.md section 6, PR 37). The kernel
+    # goes in with a change that lowers what the step keeps from its
+    # forward pass (ROADMAP.md S13).
+    return jax.vjp(lambda *a: _composition_t(*a, static), *saved)[1](dht)
+
+
+_map_core.defvjp(_map_core_fwd, _map_core_bwd)
+
+
+def mhc_map(x, phi, bias, alpha, br: int, *, n, eps, iters, clamp_min,
+            clamp_max):
+    """`latent_ops._mhc_map` at the row block `map_rows` chose: H_pre,
+    H_post [B, S, n], H_res [B, S, n n] and the gap [n], float32, by
+    `mhc_map_fwd`; the cotangents by the composition (`_map_core_bwd` says
+    why). The mappings leave the kernel as H^T with the tokens on the
+    lanes; the `.T.reshape` here and `_stacked`'s in front of `mhc_post`'s
+    kernels undo one another."""
+    b, s, _ = x.shape
+    ht = _map_core(x, phi, bias, alpha,
+                   (n, eps, iters, clamp_min, clamp_max, br))
+    res = ht[2 * n:]
+    by_entry = lax.stop_gradient(res).reshape(n, n, b * s)
+    gap = jnp.maximum(
+        jnp.max(jnp.abs(jnp.sum(by_entry, axis=1) - 1.0), axis=-1),
+        jnp.max(jnp.abs(jnp.sum(by_entry, axis=0) - 1.0), axis=-1))
+    return (ht[:n].T.reshape(b, s, n), ht[n:2 * n].T.reshape(b, s, n),
+            res.T.reshape(b, s, n * n), gap)

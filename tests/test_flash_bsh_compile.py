@@ -210,6 +210,48 @@ def test_the_mhc_post_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
         "mhc_post_fwd": 250, "mhc_post_bwd": 500}[kernel]
 
 
+@pytest.mark.parametrize("batch", [2, 1], ids=["step", "check"])
+@pytest.mark.parametrize("kernel", ["mhc_map_fwd", "mhc_map_bwd"])
+def test_the_mhc_map_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
+                                                 kernel, batch):
+    """The Xing4 cell's operands (two sequences of 4,096 tokens a step, one
+    in its check program, four streams of 3,584 bf16 columns, twenty rounds)
+    at the chooser's row block: Mosaic takes the bf16 products with the
+    streams' tile stationary, the 144-deep contraction of dX, the sublane
+    rotations of Sinkhorn's sums, the turned block of d(raw)'s terms and the
+    scoped-VMEM limit the byte model asks for. Sinkhorn's rounds are rolled
+    loops: the traced bodies do not go with their number."""
+    from paddle_tpu.ops.pallas import feasible, mhc
+
+    n, width, iters = 4, 4 * 3584, 20
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = shaped(batch, 4096, width)
+    with mock.patch.object(mhc, "_interpret", lambda: False):
+        rows = mhc.map_rows(x, shaped(width, 24, dtype=jnp.float32), n, iters)
+    assert rows == 256
+    assert feasible.mhc_map_vmem_bytes(
+        kernel[-3:], rows, width, n, 2, iters) <= feasible.MHC_VMEM_BUDGET
+    operands = [x, shaped(160, width), shaped(24, 2, dtype=jnp.float32)]
+    call = {"mhc_map_fwd": mhc._map_fwd, "mhc_map_bwd": mhc._map_bwd}[kernel]
+    if kernel == "mhc_map_bwd":
+        operands.append(shaped(24, batch * 4096, dtype=jnp.float32))
+
+    def traced(rounds):
+        return jax.jit(lambda *a: call(
+            *a, n=n, eps=1e-6, iters=rounds, lo=-30.0, hi=30.0, br=rows,
+            interpret=False)).trace(*operands)
+
+    twenty = traced(iters)
+    text = twenty.lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert kernel in text and "tpu_custom_call" in text
+    eqns = _pallas_calls(twenty.jaxpr.jaxpr, {})[kernel]
+    assert eqns == _pallas_calls(traced(2).jaxpr.jaxpr, {})[kernel]
+    assert eqns <= {"mhc_map_fwd": 400, "mhc_map_bwd": 900}[kernel]
+
+
 # ---------------------------------------------------------------------------
 # a whole step at published widths, in this file because one process of a
 # test run may describe the topology
@@ -222,8 +264,9 @@ def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
     builds it, at the cell's batch and the published widths, compiled for
     the described chip with its state given as shapes (656 M parameters
     are not allocated here): Mosaic takes the padded latent-attention
-    flash calls, the grouped products at 3584 x 1024 and the stream-mixing
-    kernels, the step holds every call the configuration lists, and XLA's
+    flash calls, the grouped products at 3584 x 1024, the stream-mixing
+    kernels and the mappings' forward kernel, the step holds every call the
+    configuration lists, and XLA's
     buffer assignment reads no more than the `peak_hbm_gb` the configuration
     states."""
     import numpy as np
@@ -259,6 +302,10 @@ def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
     present = hlo_text.read_step(compiled.as_text()).kernels
     assert set(cell.config["mosaic_calls"]) <= set(present), present
     assert {"mhc_post_fwd", "mhc_post_bwd"} <= set(present), present
+    # `mhc_map`'s forward pass is its kernel; its backward pass stays the
+    # composition's, whose float32 copy of the streams this peak rests on
+    # (with `mhc_map_bwd` in its place the step read 14.890 GB, PR 37)
+    assert "mhc_map_fwd" in present and "mhc_map_bwd" not in present
     mem = compiled.memory_analysis()
     peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9

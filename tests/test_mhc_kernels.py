@@ -4,7 +4,10 @@ against `jax.vjp` of it, over dtypes, stream counts, widths and row counts
 that take more than one row block; the twenty sums over C as float32 against
 a float64 sum; the row-block chooser at the Xing4 cell's shapes and its
 refusals; the op's gate and its counter; what the kernel path keeps for the
-backward pass."""
+backward pass. Below them the same for `mhc_map`'s two kernels against
+`latent_ops._mhc_map`: the three mappings, the gap and the four cotangents
+to float32 rounding, with logits beyond both clamps, one round and twenty,
+H^T with the tokens on the lanes, the gate, the counter, the residuals."""
 from unittest import mock
 
 import jax
@@ -231,4 +234,276 @@ def test_the_kernel_path_keeps_the_four_inputs_and_nothing_else(pinned):
     assert set(kept) <= set(jaxpr.invars), (kept, jaxpr.invars)
     assert out not in jaxpr.invars
     # and the forward pass is one kernel call
+    assert str(jaxpr).count("pallas_call") == 1
+
+
+# ---------------------------------------------------------------------------
+# mhc_map: the three mappings of a sublayer
+# ---------------------------------------------------------------------------
+
+MAP = dict(n=4, eps=1e-6, clamp_min=-30.0, clamp_max=30.0)
+
+
+def _map_operands(b, s, c, dtype, seed=0, spread=1.0):
+    """x, phi, bias, alpha and the cotangents of H_pre, H_post, H_res."""
+    rng = np.random.RandomState(seed)
+    n = MAP["n"]
+    m = 2 * n + n * n
+    return ((jnp.asarray(rng.randn(b, s, n * c), dtype),
+             jnp.asarray(0.05 * rng.randn(n * c, m), jnp.float32),
+             jnp.asarray(spread * rng.randn(m), jnp.float32),
+             jnp.asarray([0.5, 0.7, 0.9], jnp.float32)),
+            tuple(jnp.asarray(rng.randn(b, s, k), jnp.float32)
+                  for k in (n, n, n * n)))
+
+
+def _static(rows, iters):
+    return (MAP["n"], MAP["eps"], iters, MAP["clamp_min"], MAP["clamp_max"],
+            rows)
+
+
+def _map_both(operands, cotangents, iters, rows):
+    """((values, gap), cotangents) of the kernels and of the composition:
+    the op's four outputs (`mhc_map_fwd` behind `mhc.mhc_map`), and the
+    cotangents of the four inputs by `mhc_map_bwd` (`mhc.map_cotangents`)
+    against `jax.vjp` of `latent_ops._mhc_map`."""
+    n = MAP["n"]
+    assert mhc.map_rows(operands[0], operands[1], n, iters) == rows
+    out = mhc.mhc_map(*operands, rows, iters=iters, **MAP)
+    dht = mhc._stacked(*cotangents)
+    grads = mhc.map_cotangents(*operands, dht, _static(rows, iters))
+    want_out, vjp = jax.vjp(
+        lambda *a: latent_ops._mhc_map(*a, iters=iters, **MAP), *operands)
+    return (out, grads), (want_out, vjp(cotangents + (jnp.zeros(n),)))
+
+
+def _map_close(got, want, names, dtype=jnp.float32):
+    """To float32 rounding (the streams' own for their cotangent) of the
+    array's largest entry: both sides sum thousands of float32 products
+    in their own order."""
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        eps = 2.0 ** -8 if (name, dtype) == ("dx", jnp.bfloat16) else 2e-6
+        a, b = _f64(a), _f64(b)
+        assert np.isfinite(b).all(), name
+        # the gap is a distance of sums from 1
+        scale = 1.0 if name == "gap" else np.abs(b).max()
+        np.testing.assert_allclose(a, b, rtol=0, err_msg=name,
+                                   atol=2 * eps * scale)
+
+
+VALUES = ("pre", "post", "res", "gap")
+COTANGENTS = ("dx", "dphi", "dbias", "dalpha")
+
+
+@pytest.mark.parametrize("b, s, rows", [(1, 128, 128), (2, 256, 256),
+                                        (1, 384, 128)],
+                         ids=["one_block", "a_block_a_sequence",
+                              "three_blocks"])
+@pytest.mark.parametrize("c", [128, 256])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "float32"])
+def test_the_mappings_and_their_cotangents_against_the_composition(
+        pinned, dtype, c, b, s, rows):
+    operands, cotangents = _map_operands(b, s, c, dtype, seed=s + c)
+    (out, grads), (want_out, want_grads) = _map_both(operands, cotangents, 20,
+                                                     rows)
+    n = MAP["n"]
+    assert [a.shape for a in out] == [(b, s, n), (b, s, n), (b, s, n * n),
+                                      (n,)]
+    assert {a.dtype for a in out} == {jnp.dtype(jnp.float32)}
+    _map_close(out, want_out, VALUES)
+    assert [a.dtype for a in grads] == [dtype] + [jnp.float32] * 3
+    _map_close(grads, want_grads, COTANGENTS, dtype)
+    # the last division is by the sums over i: those are 1, and the gap
+    # is what twenty rounds left of the sums over j
+    res = _f64(out[2]).reshape(b, s, n, n)
+    assert np.abs(res.sum(-2) - 1).max() < 1e-6
+    np.testing.assert_allclose(
+        _f64(out[3]), np.abs(res.sum(-1) - 1).max((0, 1)), atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "float32"])
+def test_one_round_is_one_round(pinned, dtype):
+    operands, cotangents = _map_operands(1, 128, 128, dtype, seed=5)
+    (out, grads), (want_out, want_grads) = _map_both(operands, cotangents, 1,
+                                                     128)
+    _map_close(out, want_out, VALUES)
+    _map_close(grads, want_grads, COTANGENTS, dtype)
+    assert float(out[3].max()) > 1e-2     # one round is not enough
+
+
+def test_logits_beyond_both_clamps(pinned):
+    """A bias of +-36 on two entries of H_res' logits each: exp(30) and
+    exp(-30) go into the rounds, and nothing comes back through a clamped
+    entry."""
+    (x, phi, bias, alpha), cotangents = _map_operands(
+        1, 128, 128, jnp.float32, seed=9)
+    n = MAP["n"]
+    bias = bias.at[2 * n + 1].set(36.0).at[2 * n + 6].set(36.5)
+    bias = bias.at[2 * n + 8].set(-36.0).at[2 * n + 15].set(-37.0)
+    (out, grads), (want_out, want_grads) = _map_both(
+        (x, phi, bias, alpha), cotangents, 20, 128)
+    logits = float(alpha[2]) * _f64(jnp.einsum(
+        "km,tk->mt", phi, x[0] * jax.lax.rsqrt(jnp.mean(x[0] ** 2, -1)
+                                               + 1e-6)[:, None])
+    )[2 * n:] + _f64(bias)[2 * n:, None]
+    assert (logits[[1, 6]] > 30).all() and (logits[[8, 15]] < -30).all()
+    _map_close(out, want_out, VALUES)
+    _map_close(grads, want_grads, COTANGENTS)
+    dbias = np.asarray(grads[2])
+    assert (dbias[2 * n:][[1, 6, 8, 15]] == 0).all()
+    assert np.count_nonzero(dbias) == dbias.size - 4
+
+
+def test_the_op_s_cotangents_are_the_composition_s(pinned):
+    """`mhc.mhc_map` is `mhc_map_fwd` with the composition's backward pass
+    behind it (the backward kernel raises the Xing4 step's compiled peak:
+    `mhc._map_core_bwd`): one kernel call forward, none backward."""
+    operands, cotangents = _map_operands(1, 128, 128, jnp.bfloat16, seed=2)
+    fn = lambda *a: mhc.mhc_map(*a, 128, iters=2, **MAP)[:3]
+    ref = lambda *a: latent_ops._mhc_map(*a, iters=2, **MAP)[:3]
+    got = jax.vjp(fn, *operands)[1](cotangents)
+    want = jax.vjp(ref, *operands)[1](cotangents)
+    _map_close(got, want, COTANGENTS, jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](cotangents))(
+        *operands))
+    assert text.count("name=mhc_map_fwd") == 1 and "mhc_map_bwd" not in text
+
+
+def test_the_mappings_leave_the_kernel_with_the_tokens_on_the_lanes(pinned):
+    (x, phi, bias, alpha), _ = _map_operands(2, 128, 128, jnp.bfloat16)
+    kw = dict(n=4, eps=1e-6, iters=2, lo=-30.0, hi=30.0, br=128,
+              interpret=True)
+    ht = mhc._map_fwd(x, mhc._phi_stack(phi), mhc._coef(bias, alpha, 4), **kw)
+    assert ht.shape == (24, 256) and ht.dtype == jnp.float32
+    pre, post, res, _ = mhc.mhc_map(x, phi, bias, alpha, 128, iters=2, **MAP)
+    np.testing.assert_array_equal(ht[:4].T.reshape(2, 128, 4), pre)
+    np.testing.assert_array_equal(ht[8:].T.reshape(2, 128, 16), res)
+    # and so do their cotangents, and the sums `mhc_map_bwd` keeps in VMEM
+    dx, dphi_t, dcoef = mhc._map_bwd(
+        x, mhc._phi_stack(phi), mhc._coef(bias, alpha, 4), jnp.ones_like(ht),
+        **kw)
+    assert (dx.shape, dx.dtype) == (x.shape, x.dtype)
+    assert dphi_t.shape == (24, 512) and dcoef.shape == (48, 128)
+
+
+def test_the_stack_of_phi_is_its_three_bf16_terms_exactly():
+    phi = jnp.asarray(np.random.RandomState(4).randn(512, 24), jnp.float32)
+    stack = mhc._phi_stack(phi)
+    assert stack.shape == (160, 512) and stack.dtype == jnp.bfloat16
+    hi, mid, lo = (_f64(stack[k * 24:(k + 1) * 24]) for k in range(3))
+    np.testing.assert_array_equal(hi + mid + lo, _f64(phi).T)
+    # the pairs of dX's contraction, and zeros behind them
+    for at, term in ((3, hi), (4, hi), (5, mid)):
+        np.testing.assert_array_equal(_f64(stack[at * 24:(at + 1) * 24]), term)
+    assert not np.asarray(stack[144:]).any()
+
+
+@pytest.mark.parametrize("batch, s", [(2, 4096), (1, 4096)],
+                         ids=["step", "check"])
+def test_the_map_chooser_answers_for_the_cell_from_shapes_alone(batch, s):
+    assert mhc.default_map_rows(batch, s, 4 * 3584, 4, 2, 20) == 256
+    bwd = feasible.mhc_map_vmem_bytes("bwd", 256, 4 * 3584, 4, 2, 20)
+    assert feasible.mhc_map_vmem_bytes("fwd", 256, 4 * 3584, 4, 2, 20) < bwd
+    assert bwd <= feasible.MHC_VMEM_BUDGET
+    # float32 streams of that width: the backward cell at 256 rows is over
+    # the budget, at 128 under it
+    assert feasible.mhc_map_vmem_bytes("bwd", 256, 4 * 3584, 4, 4, 20) > (
+        feasible.MHC_VMEM_BUDGET)
+    assert mhc.default_map_rows(batch, s, 4 * 3584, 4, 4, 20) == 128
+
+
+@pytest.mark.parametrize("s, width, n", [
+    (4096, 4 * 64, 4),          # a stream that is not whole lane tiles
+    (4096, 4 * 3584 + 128, 4),  # nor is this one
+    (4096, 2 * 3584, 2),        # two streams: m = 8
+    (64, 512, 4), (4096 + 64, 512, 4),  # 128 rows do not tile them
+    (4096, 4 * 128 * 320, 4),   # over the budget at 128 rows too
+])
+def test_the_map_chooser_returns_nothing(s, width, n):
+    assert mhc.default_map_rows(2, s, width, n, 2, 20) is None
+    assert mhc.default_map_rows(2, 128, 512, 4, 2, 20) == 128
+    assert mhc.default_map_rows(2, 384, 512, 4, 2, 20) == 128
+    assert mhc.default_map_rows(2, 512, 512, 4, 2, 20) == 256
+
+
+def _map_lowerings(impl):
+    return get_registry().counter("mhc_map_lowerings_total", impl=impl).value
+
+
+def _map_op(ctx, x, phi, bias, alpha, iters=2):
+    out = latent_ops.mhc_map(
+        ctx, {"X": [x], "Phi": [phi], "Bias": [bias], "Alpha": [alpha]},
+        {"streams": 4, "epsilon": 1e-6, "sinkhorn_iters": iters,
+         "clamp_min": -30.0, "clamp_max": 30.0})
+    return tuple(out[k][0] for k in ("HPre", "HPost", "HRes", "SinkhornGap"))
+
+
+def _map_op_jaxpr(ctx, operands):
+    """The op as the Executor lowers it, forward and backward."""
+    return str(jax.make_jaxpr(jax.grad(
+        lambda *a: sum(jnp.sum(h) for h in _map_op(ctx, *a)[:3]),
+        argnums=(0, 1, 2, 3)))(*operands))
+
+
+@pytest.mark.parametrize("c, s, force", [(128, 128, False), (64, 128, True),
+                                         (128, 64, True)])
+def test_off_the_tpu_at_64_columns_or_rows_the_map_is_the_composition(
+        c, s, force):
+    operands, _ = _map_operands(1, s, c, jnp.bfloat16)
+    before = _map_lowerings("jnp"), _map_lowerings("pallas")
+    with mock.patch.object(attention, "FORCE_PALLAS", force):
+        assert mhc.map_rows(operands[0], operands[1], 4, 2) is None
+        text = _map_op_jaxpr(EmitContext(), operands)
+    assert "pallas_call" not in text
+    assert (_map_lowerings("jnp"), _map_lowerings("pallas")) == (
+        before[0] + 1, before[1])
+
+
+def test_pinned_the_map_is_the_kernel_and_the_counter_says_so(pinned):
+    operands, _ = _map_operands(1, 128, 128, jnp.bfloat16)
+    before = _map_lowerings("jnp"), _map_lowerings("pallas")
+    text = _map_op_jaxpr(EmitContext(), operands)
+    assert "name=mhc_map_fwd" in text
+    assert (_map_lowerings("jnp"), _map_lowerings("pallas")) == (
+        before[0], before[1] + 1)
+
+
+def test_over_a_mesh_the_map_is_the_composition(pinned):
+    from jax.sharding import Mesh
+
+    operands, _ = _map_operands(1, 128, 128, jnp.bfloat16)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    before = _map_lowerings("jnp")
+    assert "pallas_call" not in _map_op_jaxpr(EmitContext(mesh=mesh),
+                                              operands)
+    assert _map_lowerings("jnp") == before + 1
+    got = _map_op(EmitContext(mesh=mesh), *operands)
+    want = latent_ops._mhc_map(*operands, iters=2, **MAP)
+    _map_close(got, want, VALUES)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float16, jnp.int8])
+def test_the_map_gate_takes_bf16_and_float32_streams_only(pinned, dtype):
+    (x, phi, _, _), _ = _map_operands(1, 128, 128, jnp.float32)
+    assert mhc.map_rows(x, phi, 4, 20) == 128
+    assert mhc.map_rows(x.astype(jnp.bfloat16), phi, 4, 20) == 128
+    assert mhc.map_rows(jnp.tile(x, (1, 2, 1)), phi, 4, 20) == 256
+    assert mhc.map_rows(x.astype(dtype), phi, 4, 20) is None
+    assert mhc.map_rows(x, phi[:, :20], 4, 20) is None
+    assert mhc.map_rows(x[0], phi, 4, 20) is None
+
+
+def test_the_map_kernels_keep_the_four_inputs_and_nothing_else(pinned):
+    """As `mhc_post`'s: every value the backward pass is handed is an input
+    of the traced function (no float32 copy of the streams, no stack of
+    Phi, no round of Sinkhorn), and the forward pass is one kernel call."""
+    operands, _ = _map_operands(1, 128, 128, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(
+        lambda *b: mhc.mhc_map(*b, 128, iters=2, **MAP), *a))(*operands).jaxpr
+    kept = jaxpr.outvars[4:]   # behind the op's four outputs
+    assert 0 < len(kept) <= 4
+    assert set(kept) <= set(jaxpr.invars), (kept, jaxpr.invars)
     assert str(jaxpr).count("pallas_call") == 1

@@ -209,9 +209,20 @@ def _hc_attrs():
             initializer=fluid.initializer.NormalInitializer(0.0, 1.0)))
 
 
-@pytest.mark.parametrize("iters, worst", [(20, 1e-4), (1, None)])
-def test_mhc_map_against_numpy_and_sinkhorn(iters, worst):
-    x = np.random.RandomState(6).randn(2, 5, N * C).astype(np.float32)
+def _mhc_map_lowerings(impl):
+    return get_registry().counter("mhc_map_lowerings_total",
+                                  impl=impl).value
+
+
+@pytest.mark.parametrize("iters, bound, B, S, C, impl", [
+    (20, 1e-4, 2, 5, 16, "jnp"), (1, 1e-2, 2, 5, 16, "jnp"),
+    # whole lane tiles and 128 rows, the kernels pinned (the worst of 128
+    # tokens at 512 columns starts further from doubly stochastic)
+    (20, 0.1, 1, 128, 128, "pallas"), (1, 0.5, 1, 128, 128, "pallas"),
+], ids=["jnp-20", "jnp-1", "pallas-20", "pallas-1"])
+def test_mhc_map_against_numpy_and_sinkhorn(iters, bound, B, S, C, impl):
+    x = np.random.RandomState(6).randn(B, S, N * C).astype(np.float32)
+    before = _mhc_map_lowerings(impl)
 
     def build(v):
         pre, post, res, gap = layers.mhc_map(
@@ -219,35 +230,37 @@ def test_mhc_map_against_numpy_and_sinkhorn(iters, worst):
             **_hc_attrs())
         return layers.concat([pre, post, res], axis=2), [gap]
 
-    out, grads, params, w, (gap,) = _run(build, {"x": x})
+    with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
+        out, grads, params, w, (gap,) = _run(build, {"x": x})
+    assert _mhc_map_lowerings(impl) > before
     assert set(params) == {"hc.phi", "hc.b", "hc.alpha"}
     assert params["hc.phi"].shape == (N * C, 2 * N + N * N)
     pre, post, res = _maps_numpy(x, params["hc.phi"], params["hc.b"],
                                  params["hc.alpha"], iters)
-    want = np.concatenate([pre, post, res.reshape(2, 5, N * N)], -1)
+    want = np.concatenate([pre, post, res.reshape(B, S, N * N)], -1)
     assert _rel(out, want) < 1e-5
     assert (0 < pre).all() and (pre < 1).all() and (post < 2).all()
     # H_res after the last round: columns exact, rows as far as the rounds
     # brought them; the op reports the worst of both, stream by stream
-    h_res = out[..., 2 * N:].reshape(2, 5, N, N)
+    h_res = out[..., 2 * N:].reshape(B, S, N, N)
     rows = np.abs(h_res.sum(-1) - 1).max((0, 1))
     cols = np.abs(h_res.sum(-2) - 1).max((0, 1))
     assert gap.shape == (N,)
     np.testing.assert_allclose(gap, np.maximum(rows, cols), atol=1e-6)
-    if worst is not None:  # 20 rounds: doubly stochastic
-        assert gap.max() < worst
+    if iters == 20:        # doubly stochastic
+        assert gap.max() < bound
     else:                  # one round is not enough
-        assert gap.max() > 1e-2
+        assert gap.max() > bound
     # gradients, through Sinkhorn too, against jax on the same function
     settings = {"hc_mult": N, "hc_eps": 1e-6, "hc_sinkhorn_iters": iters,
                 "mhc_h_res_clamp_min": -30.0, "mhc_h_res_clamp_max": 30.0}
 
     def fn(xv, p):
         a, b_, m = ref.hyper_maps(
-            xv.reshape(2, 5, N, C),
+            xv.reshape(B, S, N, C),
             {"phi": p["hc.phi"], "b": p["hc.b"], "alpha": p["hc.alpha"]},
             settings)
-        return jnp.concatenate([a, b_, m.reshape(2, 5, N * N)], -1)
+        return jnp.concatenate([a, b_, m.reshape(B, S, N * N)], -1)
 
     _assert_close(out, grads, *_ref_grads(fn, x, params, w), tol=2e-5)
 
@@ -364,15 +377,20 @@ def test_the_shared_expert_is_a_swiglu_under_its_own_scope():
     assert _parts(lowered[0]) == {"shared_expert"}
 
 
-@pytest.mark.parametrize("width, impl", [(64, "jnp"), (128, "pallas")])
-def test_every_new_op_lowers_under_its_part_scope(width, impl):
+@pytest.mark.parametrize("width, seq, impl, map_impl", [
+    (64, 8, "jnp", "jnp"), (128, 8, "pallas", "jnp"),
+    (128, 128, "pallas", "pallas")], ids=["jnp", "pallas", "pallas-s128"])
+def test_every_new_op_lowers_under_its_part_scope(width, seq, impl, map_impl):
     """At 128 columns a stream with the kernels pinned, `mhc_post` is the
     two kernels of ops/pallas/mhc.py: they lower under `mhc_mix` like the
-    composition, the backward one under the role `backward`."""
+    composition, the backward one under the role `backward`. At 128 rows
+    too, `mhc_map`'s forward pass is its kernel, under `mhc_map`; its
+    backward pass is the composition's (`mhc._map_core_bwd` says why),
+    under the same part and the role `backward`."""
     cfg = Xing4Config.tiny(heads_held=4)
-    x = np.random.RandomState(9).randn(1, 8, width).astype(np.float32)
+    x = np.random.RandomState(9).randn(1, seq, width).astype(np.float32)
     lowered = []
-    before = _mhc_post_lowerings(impl)
+    before = _mhc_post_lowerings(impl), _mhc_map_lowerings(map_impl)
 
     def build(v):
         streams = layers.expand(v, [1, 1, 4])
@@ -386,7 +404,8 @@ def test_every_new_op_lowers_under_its_part_scope(width, impl):
 
     with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
         _run(build, {"x": x}, lowered=lowered)
-    assert _mhc_post_lowerings(impl) > before
+    assert _mhc_post_lowerings(impl) > before[0]
+    assert _mhc_map_lowerings(map_impl) > before[1]
     assert _parts(lowered[0]) == {"mla", "mhc_map", "mhc_mix"}
     backward = "\n".join(line for line in lowered[0].splitlines()
                          if "/backward/" in line)
@@ -395,12 +414,16 @@ def test_every_new_op_lowers_under_its_part_scope(width, impl):
 
     names = set(re.findall(r'"(jit\([a-z_]+\)/[^"]*)"', lowered[0]))
     # the kernels are inner jits: the call sites carry the scopes, and XLA
-    # prefixes them to the `mhc_post_*/pallas_call` it inlines
-    for entry, role in (("_mhc_fwd", "forward"), ("_mhc_bwd", "backward")):
+    # prefixes them to the `mhc_*/pallas_call` it inlines
+    for entry, role, part, on in (
+            ("_mhc_fwd", "forward", "mhc_mix", impl),
+            ("_mhc_bwd", "backward", "mhc_mix", impl),
+            ("_map_fwd", "forward", "mhc_map", map_impl),
+            ("_map_bwd", "backward", "mhc_map", None)):
         calls = {n for n in names if n.endswith(f"/jit({entry})")}
-        assert bool(calls) == (impl == "pallas"), (entry, calls)
+        assert bool(calls) == (on == "pallas"), (entry, calls)
         for n in calls:
-            assert part_scopes.part_of(n) == "mhc_mix", n
+            assert part_scopes.part_of(n) == part, n
             assert roles.role_of(n) == role, n
 
 
