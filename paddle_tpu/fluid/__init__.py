@@ -46,6 +46,7 @@ from .framework import (  # noqa: F401
     default_main_program,
     default_startup_program,
     in_dygraph_mode,
+    name_scope,
     program_guard,
 )
 from .param_attr import ParamAttr, WeightNormParamAttr  # noqa: F401

@@ -184,12 +184,13 @@ def _append_backward_impl(
                         s: [renames.get(n, n) for n in ns]
                         for s, ns in outs.items()
                     }
+                # a gradient op lowers inside its forward op's name scopes
                 block.append_op(
                     type=d["type"],
                     inputs=d.get("inputs"),
                     outputs=outs,
                     attrs=d.get("attrs"),
-                )
+                ).scope = op.scope
             for fwd_name, gname in in_map.items():
                 if fwd_name in needs:
                     partials[fwd_name].append(renames.get(gname, gname))
@@ -248,7 +249,7 @@ def _append_backward_impl(
             outputs=grad_outputs,
             attrs=attrs,
             infer=False,  # grad shapes mirror forward inputs; skip re-trace
-        )
+        ).scope = op.scope
         # set grad var metadata from forward vars
         for n, gname in registered:
             fv = block._find_var_recursive(n)

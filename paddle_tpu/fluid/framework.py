@@ -212,6 +212,13 @@ class Operator:
     and not in them (attribute comparisons, saved programs and the
     program's hash see nothing of it), and names the `jax.named_scope`
     that `ops/registry.emit_ops` lowers the op under.
+
+    `scope` is the reference's `op_namescope`: the names of the
+    `name_scope`s the op was made inside, outermost first (none by
+    default). Like the role it lives beside `attrs`, is stamped when the op
+    is made, is handed by `append_backward` to the op's gradient ops, and
+    names further `jax.named_scope`s inside the role's: metadata of the
+    compiled step and nothing else.
     """
 
     def __init__(
@@ -225,6 +232,7 @@ class Operator:
         self.block = block
         self.type = type
         self.role = block.program._op_role
+        self.scope = block.program._op_scope
         self.inputs = {k: list(v) for k, v in (inputs or {}).items()}
         self.outputs = {k: list(v) for k, v in (outputs or {}).items()}
         self.attrs = dict(attrs or {})
@@ -404,6 +412,8 @@ class Program:
         self._mesh = None  # paddle_tpu.parallel mesh attached by fleet
         # the role every op made from here on is stamped with
         self._op_role = ROLE_FORWARD
+        # and the name scopes it is made inside (`name_scope`)
+        self._op_scope = ()
 
     def _bump_version(self):
         self._version += 1
@@ -475,6 +485,7 @@ class Program:
         p._amp_enabled = self._amp_enabled
         p._mesh = self._mesh
         p._op_role = ROLE_FORWARD
+        p._op_scope = ()
         for b in self.blocks:
             nb = Block(p, b.idx, b.parent_idx)
             p.blocks.append(nb)
@@ -504,7 +515,7 @@ class Program:
                             outputs=copy.deepcopy(sop.outputs),
                             attrs=dict(sop.attrs),
                         )
-                        nsop.role = sop.role
+                        nsop.role, nsop.scope = sop.role, sop.scope
                         if for_test and "is_test" in nsop.attrs:
                             nsop.attrs["is_test"] = True
                         subs.append(nsop)
@@ -516,7 +527,7 @@ class Program:
                     outputs=copy.deepcopy(op.outputs),
                     attrs=attrs,
                 )
-                nop.role = op.role
+                nop.role, nop.scope = op.role, op.scope
                 if for_test and "is_test" in nop.attrs:
                     nop.attrs["is_test"] = True
                 nb.ops.append(nop)
@@ -657,6 +668,26 @@ def program_guard(main_program: Program, startup_program: Optional[Program] = No
         switch_main_program(old_main)
         if old_startup is not None:
             switch_startup_program(old_startup)
+
+
+@contextlib.contextmanager
+def name_scope(prefix: str):
+    """Ops made inside carry `prefix` in their `scope` (reference
+    fluid.name_scope, which writes the attribute `op_namescope` for its
+    graph viewer). Here the compiled step shows it: every such op, and the
+    gradient ops `append_backward` makes for it, lowers inside
+    `jax.named_scope(prefix)` beneath its role, so a device trace can be
+    split by the parts of a model its builder names (`mtp`, `lm_head`).
+    Scopes nest. A name is one word of letters, digits and `_`."""
+    if not prefix or not prefix.replace("_", "").isalnum():
+        raise ValueError(f"name_scope: {prefix!r} is not one word")
+    program = default_main_program()
+    before = program._op_scope
+    program._op_scope = before + (prefix,)
+    try:
+        yield
+    finally:
+        program._op_scope = before
 
 
 # ---------------------------------------------------------------------------

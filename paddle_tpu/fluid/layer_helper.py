@@ -88,11 +88,26 @@ class LayerHelper:
         dtype = convert_dtype(dtype or "float32")
 
         startup_block = self.startup_program.global_block()
-        sp = startup_block.create_parameter(
-            attr.name, shape, dtype, **{k: v for k, v in attr._to_kwargs().items() if k != "name"}
-        )
-        initializer(sp, startup_block)
         main_block = self.main_program.global_block()
+        held = main_block.vars.get(attr.name)
+        shared = isinstance(held, framework.Parameter)
+        if shared and (list(held.shape) != list(shape)
+                       or convert_dtype(held.dtype) != dtype):
+            raise ValueError(
+                f"parameter {attr.name!r} is {list(held.shape)} {held.dtype} "
+                f"and is asked for again as {list(shape)} {dtype}")
+        # a second use of a named parameter (an embedding table that two
+        # modules look up, a head that scores twice) is the one variable,
+        # initialised once in a startup program (the reference's
+        # Block.create_parameter leaves an initialised one alone); its
+        # gradient is the sum over its uses (backward.py)
+        if not (shared and attr.name in startup_block.vars):
+            sp = startup_block.create_parameter(
+                attr.name, shape, dtype, **{k: v for k, v in attr._to_kwargs().items() if k != "name"}
+            )
+            initializer(sp, startup_block)
+        if shared:
+            return held
         mp = main_block.create_parameter(
             attr.name, shape, dtype, **{k: v for k, v in attr._to_kwargs().items() if k != "name"}
         )

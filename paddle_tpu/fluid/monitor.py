@@ -310,12 +310,24 @@ def record_attention_lowering(impl: str, form: str) -> None:
     """Called by ops/attention.py each time the attention op (or `mla`,
     which shares its code) is traced into a step: `impl` is what was
     lowered (`pallas`, a flash kernel, or `jnp`, the composition), `form`
-    `mha` (one head width, the default scale) or `mla` (value heads of
-    another width than the query / key heads, or a given scale). A
+    `mha` (one head width, the default scale), `mla` (value heads of
+    another width than the query / key heads, or a given scale; on the
+    kernels, heads padded to a kernel width) or `mla_wide` (the latent form
+    on heads that are a kernel width as they stand: nothing padded, one
+    kernel call a group of heads and one count the attention call). A
     lowering-time counter, like the grouped products'."""
     _reg.counter("attention_lowerings_total",
                  help="attention calls traced, by implementation and form",
                  impl=impl, form=form).inc()
+
+
+def record_mtp_module_built() -> None:
+    """Called by a model builder each time it builds a multi-token
+    prediction module into a program (`models/glm4_moe_lite.py`): a
+    build-time counter, it moves when a program is made."""
+    _reg.counter("mtp_modules_built_total",
+                 help="multi-token prediction modules built into "
+                      "programs").inc()
 
 
 def record_mhc_post_lowering(impl: str) -> None:

@@ -43,8 +43,8 @@ from ..fluid import layers
 from ..fluid.framework import Program, program_guard
 from ..fluid.initializer import TruncatedNormalInitializer
 from ..fluid.param_attr import ParamAttr
+from .latent_moe import outputs_of, tokens_per_expert  # noqa: F401
 from .lfm2_moe import _repeat_kv
-from .xing4 import _outputs_of, tokens_per_expert  # noqa: F401
 
 MAMBA, ATTENTION, EXPERTS = "M", "*", "E"
 
@@ -254,4 +254,4 @@ def min_decays(program: Program) -> list:
     order: each head's smallest per-step decay exp(dt A) over the step's
     tokens. A head near 0 forgets its state inside a chunk; a head near 1
     never forgets."""
-    return _outputs_of(program, "mamba2", "MinDecay")
+    return outputs_of(program, "mamba2", "MinDecay")

@@ -28,7 +28,10 @@ A chip's share of a deployment is part of the configuration: `heads_held`
 `experts_held` / `first_expert` which routed experts', `vocab_rows` how
 many rows of the vocabulary it embeds and scores. The router keeps its
 published width; what absent heads and experts would add is left out.
-Multi-token prediction (`num_nextn_predict_layers`) is not built.
+The attention and the feed-forward are `models/latent_moe.py`'s, which
+`models/glm4_moe_lite.py` shares. Multi-token prediction
+(`num_nextn_predict_layers`) is not built on the four-stream residual:
+`models/glm4_moe_lite.py` builds the module, on a plain one.
 """
 from __future__ import annotations
 
@@ -40,8 +43,10 @@ import numpy as np
 
 from ..fluid import layers
 from ..fluid.framework import Program, program_guard
-from ..fluid.initializer import NormalInitializer, TruncatedNormalInitializer
+from ..fluid.initializer import NormalInitializer
 from ..fluid.param_attr import ParamAttr
+from .latent_moe import (_attr, attention, feed_forward,  # noqa: F401
+                         outputs_of, tokens_per_expert)
 
 
 def yarn_inv_freq(dim: int, theta: float, scaling: dict) -> np.ndarray:
@@ -121,7 +126,10 @@ class Xing4Config:
 
     def __post_init__(self):
         if self.num_nextn_predict_layers:
-            raise ValueError("multi-token prediction layers are not built")
+            raise ValueError(
+                "multi-token prediction is not built on the four-stream "
+                "residual (models/glm4_moe_lite.py builds the module on a "
+                "plain one)")
         if self.rope_scaling is None:
             self.rope_scaling = {
                 "type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
@@ -164,43 +172,6 @@ class Xing4Config:
             kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=8,
             v_head_dim=16, n_routed_experts=16, num_experts_per_tok=2,
             max_position_embeddings=4096), **changes})
-
-
-def _attr(cfg: Xing4Config, name: Optional[str] = None) -> ParamAttr:
-    return ParamAttr(name=name, initializer=TruncatedNormalInitializer(
-        scale=cfg.initializer_range))
-
-
-def attention(cfg: Xing4Config, z, name: str):
-    return layers.mla(
-        z, cfg.heads_held, cfg.q_lora_rank, cfg.kv_lora_rank,
-        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
-        cfg.softmax_scale, epsilon=cfg.rms_norm_eps, theta=cfg.rope_theta,
-        inv_freq=cfg.inv_freq, param_attr=_attr(cfg), name=name)
-
-
-def feed_forward(cfg: Xing4Config, z, index: int, name: str, is_test: bool):
-    """Dense SwiGLU in the leading layers; after them the held routed
-    experts' part plus the shared expert."""
-    if index < cfg.first_k_dense_replace:
-        return layers.swiglu_ffn(z, cfg.intermediate_size,
-                                 remat=cfg.remat_ffn, param_attr=_attr(cfg),
-                                 name=name)
-    routed, _ = layers.moe_swiglu(
-        z, cfg.n_routed_experts, cfg.moe_intermediate_size,
-        experts_held=cfg.experts_held, first_expert=cfg.first_expert,
-        top_k=cfg.num_experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        remat=cfg.remat_ffn, param_attr=_attr(cfg),
-        bias_update_rate=0.0 if is_test else cfg.expert_bias_update_rate,
-        # started random, as models/lfm2_moe.py does and for its reason:
-        # selection by s + b is exercised from the first step
-        bias_attr=_attr(cfg), name=name)
-    shared = layers.shared_expert(
-        z, cfg.moe_intermediate_size * cfg.n_shared_experts,
-        remat=cfg.remat_ffn, param_attr=_attr(cfg),
-        name=f"{name}.shared_experts")
-    return layers.elementwise_add(routed, shared)
 
 
 def hyper_connected(cfg: Xing4Config, streams, sublayer, name: str,
@@ -275,20 +246,8 @@ def build_xing4_pretrain_program(
     return main, startup, ["input_ids", "labels"], loss
 
 
-def _outputs_of(program: Program, op_type: str, slot: str) -> list:
-    block = program.global_block()
-    return [block.var(n) for op in block.ops if op.type == op_type
-            for n in op.outputs.get(slot, [])]
-
-
-def tokens_per_expert(program: Program) -> list:
-    """The `TokensPerExpert` variable of every expert layer, in layer
-    order: fetch them beside the loss to see each held expert's load."""
-    return _outputs_of(program, "moe_swiglu", "TokensPerExpert")
-
-
 def sinkhorn_gaps(program: Program) -> list:
     """One [hc_mult] float32 variable a sublayer, attention before
     feed-forward in layer order: the worst distance of a row's or a
     column's sum of H_res from 1 over the step's tokens."""
-    return _outputs_of(program, "mhc_map", "SinkhornGap")
+    return outputs_of(program, "mhc_map", "SinkhornGap")

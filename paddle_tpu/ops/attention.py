@@ -76,23 +76,47 @@ def _pad_heads(x3, num_heads, width):
     return x4.reshape(b, s, num_heads * width)
 
 
+def latent_head_groups(sq, skv, num_heads, width, batch=None, causal=True):
+    """Into how many equal groups of heads latent attention splits a call
+    so that the BSH kernels hold a group's slab: the fewest that divide
+    `num_heads` and whose [.., num_heads / groups * width] operands pass
+    `bsh_dispatch_ok` (the stream kernels keep a batch row's K and V of
+    all the heads they are given in VMEM, `pallas/feasible.py`); None
+    where not even one head passes. A function of the shapes and of the
+    kernels' own model: 1 for 4 heads of 256 at S 4096, 2 for 20."""
+    from .pallas.flash_attention import bsh_dispatch_ok
+
+    for groups in range(1, num_heads + 1):
+        held = num_heads // groups
+        if num_heads % groups == 0 and bsh_dispatch_ok(
+                sq, skv, held * width, held, batch=batch, causal=causal):
+            return groups
+    return None
+
+
 def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
                      mesh=None):
     """Attention whose value heads need not be as wide as its query / key
     heads and whose softmax scale is the caller's: the form latent
     attention (MLA) takes, 192-wide q / k against 128-wide v under a scale
-    that YaRN multiplies. q3, k3 [B, S, nh * dqk], v3 [B, S, nh * dv] ->
-    [B, S, nh * dv]; no bias, no dropout.
+    that YaRN multiplies, or 256-wide q / k (192 + 64) against 256-wide v.
+    q3, k3 [B, S, nh * dqk], v3 [B, S, nh * dv] -> [B, S, nh * dv]; no
+    bias, no dropout.
 
     Where the flash gates pass, every head is zero-padded to the next width
-    the BSH kernels run (192 and 128 -> 256) and the result sliced: a zero
-    column adds nothing to a score and a zero value column gives a zero
-    output column, so the result is that of the unpadded heads. The calls
-    carry names of their own (`flash_mla_causal_fwd` / `_bwd`). Elsewhere
-    the jnp composition."""
+    the BSH kernels run (192 and 128 -> 256; heads of 256 and 256 stand as
+    they are) and the result sliced: a zero column adds nothing to a score
+    and a zero value column gives a zero output column, so the result is
+    that of the unpadded heads. Where the slab of all heads is more than
+    the kernels hold, the heads go in the fewest equal groups that pass,
+    one call a group (`latent_head_groups`: twenty heads of 256 at S 4096
+    in two calls of ten). The calls carry names of their own:
+    `flash_mla_causal_fwd` / `_bwd` on padded heads, whose useful work is
+    the unpadded widths', and `flash_mla_wide_causal_fwd` / `_bwd` where
+    nothing is padded and the call's shapes are its work. Elsewhere the jnp
+    composition."""
     from ..fluid.monitor import record_attention_lowering
-    from .pallas.flash_attention import (HEAD_WIDTHS, bsh_dispatch_ok,
-                                         flash_attention_bsh)
+    from .pallas.flash_attention import HEAD_WIDTHS, flash_attention_bsh
 
     b, sq, hq = q3.shape
     skv = k3.shape[1]
@@ -100,13 +124,22 @@ def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(dqk)
     width = next((w for w in HEAD_WIDTHS if w >= max(dqk, dv)), None)
-    if width is not None and bsh_dispatch_ok(
-            sq, skv, num_heads * width, num_heads, batch=b, causal=causal):
-        record_attention_lowering("pallas", "mla")
-        out = flash_attention_bsh(
-            _pad_heads(q3, num_heads, width), _pad_heads(k3, num_heads, width),
-            _pad_heads(v3, num_heads, width), None, num_heads=num_heads,
-            sm_scale=sm_scale, causal=causal, mesh=mesh, form="mla")
+    groups = None if width is None else latent_head_groups(
+        sq, skv, num_heads, width, batch=b, causal=causal)
+    if groups is not None:
+        form = "mla_wide" if dqk == dv == width else "mla"
+        record_attention_lowering("pallas", form)
+        held = num_heads // groups
+        padded = [_pad_heads(x3, num_heads, width) for x3 in (q3, k3, v3)]
+        outs = []
+        for g in range(groups):
+            q, k, v = padded if groups == 1 else (
+                x[..., g * held * width:(g + 1) * held * width]
+                for x in padded)
+            outs.append(flash_attention_bsh(
+                q, k, v, None, num_heads=held, sm_scale=sm_scale,
+                causal=causal, mesh=mesh, form=form))
+        out = outs[0] if groups == 1 else jnp.concatenate(outs, axis=-1)
         out = out.reshape(b, sq, num_heads, width)[..., :dv]
         return out.reshape(b, sq, num_heads * dv)
     record_attention_lowering("jnp", "mla")

@@ -2,11 +2,14 @@
 
 `mla` is the whole attention sublayer of the DeepSeek-V2/V3 line: low-rank
 query and key/value projections with an RMSNorm on each latent, a rotary
-part that is 64 of a head's 192 query / key columns (one rotated key part
-serves every head), 128-wide values, a softmax scale the model gives, and
-the output projection. It is told how many heads it holds: its W_qb and
-W_kvb have those heads' columns, its W_o their rows, and its result is
-those heads' part of the sum over heads.
+part that is 64 of a head's query / key columns (of 192 in Xing4.0, of 256
+in GLM-4.7-Flash; one rotated key part serves every head), values of a
+width of their own (128, 256), a softmax scale the model gives, and the
+output projection. It is told how many heads it holds: its W_qb and W_kvb
+have those heads' columns, its W_o their rows, and its result is those
+heads' part of the sum over heads. `ops/attention.py:latent_attention` runs
+the heads through the flash kernels, padded to a kernel width where they
+are narrower and in groups where all of them are more than a kernel holds.
 
 `mhc_map`, `mhc_pre` and `mhc_post` are the residual path of "mHC:
 Manifold-Constrained Hyper-Connections" (arXiv:2512.24880) on n residual

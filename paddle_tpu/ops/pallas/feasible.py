@@ -72,7 +72,10 @@ def flash_bsh_fwd_vmem_bytes(sq: int, skv: int, h: int, bq: int,
     128-lane slab at d = 64, so 4 B per block element), and two slots
     each of f32 scores and bf16 probabilities a compute tile in size.
     Mosaic allocated 34.1 MiB at (s4096, h768, bq1024) where this counts
-    39.0 (described-v5e compiles bisected on the limit, PR 27)."""
+    39.0 (described-v5e compiles bisected on the limit, PR 27), and
+    90.1-90.9 MiB at (s4096, h2560, bq1024), ten heads of 256, where it
+    counts 102.0 (the same bisection, PR 38: the widest slab
+    `ops/attention.py:latent_head_groups` hands the kernels)."""
     if max(sq, skv) < FLASH_BSH_STREAM_FROM:
         return 8 * skv * h + 8 * bq * h + 40 * bq * bk
     cq, ck = _flash_bsh_compute_tile(bq), _flash_bsh_compute_tile(skv)
@@ -94,7 +97,8 @@ def flash_bsh_bwd_vmem_bytes(sq: int, skv: int, h: int, bq: int,
     allocated 55.8 MiB at (s4096, bk1024, h768) where this counts 56.4,
     and at most 69.4 MiB for either pass at s8192 (80.4 here)
     (described-v5e compiles bisected on the limit, PR 27; the
-    whole-tile kernel measured 124 MB at (s8192, bq1024))."""
+    whole-tile kernel measured 124 MB at (s8192, bq1024)); 101.4-102.3
+    MiB at (s4096, bk256, h2560) where this counts 109.5 (PR 38)."""
     if max(sq, skv) < FLASH_BSH_STREAM_FROM:
         return 12 * sq * h + 8 * bk * h + 40 * bq * bk
     cq, ck = _flash_bsh_compute_tile(sq), _flash_bsh_compute_tile(bk)

@@ -1305,7 +1305,10 @@ def _bsh_kernel_name(direction: str, causal: bool, form: str = "bsh") -> str:
     the square's count over a causal call's time would read up to twice
     its true share of the roofline. `form` "mla" names the calls that
     latent attention makes on heads padded to a kernel width
-    (`flash_mla_causal_fwd`): their useful work is the unpadded heads'."""
+    (`flash_mla_causal_fwd`): their useful work is the unpadded heads'.
+    "mla_wide" names those it makes on heads that are a kernel width as
+    they stand (`flash_mla_wide_causal_fwd`, 256 and 256): a call's
+    shapes are then its work, one call a group of heads."""
     return f"flash_{form}_{'causal_' if causal else ''}{direction}"
 
 

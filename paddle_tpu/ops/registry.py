@@ -299,18 +299,22 @@ def emit_ops(ctx: EmitContext, ops, env: Dict[str, Any],
     and without it (tests/test_op_role.py), which is why there is no flag
     here and nothing in the executor's cache key. The op<idx>:<type>
     scope nests inside; a sub-block's ops nest a second role, and only
-    the outermost counts."""
+    the outermost counts. Between the two lie the op's own name scopes
+    (`fluid.name_scope`, `Operator.scope`), metadata in the same way."""
     import contextlib
 
     import jax
 
     def _scope(idx, op):
         role = role_scope(op.role)
-        if not ctx.op_scopes:
+        if not ctx.op_scopes and not op.scope:
             return role
         stack = contextlib.ExitStack()
         stack.enter_context(role)
-        stack.enter_context(jax.named_scope(f"op{idx}:{op.type}"))
+        for name in op.scope:
+            stack.enter_context(jax.named_scope(name))
+        if ctx.op_scopes:
+            stack.enter_context(jax.named_scope(f"op{idx}:{op.type}"))
         return stack
 
     wanted: Dict[tuple, int] = {}

@@ -127,6 +127,73 @@ def test_traced_size_does_not_go_with_the_tile(traced, kernel):
 
 
 # ---------------------------------------------------------------------------
+# latent attention's widest group: ten heads of 256 at S 4096
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("passes, mosaic_mib", [
+    ("fwd", (90.06, 90.88)), ("fwd_bwd", (101.43, 102.25))])
+def test_the_vmem_model_bounds_mosaic_at_ten_heads_of_256(
+        one_chip, no_compile_cache, passes, mosaic_mib):
+    """`latent_head_groups` trusts `feasible.py`'s model of the stream
+    kernels' residency: twenty heads of 256 at S 4096 (5,120 columns) are
+    over it and ten (2,560) under. Mosaic's own scoped allocation at
+    [1, 4096, 2560], causal, bisected on the limit by described-v5e
+    compiles (PR 38): the forward 90.06-90.88 MiB at a 1,024-row tile
+    where the model counts 102.0, forward and backward 101.43-102.25 MiB
+    (the backward at 256 rows, the largest tile the model admits) where it
+    counts 109.5. Here: the calls compile under the model's own estimate as
+    the limit, and not under 85 % of it, so the model is an upper bound
+    and within a fifth of Mosaic."""
+    from paddle_tpu.ops.pallas import feasible
+
+    s, nh, d = 4096, 10, 256
+    h = nh * d
+    assert not feasible.flash_bsh_ok(s, s, 2 * h, 128, 128)[0]
+    assert feasible.flash_bsh_ok(s, s, h, 128, 128)[0]
+    bq = fa.default_bsh_block(s, s, h)
+    bk = fa.default_bsh_block(s, s, h, bwd=True)
+    assert (bq, bk) == (1024, 256)
+    model = feasible.flash_bsh_fwd_vmem_bytes(s, s, h, bq, bq)
+    if passes == "fwd_bwd":
+        model = max(model, feasible.flash_bsh_bwd_vmem_bytes(s, s, h, bk, bk))
+    assert mosaic_mib[1] * 2 ** 20 <= model <= feasible.BSH_VMEM_LIMIT
+    x = jax.ShapeDtypeStruct((1, s, h), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return fa.flash_attention_bsh(q, k, v, None, num_heads=nh,
+                                      sm_scale=1 / 16, causal=True,
+                                      form="mla_wide")
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    def compile_under(limit):
+        blocks = fa._resolve_bsh_blocks
+
+        def resolve(sq, skv, hdim, *, bwd=False):
+            return blocks(sq, skv, hdim, bwd=bwd)[:2] + (int(limit),)
+
+        fn = attend if passes == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
+        with mock.patch.object(fa, "_interpret", lambda: False), \
+                mock.patch.object(fa, "_resolve_bsh_blocks", resolve):
+            fa._make_flash_core_bsh.cache_clear()
+            try:
+                # a new function each time: jit's trace cache would hand
+                # back the kernels traced under another limit
+                return jax.jit(lambda *a: fn(*a)).trace(x, x, x).lower(
+                    lowering_platforms=("tpu",)).compile().as_text()
+            finally:
+                fa._make_flash_core_bsh.cache_clear()
+
+    text = compile_under(model)
+    assert "flash_mla_wide_causal_fwd" in text
+    assert ("flash_mla_wide_causal_bwd" in text) == (passes == "fwd_bwd")
+    with pytest.raises(Exception, match="(?i)vmem"):
+        compile_under(0.85 * model)
+
+
+# ---------------------------------------------------------------------------
 # the grouped-matmul kernels of moe_swiglu (ops/pallas/grouped_matmul.py),
 # in this file because one process of a test run may describe the topology
 # ---------------------------------------------------------------------------
@@ -369,3 +436,72 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     stated = cell.config["stated"]["peak_hbm_gb"]
     assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb < 15.2
+
+
+def test_the_glm_cell_step_compiles_and_fits_the_v5e(one_chip,
+                                                     no_compile_cache):
+    """The step of `glm-4.7-flash.ep8share.mtp.s4096` as the harness builds
+    it, at the cell's batch and the published widths, compiled for the
+    described chip with its state given as shapes (707 M parameters are not
+    allocated here): the trunk and the multi-token-prediction module, the
+    embedding table and the head used twice each. Mosaic takes latent
+    attention's twenty heads of 256 / 256 as two calls of ten a block under
+    a name of their own (six blocks: twelve forward calls), and the grouped
+    products at 2048 x 1536; the step holds every call the configuration
+    lists, no attention call is the composition's, XLA rematerializes
+    nothing the program did not ask for, and its buffer assignment reads no
+    more than the `peak_hbm_gb` the configuration states."""
+    import numpy as np
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import harness, hlo_text, manifest
+    from paddle_tpu.fluid.executor import Scope
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.telemetry import get_registry
+
+    cell = manifest.load_cell(manifest.load_manifest(),
+                              "glm-4.7-flash.ep8share.mtp.s4096")
+    batch = int(cell.traffic["batch"])
+    built = harness.build_program(cell, batch, dropout=True, seed=1)
+    assert sum(int(np.prod(p.shape)) for p in built.main.all_parameters()
+               ) == cell.config["stated"]["parameters"]
+    exe, scope = fluid.Executor(), Scope()
+    for program in (built.startup, built.main):
+        for v in program.global_block().vars.values():
+            if v.persistable and v.shape is not None:
+                scope.set_var(v.name, jax.ShapeDtypeStruct(
+                    tuple(v.shape), np.dtype(v.dtype)))
+    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
+                                  harness.batch_rng(1, 1, 0))
+
+    def traced(impl, form):
+        return get_registry().counter(
+            "attention_lowerings_total", impl=impl, form=form).value
+
+    before = traced("pallas", "mla_wide"), traced("jnp", "mla")
+    with mock.patch.object(fa, "_interpret", lambda: False), \
+            mock.patch.object(gm, "_interpret", lambda: False):
+        fa._make_flash_core_bsh.cache_clear()
+        try:
+            compiled = exe._lower_step(
+                built.main, feed=feed, fetch_list=[built.loss], scope=scope,
+                platforms=("tpu",), sharding=one_chip).compile()
+        finally:
+            fa._make_flash_core_bsh.cache_clear()
+    # five trunk blocks and the module's: six attention calls, all kernels
+    assert traced("pallas", "mla_wide") == before[0] + 6
+    assert traced("jnp", "mla") == before[1]
+    text = compiled.as_text()
+    step = hlo_text.read_step(text)
+    assert set(cell.config["mosaic_calls"]) <= set(step.kernels), step.kernels
+    forward = [c for c in step.calls.values()
+               if c.kernel == "flash_mla_wide_causal_fwd"]
+    assert {c.operands[0].dims for c in forward} == {(batch, 4096, 2560)}
+    assert len({c.instruction for c in forward}) >= 12
+    assert ".remat" not in text
+    mem = compiled.memory_analysis()
+    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
+    stated = cell.config["stated"]["peak_hbm_gb"]
+    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
+    assert 4.0 < peak_gb <= 15.2

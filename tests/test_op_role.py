@@ -262,3 +262,84 @@ def test_op_profile_scope_nests_inside_the_role(name):
             ops[index].role, f"op{index}:{op_type}"], op_name
         seen.add(ops[index].role)
     assert seen == set(ORDER)
+
+
+# ---------------------------------------------------------------------------
+# name scopes: a model's own parts, beneath the role
+# ---------------------------------------------------------------------------
+
+
+def _scoped_program(scoped=True):
+    """Two `fc` layers, the second inside `head` and its bias inside
+    `head/bias` (or inside nothing), and their loss, minimized."""
+    from paddle_tpu.fluid import layers
+
+    scope_of = (fluid.name_scope if scoped
+                else lambda name: contextlib.nullcontext())
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[4, 8], dtype="float32",
+                        append_batch_size=False)
+        h = layers.fc(x, 8, act="relu", bias_attr=False)
+        with scope_of("head"):
+            y = layers.fc(h, 3, bias_attr=False)
+            with scope_of("bias"):
+                y = layers.scale(y, 2.0, bias=1.0)
+        loss = layers.reduce_mean(layers.square(y))
+        fluid.optimizer.SGD(0.1).minimize(loss, startup_program=startup)
+    return main, startup, loss
+
+
+def test_ops_and_their_gradient_ops_carry_the_name_scopes():
+    main, _, _ = _scoped_program()
+    ops = main.global_block().ops
+    by_type = {op.type: op for op in ops}
+    assert by_type["relu"].scope == () == by_type["reduce_mean"].scope
+    assert by_type["scale"].scope == ("head", "bias")
+    assert by_type["scale_grad"].scope == ("head", "bias")
+    assert by_type["scale_grad"].role == framework.ROLE_BACKWARD
+    muls = [op for op in ops if op.type.startswith("mul")]
+    assert [(op.type, op.scope) for op in muls] == [
+        ("mul", ()), ("mul", ("head",)), ("mul_grad", ("head",)),
+        ("mul_grad", ())]
+    assert all(op.scope == () for op in ops
+               if op.role == framework.ROLE_OPTIMIZER)
+    # the guard restores, a clone keeps, and a name is one word
+    assert main._op_scope == ()
+    cloned = main.clone()
+    assert [op.scope for op in cloned.global_block().ops] == [
+        op.scope for op in ops]
+    for bad in ("", "a/b", "a b", "jvp(x)"):
+        with pytest.raises(ValueError, match="one word"), \
+                fluid.name_scope(bad):
+            pass
+
+
+def test_name_scopes_name_the_step_and_leave_the_executable_alone():
+    import numpy as np
+
+    def texts(scoped):
+        main, startup, loss = _scoped_program(scoped)
+        exe, scope = fluid.Executor(), Scope()
+        exe.run(startup, scope=scope)
+        lowered = exe._lower_step(
+            main, feed={"x": np.ones((4, 8), np.float32)}, fetch_list=[loss],
+            scope=scope)
+        return lowered.as_text(), lowered.compile().as_text()
+
+    (given, scoped), (given_bare, bare) = texts(True), texts(False)
+    assert given == given_bare  # printed without locations
+    names = _op_names(scoped)
+    assert any(n.startswith("jit(step)/forward/head/bias/") for n in names)
+    assert any(n.startswith("jit(step)/backward/head/") for n in names)
+    assert not any("/head/" in n for n in _op_names(bare))
+    # the role is still the first component, the scope the next
+    assert {roles.role_of(n) for n in names if "/head/" in n} == {
+        "forward", "backward"}
+
+    def stripped(text):
+        text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+        return re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                      r"\n(\d+ .*\n)*", "\n", text)
+
+    assert stripped(scoped) == stripped(bare)
