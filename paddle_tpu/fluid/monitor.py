@@ -353,8 +353,9 @@ def record_mhc_map_lowering(impl: str) -> None:
 def record_ssd_scan_lowering(impl: str) -> None:
     """Called by ops/ssm_ops.py each time a `mamba2` op, and the state-space
     scan inside it, is traced into a step: `impl` is what was lowered
-    (`jnp`, the chunked composition of matrix products; `pallas` is kept
-    for a kernel). A lowering-time counter, like the grouped products'."""
+    (`pallas`, ops/pallas/ssd_scan.py's two kernels, or `jnp`, the chunked
+    composition of matrix products). A lowering-time counter, like the
+    grouped products'."""
     _reg.counter("ssd_scan_lowerings_total",
                  help="state-space scans traced, by implementation",
                  impl=impl).inc()

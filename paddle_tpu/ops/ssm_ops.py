@@ -26,6 +26,11 @@ take x's dtype in and accumulate in float32. The scan keeps only its
 inputs for the backward pass (`jax.checkpoint`): the chunk states and the
 [Q, Q] matrices are computed again there, and nothing quadratic in S
 exists in either pass. Scopes: `mamba2`, and inside it `ssd_scan`.
+
+On the TPU, where `ops/pallas/ssd_scan.py`'s gate admits the shapes, the
+same mathematics runs as its two kernels (`ssd_scan_fwd`, `ssd_scan_bwd`),
+with the [Q, Q] matrices and the states in VMEM; this composition is the
+path everywhere else and the kernels' reference.
 """
 from __future__ import annotations
 
@@ -35,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from .decoder_ops import causal_depthwise_conv
+from .pallas import ssd_scan as ssd_pallas
 from .registry import register
 
 _F32 = jnp.float32
@@ -107,20 +113,38 @@ def _ssd_chunked(x, dt, a, b, c, d, *, chunk):
     return y.reshape(bsz, s, h, p).astype(x.dtype)
 
 
+def scan_kernels(x_shape, b_shape, chunk: int, dtype) -> bool:
+    """Whether `ssd_scan` at these shapes (x [B, S, H, P] and b [B, S, G,
+    N] after the row's padding) runs `ops/pallas/ssd_scan.py`'s kernels:
+    its gate, from the shapes, the dtype and the platform alone."""
+    _, s, h, p = x_shape
+    g, n = b_shape[2:]
+    return ssd_pallas.use_kernels(s, h, p, g, n, chunk, dtype)
+
+
 def ssd_scan(x, dt, a, b, c, d, chunk: int):
     """The recurrence of the module docstring in chunks of `chunk`
     positions (the whole row where it is shorter). A row that is no
     multiple of the chunk is continued with dt = 0, which neither decays
-    the state nor adds to it. Lowered under the scope `ssd_scan`."""
+    the state nor adds to it. Lowered under the scope `ssd_scan`, as the
+    kernels where `scan_kernels` admits the shapes; counted by
+    `ssd_scan_lowerings_total{impl}` (one a `mamba2` op traced)."""
+    from ..fluid.monitor import record_ssd_scan_lowering
+
     s = x.shape[1]
     chunk = min(int(chunk), s)
     pad = -s % chunk
     if pad:
         x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                        for t in (x, dt, b, c))
+    kernels = scan_kernels(x.shape, b.shape, chunk, x.dtype)
+    record_ssd_scan_lowering("pallas" if kernels else "jnp")
     with jax.named_scope("ssd_scan"):
-        y = jax.checkpoint(functools.partial(_ssd_chunked, chunk=chunk))(
-            x, dt, a, b, c, d)
+        if kernels:
+            y = ssd_pallas.ssd_scan(x, dt, a, b, c, d, chunk)
+        else:
+            y = jax.checkpoint(functools.partial(_ssd_chunked, chunk=chunk))(
+                x, dt, a, b, c, d)
     return y[:, :s] if pad else y
 
 
@@ -170,8 +194,6 @@ def mamba2(ctx, ins, attrs):
 
     MinDecay is each head's smallest exp(dt A) over the batch: a head
     near 0 forgets its state inside a step, a head near 1 never does."""
-    from ..fluid.monitor import record_ssd_scan_lowering
-
     names = ("X", "InW", "ConvW", "ConvB", "DtBias", "ALog", "D", "NormW",
              "OutW")
     fn = functools.partial(
@@ -179,7 +201,6 @@ def mamba2(ctx, ins, attrs):
         groups=int(attrs["n_groups"]), state=int(attrs["state_size"]),
         chunk=int(attrs.get("chunk_size", 128)),
         eps=float(attrs.get("epsilon", 1e-5)))
-    record_ssd_scan_lowering("jnp")
     with jax.named_scope("mamba2"):
         out, min_decay = fn(*(ins[n][0] for n in names))
     return {"Out": [out], "MinDecay": [min_decay]}

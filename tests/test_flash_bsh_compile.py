@@ -319,6 +319,38 @@ def test_the_mhc_map_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
     assert eqns <= {"mhc_map_fwd": 400, "mhc_map_bwd": 900}[kernel]
 
 
+@pytest.mark.parametrize("batch", [2, 1], ids=["step", "check"])
+def test_the_ssd_scan_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
+                                                  batch):
+    """The Nemotron cell's scan (two rows of 4,096 positions a step, one in
+    its check program; 64 heads of 64 in 8 groups at state 128, chunk 128,
+    bf16), its gradient through the custom VJP: Mosaic takes both kernels'
+    blocks, the turned x and cotangent, the heads' rows at sublane offsets,
+    the per-group lane slices of B and C and the scoped-VMEM limit the byte
+    model asks for. The heads are one loop body in the traced jaxpr,
+    unrolled where it lowers: the equation count does not go with them."""
+    from paddle_tpu.ops.pallas import ssd_scan as ssd
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x, bc = shaped(batch, 4096, 64, 64), shaped(batch, 4096, 8, 128)
+    dt = shaped(batch, 4096, 64, dtype=jnp.float32)
+    vec = shaped(64, dtype=jnp.float32)
+
+    def loss(*args):
+        return ssd.ssd_scan(*args, 128).astype(jnp.float32).sum()
+
+    with mock.patch.object(ssd, "_interpret", lambda: False):
+        assert ssd.use_kernels(4096, 64, 64, 8, 128, 128, jnp.bfloat16)
+        traced = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(6)))).trace(x, dt, vec, bc, bc, vec)
+    text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    eqns = _pallas_calls(traced.jaxpr.jaxpr, {})
+    assert eqns["ssd_scan_fwd"] <= 250 and eqns["ssd_scan_bwd"] <= 650
+
+
 # ---------------------------------------------------------------------------
 # a whole step at published widths, in this file because one process of a
 # test run may describe the topology
@@ -401,6 +433,7 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     from benchmark import harness, hlo_text, manifest
     from paddle_tpu.fluid.executor import Scope
     from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import ssd_scan as ssd
 
     cell = manifest.load_cell(manifest.load_manifest(),
                               "nemotron-3-nano-30b-a3b.ep16share.s4096")
@@ -415,7 +448,8 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     feed = cell.family.make_batch(cell.config, cell.traffic, batch,
                                   harness.batch_rng(1, 1, 0))
     with mock.patch.object(fa, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False):
+            mock.patch.object(gm, "_interpret", lambda: False), \
+            mock.patch.object(ssd, "_interpret", lambda: False):
         compiled = exe._lower_step(
             built.main, feed=feed, fetch_list=[built.loss], scope=scope,
             platforms=("tpu",), sharding=one_chip).compile()
@@ -427,6 +461,9 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     # are the kernels, and XLA's `ragged-dot` stays for the dropless
     # fallback's product and weight gradient
     assert {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} <= set(present), present
+    # since PR 39 the state-space scan is its two kernels (14.421 GB here,
+    # 14.502 with the composition)
+    assert {"ssd_scan_fwd", "ssd_scan_bwd"} <= set(present), present
     assert "ragged-dot" in text
     mem = compiled.memory_analysis()
     peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
