@@ -361,6 +361,19 @@ def record_ssd_scan_lowering(impl: str) -> None:
                  impl=impl).inc()
 
 
+def record_ssd_scan_gate_refusal(reason: str) -> None:
+    """Called by ops/ssm_ops.py each time a state-space scan is traced
+    where ops/pallas/ssd_scan.py's kernels would run (on the TPU, or pinned
+    by a test) and their gate refuses the shapes: `reason` is the first
+    check that failed (`kernel_fits_reason`: `dtype`, `groups`, `lanes`,
+    `heads_per_group`, `vmem`). A lowering-time counter, like
+    `ssd_scan_lowerings_total`."""
+    _reg.counter("ssd_scan_gate_refusals_total",
+                 help="state-space scans the kernels' gate refused where "
+                      "they would run, by the first check that failed",
+                 reason=reason).inc()
+
+
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

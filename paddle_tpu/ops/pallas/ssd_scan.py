@@ -42,7 +42,7 @@ The gate (`kernel_fits`) reads shapes, dtypes and the platform alone.
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +59,27 @@ _LANES = 128
 _MAX_HEADS = 16
 
 
+def kernel_fits_reason(s: int, heads: int, head_dim: int, groups: int,
+                       state: int, chunk: int, dtype) -> Optional[str]:
+    """The checks of `kernel_fits`, in order; the first that refuses the
+    shapes by its name (`dtype`, `groups`, `lanes`, `heads_per_group`,
+    `vmem`), or None where all pass."""
+    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
+        return "dtype"
+    if groups < 1 or heads % groups:
+        return "groups"
+    r = heads // groups
+    if chunk % _LANES or s % chunk or state % _LANES or head_dim % 8:
+        return "lanes"
+    if r % 8 or r > _MAX_HEADS or (r * head_dim) % _LANES:
+        return "heads_per_group"
+    if _feas.ssd_scan_vmem_bytes(
+            "bwd", s, chunk, r, head_dim, state, jnp.dtype(dtype).itemsize
+    ) > _feas.SSD_VMEM_BUDGET:
+        return "vmem"
+    return None
+
+
 def kernel_fits(s: int, heads: int, head_dim: int, groups: int, state: int,
                 chunk: int, dtype) -> bool:
     """THE shape gate of the kernels, for a row of s positions (a multiple
@@ -66,28 +87,22 @@ def kernel_fits(s: int, heads: int, head_dim: int, groups: int, state: int,
     heads whole sublane tiles of dt (8 or 16 of them) and its columns of x
     whole lane tiles, a head whole sublane tiles, bf16 or float32, and the
     backward cell (the larger) under the budget. Else the composition runs."""
-    if jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
-        return False
-    if groups < 1 or heads % groups:
-        return False
-    r = heads // groups
-    if chunk % _LANES or s % chunk or state % _LANES or head_dim % 8:
-        return False
-    if r % 8 or r > _MAX_HEADS or (r * head_dim) % _LANES:
-        return False
-    return _feas.ssd_scan_vmem_bytes(
-        "bwd", s, chunk, r, head_dim, state, jnp.dtype(dtype).itemsize
-    ) <= _feas.SSD_VMEM_BUDGET
+    return kernel_fits_reason(s, heads, head_dim, groups, state, chunk,
+                              dtype) is None
+
+
+def on_kernels() -> bool:
+    """Whether the kernels run where the gate admits the shapes: on the
+    TPU, or where a test pins them (interpreted)."""
+    from ..attention import FORCE_PALLAS
+
+    return FORCE_PALLAS or not _interpret()
 
 
 def use_kernels(s, heads, head_dim, groups, state, chunk, dtype) -> bool:
-    """`kernel_fits` on the TPU, or where a test pins the kernels
-    (interpreted)."""
-    from ..attention import FORCE_PALLAS
-
-    if _interpret() and not FORCE_PALLAS:
-        return False
-    return kernel_fits(s, heads, head_dim, groups, state, chunk, dtype)
+    """`kernel_fits` where `on_kernels`."""
+    return on_kernels() and kernel_fits(s, heads, head_dim, groups, state,
+                                        chunk, dtype)
 
 
 # ---------------------------------------------------------------------------

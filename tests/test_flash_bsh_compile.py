@@ -542,3 +542,72 @@ def test_the_glm_cell_step_compiles_and_fits_the_v5e(one_chip,
     stated = cell.config["stated"]["peak_hbm_gb"]
     assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb <= 15.2
+
+
+def test_the_granite_cell_step_compiles_and_fits_the_v5e(one_chip,
+                                                         no_compile_cache):
+    """The step of `granite-4.0-h-micro.vocab8.s4096` as the harness builds
+    it, at the cell's batch and the published widths, compiled for the
+    described chip with its state given as shapes (772 M parameters are not
+    allocated here): the one NoPE attention layer is the latent form's wide
+    kernels on 32 heads of 64 at the given scale, and all nine state-space
+    scans are the composition, whose gate refuses a group of 64 heads: the
+    lowering counts nine refusals by `heads_per_group`. Nothing is
+    rematerialized, and XLA's buffer assignment reads no more than the
+    `peak_hbm_gb` the configuration states."""
+    import numpy as np
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import harness, hlo_text, manifest
+    from paddle_tpu.fluid.executor import Scope
+    from paddle_tpu.ops.pallas import ssd_scan as ssd
+    from paddle_tpu.telemetry import get_registry
+
+    cell = manifest.load_cell(manifest.load_manifest(),
+                              "granite-4.0-h-micro.vocab8.s4096")
+    batch = int(cell.traffic["batch"])
+    built = harness.build_program(cell, batch, dropout=True, seed=1)
+    exe, scope = fluid.Executor(), Scope()
+    for program in (built.startup, built.main):
+        for v in program.global_block().vars.values():
+            if v.persistable and v.shape is not None:
+                scope.set_var(v.name, jax.ShapeDtypeStruct(
+                    tuple(v.shape), np.dtype(v.dtype)))
+    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
+                                  harness.batch_rng(1, 1, 0))
+
+    def counted(name, **labels):
+        return get_registry().counter(name, **labels).value
+
+    before = (counted("ssd_scan_gate_refusals_total",
+                      reason="heads_per_group"),
+              counted("ssd_scan_lowerings_total", impl="pallas"),
+              counted("attention_lowerings_total", impl="pallas",
+                      form="mla_wide"))
+    with mock.patch.object(fa, "_interpret", lambda: False), \
+            mock.patch.object(ssd, "_interpret", lambda: False):
+        fa._make_flash_core_bsh.cache_clear()
+        try:
+            compiled = exe._lower_step(
+                built.main, feed=feed, fetch_list=[built.loss], scope=scope,
+                platforms=("tpu",), sharding=one_chip).compile()
+        finally:
+            fa._make_flash_core_bsh.cache_clear()
+    assert (counted("ssd_scan_gate_refusals_total", reason="heads_per_group"),
+            counted("ssd_scan_lowerings_total", impl="pallas"),
+            counted("attention_lowerings_total", impl="pallas",
+                    form="mla_wide")) == (
+        before[0] + 9, before[1], before[2] + 1)
+    text = compiled.as_text()
+    step = hlo_text.read_step(text)
+    assert set(step.kernels) == set(cell.config["mosaic_calls"]), step.kernels
+    forward = [c for c in step.calls.values()
+               if c.kernel == "flash_mla_wide_causal_fwd"]
+    assert {c.operands[0].dims for c in forward} == {(batch, 4096, 2048)}
+    assert ".remat" not in text
+    mem = compiled.memory_analysis()
+    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
+    stated = cell.config["stated"]["peak_hbm_gb"]
+    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
+    assert 4.0 < peak_gb <= 15.2

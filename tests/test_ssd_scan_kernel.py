@@ -299,3 +299,62 @@ def test_the_vmem_model_admits_the_nemotron_cell_with_margin():
     assert ssd.kernel_fits(s, 64, 64, 8, 128, 128, jnp.bfloat16)
     assert not ssd.kernel_fits(16 * s, 64, 64, 8, 128, 128, jnp.bfloat16)
     assert ssd._cell(64, 8, 64, 128, s, Q, 2).groups == 2
+
+
+# ---------------------------------------------------------------------------
+# why the gate refused: the first check that failed, counted
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("sizes, reason", [
+    # the Nemotron cell: eight groups of eight heads at chunk 128
+    (dict(s=4096, heads=64, head_dim=64, groups=8, state=128, chunk=128),
+     None),
+    # the Granite cell: one group of all 64 heads at chunk 256
+    (dict(s=4096, heads=64, head_dim=64, groups=1, state=128, chunk=256),
+     "heads_per_group"),
+    (dict(s=4096, heads=64, head_dim=64, groups=8, state=128, chunk=128,
+          dtype=jnp.float16), "dtype"),
+    (dict(s=4096, heads=64, head_dim=64, groups=3, state=128, chunk=128),
+     "groups"),
+    (dict(s=4096, heads=64, head_dim=64, groups=8, state=64, chunk=128),
+     "lanes"),
+    (dict(s=16 * 4096, heads=64, head_dim=64, groups=8, state=128,
+          chunk=128), "vmem"),
+], ids=["nemotron", "granite", "dtype", "groups", "lanes", "vmem"])
+def test_the_gate_names_the_first_check_that_refused(sizes, reason):
+    sizes = {"dtype": jnp.bfloat16, **sizes}
+    assert ssd.kernel_fits_reason(**sizes) == reason
+    assert ssd.kernel_fits(**sizes) == (reason is None)
+
+
+def _refusals(reason):
+    return get_registry().counter("ssd_scan_gate_refusals_total",
+                                  reason=reason).value
+
+
+def _trace_scan(heads, groups):
+    """`ssm_ops.ssd_scan` traced once at two chunks of Q, heads of P."""
+    x = jax.ShapeDtypeStruct((1, 2 * Q, heads, P), jnp.float32)
+    dt = jax.ShapeDtypeStruct((1, 2 * Q, heads), jnp.float32)
+    vec = jax.ShapeDtypeStruct((heads,), jnp.float32)
+    bc = jax.ShapeDtypeStruct((1, 2 * Q, groups, N), jnp.float32)
+    jax.eval_shape(functools.partial(ssm_ops.ssd_scan, chunk=Q),
+                   x, dt, vec, bc, bc, vec)
+
+
+def test_a_refusal_is_counted_where_the_kernels_would_run():
+    """Thirty-two heads in one group: refused by `heads_per_group` and
+    counted once a trace where the kernels would run (pinned here, as on
+    the TPU); off the TPU the composition is the path and nothing is
+    counted; a shape the gate admits is no refusal."""
+    before = _refusals("heads_per_group")
+    _trace_scan(32, 1)
+    assert _refusals("heads_per_group") == before
+    with mock.patch.object(attention, "FORCE_PALLAS", True):
+        _trace_scan(32, 1)
+        assert _refusals("heads_per_group") == before + 1
+        counted = {r: _refusals(r) for r in ("dtype", "groups", "lanes",
+                                              "heads_per_group", "vmem")}
+        _trace_scan(GROUPS * PER, GROUPS)
+        assert {r: _refusals(r) for r in counted} == counted
