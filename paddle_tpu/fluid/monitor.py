@@ -374,6 +374,18 @@ def record_ssd_scan_gate_refusal(reason: str) -> None:
                  reason=reason).inc()
 
 
+def record_ssd_scan_head_blocks(blocks: int) -> None:
+    """Called by ops/ssm_ops.py each time the state-space scan's kernels are
+    traced into a step with a group's heads split across the grid
+    (ops/pallas/ssd_scan.py: a group wider than a cell's 16 heads goes in
+    `blocks` blocks). A lowering-time counter, like
+    `ssd_scan_lowerings_total`."""
+    _reg.counter("ssd_scan_head_blocks_total",
+                 help="state-space scans traced as the kernels with a "
+                      "group's heads split, by the blocks a group",
+                 blocks=str(blocks)).inc()
+
+
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

@@ -277,25 +277,30 @@ SSD_VMEM_SLACK = 4 * 1024 * 1024
 
 
 def ssd_scan_vmem_bytes(pass_: str, s: int, q: int, r: int, p: int, n: int,
-                        itemsize: int, groups: int = 1) -> int:
+                        itemsize: int, groups: int = 1,
+                        blocks: int = 1) -> int:
     """Footprint of one grid cell of ops/pallas/ssd_scan.py: `groups` groups
-    of r heads in all, each of p columns, state n, chunk q, a row of s
+    of r heads in all, or, where a group is split into `blocks` blocks, r
+    heads of one group; each head of p columns, state n, chunk q, a row of s
     positions; every block double-buffered. fwd: x in and y out [q, r p],
     B and C [q, groups n], dt and D [r, q]; scratch: the states [r, p, n]
     float32, x turned and y^T [r p, q], C B^T a group and each head's cum
     as lane-wide columns, [q, q] float32 each; two float32 values of [r p,
     q] and six [q, q] temporaries. bwd: x and the cotangent in and dx out,
-    B and C in and their cotangents out, five [r, q] float32 blocks; the
-    states entering every chunk and dS, (s / q + 1) [r, p, n] float32; x,
-    the cotangent and dx turned, their per-head copies (lanes padded to
-    128), C B^T and d(C B^T) a group and the columns of cum; six [r p, q]
-    values and ten [q, q] temporaries. Both: 1 MiB."""
+    B and C in and their cotangents out (float32 partials where a group is
+    split), five [r, q] float32 blocks; the states entering every chunk and
+    dS, (s / q + 1) [r, p, n] float32; x, the cotangent and dx turned,
+    their per-head copies (lanes padded to 128), C B^T and d(C B^T) a group
+    and the columns of cum; six [r p, q] values and ten [q, q] temporaries.
+    Both: 1 MiB."""
     wide_p = -(-p // 128) * 128
     if pass_ == "fwd":
         return (2 * itemsize * q * (2 * r * p + 2 * groups * n)
                 + 2 * 4 * 3 * r * q + 4 * r * p * n + 4 * 4 * r * p * q
                 + 4 * q * q * (groups + r + 6) + 2 ** 20)
-    return (2 * itemsize * q * (3 * r * p + 4 * groups * n)
+    partials = itemsize if blocks == 1 else 4
+    return (2 * q * (itemsize * (3 * r * p + 2 * groups * n)
+                     + partials * 2 * groups * n)
             + 2 * 4 * 6 * r * q + 4 * (s // q + 1) * r * p * n
             + 4 * 9 * r * p * q + 2 * itemsize * r * q * wide_p
             + 4 * q * q * (2 * groups + r + 10) + 2 ** 20)

@@ -17,6 +17,17 @@ in VMEM a head at a time and never reach HBM, and the states travel from
 chunk to chunk in VMEM scratch, float32: the [S/Q, S/Q] product over the
 chunks of the composition is this carry.
 
+**A group wider than a cell's heads is split** (`_head_block`): a group of
+more than `_MAX_HEADS` heads goes in blocks of 16 or 8, one block a cell,
+the grid's middle axis then over (group, block); each block reads its
+group's B and C, and, backward, writes dB and dC of its own heads as a
+float32 partial that `_scan_bwd` sums over the blocks (dB and dC are sums
+over a group's heads; no two cells write one block). At Q 256 the [Q, Q]
+matrices stay whole: the compiler folds the masked upper half's exps
+itself, and 128 x 128 tiles on or below the diagonal ran about as fast
+(TPU v5e, one group of 64 heads of 64 at S 4096: a forward pass and VJP
+1.224 ms in tiles and 1.250 whole, the forward kernel 0.239 and 0.228).
+
 **Inside a cell the positions lie on the lanes.** x (and, backward, the
 cotangent) is turned once a cell to [R P, Q], so that every per-position
 vector of a head (cum, dt, the decays to the chunk's end, their cotangents)
@@ -59,6 +70,24 @@ _LANES = 128
 _MAX_HEADS = 16
 
 
+def _widths(r: int, p: int):
+    """The heads a cell may take of a group of r, widest first: the whole
+    group where r <= `_MAX_HEADS`, else blocks of 16 or 8 that divide it;
+    each whole sublane tiles of dt and its columns of x whole lane tiles."""
+    return [w for w in ((r,) if r <= _MAX_HEADS else (16, 8))
+            if w % 8 == 0 and r % w == 0 and (w * p) % _LANES == 0]
+
+
+def _head_block(s, r, p, n, q, itemsize) -> Optional[int]:
+    """The heads of a group of r that a grid cell takes: the widest of
+    `_widths` whose backward cell is within the budget, or None."""
+    for w in _widths(r, p):
+        if _feas.ssd_scan_vmem_bytes("bwd", s, q, w, p, n, itemsize,
+                                     blocks=r // w) <= _feas.SSD_VMEM_BUDGET:
+            return w
+    return None
+
+
 def kernel_fits_reason(s: int, heads: int, head_dim: int, groups: int,
                        state: int, chunk: int, dtype) -> Optional[str]:
     """The checks of `kernel_fits`, in order; the first that refuses the
@@ -71,11 +100,10 @@ def kernel_fits_reason(s: int, heads: int, head_dim: int, groups: int,
     r = heads // groups
     if chunk % _LANES or s % chunk or state % _LANES or head_dim % 8:
         return "lanes"
-    if r % 8 or r > _MAX_HEADS or (r * head_dim) % _LANES:
+    if not _widths(r, head_dim):
         return "heads_per_group"
-    if _feas.ssd_scan_vmem_bytes(
-            "bwd", s, chunk, r, head_dim, state, jnp.dtype(dtype).itemsize
-    ) > _feas.SSD_VMEM_BUDGET:
+    if _head_block(s, r, head_dim, state, chunk,
+                   jnp.dtype(dtype).itemsize) is None:
         return "vmem"
     return None
 
@@ -83,12 +111,22 @@ def kernel_fits_reason(s: int, heads: int, head_dim: int, groups: int,
 def kernel_fits(s: int, heads: int, head_dim: int, groups: int, state: int,
                 chunk: int, dtype) -> bool:
     """THE shape gate of the kernels, for a row of s positions (a multiple
-    of the chunk): the chunk and the state whole lane tiles, a group's
-    heads whole sublane tiles of dt (8 or 16 of them) and its columns of x
-    whole lane tiles, a head whole sublane tiles, bf16 or float32, and the
-    backward cell (the larger) under the budget. Else the composition runs."""
+    of the chunk): the chunk and the state whole lane tiles, a head whole
+    sublane tiles, bf16 or float32; a group of 8 or 16 heads, or of more
+    that blocks of 16 or 8 divide (a cell's heads whole sublane tiles of dt
+    and its columns of x whole lane tiles), and the backward cell (the
+    larger) under the budget at that block. Else the composition runs."""
     return kernel_fits_reason(s, heads, head_dim, groups, state, chunk,
                               dtype) is None
+
+
+def head_blocks(s: int, heads: int, head_dim: int, groups: int, state: int,
+                chunk: int, dtype) -> int:
+    """The blocks a group's heads are split into across the grid, at shapes
+    the gate admits: 1 where a cell takes whole groups."""
+    r = heads // groups
+    return r // _head_block(s, r, head_dim, state, chunk,
+                            jnp.dtype(dtype).itemsize)
 
 
 def on_kernels() -> bool:
@@ -206,11 +244,13 @@ def _turn_in(x_ref, xt_ref):
 
 
 class _Groups:
-    """The cell's `groups` groups of `per` heads: the [Q, N] columns of a
+    """The cell's `groups` groups of `per` heads (or, where a group is split
+    into `blocks` blocks, one block of `per` heads): the [Q, N] columns of a
     B or C block, and a [R P, X] stack's rows, by group."""
 
-    def __init__(self, groups, per, p, n):
+    def __init__(self, groups, per, p, n, blocks=1):
         self.groups, self.per, self.p, self.n = groups, per, p, n
+        self.blocks = blocks
 
     def cols(self, a, g):
         return a[:, g * self.n:(g + 1) * self.n]
@@ -427,8 +467,12 @@ def _cell(h, groups, p, n, s, q, itemsize):
     """The groups a grid cell takes: two where they divide the groups and
     the backward cell stays within `_MAX_HEADS` heads and the budget (two
     groups' independent work lets the scheduler hide either's latencies,
-    and halves the cells), else one. From the shapes alone."""
+    and halves the cells), else one; a block of a group wider than
+    `_MAX_HEADS` (`_head_block`). From the shapes alone."""
     per = h // groups
+    if per > _MAX_HEADS:
+        width = _head_block(s, per, p, n, q, itemsize)
+        return _Groups(1, width, p, n, blocks=per // width)
     two = (groups % 2 == 0 and 2 * per <= _MAX_HEADS
            and _feas.ssd_scan_vmem_bytes("bwd", s, q, 2 * per, p, n, itemsize,
                                          groups=2) <= _feas.SSD_VMEM_BUDGET)
@@ -438,13 +482,17 @@ def _cell(h, groups, p, n, s, q, itemsize):
 def _specs(q, gr, chunk_of):
     """Blocks of x [B, S, H P], dt [B, H, S], a [H, 1], B / C [B, S, G N],
     D [H, Q] over the grid (b, cell, k); `chunk_of(k)` is the chunk of step
-    k (a pair: that of x, dt, B and of C, the cotangent and the outputs)."""
+    k (a pair: that of x, dt, B and of C, the cotangent and the outputs).
+    `rows(.., shared=True)` is B's or C's: one block for all the cells of a
+    split group."""
     heads = gr.groups * gr.per
 
-    def rows(width, late=False):
+    def rows(width, late=False, shared=False):
+        blocks = gr.blocks if shared else 1
         return pl.BlockSpec(
             (None, q, width),
-            lambda bi, g, k: (bi, chunk_of(k)[int(late)], g),
+            lambda bi, g, k: (bi, chunk_of(k)[int(late)],
+                              g // blocks if blocks > 1 else g),
             memory_space=pltpu.VMEM)
 
     def turned(late=False):
@@ -465,7 +513,7 @@ def _params(pass_, s, q, gr, itemsize):
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=(_feas.ssd_scan_vmem_bytes(
             pass_, s, q, gr.groups * gr.per, gr.p, gr.n, itemsize,
-            groups=gr.groups) + _feas.SSD_VMEM_SLACK))
+            groups=gr.groups, blocks=gr.blocks) + _feas.SSD_VMEM_SLACK))
 
 
 def _scratch(q, gr):
@@ -484,7 +532,7 @@ def _shapes(x, dt_t, b, groups, chunk):
     h = dt_t.shape[1]
     p, n = width // h, b.shape[2] // groups
     gr = _cell(h, groups, p, n, s, chunk, x.dtype.itemsize)
-    return bsz, s, gr, (bsz, groups // gr.groups)
+    return bsz, s, gr, (bsz, groups * gr.blocks // gr.groups)
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "chunk", "interpret"))
@@ -499,8 +547,8 @@ def _scan_fwd(x, dt_t, a, b, c, d, *, groups, chunk, interpret):
         functools.partial(_fwd_kernel, gr=gr),
         grid=cells + (s // chunk,),
         in_specs=[rows(heads * gr.p), turned(), per_head(1),
-                  rows(gr.groups * gr.n), rows(gr.groups * gr.n),
-                  per_head(chunk)],
+                  rows(gr.groups * gr.n, shared=True),
+                  rows(gr.groups * gr.n, shared=True), per_head(chunk)],
         out_specs=rows(heads * gr.p),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=_scratch(chunk, gr),
@@ -514,7 +562,9 @@ def _scan_fwd(x, dt_t, a, b, c, d, *, groups, chunk, interpret):
 def _scan_bwd(x, dt_t, a, b, c, d, g, *, groups, chunk, interpret):
     """The cotangents from g, that of `_scan_fwd`'s y: dx, dB, dC in their
     dtypes, ddt_t [B, H, S] and the partial sums of dA and dD [B, H, Q]
-    (over the batch and the lanes still to be summed), float32."""
+    (over the batch and the lanes still to be summed), float32. Where a
+    group is split, each block's dB and dC leave as a float32 partial
+    [B, S, G blocks N], summed here."""
     bsz, s, gr, cells = _shapes(x, dt_t, b, groups, chunk)
     heads, p = gr.groups * gr.per, gr.p
     nc = s // chunk
@@ -528,18 +578,22 @@ def _scan_bwd(x, dt_t, a, b, c, d, g, *, groups, chunk, interpret):
                         memory_space=pltpu.VMEM)
     partial = jax.ShapeDtypeStruct((bsz, dt_t.shape[1], chunk), _F32)
     bc = gr.groups * gr.n
+    if gr.blocks > 1:
+        d_bc = jax.ShapeDtypeStruct((bsz, s, groups * gr.blocks * gr.n), _F32)
+    else:
+        d_bc = jax.ShapeDtypeStruct(b.shape, b.dtype)
     carry, x_t, g_t, cb, cum, crep, last = _scratch(chunk, gr)
-    return pl.pallas_call(
+    dx, ddt_t, db, dc, da, dd = pl.pallas_call(
         functools.partial(_bwd_kernel, gr=gr, chunks=nc),
         grid=cells + (2 * nc,),
-        in_specs=[rows(heads * p), turned(), per_head(1), rows(bc),
-                  rows(bc, True), per_head(chunk), rows(heads * p, True)],
+        in_specs=[rows(heads * p), turned(), per_head(1),
+                  rows(bc, shared=True), rows(bc, True, shared=True),
+                  per_head(chunk), rows(heads * p, True)],
         out_specs=[rows(heads * p, True), turned(True), rows(bc, True),
                    rows(bc, True), sums, sums],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(dt_t.shape, _F32),
-                   jax.ShapeDtypeStruct(b.shape, b.dtype),
-                   jax.ShapeDtypeStruct(c.shape, c.dtype), partial, partial],
+                   jax.ShapeDtypeStruct(dt_t.shape, _F32), d_bc, d_bc,
+                   partial, partial],
         scratch_shapes=[
             pltpu.VMEM((nc, heads, p, gr.n), _F32), carry, x_t, g_t,
             pltpu.VMEM((heads, chunk, p), x.dtype),
@@ -552,6 +606,11 @@ def _scan_bwd(x, dt_t, a, b, c, d, g, *, groups, chunk, interpret):
         name="ssd_scan_bwd",
         interpret=interpret,
     )(x, dt_t, a, b, c, d, g)
+    if gr.blocks > 1:
+        db, dc = (jnp.sum(t.reshape(bsz, s, groups, gr.blocks, gr.n),
+                          axis=3).reshape(b.shape).astype(b.dtype)
+                  for t in (db, dc))
+    return dx, ddt_t, db, dc, da, dd
 
 
 def _layouts(x, dt, a, b, c, d, chunk):

@@ -128,10 +128,13 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
     multiple of the chunk is continued with dt = 0, which neither decays
     the state nor adds to it. Lowered under the scope `ssd_scan`, as the
     kernels where `scan_kernels` admits the shapes; counted by
-    `ssd_scan_lowerings_total{impl}` (one a `mamba2` op traced) and, where
-    the kernels would run but their gate refuses the shapes, by
+    `ssd_scan_lowerings_total{impl}` (one a `mamba2` op traced), where the
+    kernels split a group's heads into blocks by
+    `ssd_scan_head_blocks_total{blocks}`, and, where the kernels would run
+    but their gate refuses the shapes, by
     `ssd_scan_gate_refusals_total{reason}`."""
     from ..fluid.monitor import (record_ssd_scan_gate_refusal,
+                                 record_ssd_scan_head_blocks,
                                  record_ssd_scan_lowering)
 
     s = x.shape[1]
@@ -142,8 +145,13 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
                        for t in (x, dt, b, c))
     kernels = scan_kernels(x.shape, b.shape, chunk, x.dtype)
     record_ssd_scan_lowering("pallas" if kernels else "jnp")
-    if not kernels and ssd_pallas.on_kernels():
-        _, rows, h, p = x.shape
+    _, rows, h, p = x.shape
+    if kernels:
+        blocks = ssd_pallas.head_blocks(rows, h, p, *b.shape[2:], chunk,
+                                        x.dtype)
+        if blocks > 1:
+            record_ssd_scan_head_blocks(blocks)
+    elif ssd_pallas.on_kernels():
         record_ssd_scan_gate_refusal(ssd_pallas.kernel_fits_reason(
             rows, h, p, *b.shape[2:], chunk, x.dtype))
     with jax.named_scope("ssd_scan"):

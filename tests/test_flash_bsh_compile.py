@@ -551,10 +551,11 @@ def test_the_granite_cell_step_compiles_and_fits_the_v5e(one_chip,
     described chip with its state given as shapes (772 M parameters are not
     allocated here): the one NoPE attention layer is the latent form's wide
     kernels on 32 heads of 64 at the given scale, and all nine state-space
-    scans are the composition, whose gate refuses a group of 64 heads: the
-    lowering counts nine refusals by `heads_per_group`. Nothing is
-    rematerialized, and XLA's buffer assignment reads no more than the
-    `peak_hbm_gb` the configuration states."""
+    scans are the scan's kernels, a group of 64 heads in four blocks of
+    sixteen: the lowering counts nine `pallas` scans, nine splits into four
+    blocks and no refusal by `heads_per_group`. Nothing is rematerialized,
+    and XLA's buffer assignment reads no more than the `peak_hbm_gb` the
+    configuration states."""
     import numpy as np
 
     import paddle_tpu.fluid as fluid
@@ -583,7 +584,8 @@ def test_the_granite_cell_step_compiles_and_fits_the_v5e(one_chip,
                       reason="heads_per_group"),
               counted("ssd_scan_lowerings_total", impl="pallas"),
               counted("attention_lowerings_total", impl="pallas",
-                      form="mla_wide"))
+                      form="mla_wide"),
+              counted("ssd_scan_head_blocks_total", blocks="4"))
     with mock.patch.object(fa, "_interpret", lambda: False), \
             mock.patch.object(ssd, "_interpret", lambda: False):
         fa._make_flash_core_bsh.cache_clear()
@@ -596,11 +598,13 @@ def test_the_granite_cell_step_compiles_and_fits_the_v5e(one_chip,
     assert (counted("ssd_scan_gate_refusals_total", reason="heads_per_group"),
             counted("ssd_scan_lowerings_total", impl="pallas"),
             counted("attention_lowerings_total", impl="pallas",
-                    form="mla_wide")) == (
-        before[0] + 9, before[1], before[2] + 1)
+                    form="mla_wide"),
+            counted("ssd_scan_head_blocks_total", blocks="4")) == (
+        before[0], before[1] + 9, before[2] + 1, before[3] + 9)
     text = compiled.as_text()
     step = hlo_text.read_step(text)
-    assert set(step.kernels) == set(cell.config["mosaic_calls"]), step.kernels
+    assert set(step.kernels) == set(cell.config["mosaic_calls"]) | {
+        "ssd_scan_fwd", "ssd_scan_bwd"}, step.kernels
     forward = [c for c in step.calls.values()
                if c.kernel == "flash_mla_wide_causal_fwd"]
     assert {c.operands[0].dims for c in forward} == {(batch, 4096, 2048)}

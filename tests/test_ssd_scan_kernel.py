@@ -4,10 +4,11 @@ against the chunked composition `ssm_ops._ssd_chunked` and against the
 recurrence computed position by position in float64 (`_recurrence64` of
 test_nemotron_ops), over three chunks (the state carried forward and, in
 the backward kernel, back), two groups of eight heads (each head reads its
-own group), a row continued with dt = 0, bf16 and float32; the gate's
+own group), one group split into blocks of sixteen heads (at chunk 128
+and 256), a row continued with dt = 0, bf16 and float32; the gate's
 refusals and the composition they leave bit for bit; the scopes, roles and
-counter of a `mamba2` step with the kernels pinned; the VMEM model at the
-Nemotron cell's shapes."""
+counters of a `mamba2` step with the kernels pinned; the VMEM model at the
+Nemotron and Granite cells' shapes."""
 import functools
 import re
 from unittest import mock
@@ -39,18 +40,17 @@ def pinned():
         yield
 
 
-def _inputs(seq, seed=0, batch=2, p=P):
+def _inputs(seq, seed=0, batch=2, p=P, h=GROUPS * PER, groups=GROUPS):
     """Two batch rows; head 0 hardly decays, head 1 forgets within a few
     positions, the rest in between; dt as softplus gives it in the cell."""
     rng = np.random.default_rng(seed)
-    h = GROUPS * PER
     a = -rng.uniform(1.0, 16.0, h)
     a[0], a[1] = -1e-3, -40.0
     return dict(
         x=rng.normal(size=(batch, seq, h, p)),
         dt=np.log1p(np.exp(rng.normal(-3.0, 1.0, (batch, seq, h)))),
-        a=a, b=rng.normal(size=(batch, seq, GROUPS, N)) / np.sqrt(N),
-        c=rng.normal(size=(batch, seq, GROUPS, N)) / np.sqrt(N),
+        a=a, b=rng.normal(size=(batch, seq, groups, N)) / np.sqrt(N),
+        c=rng.normal(size=(batch, seq, groups, N)) / np.sqrt(N),
         d=rng.normal(size=h))
 
 
@@ -82,6 +82,28 @@ def test_forward_and_every_cotangent_against_the_composition_float32(pinned):
     with jax.default_matmul_precision("highest"):
         want = _vjp(_composition(), args, g)
     got = _vjp(_kernels, args, g)
+    for name, u, v in zip(("y",) + NAMES, got, want):
+        assert u.dtype == v.dtype and u.shape == v.shape, name
+        assert _rel(u, v) < 2e-5, (name, _rel(u, v))
+
+
+@pytest.mark.parametrize("heads, chunk, seq, blocks", [
+    (32, Q, 3 * Q, 2), (64, 2 * Q, 4 * Q, 4)], ids=["32_heads_q128",
+                                                   "64_heads_q256"])
+def test_a_split_group_against_the_composition(pinned, heads, chunk, seq,
+                                               blocks):
+    """One group of B and C over more heads than a cell takes: blocks of
+    sixteen heads, each reading the group's B and C; dB and dC are the sums
+    of the blocks' partials (a block left out reads (blocks - 1) / blocks
+    off)."""
+    ins = _inputs(seq, seed=13, batch=1, h=heads, groups=1)
+    args = _arrays(ins, jnp.float32)
+    assert ssd.head_blocks(seq, heads, P, 1, N, chunk, jnp.float32) == blocks
+    g = jnp.asarray(np.random.default_rng(14).normal(size=ins["x"].shape),
+                    jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = _vjp(_composition(chunk), args, g)
+    got = _vjp(lambda *t: ssd.ssd_scan(*t, chunk), args, g)
     for name, u, v in zip(("y",) + NAMES, got, want):
         assert u.dtype == v.dtype and u.shape == v.shape, name
         assert _rel(u, v) < 2e-5, (name, _rel(u, v))
@@ -177,7 +199,7 @@ def test_the_state_is_carried_and_each_head_reads_its_group(pinned):
     (dict(head_dim=12), "head not whole sublane tiles"),
     (dict(head_dim=8), "a group's x not a lane tile"),
     (dict(heads=8, groups=2), "four heads a group"),
-    (dict(heads=64, groups=2), "32 heads a group"),
+    (dict(heads=40, groups=2), "20 heads a group"),
     (dict(s=192), "row not whole chunks"),
 ], ids=lambda v: v if isinstance(v, str) else None)
 def test_the_gate_refuses_what_it_cannot_tile(shape, why):
@@ -298,7 +320,26 @@ def test_the_vmem_model_admits_the_nemotron_cell_with_margin():
         "bwd", 8 * s, Q, r, 64, 128, 2, groups=2) > feasible.SSD_VMEM_BUDGET
     assert ssd.kernel_fits(s, 64, 64, 8, 128, 128, jnp.bfloat16)
     assert not ssd.kernel_fits(16 * s, 64, 64, 8, 128, 128, jnp.bfloat16)
-    assert ssd._cell(64, 8, 64, 128, s, Q, 2).groups == 2
+    cell = ssd._cell(64, 8, 64, 128, s, Q, 2)
+    assert (cell.groups, cell.per, cell.blocks) == (2, 8, 1)
+    assert ssd.head_blocks(s, 64, 64, 8, 128, 128, jnp.bfloat16) == 1
+
+
+def test_the_vmem_model_admits_the_granite_cell_in_blocks_of_sixteen():
+    """The Granite cell: one group of 64 heads of 64 at state 128, chunk
+    256, rows of 4,096, bf16. All 64 heads in one cell want ~116 MB, over
+    the budget; a block of sixteen is within it (~33 MB), so a group goes
+    in four blocks, one a cell."""
+    s, q = 4096, 2 * Q
+    whole = feasible.ssd_scan_vmem_bytes("bwd", s, q, 64, 64, 128, 2)
+    assert 1.1e8 < whole and whole > feasible.SSD_VMEM_BUDGET
+    for pass_ in ("fwd", "bwd"):
+        est = feasible.ssd_scan_vmem_bytes(pass_, s, q, 16, 64, 128, 2,
+                                           blocks=4)
+        assert est <= 0.6 * feasible.SSD_VMEM_BUDGET, (pass_, est)
+    cell = ssd._cell(64, 1, 64, 128, s, q, 2)
+    assert (cell.groups, cell.per, cell.blocks) == (1, 16, 4)
+    assert ssd.head_blocks(s, 64, 64, 1, 128, q, jnp.bfloat16) == 4
 
 
 # ---------------------------------------------------------------------------
@@ -310,8 +351,11 @@ def test_the_vmem_model_admits_the_nemotron_cell_with_margin():
     # the Nemotron cell: eight groups of eight heads at chunk 128
     (dict(s=4096, heads=64, head_dim=64, groups=8, state=128, chunk=128),
      None),
-    # the Granite cell: one group of all 64 heads at chunk 256
+    # the Granite cell: one group of all 64 heads at chunk 256, in blocks
     (dict(s=4096, heads=64, head_dim=64, groups=1, state=128, chunk=256),
+     None),
+    # twenty heads in one group: neither 16 nor 8 divides them
+    (dict(s=4096, heads=20, head_dim=64, groups=1, state=128, chunk=256),
      "heads_per_group"),
     (dict(s=4096, heads=64, head_dim=64, groups=8, state=128, chunk=128,
           dtype=jnp.float16), "dtype"),
@@ -321,7 +365,8 @@ def test_the_vmem_model_admits_the_nemotron_cell_with_margin():
      "lanes"),
     (dict(s=16 * 4096, heads=64, head_dim=64, groups=8, state=128,
           chunk=128), "vmem"),
-], ids=["nemotron", "granite", "dtype", "groups", "lanes", "vmem"])
+], ids=["nemotron", "granite", "heads_per_group", "dtype", "groups", "lanes",
+        "vmem"])
 def test_the_gate_names_the_first_check_that_refused(sizes, reason):
     sizes = {"dtype": jnp.bfloat16, **sizes}
     assert ssd.kernel_fits_reason(**sizes) == reason
@@ -344,17 +389,40 @@ def _trace_scan(heads, groups):
 
 
 def test_a_refusal_is_counted_where_the_kernels_would_run():
-    """Thirty-two heads in one group: refused by `heads_per_group` and
+    """Twenty heads in one group: refused by `heads_per_group` and
     counted once a trace where the kernels would run (pinned here, as on
     the TPU); off the TPU the composition is the path and nothing is
     counted; a shape the gate admits is no refusal."""
     before = _refusals("heads_per_group")
-    _trace_scan(32, 1)
+    _trace_scan(20, 1)
     assert _refusals("heads_per_group") == before
     with mock.patch.object(attention, "FORCE_PALLAS", True):
-        _trace_scan(32, 1)
+        _trace_scan(20, 1)
         assert _refusals("heads_per_group") == before + 1
         counted = {r: _refusals(r) for r in ("dtype", "groups", "lanes",
                                               "heads_per_group", "vmem")}
         _trace_scan(GROUPS * PER, GROUPS)
         assert {r: _refusals(r) for r in counted} == counted
+
+
+def _head_blocks(blocks):
+    return get_registry().counter("ssd_scan_head_blocks_total",
+                                  blocks=blocks).value
+
+
+def test_a_split_group_is_counted_by_its_blocks():
+    """Thirty-two heads in one group where the kernels run: one `pallas`
+    lowering, no refusal, and one count under `blocks="2"`; two groups of
+    eight heads, which a cell takes whole, count no blocks; off the TPU
+    nothing is counted."""
+    before = _head_blocks("2")
+    _trace_scan(32, 1)
+    assert _head_blocks("2") == before
+    with mock.patch.object(attention, "FORCE_PALLAS", True):
+        counted = _lowerings("pallas"), _refusals("heads_per_group")
+        _trace_scan(32, 1)
+        assert (_lowerings("pallas"), _refusals("heads_per_group")) == (
+            counted[0] + 1, counted[1])
+        assert _head_blocks("2") == before + 1
+        _trace_scan(GROUPS * PER, GROUPS)
+        assert _head_blocks("2") == before + 1
