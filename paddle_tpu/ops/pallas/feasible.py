@@ -282,17 +282,19 @@ def ssd_scan_vmem_bytes(pass_: str, s: int, q: int, r: int, p: int, n: int,
     """Footprint of one grid cell of ops/pallas/ssd_scan.py: `groups` groups
     of r heads in all, or, where a group is split into `blocks` blocks, r
     heads of one group; each head of p columns, state n, chunk q, a row of s
-    positions; every block double-buffered. fwd: x in and y out [q, r p],
-    B and C [q, groups n], dt and D [r, q]; scratch: the states [r, p, n]
-    float32, x turned and y^T [r p, q], C B^T a group and each head's cum
-    as lane-wide columns, [q, q] float32 each; two float32 values of [r p,
-    q] and six [q, q] temporaries. bwd: x and the cotangent in and dx out,
-    B and C in and their cotangents out (float32 partials where a group is
-    split), five [r, q] float32 blocks; the states entering every chunk and
-    dS, (s / q + 1) [r, p, n] float32; x, the cotangent and dx turned,
-    their per-head copies (lanes padded to 128), C B^T and d(C B^T) a group
-    and the columns of cum; six [r p, q] values and ten [q, q] temporaries.
-    Both: 1 MiB."""
+    positions; every block double-buffered, every per-position one turned
+    (positions last; the same bytes either way). fwd: x in and y out [r p,
+    q], B and C [groups n, q], dt and D [r, q]; scratch: the states [r, p,
+    n] float32, y^T [r p, q], C B^T a group and each head's cum as
+    lane-wide columns, [q, q] float32 each; x in float32, two float32
+    values of [r p, q] and six [q, q] temporaries. bwd: x and the cotangent
+    in and dx out, B and C in and their cotangents out (float32 partials
+    where a group is split), five [r, q] float32 blocks; the states
+    entering every chunk and dS, (s / q + 1) [r, p, n] float32; x, the
+    cotangent and dx [r p, q] float32, the cotangent turned and its
+    per-head copies (two [r, q, p] stacks at x's itemsize, lanes padded to
+    128), C B^T and d(C B^T) a group and the columns of cum; six [r p, q]
+    values and ten [q, q] temporaries. Both: 1 MiB."""
     wide_p = -(-p // 128) * 128
     if pass_ == "fwd":
         return (2 * itemsize * q * (2 * r * p + 2 * groups * n)

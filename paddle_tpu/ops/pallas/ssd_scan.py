@@ -10,12 +10,16 @@ i / j a position in the chunk, cum the running sum of dt A over it):
 
 with S [P, N] the state a head carries into the chunk. Both kernels run a
 grid over (batch, group, chunk), the chunk axis in order: a group's R heads
-share its B and C, so a cell takes the group's [Q, R P] columns of x, its
-[Q, N] of B and C, and dt turned, [R, Q] with the positions on the lanes.
-The [Q, Q] matrices (C B^T, the masked decays, their product with dt) live
-in VMEM a head at a time and never reach HBM, and the states travel from
-chunk to chunk in VMEM scratch, float32: the [S/Q, S/Q] product over the
-chunks of the composition is this carry.
+share its B and C, so a cell takes the group's [R P, Q] rows of x, its
+[N, Q] of B and C, and [R, Q] of dt. **Every per-position array crosses
+the call turned, the positions last** (x, dt, B, C, the cotangent, y, dx,
+ddt, dB, dC: [B, H P, S], [B, H, S], [B, G N, S]; the turns are
+`jnp.swapaxes` outside, which XLA lays into its neighbours' layouts), so a
+block arrives with the positions on the lanes. The [Q, Q] matrices (C B^T,
+the masked decays, their product with dt) live in VMEM a head at a time
+and never reach HBM, and the states travel from chunk to chunk in VMEM
+scratch, float32: the [S/Q, S/Q] product over the chunks of the
+composition is this carry.
 
 **A group wider than a cell's heads is split** (`_head_block`): a group of
 more than `_MAX_HEADS` heads goes in blocks of 16 or 8, one block a cell,
@@ -28,13 +32,16 @@ itself, and 128 x 128 tiles on or below the diagonal ran about as fast
 (TPU v5e, one group of 64 heads of 64 at S 4096: a forward pass and VJP
 1.224 ms in tiles and 1.250 whole, the forward kernel 0.239 and 0.228).
 
-**Inside a cell the positions lie on the lanes.** x (and, backward, the
-cotangent) is turned once a cell to [R P, Q], so that every per-position
-vector of a head (cum, dt, the decays to the chunk's end, their cotangents)
-is one [1, Q] row and the work on x is a batch of [R, P, Q]; the heads'
-states stack to [R P, N], so that the read-out, what a chunk leaves behind
-and their cotangents are one product each for the whole group. Only the
-[Q, Q] matrices go a head at a time, through an unrolled loop.
+**Inside a cell the positions lie on the lanes**, as the blocks bring
+them: every per-position vector of a head (cum, dt, the decays to the
+chunk's end, their cotangents) is one [1, Q] row and the work on x is a
+batch of [R, P, Q]; the heads' states stack to [R P, N], so that the
+read-out, what a chunk leaves behind and their cotangents are one product
+each for the whole group. Only the [Q, Q] matrices go a head at a time,
+through an unrolled loop. What a cell still turns is what a product needs
+with the contracted axis first: C's block for C B^T, and, backward, the
+cotangent (once, for each head's [Q, P] of it), the states and dS for dC
+and dB.
 
 **The backward kernel sweeps twice.** Its grid's last axis is 2 S/Q long:
 the first half walks the chunks forward and keeps every state that enters
@@ -73,7 +80,7 @@ _MAX_HEADS = 16
 def _widths(r: int, p: int):
     """The heads a cell may take of a group of r, widest first: the whole
     group where r <= `_MAX_HEADS`, else blocks of 16 or 8 that divide it;
-    each whole sublane tiles of dt and its columns of x whole lane tiles."""
+    each whole sublane tiles of dt and its rows of x whole lane tiles."""
     return [w for w in ((r,) if r <= _MAX_HEADS else (16, 8))
             if w % 8 == 0 and r % w == 0 and (w * p) % _LANES == 0]
 
@@ -114,7 +121,7 @@ def kernel_fits(s: int, heads: int, head_dim: int, groups: int, state: int,
     of the chunk): the chunk and the state whole lane tiles, a head whole
     sublane tiles, bf16 or float32; a group of 8 or 16 heads, or of more
     that blocks of 16 or 8 divide (a cell's heads whole sublane tiles of dt
-    and its columns of x whole lane tiles), and the backward cell (the
+    and its rows of x whole lane tiles), and the backward cell (the
     larger) under the budget at that block. Else the composition runs."""
     return kernel_fits_reason(s, heads, head_dim, groups, state, chunk,
                               dtype) is None
@@ -165,6 +172,12 @@ def _nt(a, b):
 
 def _dot(a, b):
     return jnp.dot(*_operands(a, b), preferred_element_type=_F32)
+
+
+def _tn(a, b):
+    """a [k, m]^T . b [k, n], float32 accumulation: a turned in the cell,
+    as float32, and rounded to b's dtype."""
+    return _dot(a.astype(_F32).T.astype(b.dtype), b)
 
 
 def _iotas(q):
@@ -236,24 +249,17 @@ def _causal(q):
     return i >= j
 
 
-def _turn_in(x_ref, xt_ref):
-    """A block [Q, R P] turned to float32 [R P, Q], kept in `xt_ref`."""
-    x_t = x_ref[...].astype(_F32).T
-    xt_ref[...] = x_t
-    return x_t
-
-
 class _Groups:
     """The cell's `groups` groups of `per` heads (or, where a group is split
-    into `blocks` blocks, one block of `per` heads): the [Q, N] columns of a
+    into `blocks` blocks, one block of `per` heads): the [N, Q] rows of a
     B or C block, and a [R P, X] stack's rows, by group."""
 
     def __init__(self, groups, per, p, n, blocks=1):
         self.groups, self.per, self.p, self.n = groups, per, p, n
         self.blocks = blocks
 
-    def cols(self, a, g):
-        return a[:, g * self.n:(g + 1) * self.n]
+    def bc(self, a, g):
+        return a[g * self.n:(g + 1) * self.n]
 
     def rows(self, a, g):
         width = self.per * self.p
@@ -270,7 +276,13 @@ def _left(ch, x_t, b, gr, mx):
     heads = gr.groups * gr.per
     xw = _stack(_stack(x_t, heads, gr.p) * ch.w[:, None, :], heads, gr.p)
     xw = xw.astype(mx)
-    return gr.each(lambda g: _dot(gr.rows(xw, g), gr.cols(b, g)))
+    return gr.each(lambda g: _nt(gr.rows(xw, g), gr.bc(b, g)))
+
+
+def _cb(c, b, gr, cb_ref):
+    """C B^T [i, j] of each group into `cb_ref`, from the turned blocks."""
+    for g in range(gr.groups):
+        cb_ref[g] = _tn(gr.bc(c, g), gr.bc(b, g))
 
 
 def _over_heads(heads, head):
@@ -281,39 +293,44 @@ def _over_heads(heads, head):
     lax.fori_loop(0, heads, head, 0, unroll=True)
 
 
+def _head_rows(r, p):
+    """Head r's rows of a [R P, X] stack (at a multiple of p's largest
+    power-of-two factor: bf16 rows pack two to a sublane)."""
+    return pl.ds(pl.multiple_of(r * p, p & -p), p)
+
+
 def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, s_ref,
-                xt_ref, yt_ref, cb_ref, cum_ref, crep_ref, last_ref, *, gr):
+                yt_ref, cb_ref, cum_ref, crep_ref, last_ref, *, gr):
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
     mx = x_ref.dtype
     heads, p = gr.groups * gr.per, gr.p
-    b, c = b_ref[...], c_ref[...]
+    b, c = b_ref[...], c_ref[...]                      # [G N, Q]
     ch = _Chunk(dt_ref, a_ref, last_ref, gr.n)
     cum_ref[...] = ch.cum
     _columns(ch, crep_ref)
-    for g in range(gr.groups):
-        cb_ref[g] = _nt(gr.cols(c, g), gr.cols(b, g))  # [i, j]
-    x_t = _turn_in(x_ref, xt_ref)                      # [R P, Q]
+    _cb(c, b, gr, cb_ref)
     causal = _causal(ch.dt.shape[1])
 
     def head(r, carry):
         m = (cb_ref[r // gr.per] * _decay(cum_ref, crep_ref, r, causal)
              * dt_ref[pl.ds(r, 1), :]).astype(mx)
-        rows = pl.ds(pl.multiple_of(r * p, 8), p)
+        rows = _head_rows(r, p)
         # y^T [P, i] = x^T [P, j] M^T
-        yt_ref[rows, :] = _nt(xt_ref[rows, :].astype(mx), m)
+        yt_ref[rows, :] = _nt(x_ref[rows, :], m)
         return carry
 
     _over_heads(heads, head)
+    x_t = x_ref[...].astype(_F32)                      # [R P, Q]
     s = s_ref[...]
     s_rows = _stack(s, heads, p).astype(mx)
-    y_in = gr.each(lambda g: _nt(gr.rows(s_rows, g), gr.cols(c, g)))
+    y_in = gr.each(lambda g: _dot(gr.rows(s_rows, g), gr.bc(c, g)))
     y = (_stack(yt_ref[...], heads, p)
          + _stack(y_in, heads, p) * jnp.exp(ch.cum)[:, None, :]
          + d_ref[...][:, None, :] * _stack(x_t, heads, p))
-    y_ref[...] = _stack(y, heads, p).T.astype(y_ref.dtype)
+    y_ref[...] = _stack(y, heads, p).astype(y_ref.dtype)
     s_ref[...] = (ch.grow_last[:, None, :] * s
                   + _stack(_left(ch, x_t, b, gr, mx), heads, p))
 
@@ -337,9 +354,9 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, g_ref,
         ch = _Chunk(dt_ref, a_ref, sc.last, gr.n)
         s = carry[...]
         states[k] = s
-        x_t = _turn_in(x_ref, sc.xt)
         carry[...] = (ch.grow_last[:, None, :] * s + _stack(
-            _left(ch, x_t, b_ref[...], gr, mx), gr.groups * gr.per, gr.p))
+            _left(ch, x_ref[...].astype(_F32), b_ref[...], gr, mx),
+            gr.groups * gr.per, gr.p))
 
     @pl.when(k == chunks)
     def _():
@@ -353,13 +370,10 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, g_ref,
 
 
 class _BwdScratch(NamedTuple):
-    """The backward kernel's VMEM beyond the states and dS: x and the
-    cotangent turned [R P, Q] and each head's [Q, P] of them, dx^T
-    [R P, Q], C B^T and d(C B^T) a group, cum and its columns, cum_Q, and
-    the per-head rows of ddt and d cum from the [Q, Q] matrices."""
-    xt: Any
-    gt: Any
-    xr: Any
+    """The backward kernel's VMEM beyond the states and dS: each head's
+    [Q, P] of the cotangent, dx [R P, Q] from the [Q, Q] matrices, C B^T
+    and d(C B^T) a group, cum and its columns, cum_Q, and the per-head rows
+    of ddt and d cum from the [Q, Q] matrices."""
     gr: Any
     dxt: Any
     cb: Any
@@ -380,29 +394,28 @@ def _bwd_chunk(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, g_ref,
     through cum the sums of dM o M over j (at i) and over i (at j,
     negative)."""
     heads, p = gr.groups * gr.per, gr.p
-    b, c = b_ref[...], c_ref[...]
+    b, c = b_ref[...], c_ref[...]                      # [G N, Q]
     ch = _Chunk(dt_ref, a_ref, sc.last, gr.n)
     q = ch.dt.shape[1]
     sc.cum[...] = ch.cum
     _columns(ch, sc.crep)
-    for g in range(gr.groups):
-        sc.cb[g] = _nt(gr.cols(c, g), gr.cols(b, g))  # [i, j]
+    _cb(c, b, gr, sc.cb)
     sc.dcb[...] = jnp.zeros_like(sc.dcb)
-    x_t = _turn_in(x_ref, sc.xt)
-    g_t = _turn_in(g_ref, sc.gt)
+    x_t = x_ref[...].astype(_F32)                      # [R P, Q]
+    g_t = g_ref[...].astype(_F32)
+    # each head's [Q, P] of the cotangent, the operand of dM = g x^T
+    g_pos = g_t.T
     for r in range(heads):
-        sc.xr[r] = x_ref[:, r * p:(r + 1) * p]
-        sc.gr[r] = g_ref[:, r * p:(r + 1) * p]
+        sc.gr[r] = g_pos[:, r * p:(r + 1) * p].astype(mx)
     causal = _causal(q)
 
     def head(r, carry_):
         decay = _decay(sc.cum, sc.crep, r, causal)
         dt_row = dt_ref[pl.ds(r, 1), :]
         e = sc.cb[r // gr.per] * decay
-        rows = pl.ds(pl.multiple_of(r * p, 8), p)
-        sc.dxt[rows, :] = _dot(sc.gt[rows, :].astype(mx),
-                                (e * dt_row).astype(mx))
-        dm = _nt(sc.gr[r], sc.xr[r])                 # [i, j]
+        rows = _head_rows(r, p)
+        sc.dxt[rows, :] = _dot(g_ref[rows, :], (e * dt_row).astype(mx))
+        dm = _dot(sc.gr[r], x_ref[rows, :])          # [i, j]
         f = dm * e
         sc.ddt_in[pl.ds(r, 1), :] = jnp.sum(f, axis=0, keepdims=True)
         # sum_j f_ij dt_j, with the positions i on the lanes
@@ -414,12 +427,11 @@ def _bwd_chunk(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, g_ref,
     _over_heads(heads, head)
     x3, g3 = _stack(x_t, heads, p), _stack(g_t, heads, p)
     s, ds = s_ref[...], carry[...]                     # [R, P, N]
-    s_rows = _stack(s, heads, p).astype(mx)
-    ds_rows = _stack(ds, heads, p).astype(mx)
-    y_in = _stack(gr.each(lambda g: _nt(gr.rows(s_rows, g), gr.cols(c, g))),
-                  heads, p)                            # [R, P, i]
-    dxw = _stack(gr.each(lambda g: _nt(gr.rows(ds_rows, g), gr.cols(b, g))),
-                 heads, p)                             # [R, P, j]
+    s_rows, ds_rows = _stack(s, heads, p), _stack(ds, heads, p)
+    y_in = _stack(gr.each(lambda g: _dot(gr.rows(s_rows, g).astype(mx),
+                                         gr.bc(c, g))), heads, p)  # [R, P, i]
+    dxw = _stack(gr.each(lambda g: _dot(gr.rows(ds_rows, g).astype(mx),
+                                        gr.bc(b, g))), heads, p)   # [R, P, j]
     grow = jnp.exp(ch.cum)
     w = ch.w
     dw = jnp.sum(x3 * dxw, axis=1)
@@ -433,23 +445,21 @@ def _bwd_chunk(s_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, g_ref,
     dcum = dcum + jnp.where(lane == q - 1, d_last, 0.0)
     dx = (_stack(sc.dxt[...], heads, p) + dxw * w[:, None, :]
           + d_ref[...][:, None, :] * g3)
-    dx_ref[...] = _stack(dx, heads, p).T.astype(dx_ref.dtype)
-    # [Q, R P]: x w and g exp(cum), the positions back on the sublanes
-    xw = _stack(x3 * w[:, None, :], heads, p).T.astype(mx)
-    dye = _stack(g3 * grow[:, None, :], heads, p).T.astype(mx)
-    width = gr.per * p
+    dx_ref[...] = _stack(dx, heads, p).astype(dx_ref.dtype)
+    # [R P, Q]: x w and g exp(cum)
+    xw = _stack(x3 * w[:, None, :], heads, p).astype(mx)
+    dye = _stack(g3 * grow[:, None, :], heads, p).astype(mx)
     for g in range(gr.groups):
-        dcb = sc.dcb[g]
-        cols = slice(g * width, (g + 1) * width)
-        db = (_dot(xw[:, cols], gr.rows(ds_rows, g))
-              + _dot(dcb.T.astype(mx), gr.cols(c, g)))
-        dc = (_dot(dye[:, cols], gr.rows(s_rows, g))
-              + _dot(dcb.astype(mx), gr.cols(b, g)))
-        db_ref[:, g * gr.n:(g + 1) * gr.n] = db.astype(db_ref.dtype)
-        dc_ref[:, g * gr.n:(g + 1) * gr.n] = dc.astype(dc_ref.dtype)
+        dcb = sc.dcb[g].astype(mx)                     # [i, j]
+        # dB^T [N, j] = dS^T (x w) + C^T d(C B^T), dC^T [N, i] likewise
+        db = (_tn(gr.rows(ds_rows, g), gr.rows(xw, g))
+              + _dot(gr.bc(c, g), dcb))
+        dc = (_tn(gr.rows(s_rows, g), gr.rows(dye, g))
+              + _nt(gr.bc(b, g), dcb))
+        db_ref[g * gr.n:(g + 1) * gr.n] = db.astype(db_ref.dtype)
+        dc_ref[g * gr.n:(g + 1) * gr.n] = dc.astype(dc_ref.dtype)
     # dS of the states entering the chunk: (g exp(cum))^T C
-    dye_rows = _stack(g3 * grow[:, None, :], heads, p).astype(mx)
-    d_in = gr.each(lambda g: _dot(gr.rows(dye_rows, g), gr.cols(c, g)))
+    d_in = gr.each(lambda g: _nt(gr.rows(dye, g), gr.bc(c, g)))
     carry[...] = ch.grow_last[:, None, :] * ds + _stack(d_in, heads, p)
     # d(dt A)_k = sum_{i >= k} d cum_i
     d_dta = _running(dcum, reverse=True)
@@ -480,32 +490,26 @@ def _cell(h, groups, p, n, s, q, itemsize):
 
 
 def _specs(q, gr, chunk_of):
-    """Blocks of x [B, S, H P], dt [B, H, S], a [H, 1], B / C [B, S, G N],
-    D [H, Q] over the grid (b, cell, k); `chunk_of(k)` is the chunk of step
-    k (a pair: that of x, dt, B and of C, the cotangent and the outputs).
-    `rows(.., shared=True)` is B's or C's: one block for all the cells of a
-    split group."""
+    """Blocks of the turned arrays, x [B, H P, S], dt [B, H, S], B / C
+    [B, G N, S], and of a [H, 1] and D [H, Q], over the grid (b, cell, k);
+    `chunk_of(k)` is the chunk of step k (a pair: that of x, dt, B and of
+    C, the cotangent and the outputs). `turned(.., shared=True)` is B's or
+    C's: one block for all the cells of a split group."""
     heads = gr.groups * gr.per
 
-    def rows(width, late=False, shared=False):
+    def turned(width, late=False, shared=False):
         blocks = gr.blocks if shared else 1
         return pl.BlockSpec(
-            (None, q, width),
-            lambda bi, g, k: (bi, chunk_of(k)[int(late)],
-                              g // blocks if blocks > 1 else g),
-            memory_space=pltpu.VMEM)
-
-    def turned(late=False):
-        return pl.BlockSpec(
-            (None, heads, q),
-            lambda bi, g, k: (bi, g, chunk_of(k)[int(late)]),
+            (None, width, q),
+            lambda bi, g, k: (bi, g // blocks if blocks > 1 else g,
+                              chunk_of(k)[int(late)]),
             memory_space=pltpu.VMEM)
 
     def per_head(width):
         return pl.BlockSpec((heads, width), lambda bi, g, k: (g, 0),
                             memory_space=pltpu.VMEM)
 
-    return rows, turned, per_head
+    return turned, per_head
 
 
 def _params(pass_, s, q, gr, itemsize):
@@ -517,39 +521,40 @@ def _params(pass_, s, q, gr, itemsize):
 
 
 def _scratch(q, gr):
-    """What both kernels keep in VMEM: the states [R, P, N], x turned
-    [R P, Q], a turned [R P, Q] operand, C B^T a group [Q, Q], cum [R, Q]
-    and its columns [R, Q, Q], cum_Q across max(Q, N) lanes."""
+    """What both kernels keep in VMEM: the states [R, P, N], what the
+    [Q, Q] matrices give (y forward, dx backward) [R P, Q], C B^T a group
+    [Q, Q], cum [R, Q] and its columns [R, Q, Q], cum_Q across max(Q, N)
+    lanes."""
     r, p, n = gr.groups * gr.per, gr.p, gr.n
     return [pltpu.VMEM((r, p, n), _F32), pltpu.VMEM((r * p, q), _F32),
-            pltpu.VMEM((r * p, q), _F32), pltpu.VMEM((gr.groups, q, q), _F32),
-            pltpu.VMEM((r, q), _F32), pltpu.VMEM((r, q, q), _F32),
-            pltpu.VMEM((r, max(q, n)), _F32)]
+            pltpu.VMEM((gr.groups, q, q), _F32), pltpu.VMEM((r, q), _F32),
+            pltpu.VMEM((r, q, q), _F32), pltpu.VMEM((r, max(q, n)), _F32)]
 
 
 def _shapes(x, dt_t, b, groups, chunk):
-    bsz, s, width = x.shape
+    bsz, width, s = x.shape
     h = dt_t.shape[1]
-    p, n = width // h, b.shape[2] // groups
+    p, n = width // h, b.shape[1] // groups
     gr = _cell(h, groups, p, n, s, chunk, x.dtype.itemsize)
     return bsz, s, gr, (bsz, groups * gr.blocks // gr.groups)
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "chunk", "interpret"))
 def _scan_fwd(x, dt_t, a, b, c, d, *, groups, chunk, interpret):
-    """x [B, S, H P], dt_t [B, H, S], a [H, 1], d [H, Q] float32, b / c
-    [B, S, G N] -> y [B, S, H P]. An inner jit: a step's layers (and its
-    check program's) share one traced and one lowered body."""
+    """x [B, H P, S], dt_t [B, H, S], a [H, 1], d [H, Q] float32, b / c
+    [B, G N, S] -> y [B, H P, S], every per-position array turned. An
+    inner jit: a step's layers (and its check program's) share one traced
+    and one lowered body."""
     bsz, s, gr, cells = _shapes(x, dt_t, b, groups, chunk)
     heads = gr.groups * gr.per
-    rows, turned, per_head = _specs(chunk, gr, lambda k: (k, k))
+    turned, per_head = _specs(chunk, gr, lambda k: (k, k))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, gr=gr),
         grid=cells + (s // chunk,),
-        in_specs=[rows(heads * gr.p), turned(), per_head(1),
-                  rows(gr.groups * gr.n, shared=True),
-                  rows(gr.groups * gr.n, shared=True), per_head(chunk)],
-        out_specs=rows(heads * gr.p),
+        in_specs=[turned(heads * gr.p), turned(heads), per_head(1),
+                  turned(gr.groups * gr.n, shared=True),
+                  turned(gr.groups * gr.n, shared=True), per_head(chunk)],
+        out_specs=turned(heads * gr.p),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=_scratch(chunk, gr),
         compiler_params=_params("fwd", s, chunk, gr, x.dtype.itemsize),
@@ -560,11 +565,11 @@ def _scan_fwd(x, dt_t, a, b, c, d, *, groups, chunk, interpret):
 
 @functools.partial(jax.jit, static_argnames=("groups", "chunk", "interpret"))
 def _scan_bwd(x, dt_t, a, b, c, d, g, *, groups, chunk, interpret):
-    """The cotangents from g, that of `_scan_fwd`'s y: dx, dB, dC in their
-    dtypes, ddt_t [B, H, S] and the partial sums of dA and dD [B, H, Q]
-    (over the batch and the lanes still to be summed), float32. Where a
-    group is split, each block's dB and dC leave as a float32 partial
-    [B, S, G blocks N], summed here."""
+    """The cotangents from g [B, H P, S], that of `_scan_fwd`'s y: dx, dB,
+    dC turned in their dtypes, ddt_t [B, H, S] and the partial sums of dA
+    and dD [B, H, Q] (over the batch and the lanes still to be summed),
+    float32. Where a group is split, each block's dB and dC leave as a
+    float32 partial [B, G blocks N, S], summed here."""
     bsz, s, gr, cells = _shapes(x, dt_t, b, groups, chunk)
     heads, p = gr.groups * gr.per, gr.p
     nc = s // chunk
@@ -573,32 +578,30 @@ def _scan_bwd(x, dt_t, a, b, c, d, g, *, groups, chunk, interpret):
         back = 2 * nc - 1 - k
         return (jnp.where(k < nc, k, back), jnp.where(k < nc, nc - 1, back))
 
-    rows, turned, per_head = _specs(chunk, gr, chunk_of)
+    turned, per_head = _specs(chunk, gr, chunk_of)
     sums = pl.BlockSpec((None, heads, chunk), lambda bi, gi, k: (bi, gi, 0),
                         memory_space=pltpu.VMEM)
     partial = jax.ShapeDtypeStruct((bsz, dt_t.shape[1], chunk), _F32)
     bc = gr.groups * gr.n
     if gr.blocks > 1:
-        d_bc = jax.ShapeDtypeStruct((bsz, s, groups * gr.blocks * gr.n), _F32)
+        d_bc = jax.ShapeDtypeStruct((bsz, groups * gr.blocks * gr.n, s), _F32)
     else:
         d_bc = jax.ShapeDtypeStruct(b.shape, b.dtype)
-    carry, x_t, g_t, cb, cum, crep, last = _scratch(chunk, gr)
+    carry, dx_t, cb, cum, crep, last = _scratch(chunk, gr)
     dx, ddt_t, db, dc, da, dd = pl.pallas_call(
         functools.partial(_bwd_kernel, gr=gr, chunks=nc),
         grid=cells + (2 * nc,),
-        in_specs=[rows(heads * p), turned(), per_head(1),
-                  rows(bc, shared=True), rows(bc, True, shared=True),
-                  per_head(chunk), rows(heads * p, True)],
-        out_specs=[rows(heads * p, True), turned(True), rows(bc, True),
-                   rows(bc, True), sums, sums],
+        in_specs=[turned(heads * p), turned(heads), per_head(1),
+                  turned(bc, shared=True), turned(bc, True, shared=True),
+                  per_head(chunk), turned(heads * p, True)],
+        out_specs=[turned(heads * p, True), turned(heads, True),
+                   turned(bc, True), turned(bc, True), sums, sums],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(dt_t.shape, _F32), d_bc, d_bc,
                    partial, partial],
         scratch_shapes=[
-            pltpu.VMEM((nc, heads, p, gr.n), _F32), carry, x_t, g_t,
-            pltpu.VMEM((heads, chunk, p), x.dtype),
-            pltpu.VMEM((heads, chunk, p), x.dtype),
-            pltpu.VMEM((heads * p, chunk), _F32), cb,
+            pltpu.VMEM((nc, heads, p, gr.n), _F32), carry,
+            pltpu.VMEM((heads, chunk, p), x.dtype), dx_t, cb,
             pltpu.VMEM((gr.groups, chunk, chunk), _F32), cum, crep, last,
             pltpu.VMEM((heads, chunk), _F32),
             pltpu.VMEM((heads, chunk), _F32)],
@@ -607,22 +610,26 @@ def _scan_bwd(x, dt_t, a, b, c, d, g, *, groups, chunk, interpret):
         interpret=interpret,
     )(x, dt_t, a, b, c, d, g)
     if gr.blocks > 1:
-        db, dc = (jnp.sum(t.reshape(bsz, s, groups, gr.blocks, gr.n),
-                          axis=3).reshape(b.shape).astype(b.dtype)
+        db, dc = (jnp.sum(t.reshape(bsz, groups, gr.blocks, gr.n, s),
+                          axis=2).reshape(b.shape).astype(b.dtype)
                   for t in (db, dc))
     return dx, ddt_t, db, dc, da, dd
 
 
+def _turn(t):
+    """[B, S, X] <-> [B, X, S]."""
+    return jnp.swapaxes(t, 1, 2)
+
+
 def _layouts(x, dt, a, b, c, d, chunk):
-    """The kernels' layouts: x [B, S, H P], dt turned [B, H, S], a [H, 1]
-    and D across the chunk's lanes [H, Q], float32, B and C [B, S, G N]
-    (reshapes of the op's arrays)."""
+    """The kernels' layouts: x, dt, B and C turned, [B, H P, S], [B, H, S]
+    float32 and [B, G N, S]; a [H, 1] and D across the chunk's lanes
+    [H, Q], float32."""
     bsz, s, h, p = x.shape
     g, n = b.shape[2:]
-    return (x.reshape(bsz, s, h * p),
-            jnp.swapaxes(dt.astype(_F32), 1, 2),
+    return (_turn(x.reshape(bsz, s, h * p)), _turn(dt.astype(_F32)),
             a.astype(_F32).reshape(h, 1),
-            b.reshape(bsz, s, g * n), c.reshape(bsz, s, g * n),
+            _turn(b.reshape(bsz, s, g * n)), _turn(c.reshape(bsz, s, g * n)),
             jnp.broadcast_to(d.astype(_F32)[:, None], (h, chunk)))
 
 
@@ -630,7 +637,7 @@ def _layouts(x, dt, a, b, c, d, chunk):
 def _scan(x, dt, a, b, c, d, chunk):
     y = _scan_fwd(*_layouts(x, dt, a, b, c, d, chunk), groups=b.shape[2],
                   chunk=chunk, interpret=_interpret())
-    return y.reshape(x.shape)
+    return _turn(y).reshape(x.shape)
 
 
 def _scan_vjp_fwd(x, dt, a, b, c, d, chunk):
@@ -642,11 +649,12 @@ def _scan_vjp_bwd(chunk, saved, g):
     x, dt, a, b, c, d = saved
     bsz, s, h, p = x.shape
     dx, ddt_t, db, dc, da, dd = _scan_bwd(
-        *_layouts(*saved, chunk), g.reshape(bsz, s, h * p),
+        *_layouts(*saved, chunk), _turn(g.reshape(bsz, s, h * p)),
         groups=b.shape[2], chunk=chunk, interpret=_interpret())
-    return (dx.reshape(x.shape), jnp.swapaxes(ddt_t, 1, 2).astype(dt.dtype),
-            jnp.sum(da, axis=(0, 2)).astype(a.dtype), db.reshape(b.shape),
-            dc.reshape(c.shape), jnp.sum(dd, axis=(0, 2)).astype(d.dtype))
+    return (_turn(dx).reshape(x.shape), _turn(ddt_t).astype(dt.dtype),
+            jnp.sum(da, axis=(0, 2)).astype(a.dtype),
+            _turn(db).reshape(b.shape), _turn(dc).reshape(c.shape),
+            jnp.sum(dd, axis=(0, 2)).astype(d.dtype))
 
 
 _scan.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)

@@ -8,6 +8,7 @@ the DMA tile. PR 25 unrolled its compute tiles in Python; the kernels were
 16 % faster and `first_step_s` went from 7 s to 29 s. No wall clock is read
 here: the equation count of each `pallas_call`'s jaxpr is the measure.
 """
+import re
 from unittest import mock
 
 import jax
@@ -357,6 +358,47 @@ def test_the_ssd_scan_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
 # ---------------------------------------------------------------------------
 
 
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*[a-z0-9]+\[([0-9,]*)\]\{([0-9,]*)")
+
+
+def _in_proj_weight_products(text, program):
+    """Each Mamba-2 in_proj weight-gradient product of a compiled step's
+    text, the `convolution` under `backward/transpose(jvp(mamba2))` whose
+    result has an in_proj weight's shape (axes of 1 aside), as the places
+    where its two operands' layouts hold the contracted positions: the
+    index of the contracted axis (`f` of the lhs, `i` of the rhs) in each
+    layout's minor-to-major order, 0 the lanes; the block norm's output
+    first, d(in_proj) (as wide as the weight's columns) second."""
+    block = program.global_block()
+    weights = {tuple(block.var(op.input("InW")[0]).shape)
+               for op in block.ops if op.type == "mamba2"}
+    found, shapes = [], {}
+    for line in text.splitlines():
+        if line.endswith("{"):  # a computation starts: names are its own
+            shapes = {}
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, dims, order = m.groups()
+        dims = tuple(int(v) for v in dims.split(",") if v)
+        shapes[name] = dims, tuple(int(v) for v in order.split(",") if v)
+        weight = tuple(d for d in dims if d != 1)
+        if (" convolution(" not in line
+                or "backward/transpose(jvp(mamba2))" not in line
+                or weight not in weights):
+            continue
+        operands = re.search(r"convolution\(%([\w.\-]+), %([\w.\-]+)\)",
+                             line).groups()
+        labels = re.search(r"dim_labels=(\w+)_(\w+)->", line).groups()
+        places = [shapes[o][1].index(lab.index(axis)) for o, lab, axis in
+                  zip(operands, labels, "fi")]
+        if weight[1] in shapes[operands[0]][0]:
+            places.reverse()
+        found.append(tuple(places))
+    return found
+
+
 def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
                                                        no_compile_cache):
     """The step of `xing4.0-29b-a4b.tp8ep8share.s4096` as the harness
@@ -473,6 +515,15 @@ def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
     stated = cell.config["stated"]["peak_hbm_gb"]
     assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb < 15.2
+    # the scan's kernels' step (PR 39) read 14.421 GB
+    assert abs(peak_gb - 14.421) <= 0.01 * 14.421, peak_gb
+    # d(in_proj) reaches the four in_proj weight-gradient products as the
+    # scan's kernels give it, the positions on its lanes (PR 43), and the
+    # block norm's output keeps the features there; the kernels' layouts
+    # under which both agree here (B and C row-major) made XLA turn dx
+    # back, and the step 3.5 % slower on the chip (PR 43)
+    products = _in_proj_weight_products(text, built.main)
+    assert [d for _, d in products] == [0] * 4, products
 
 
 def test_the_glm_cell_step_compiles_and_fits_the_v5e(one_chip,
@@ -609,9 +660,18 @@ def test_the_granite_cell_step_compiles_and_fits_the_v5e(one_chip,
                if c.kernel == "flash_mla_wide_causal_fwd"]
     assert {c.operands[0].dims for c in forward} == {(batch, 4096, 2048)}
     assert ".remat" not in text
+    # since PR 43 the scan's kernels give dx, dB and dC turned, positions
+    # last, and each of the nine in_proj weight-gradient products finds the
+    # positions on the lanes of both operands (before, d(in_proj) came
+    # features-minor against the block norm's positions-minor output, and
+    # one operand was turned for each product)
+    products = _in_proj_weight_products(text, built.main)
+    assert products == [(0, 0)] * 9, products
     mem = compiled.memory_analysis()
     peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
     stated = cell.config["stated"]["peak_hbm_gb"]
     assert peak_gb <= 1.01 * stated, (peak_gb, stated)
     assert 4.0 < peak_gb <= 15.2
+    # the scan's kernels' step (PR 41) read 14.409 GB
+    assert abs(peak_gb - 14.409) <= 0.01 * 14.409, peak_gb

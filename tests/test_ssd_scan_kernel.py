@@ -5,7 +5,10 @@ recurrence computed position by position in float64 (`_recurrence64` of
 test_nemotron_ops), over three chunks (the state carried forward and, in
 the backward kernel, back), two groups of eight heads (each head reads its
 own group), one group split into blocks of sixteen heads (at chunk 128
-and 256), a row continued with dt = 0, bf16 and float32; the gate's
+and 256), a row continued with dt = 0, bf16 and float32; the kernels
+called at their own layouts, every per-position array turned, positions
+last (two groups of 8 or one of 16 heads at chunk 128, one of 64 in blocks
+of 16 or 8 at chunk 256, bf16 and float32); the gate's
 refusals and the composition they leave bit for bit; the scopes, roles and
 counters of a `mamba2` step with the kernels pinned; the VMEM model at the
 Nemotron and Granite cells' shapes."""
@@ -107,6 +110,83 @@ def test_a_split_group_against_the_composition(pinned, heads, chunk, seq,
     for name, u, v in zip(("y",) + NAMES, got, want):
         assert u.dtype == v.dtype and u.shape == v.shape, name
         assert _rel(u, v) < 2e-5, (name, _rel(u, v))
+
+
+def _turned(t):
+    return jnp.swapaxes(t, 1, 2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("heads, groups, chunk, blocks", [
+    (16, 2, Q, 1), (16, 1, Q, 1), (64, 1, 2 * Q, 4), (64, 1, 2 * Q, 8)],
+    ids=["two_groups_of_8_q128", "one_group_of_16_q128",
+         "64_heads_blocks_of_16_q256", "64_heads_blocks_of_8_q256"])
+def test_the_kernels_take_and_give_turned_arrays(pinned, heads, groups, chunk,
+                                                 blocks, dtype):
+    """The kernels called at their own layouts, every per-position array
+    with the positions last: x, the cotangent, y and dx [B, H P, S], dt and
+    ddt [B, H, S], B, C, dB and dC [B, G N, S]; against the composition's
+    forward pass and VJP turned the same way, in float32 on the same
+    (rounded) inputs. bf16 lies as near as the composition's own bf16 pass,
+    within a rounding of bf16 either way (heads of 64 columns: XLA's CPU
+    runtime takes no bf16 x bf16 -> float32 product of 16). A group of 64
+    heads goes in blocks of 16, or of 8 where the budget admits no wider
+    block."""
+    seq, p = 2 * chunk, (P if dtype == jnp.float32 else 64)
+    ins = _inputs(seq, seed=15, batch=1, p=p, h=heads, groups=groups)
+    args = _arrays(ins, dtype)
+    g = jnp.asarray(np.random.default_rng(16).normal(size=ins["x"].shape),
+                    dtype)
+    budget = feasible.SSD_VMEM_BUDGET
+    if blocks == 8:  # between the backward cells of blocks of 8 and of 16
+        budget = sum(feasible.ssd_scan_vmem_bytes(
+            "bwd", seq, chunk, w, p, N, jnp.dtype(dtype).itemsize,
+            blocks=heads // w) for w in (8, 16)) // 2
+    turned = ssd._layouts(*args, chunk)
+    x_t, dt_t, b_t = turned[0], turned[1], turned[3]
+    assert x_t.shape == (1, heads * p, seq)
+    assert dt_t.shape == (1, heads, seq)
+    assert b_t.shape == turned[4].shape == (1, groups * N, seq)
+    g_t = _turned(g.reshape(1, seq, heads * p))
+    kw = dict(groups=groups, chunk=chunk, interpret=True)
+    # the inner jits' caches know nothing of the budget: under a budget of
+    # its own a call is traced afresh, and leaves nothing traced behind
+    fresh = budget != feasible.SSD_VMEM_BUDGET
+    if fresh:
+        jax.clear_caches()
+    try:
+        with mock.patch.object(feasible, "SSD_VMEM_BUDGET", budget):
+            assert ssd.head_blocks(seq, heads, p, groups, N, chunk,
+                                   dtype) == blocks
+            grids = [e.params["grid_mapping"].grid for e in jax.make_jaxpr(
+                functools.partial(ssd._scan_fwd.__wrapped__, **kw))(
+                    *turned).eqns if e.primitive.name == "pallas_call"]
+            y_t = ssd._scan_fwd(*turned, **kw)
+            dx_t, ddt_t, db_t, dc_t, da, dd = ssd._scan_bwd(*turned, g_t,
+                                                            **kw)
+    finally:
+        if fresh:
+            jax.clear_caches()
+    # (batch, the cells over a group's blocks, chunks)
+    assert grids == [(1, blocks, seq // chunk)]
+    assert y_t.shape == dx_t.shape == x_t.shape
+    assert ddt_t.shape == dt_t.shape
+    assert db_t.shape == dc_t.shape == b_t.shape
+    exact = [a.astype(jnp.float32) for a in args]
+    with jax.default_matmul_precision("highest"):
+        want = _vjp(_composition(chunk), exact, g.astype(jnp.float32))
+        comp = _vjp(_composition(chunk), args, g)
+    got = [_turned(y_t).reshape(ins["x"].shape),
+           _turned(dx_t).reshape(ins["x"].shape), _turned(ddt_t),
+           jnp.sum(da, axis=(0, 2)), _turned(db_t).reshape(ins["b"].shape),
+           _turned(dc_t).reshape(ins["c"].shape), jnp.sum(dd, axis=(0, 2))]
+    for name, u, c, v in zip(("y",) + NAMES, got, comp, want):
+        assert u.shape == v.shape, name
+        bound = (2e-5 if dtype == jnp.float32
+                 else max(2 * _rel(c, v), 2.0 ** -8))
+        assert _rel(u, v) < bound, (name, _rel(u, v), bound)
+    assert y_t.dtype == dx_t.dtype == db_t.dtype == dtype
 
 
 def test_forward_and_every_cotangent_against_the_recurrence(pinned):
