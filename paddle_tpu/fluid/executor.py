@@ -824,6 +824,15 @@ class Executor:
         for a chip that is not attached; the scope may then hold
         `jax.ShapeDtypeStruct`s in place of arrays, and nothing of the
         program's size is allocated."""
+        traced = self._trace_step(program, feed, fetch_list, scope, sharding)
+        if platforms is None:
+            return traced.lower()
+        return traced.lower(lowering_platforms=tuple(platforms))
+
+    def _trace_step(self, program=None, feed=None, fetch_list=None,
+                    scope=None, sharding=None):
+        """jax Traced of the step `_lower_step` lowers: its `.jaxpr` holds
+        every `pallas_call` the step makes, by name."""
         import jax
 
         if program is None:
@@ -879,10 +888,7 @@ class Executor:
                      for n, a in feed_arrays.items()}
         rng_abs = _abstract(
             rng, getattr(compiled, "repl_sharding", None) or sharding)
-        traced = compiled.fn.trace(feeds_abs, donated, kept, rng_abs)
-        if platforms is None:
-            return traced.lower()
-        return traced.lower(lowering_platforms=tuple(platforms))
+        return compiled.fn.trace(feeds_abs, donated, kept, rng_abs)
 
     def memory_analysis(self, program=None, feed=None, fetch_list=None,
                         scope=None):

@@ -294,98 +294,6 @@ def record_cache_hit() -> None:
     _counter("executor_cache_hits_total", "compile-cache hits").inc()
 
 
-def record_grouped_product_lowering(impl: str, form: str) -> None:
-    """Called by ops/pallas/grouped_matmul.py each time a grouped product
-    of `moe_swiglu` is traced into a step: `impl` is what was lowered
-    (`pallas`, the repo's kernel, or `ragged_dot`, XLA's own), `form` the
-    product (`nn`) or its transpose (`nt` input gradient, `tn` weight
-    gradient). A lowering-time counter: it moves when a step is built,
-    never while one runs."""
-    _reg.counter("moe_grouped_product_lowerings_total",
-                 help="grouped expert products traced, by implementation "
-                      "and form", impl=impl, form=form).inc()
-
-
-def record_attention_lowering(impl: str, form: str) -> None:
-    """Called by ops/attention.py each time the attention op (or `mla`,
-    which shares its code) is traced into a step: `impl` is what was
-    lowered (`pallas`, a flash kernel, or `jnp`, the composition), `form`
-    `mha` (one head width, the default scale), `mla` (value heads of
-    another width than the query / key heads, or a given scale; on the
-    kernels, heads padded to a kernel width) or `mla_wide` (the latent form
-    on heads that are a kernel width as they stand: nothing padded, one
-    kernel call a group of heads and one count the attention call). A
-    lowering-time counter, like the grouped products'."""
-    _reg.counter("attention_lowerings_total",
-                 help="attention calls traced, by implementation and form",
-                 impl=impl, form=form).inc()
-
-
-def record_mtp_module_built() -> None:
-    """Called by a model builder each time it builds a multi-token
-    prediction module into a program (`models/glm4_moe_lite.py`): a
-    build-time counter, it moves when a program is made."""
-    _reg.counter("mtp_modules_built_total",
-                 help="multi-token prediction modules built into "
-                      "programs").inc()
-
-
-def record_mhc_post_lowering(impl: str) -> None:
-    """Called by ops/latent_ops.py each time `mhc_post` is traced into a
-    step: `impl` is what was lowered (`pallas`, the one-pass kernels of
-    ops/pallas/mhc.py, or `jnp`, the composition). A lowering-time
-    counter, like the grouped products'."""
-    _reg.counter("mhc_post_lowerings_total",
-                 help="mhc_post ops traced, by implementation",
-                 impl=impl).inc()
-
-
-def record_mhc_map_lowering(impl: str) -> None:
-    """Called by ops/latent_ops.py each time `mhc_map` is traced into a
-    step: `impl` is what was lowered (`pallas`, the forward kernel of
-    ops/pallas/mhc.py, or `jnp`, the composition in both passes). A
-    lowering-time counter, like `mhc_post`'s."""
-    _reg.counter("mhc_map_lowerings_total",
-                 help="mhc_map ops traced, by implementation",
-                 impl=impl).inc()
-
-
-def record_ssd_scan_lowering(impl: str) -> None:
-    """Called by ops/ssm_ops.py each time a `mamba2` op, and the state-space
-    scan inside it, is traced into a step: `impl` is what was lowered
-    (`pallas`, ops/pallas/ssd_scan.py's two kernels, or `jnp`, the chunked
-    composition of matrix products). A lowering-time counter, like the
-    grouped products'."""
-    _reg.counter("ssd_scan_lowerings_total",
-                 help="state-space scans traced, by implementation",
-                 impl=impl).inc()
-
-
-def record_ssd_scan_gate_refusal(reason: str) -> None:
-    """Called by ops/ssm_ops.py each time a state-space scan is traced
-    where ops/pallas/ssd_scan.py's kernels would run (on the TPU, or pinned
-    by a test) and their gate refuses the shapes: `reason` is the first
-    check that failed (`kernel_fits_reason`: `dtype`, `groups`, `lanes`,
-    `heads_per_group`, `vmem`). A lowering-time counter, like
-    `ssd_scan_lowerings_total`."""
-    _reg.counter("ssd_scan_gate_refusals_total",
-                 help="state-space scans the kernels' gate refused where "
-                      "they would run, by the first check that failed",
-                 reason=reason).inc()
-
-
-def record_ssd_scan_head_blocks(blocks: int) -> None:
-    """Called by ops/ssm_ops.py each time the state-space scan's kernels are
-    traced into a step with a group's heads split across the grid
-    (ops/pallas/ssd_scan.py: a group wider than a cell's 16 heads goes in
-    `blocks` blocks). A lowering-time counter, like
-    `ssd_scan_lowerings_total`."""
-    _reg.counter("ssd_scan_head_blocks_total",
-                 help="state-space scans traced as the kernels with a "
-                      "group's heads split, by the blocks a group",
-                 blocks=str(blocks)).inc()
-
-
 def add_data_wait(ms: float) -> None:
     """Input-pipeline wait attributed to the NEXT step (dataset loops
     block on the iterator BEFORE calling run)."""

@@ -49,7 +49,6 @@ from typing import List, Optional, Tuple
 
 from ..fluid import layers
 from ..fluid.framework import Program, name_scope, program_guard
-from ..fluid.monitor import record_mtp_module_built
 from ..fluid.param_attr import ParamAttr
 from .latent_moe import (_attr, attention, feed_forward, outputs_of,
                          tokens_per_expert)  # noqa: F401
@@ -170,7 +169,6 @@ def mtp_module(cfg: Glm4MoeLiteConfig, hidden, next_ids, labels_next,
     """L_mtp from the trunk's last hidden state (in front of the final
     norm), the ids of the tokens that follow (t_{i+1}: the trunk's labels)
     and of those after them (t_{i+2})."""
-    record_mtp_module_built()
     with name_scope("mtp"):
         with name_scope("mtp_combine"):
             e = layers.rms_norm(

@@ -48,10 +48,6 @@ def _reference_attention(q, k, v, bias, dropout_prob, deterministic, rng_key,
     return jnp.einsum("bnqk,bnkd->bnqd", probs, v)
 
 
-# test hook: force the pallas path (interpret mode) on CPU
-FORCE_PALLAS = False
-
-
 def _use_pallas(q):
     from .pallas.flash_attention import flash_shapes_ok
 
@@ -115,7 +111,6 @@ def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
     the unpadded widths', and `flash_mla_wide_causal_fwd` / `_bwd` where
     nothing is padded and the call's shapes are its work. Elsewhere the jnp
     composition."""
-    from ..fluid.monitor import record_attention_lowering
     from .pallas.flash_attention import HEAD_WIDTHS, flash_attention_bsh
 
     b, sq, hq = q3.shape
@@ -128,7 +123,6 @@ def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
         sq, skv, num_heads, width, batch=b, causal=causal)
     if groups is not None:
         form = "mla_wide" if dqk == dv == width else "mla"
-        record_attention_lowering("pallas", form)
         held = num_heads // groups
         padded = [_pad_heads(x3, num_heads, width) for x3 in (q3, k3, v3)]
         outs = []
@@ -142,7 +136,6 @@ def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
         out = outs[0] if groups == 1 else jnp.concatenate(outs, axis=-1)
         out = out.reshape(b, sq, num_heads, width)[..., :dv]
         return out.reshape(b, sq, num_heads * dv)
-    record_attention_lowering("jnp", "mla")
     out = _reference_attention(
         _split_heads(q3, num_heads), _split_heads(k3, num_heads),
         _split_heads(v3, num_heads), _causal_bias(sq) if causal else None,
@@ -152,7 +145,6 @@ def latent_attention(q3, k3, v3, num_heads, sm_scale=None, causal=True,
 
 @register("fused_multihead_attention")
 def fused_multihead_attention(ctx, ins, attrs):
-    from ..fluid.monitor import record_attention_lowering
     from ..parallel.ring_attention import (
         key_bias_from_attn_bias,
         ring_attention_global,
@@ -204,7 +196,6 @@ def fused_multihead_attention(ctx, ins, attrs):
                        causal=causal):
         from .pallas.flash_attention import flash_attention_bsh
 
-        record_attention_lowering("pallas", "mha")
         dkey = None
         if not is_test and dropout_prob > 0.0:
             dkey = ctx.salted_rng(int(attrs.get("rng_salt", 0)))
@@ -224,7 +215,6 @@ def fused_multihead_attention(ctx, ins, attrs):
     if _use_pallas(q) and q.shape[2] == k.shape[2]:
         from .pallas.flash_attention import flash_attention
 
-        record_attention_lowering("pallas", "mha")
         dkey = None
         if not is_test and dropout_prob > 0.0:
             dkey = ctx.salted_rng(int(attrs.get("rng_salt", 0)))
@@ -234,7 +224,6 @@ def fused_multihead_attention(ctx, ins, attrs):
             dropout_key=dkey, mesh=ctx.mesh,
         )
     else:
-        record_attention_lowering("jnp", "mha")
         if causal:
             cmask = _causal_bias(q.shape[2])
             bias = cmask if bias is None else bias + cmask

@@ -135,7 +135,6 @@ def mhc_map(ctx, ins, attrs):
     the gate; the backward pass is `_mhc_map`'s, see `mhc._map_core_bwd`);
     `_mhc_map` everywhere else. Either way only the four inputs are kept
     for the backward pass."""
-    from ..fluid.monitor import record_mhc_map_lowering
     from .pallas import mhc
 
     args = ins["X"][0], ins["Phi"][0], ins["Bias"][0], ins["Alpha"][0]
@@ -148,7 +147,6 @@ def mhc_map(ctx, ins, attrs):
     alone = ctx.mesh is None or ctx.mesh.size == 1
     rows = (mhc.map_rows(args[0], args[1], settings["n"], settings["iters"])
             if alone else None)
-    record_mhc_map_lowering("jnp" if rows is None else "pallas")
     with jax.named_scope("mhc_map"):
         if rows is None:
             pre, post, res, gap = jax.checkpoint(
@@ -200,14 +198,12 @@ def mhc_post(ctx, ins, attrs):
     lane tiles and rows a block tiles, the two one-pass kernels of
     ops/pallas/mhc.py (`mhc.mhc_rows` is the gate); `_mhc_post` everywhere
     else. Either way only the four inputs are kept for the backward pass."""
-    from ..fluid.monitor import record_mhc_post_lowering
     from .pallas import mhc
 
     args = ins["X"][0], ins["Y"][0], ins["HRes"][0], ins["HPost"][0]
     # XLA cannot partition a Mosaic call: over a mesh, the composition
     alone = ctx.mesh is None or ctx.mesh.size == 1
     rows = mhc.mhc_rows(*args) if alone else None
-    record_mhc_post_lowering("jnp" if rows is None else "pallas")
     with jax.named_scope("mhc_mix"):
         if rows is None:
             return {"Out": [jax.checkpoint(_mhc_post)(*args)]}

@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 from . import feasible as _feas
-from .flash_attention import _identity, _interpret, _to_lanes, _to_sublanes
 
 _ROW_ALIGN = _feas.LN_ROW_ALIGN
 _ROW_CANDIDATES = (1024, 512, 256, 128)
@@ -81,9 +81,9 @@ def _fwd_kernel(*refs, eps, has_y, br):
         xhat * scale_ref[...].astype(jnp.float32)
         + shift_ref[...].astype(jnp.float32)
     ).astype(out_ref.dtype)
-    ident = _identity(br)
-    mean_ref[...] = _to_lanes(mu, ident)
-    rstd_ref[...] = _to_lanes(rstd, ident)
+    ident = backend.identity(br)
+    mean_ref[...] = backend.to_lanes(mu, ident)
+    rstd_ref[...] = backend.to_lanes(rstd, ident)
 
 
 def _bwd_kernel(*refs, has_y, br):
@@ -97,12 +97,12 @@ def _bwd_kernel(*refs, has_y, br):
     dx_ref = next(it)
     dsc_ref = next(it)
     dsh_ref = next(it)
-    ident = _identity(br)
+    ident = backend.identity(br)
     s = x_ref[...].astype(jnp.float32)
     if has_y:
         s = s + y_ref[...].astype(jnp.float32)
-    mu = _to_sublanes(mean_ref[...], ident)
-    rstd = _to_sublanes(rstd_ref[...], ident)
+    mu = backend.to_sublanes(mean_ref[...], ident)
+    rstd = backend.to_sublanes(rstd_ref[...], ident)
     xhat = (s - mu) * rstd
     g = g_ref[...].astype(jnp.float32)
     # per-block partials land in [NB, 1, H] (the 3-D shape keeps the
@@ -135,7 +135,7 @@ def _ln_fwd(x, y, scale, shift, *, eps):
             jax.ShapeDtypeStruct((1, r), jnp.float32),
         ],
         name="add_ln_fwd",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return out, mean, rstd
 
@@ -166,7 +166,7 @@ def _ln_bwd(x, y, scale, mean, rstd, g, *, eps):
             jax.ShapeDtypeStruct((nb, 1, h), jnp.float32),
         ],
         name="add_ln_bwd",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return dx, dsc.sum(axis=(0, 1)), dsh.sum(axis=(0, 1))
 
@@ -200,7 +200,6 @@ def fused_ln_dispatch_ok(shape) -> bool:
     """Backend/flag/shape gate for every fused-LN dispatch site (mirrors
     flash_shapes_ok)."""
     from ...fluid.flags import flag
-    from ..attention import FORCE_PALLAS
 
     if not flag("FLAGS_use_fused_ln"):
         return False
@@ -208,10 +207,7 @@ def fused_ln_dispatch_ok(shape) -> bool:
     r = 1
     for d in shape[:-1]:
         r *= d
-    ok = ln_shapes_ok(r, h)
-    if FORCE_PALLAS:
-        return ok
-    return ok and not _interpret()
+    return ln_shapes_ok(r, h) and backend.on_kernels()
 
 
 def _row_spec(mesh, shape):

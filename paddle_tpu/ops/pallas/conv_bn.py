@@ -46,8 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 from . import feasible as _feas
-from .flash_attention import _interpret
 
 # per-grid-step VMEM budget: in/out blocks double-buffered + the f32
 # accumulator; leaves headroom of the ~16MB/core for Mosaic's own use.
@@ -114,13 +114,9 @@ def conv_bn_dispatch_ok(x_shape, w_shape, strides, pads, dilations=(1, 1),
                         groups=1) -> bool:
     """Backend + shape gate for dispatch sites (mirrors
     fused_ln_dispatch_ok): CPU/interpret runs take the jnp reference path
-    unless FORCE_PALLAS pins the kernel (tests)."""
-    from ..attention import FORCE_PALLAS
-
-    ok = conv_bn_shapes_ok(x_shape, w_shape, strides, pads, dilations, groups)
-    if FORCE_PALLAS:
-        return ok
-    return ok and not _interpret()
+    unless `backend.FORCE_PALLAS` pins the kernel (tests)."""
+    return (conv_bn_shapes_ok(x_shape, w_shape, strides, pads, dilations,
+                              groups) and backend.on_kernels())
 
 
 # ---------------------------------------------------------------------------
@@ -292,7 +288,7 @@ def _conv_fwd(x, w2d, out_dtype, kh, kw, pads):
             jax.ShapeDtypeStruct((1, o), jnp.float32),
         ],
         name="conv_bn_conv_stats",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(xp, w2d)
     return y.reshape(n * ho * wo, o), (n, ho, wo, o), s, ss
 
@@ -317,7 +313,7 @@ def _mm_fwd(x, w2d, out_dtype, strides):
             jax.ShapeDtypeStruct((1, o), jnp.float32),
         ],
         name="conv_bn_mm_stats",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(x.reshape(r, c), w2d)
     return y, (n, ho, wo, o), s, ss
 
@@ -355,7 +351,7 @@ def _pallas_fwd(x, w, scale, bias, *, strides, pads, eps, with_relu):
         out_specs=_row_specs(br, o),
         out_shape=jax.ShapeDtypeStruct((r, o), x.dtype),
         name="conv_bn_apply",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(z2d, stat)
     return y2d.reshape(oshape), z2d, stat, m, v
 
@@ -377,7 +373,7 @@ def _pallas_bwd(x, w, z2d, stat, g, *, strides, pads, with_relu):
             jax.ShapeDtypeStruct((nb, 1, o), jnp.float32),
         ],
         name="conv_bn_bwd_reduce",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(z2d, g2d, stat)
     dgamma = dg.sum(axis=(0, 1))
     dbeta = db.sum(axis=(0, 1))
@@ -391,7 +387,7 @@ def _pallas_bwd(x, w, z2d, stat, g, *, strides, pads, with_relu):
         out_specs=_row_specs(br, o),
         out_shape=jax.ShapeDtypeStruct((r, o), x.dtype),
         name="conv_bn_bwd_dz",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(z2d, g2d, stat, tot)
     # dX / dW on XLA's native conv schedules (the measured best — see the
     # round-5 im2col experiment); the primal conv is dead code under jit

@@ -60,6 +60,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 from . import feasible as _feas
 
 MIN_BLOCK = 128
@@ -126,12 +127,6 @@ _SEED_QI = 0x85EBCA6B - (1 << 32)
 _SEED_KI = 0xC2B2AE35 - (1 << 32)
 
 
-def _interpret() -> bool:
-    # off the TPU (cpu tests) the kernels run in interpreter mode for
-    # exact-semantics checking
-    return jax.default_backend() != "tpu"
-
-
 def _dropout_quantized_thresh(keep_prob):
     """THE single source of the 8-bit dropout quantization: keep a byte
     iff byte < t, with t in [1, 256]. t == 256 keeps everything exactly
@@ -174,34 +169,6 @@ def _dropout_keep(seed_ref, bh, qi, ki, keep_prob, bq, bk):
         return jnp.concatenate(parts, axis=1)
     bits = pltpu.bitcast(pltpu.prng_random_bits((bq, bk)), jnp.int32)
     return (bits & jnp.int32(0xFF)) < thresh
-
-
-def _identity(n):
-    """[n, n] f32 identity for MXU-side layout transposes (built once per
-    grid cell, outside the head loop)."""
-    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    return (r == c).astype(jnp.float32)
-
-
-def _to_lanes(x_sparse, ident):
-    """(n, 1) sublane-major -> (1, n) lane-major via an MXU matmul.
-
-    The VPU relayout Mosaic emits for a plain reshape walks 1-lane-wide
-    vregs and costs ~0.7us per call (profiled: it was 40% of the whole
-    fwd kernel); the [1,n]x[n,n] identity matmul is noise on the MXU."""
-    return jax.lax.dot_general(
-        x_sparse, ident, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _to_sublanes(x_lane, ident):
-    """(1, n) lane-major -> (n, 1) sublane-major via an MXU matmul."""
-    return jax.lax.dot_general(
-        ident, x_lane, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
 
 
 def _causal_mask(s, qglob, kglob, bq, bk):
@@ -262,7 +229,7 @@ def _make_fwd_kernel(*, sm_scale, causal, dropout_prob, bias_mode, use_prng,
         )
         q_off = off_ref[0] if has_offsets else 0
         k_off = off_ref[1] if has_offsets else 0
-        ident = _identity(bq)
+        ident = backend.identity(bq)
 
         def head(g, _):
             bh = gi * G + g
@@ -314,7 +281,7 @@ def _make_fwd_kernel(*, sm_scale, causal, dropout_prob, bias_mode, use_prng,
             m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
             l_safe = jnp.maximum(l, 1e-30)
             o_ref[g] = (acc / l_safe).astype(o_ref.dtype)
-            lse_ref[g, 0] = _to_lanes(m + jnp.log(l_safe), ident)[0]
+            lse_ref[g, 0] = backend.to_lanes(m + jnp.log(l_safe), ident)[0]
             return 0
 
         jax.lax.fori_loop(0, G, head, 0)
@@ -398,7 +365,7 @@ def _flash_fwd(q, k, v, bias, mask, seed, offsets, *, sm_scale, num_heads,
             lse_shape,
         ],
         name="flash_fwd",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return o, lse
 
@@ -438,14 +405,14 @@ def _make_bwd_dq_kernel(*, sm_scale, causal, dropout_prob, bias_mode,
         )
         q_off = off_ref[0] if has_offsets else 0
         k_off = off_ref[1] if has_offsets else 0
-        ident = _identity(bq)
+        ident = backend.identity(bq)
 
         def head(g, _):
             bh = gi * G + g
             q = q_ref[g]
             do = do_ref[g]
-            lse = _to_sublanes(lse_ref[g], ident)
-            delta = _to_sublanes(delta_ref[g], ident)
+            lse = backend.to_sublanes(lse_ref[g], ident)
+            delta = backend.to_sublanes(delta_ref[g], ident)
 
             def body(i, dq):
                 k = k_ref[g, pl.ds(i * bk, bk), :]
@@ -528,7 +495,7 @@ def _make_bwd_dkv_kernel(*, sm_scale, causal, dropout_prob, bias_mode,
         )
         q_off = off_ref[0] if has_offsets else 0
         k_off = off_ref[1] if has_offsets else 0
-        ident = _identity(bq)
+        ident = backend.identity(bq)
         if dbias_full_ref is not None:
             dbias_full_ref[0] = jnp.zeros_like(dbias_full_ref[0])
 
@@ -541,10 +508,10 @@ def _make_bwd_dkv_kernel(*, sm_scale, causal, dropout_prob, bias_mode,
                 dk, dv, dbsum = carry
                 q = q_ref[g, pl.ds(i * bq, bq), :]
                 do = do_ref[g, pl.ds(i * bq, bq), :]
-                lse = _to_sublanes(
+                lse = backend.to_sublanes(
                     lse_ref[g, :, pl.ds(i * bq, bq)], ident
                 )
-                delta = _to_sublanes(
+                delta = backend.to_sublanes(
                     delta_ref[g, :, pl.ds(i * bq, bq)], ident
                 )
                 s = jax.lax.dot_general(
@@ -647,7 +614,7 @@ def _make_bwd_fused_kernel(*, sm_scale, causal, dropout_prob, bias_mode,
         )
         q_off = off_ref[0] if has_offsets else 0
         k_off = off_ref[1] if has_offsets else 0
-        ident = _identity(bq)
+        ident = backend.identity(bq)
 
         @pl.when(ki == 0)
         def _init():
@@ -664,10 +631,10 @@ def _make_bwd_fused_kernel(*, sm_scale, causal, dropout_prob, bias_mode,
                 dk, dv, dbsum = carry
                 q = q_ref[g, pl.ds(i * bq, bq), :]
                 do = do_ref[g, pl.ds(i * bq, bq), :]
-                lse = _to_sublanes(
+                lse = backend.to_sublanes(
                     lse_ref[g, :, pl.ds(i * bq, bq)], ident
                 )
-                delta = _to_sublanes(
+                delta = backend.to_sublanes(
                     delta_ref[g, :, pl.ds(i * bq, bq)], ident
                 )
                 s = jax.lax.dot_general(
@@ -797,7 +764,7 @@ def _bwd_fused(q, k, v, bias, mask, seed, offsets, g, lse, delta, *,
         out_specs=out_specs,
         out_shape=out_shapes,
         name="flash_bwd",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     dq = outs[0].astype(q.dtype)
     dk, dv = outs[1], outs[2]
@@ -904,7 +871,7 @@ def _flash_bwd(res, g, *, sm_scale, num_heads, causal, dropout_prob,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         name="flash_bwd_dq",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
 
     # ---- dk/dv (+dbias): grid over k blocks
@@ -946,7 +913,7 @@ def _flash_bwd(res, g, *, sm_scale, num_heads, causal, dropout_prob,
         out_specs=out_specs2,
         out_shape=out_shapes2,
         name="flash_bwd_dkv",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args2)
     dk, dv = outs[0], outs[1]
 
@@ -1104,7 +1071,7 @@ def flash_attention(q, k, v, bias=None, sm_scale=None, causal=False,
             )
         else:
             raise ValueError("dropout needs dropout_key or dropout_seed")
-        if _interpret():
+        if backend.interpret():
             # CPU tests: TPU hardware PRNG is unavailable in interpret
             # mode; draw the mask host-side (same numerator-only math)
             mkey = dropout_key if dropout_key is not None else jax.random.PRNGKey(
@@ -1343,7 +1310,7 @@ def _make_fwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
         )
         q_off = off_ref[0] if has_offsets else 0
         k_off = off_ref[1] if has_offsets else 0
-        ident = _identity(bq)
+        ident = backend.identity(bq)
         hi = _hi_blocks(causal, qi, bq, bk, nk, q_off, k_off)
 
         for h in range(nh):
@@ -1393,7 +1360,8 @@ def _make_fwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
             m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, acc0))
             l_safe = jnp.maximum(l, 1e-30)
             o_ref[0, :, h * d:(h + 1) * d] = (acc / l_safe).astype(o_ref.dtype)
-            lse_ref[0, h:h + 1, :] = _to_lanes(m + jnp.log(l_safe), ident)
+            lse_ref[0, h:h + 1, :] = backend.to_lanes(
+                m + jnp.log(l_safe), ident)
 
     return kernel
 
@@ -1458,7 +1426,7 @@ def _flash_fwd_bsh_tile(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
         name=_bsh_kernel_name("fwd", causal, form),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return o, lse
 
@@ -1497,7 +1465,7 @@ def _make_bwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
         )
         q_off = off_ref[0] if has_offsets else 0
         k_off = off_ref[1] if has_offsets else 0
-        ident = _identity(bq)
+        ident = backend.identity(bq)
 
         @pl.when(ki == 0)
         def _init():
@@ -1521,10 +1489,10 @@ def _make_bwd_bsh_tile_kernel(*, sm_scale, causal, dropout_prob, has_bias,
                     # both per-block [BQ,BK] sm_scale multiplies gone
                     q = q * jnp.asarray(sm_scale, q.dtype)
                 do = do_ref[0, pl.ds(i * bq, bq), h * d:(h + 1) * d]
-                lse = _to_sublanes(
+                lse = backend.to_sublanes(
                     lse_ref[0, h:h + 1, pl.ds(i * bq, bq)], ident
                 )
-                delta = _to_sublanes(
+                delta = backend.to_sublanes(
                     delta_ref[0, h:h + 1, pl.ds(i * bq, bq)], ident
                 )
                 s = jax.lax.dot_general(
@@ -1657,7 +1625,7 @@ def _flash_bwd_bsh_tile(res, g, *, sm_scale, nh, causal, dropout_prob,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
         name=_bsh_kernel_name("bwd", causal, form),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return dq.astype(q.dtype), dk, dv
 
@@ -2274,7 +2242,7 @@ def _flash_fwd_bsh_stream(q, k, v, bias, mask, seed, offsets, *, sm_scale, nh,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
         name=_bsh_kernel_name("fwd", causal, form),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return o, lse
 
@@ -2653,7 +2621,7 @@ def _flash_bwd_bsh_stream(res, g, *, sm_scale, nh, causal, dropout_prob,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
         name=_bsh_kernel_name("bwd", causal, form),
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return dq.astype(q.dtype), dk, dv
 
@@ -2795,7 +2763,7 @@ def flash_attention_bsh(q, k, v, bias=None, num_heads=None, sm_scale=None,
         # interpret mode (the CPU tests' oracle) draws the keep mask
         # with jax.random outside the kernel; on the chip the kernel
         # regenerates it with zero HBM traffic
-        if _interpret():
+        if backend.interpret():
             mkey = dropout_key if dropout_key is not None else (
                 jax.random.PRNGKey(seed[0]))
             mask = jax.random.bernoulli(
@@ -2857,14 +2825,10 @@ def flash_shapes_ok(s, d) -> bool:
     """THE shape/backend/flag gate for every flash dispatch site (the
     attention op, the encoder stack, and the ring path all call this)."""
     from ...fluid.flags import flag
-    from ..attention import FORCE_PALLAS
 
     if not flag("FLAGS_use_flash_attention"):
         return False
-    shapes_ok = d in HEAD_WIDTHS and s % MIN_BLOCK == 0
-    if FORCE_PALLAS:
-        return shapes_ok
-    return shapes_ok and not _interpret()
+    return d in HEAD_WIDTHS and s % MIN_BLOCK == 0 and backend.on_kernels()
 
 
 flash_block_ok = flash_shapes_ok  # ring-path alias

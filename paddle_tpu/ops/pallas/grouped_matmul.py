@@ -66,8 +66,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 from . import feasible as _feas
-from .flash_attention import _interpret
 
 FORMS = ("nn", "nt", "tn")
 
@@ -383,9 +383,7 @@ def gmm_tiles(form, lhs, rhs_shape) -> Optional[Tuple[int, int, int]]:
     """THE backend / shape gate of every grouped product: the chooser's
     tiles on the TPU (or where a test pins the kernels, interpreted) for
     bf16 or float32 rows, else None."""
-    from ..attention import FORCE_PALLAS
-
-    if _interpret() and not FORCE_PALLAS:
+    if not backend.on_kernels():
         return None
     if lhs.dtype not in (jnp.bfloat16, jnp.float32):
         return None
@@ -419,11 +417,7 @@ def _run(form, a, b, group_sizes, rhs_shape, kernels=FORMS):
     """One form over operands (a, b): nn (lhs, rhs), nt (d_out, rhs), tn
     (lhs, d_out); `rhs_shape` is the matrices' [G, k, n]; a form that is
     not among `kernels` keeps `ragged_dot` whatever the gate says."""
-    from ...fluid import monitor
-
     tiles = gmm_tiles(form, a, rhs_shape) if form in kernels else None
-    monitor.record_grouped_product_lowering(
-        "ragged_dot" if tiles is None else "pallas", form)
     if tiles is None:
         return _RAGGED_DOT[form](a, b, group_sizes, rhs_shape)
     tm, tk, tn = tiles
@@ -440,7 +434,7 @@ def _kernel(form, a, b, group_sizes, rhs_shape, tm, tk, tn, k, n):
     visits = group_visits(group_sizes, a.shape[0], tm)
     # a call asks Mosaic for what its cell needs, not for the core's VMEM:
     # what it reserves, XLA cannot give to the buffers it keeps on the chip
-    common = dict(tm=tm, interpret=_interpret(), vmem_limit=(
+    common = dict(tm=tm, interpret=backend.interpret(), vmem_limit=(
         _feas.gmm_vmem_bytes(form, tm, tk, tn, k, n, a.dtype.itemsize)
         + _feas.GMM_VMEM_SLACK))
     if form == "tn":

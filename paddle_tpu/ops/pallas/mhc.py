@@ -46,8 +46,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 from . import feasible as _feas
-from .flash_attention import _identity, _interpret
 
 # a block of H^T has its rows on the lanes: 128 of them, or all there are
 _ROWS = 128
@@ -94,9 +94,7 @@ def mhc_rows(x, y, h_res, h_post) -> Optional[int]:
     """THE backend / shape gate of `mhc_post`: the chooser's row block on
     the TPU (or where a test pins the kernels, interpreted) for bf16 or
     float32 streams [..., S, n C], else None."""
-    from ..attention import FORCE_PALLAS
-
-    if _interpret() and not FORCE_PALLAS:
+    if not backend.on_kernels():
         return None
     if x.dtype != y.dtype or x.dtype not in (jnp.bfloat16, jnp.float32):
         return None
@@ -129,7 +127,7 @@ def _turned(a, contract):
     """a^T by a product with the identity on the MXU, exact in float32 at
     the highest precision: a [m, rows] contracted over its lanes gives
     [rows, m], a [rows, m] contracted over its rows gives [m, rows]."""
-    ident = _identity(a.shape[contract])
+    ident = backend.identity(a.shape[contract])
     operands = (ident, a) if contract else (a, ident)
     return lax.dot_general(
         *operands, (((contract,), (contract,)), ((), ())),
@@ -299,7 +297,7 @@ def _stacked(*maps):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _core(x, y, h_res, h_post, br):
     return _mhc_fwd(x, y, _stacked(h_res, h_post), br=br,
-                    interpret=_interpret())
+                    interpret=backend.interpret())
 
 
 def _core_fwd(x, y, h_res, h_post, br):
@@ -310,7 +308,7 @@ def _core_fwd(x, y, h_res, h_post, br):
 def _core_bwd(br, saved, g):
     x, y, h_res, h_post = saved
     dx, dy, dht = _mhc_bwd(g, x, y, _stacked(h_res, h_post), br=br,
-                           interpret=_interpret())
+                           interpret=backend.interpret())
     split = h_res.shape[-1]
     return (dx, dy, dht[:split].T.reshape(h_res.shape),
             dht[split:].T.reshape(h_post.shape))
@@ -402,9 +400,7 @@ def map_rows(x, phi, n: int, iters: int) -> Optional[int]:
     """THE backend / shape gate of `mhc_map`: the chooser's row block on
     the TPU (or where a test pins the kernels, interpreted) for bf16 or
     float32 streams [B, S, n C] and Phi [n C, 2 n + n n], else None."""
-    from ..attention import FORCE_PALLAS
-
-    if _interpret() and not FORCE_PALLAS:
+    if not backend.on_kernels():
         return None
     if x.dtype not in (jnp.bfloat16, jnp.float32) or x.ndim != 3:
         return None
@@ -693,7 +689,7 @@ def _coef(bias, alpha, n):
 
 
 def _map_kwargs(static):
-    return dict(zip(_MAP_STATICS, static + (_interpret(),)))
+    return dict(zip(_MAP_STATICS, static + (backend.interpret(),)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret
+from . import backend
 
 _NEG_INF = float("-inf")
 
@@ -144,7 +144,7 @@ def _pallas_paged_attention(q, k_pages, v_pages, page_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         name="paged_attention",
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(page_table, lengths, q,
       k_pages.reshape(n_pages, page, h * d),
       v_pages.reshape(n_pages, page, h * d))
@@ -173,7 +173,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths,
         # kernel on real TPU backends for MHA; dense-gather reference
         # otherwise (GQA, and CPU tests — interpret mode is for parity
         # checks, not the serving hot loop)
-        impl = ("pallas" if not _interpret()
+        impl = ("pallas" if not backend.interpret()
                 and q.shape[1] == k_pages.shape[2] else "jnp")
     if impl == "pallas":
         return _pallas_paged_attention(q, k_pages, v_pages,

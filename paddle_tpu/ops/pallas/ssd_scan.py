@@ -68,8 +68,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
 from . import feasible as _feas
-from .flash_attention import _interpret
 
 _F32 = jnp.float32
 _LANES = 128
@@ -127,27 +127,10 @@ def kernel_fits(s: int, heads: int, head_dim: int, groups: int, state: int,
                               dtype) is None
 
 
-def head_blocks(s: int, heads: int, head_dim: int, groups: int, state: int,
-                chunk: int, dtype) -> int:
-    """The blocks a group's heads are split into across the grid, at shapes
-    the gate admits: 1 where a cell takes whole groups."""
-    r = heads // groups
-    return r // _head_block(s, r, head_dim, state, chunk,
-                            jnp.dtype(dtype).itemsize)
-
-
-def on_kernels() -> bool:
-    """Whether the kernels run where the gate admits the shapes: on the
-    TPU, or where a test pins them (interpreted)."""
-    from ..attention import FORCE_PALLAS
-
-    return FORCE_PALLAS or not _interpret()
-
-
 def use_kernels(s, heads, head_dim, groups, state, chunk, dtype) -> bool:
-    """`kernel_fits` where `on_kernels`."""
-    return on_kernels() and kernel_fits(s, heads, head_dim, groups, state,
-                                        chunk, dtype)
+    """`kernel_fits` where `backend.on_kernels`."""
+    return backend.on_kernels() and kernel_fits(
+        s, heads, head_dim, groups, state, chunk, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +142,7 @@ def _operands(a, b):
     """A product's operands: as they are on the TPU; interpreted, in
     float32 (exact for bf16 values: XLA's CPU runtime has no bf16 x bf16 ->
     float32 product of every shape)."""
-    if _interpret():
+    if backend.interpret():
         return a.astype(_F32), b.astype(_F32)
     return a, b
 
@@ -636,7 +619,7 @@ def _layouts(x, dt, a, b, c, d, chunk):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _scan(x, dt, a, b, c, d, chunk):
     y = _scan_fwd(*_layouts(x, dt, a, b, c, d, chunk), groups=b.shape[2],
-                  chunk=chunk, interpret=_interpret())
+                  chunk=chunk, interpret=backend.interpret())
     return _turn(y).reshape(x.shape)
 
 
@@ -650,7 +633,7 @@ def _scan_vjp_bwd(chunk, saved, g):
     bsz, s, h, p = x.shape
     dx, ddt_t, db, dc, da, dd = _scan_bwd(
         *_layouts(*saved, chunk), _turn(g.reshape(bsz, s, h * p)),
-        groups=b.shape[2], chunk=chunk, interpret=_interpret())
+        groups=b.shape[2], chunk=chunk, interpret=backend.interpret())
     return (_turn(dx).reshape(x.shape), _turn(ddt_t).astype(dt.dtype),
             jnp.sum(da, axis=(0, 2)).astype(a.dtype),
             _turn(db).reshape(b.shape), _turn(dc).reshape(c.shape),

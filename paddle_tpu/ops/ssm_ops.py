@@ -127,16 +127,8 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
     positions (the whole row where it is shorter). A row that is no
     multiple of the chunk is continued with dt = 0, which neither decays
     the state nor adds to it. Lowered under the scope `ssd_scan`, as the
-    kernels where `scan_kernels` admits the shapes; counted by
-    `ssd_scan_lowerings_total{impl}` (one a `mamba2` op traced), where the
-    kernels split a group's heads into blocks by
-    `ssd_scan_head_blocks_total{blocks}`, and, where the kernels would run
-    but their gate refuses the shapes, by
-    `ssd_scan_gate_refusals_total{reason}`."""
-    from ..fluid.monitor import (record_ssd_scan_gate_refusal,
-                                 record_ssd_scan_head_blocks,
-                                 record_ssd_scan_lowering)
-
+    kernels where `scan_kernels` admits the shapes (`ssd_scan_fwd` and
+    `ssd_scan_bwd` in the lowered step)."""
     s = x.shape[1]
     chunk = min(int(chunk), s)
     pad = -s % chunk
@@ -144,16 +136,6 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
         x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                        for t in (x, dt, b, c))
     kernels = scan_kernels(x.shape, b.shape, chunk, x.dtype)
-    record_ssd_scan_lowering("pallas" if kernels else "jnp")
-    _, rows, h, p = x.shape
-    if kernels:
-        blocks = ssd_pallas.head_blocks(rows, h, p, *b.shape[2:], chunk,
-                                        x.dtype)
-        if blocks > 1:
-            record_ssd_scan_head_blocks(blocks)
-    elif ssd_pallas.on_kernels():
-        record_ssd_scan_gate_refusal(ssd_pallas.kernel_fits_reason(
-            rows, h, p, *b.shape[2:], chunk, x.dtype))
     with jax.named_scope("ssd_scan"):
         if kernels:
             y = ssd_pallas.ssd_scan(x, dt, a, b, c, d, chunk)

@@ -129,14 +129,15 @@ def _ring_flash(q, k, v, axis_name, bias, sm_scale, n, causal=False,
     import jax.numpy as jnp
     from jax import lax
 
-    from ..ops.pallas.flash_attention import _interpret, flash_block_with_lse
+    from ..ops.pallas import backend
+    from ..ops.pallas.flash_attention import flash_block_with_lse
 
     b, nh, s_loc, d = q.shape
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     use_dropout = dropout_prob > 0.0 and dropout_key is not None
     seed_base = None
-    if use_dropout and not _interpret():
+    if use_dropout and not backend.interpret():
         seed_base = jax.random.randint(
             dropout_key, (), 0, jnp.iinfo(jnp.int32).max, dtype=jnp.int32
         )

@@ -59,3 +59,26 @@ def _fresh_programs():
     framework.switch_startup_program(old_startup)
     unique_name.switch(old_gen)
     executor_mod._global_scope = old_scope
+
+
+@pytest.fixture
+def pinned():
+    """The Pallas kernels where their gates admit the shapes, interpreted
+    on this CPU, as they run on the TPU."""
+    from paddle_tpu.ops.pallas import backend
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backend, "FORCE_PALLAS", True)
+        yield
+
+
+@pytest.fixture
+def described_tpu():
+    """Every kernel module takes its TPU branch (no interpreter, the
+    in-kernel PRNG), so that a step lowers for `lowering_platforms=("tpu",)`
+    or compiles for a described chip from this CPU process."""
+    from paddle_tpu.ops.pallas import backend
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backend, "interpret", lambda: False)
+        yield

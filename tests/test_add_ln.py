@@ -77,16 +77,13 @@ def test_fused_add_ln_bf16():
         rtol=2e-2, atol=2e-2)
 
 
-def test_encoder_stack_dispatches_fused_ln():
+def test_encoder_stack_dispatches_fused_ln(monkeypatch):
     """FORCE_PALLAS: the fused stack must route its residual+LN pairs
     through the kernel and still match the jnp composition."""
-    from paddle_tpu.ops import attention
+    from paddle_tpu.ops.pallas import backend
     from paddle_tpu.ops.pallas.add_ln import fused_ln_dispatch_ok
 
     assert not fused_ln_dispatch_ok((4, 32, 128))  # interpret off by default
-    attention.FORCE_PALLAS = True
-    try:
-        assert fused_ln_dispatch_ok((4, 32, 128))
-        assert not fused_ln_dispatch_ok((4, 32, 96))  # H % 128 != 0
-    finally:
-        attention.FORCE_PALLAS = False
+    monkeypatch.setattr(backend, "FORCE_PALLAS", True)
+    assert fused_ln_dispatch_ok((4, 32, 128))
+    assert not fused_ln_dispatch_ok((4, 32, 96))  # H % 128 != 0

@@ -40,14 +40,11 @@ def test_bert_tiny_loss_decreases(use_flash, fuse_stack):
     assert losses[-1] < losses[0], losses
 
 
-def test_bert_flash_and_reference_agree():
+def test_bert_flash_and_reference_agree(pinned):
     """Same init, same data, no dropout: both attention paths give the
     same loss (flash kernel runs in interpret mode on CPU)."""
-    from paddle_tpu.ops import attention
-
     b, s, mp = 2, 128, 4
     results = {}
-    attention.FORCE_PALLAS = True
     for use_flash in (False, True):
         cfg = BertConfig.tiny()
         cfg.max_position_embeddings = 128
@@ -70,7 +67,6 @@ def test_bert_flash_and_reference_agree():
             batch = random_pretrain_batch(cfg, b, s, mp, seed=3)
             (lv,) = exe.run(m, feed=batch, fetch_list=[loss])
         results[use_flash] = float(lv)
-    attention.FORCE_PALLAS = False
     np.testing.assert_allclose(results[False], results[True], rtol=1e-4)
 
 

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import flags, layers
 from paddle_tpu.fluid.fusion_pass import apply_conv_bn_fusion
-from paddle_tpu.ops import attention, nn_ops
+from paddle_tpu.ops import nn_ops
 from paddle_tpu.ops.pallas import conv_bn as cb
 
 from op_test import OpTest
@@ -35,7 +35,7 @@ from op_test import OpTest
     ((2, 9, 9, 5), (7, 5, 3, 3), (1, 1), "VALID", False),  # odd channels/size
     ((1, 6, 6, 4), (8, 4, 7, 7), (1, 1), "SAME", True),    # stem-class kernel
 ])
-def test_kernel_matches_oracle(xs, ws, strides, pads, with_relu):
+def test_kernel_matches_oracle(pinned, xs, ws, strides, pads, with_relu):
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(*xs).astype(np.float32))
     w = jnp.asarray(rng.randn(*ws).astype(np.float32) * 0.1)
@@ -45,13 +45,9 @@ def test_kernel_matches_oracle(xs, ws, strides, pads, with_relu):
     pr = cb._resolve_pads(pads, xs[1], xs[2], ws[2], ws[3], strides)
     ref = cb.conv_bn_reference(x, w, scale, bias, strides=strides, pads=pr,
                                with_relu=with_relu)
-    attention.FORCE_PALLAS = True
-    try:
-        assert cb.conv_bn_dispatch_ok(x.shape, w.shape, tuple(strides), pr)
-        out = cb.fused_conv_bn(x, w, scale, bias, strides=strides, pads=pads,
-                               with_relu=with_relu)
-    finally:
-        attention.FORCE_PALLAS = False
+    assert cb.conv_bn_dispatch_ok(x.shape, w.shape, tuple(strides), pr)
+    out = cb.fused_conv_bn(x, w, scale, bias, strides=strides, pads=pads,
+                           with_relu=with_relu)
     for got, exp, nm in zip(out, ref, ("y", "mean", "var")):
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    rtol=2e-5, atol=2e-5, err_msg=nm)
@@ -63,12 +59,8 @@ def test_kernel_matches_oracle(xs, ws, strides, pads, with_relu):
             return jnp.sum(y * jnp.cos(y))
         return f
 
-    attention.FORCE_PALLAS = True
-    try:
-        g_pallas = jax.grad(make_loss(cb.fused_conv_bn, pads),
-                            argnums=(0, 1, 2, 3))(x, w, scale, bias)
-    finally:
-        attention.FORCE_PALLAS = False
+    g_pallas = jax.grad(make_loss(cb.fused_conv_bn, pads),
+                        argnums=(0, 1, 2, 3))(x, w, scale, bias)
     g_ref = jax.grad(make_loss(cb.conv_bn_reference, pr),
                      argnums=(0, 1, 2, 3))(x, w, scale, bias)
     for got, exp, nm in zip(g_pallas, g_ref, ("dx", "dw", "dscale", "dbias")):

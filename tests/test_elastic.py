@@ -521,19 +521,27 @@ def test_launch_per_rank_budget_evicts_and_resizes(tmp_path):
     script = tmp_path / "worker.py"
     script.write_text(textwrap.dedent(
         """
-        import os, sys
+        import os, sys, time
         out = sys.argv[1]
         tag = os.environ["PADDLE_TRAINER_TAG"]
         attempt = os.environ["PADDLE_ELASTIC_RESTART"]
-        with open(os.path.join(
-                out, f"run.{attempt}.{tag}"), "w") as f:
+        path = os.path.join(out, f"run.{attempt}.{tag}")
+        with open(path + ".tmp", "w") as f:
             f.write("|".join([
                 os.environ["PADDLE_TRAINER_ID"],
                 os.environ["PADDLE_TRAINERS_NUM"],
                 os.environ["PADDLE_MEMBERSHIP_EPOCH"],
                 os.environ.get("PADDLE_ELASTIC_RESHARD", ""),
             ]))
+        os.replace(path + ".tmp", path)  # whole, or not there
         if tag == "trainer1":
+            # the launcher tears the attempt down at this exit: let the
+            # other ranks of a loaded machine write theirs first
+            deadline = time.monotonic() + 10.0
+            while time.monotonic() < deadline and not all(
+                    os.path.exists(os.path.join(out, f"run.0.{t}"))
+                    for t in ("trainer0", "trainer2")):
+                time.sleep(0.05)
             sys.exit(5)
         """))
     cmd = [sys.executable, "-m", "paddle_tpu.distributed.launch",

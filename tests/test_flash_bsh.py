@@ -45,13 +45,7 @@ def _mk(sq, skv, seed=0):
     return q, k, v
 
 
-@pytest.fixture(autouse=True)
-def _force_pallas():
-    from paddle_tpu.ops import attention
-
-    attention.FORCE_PALLAS = True
-    yield
-    attention.FORCE_PALLAS = False
+pytestmark = pytest.mark.usefixtures("pinned")
 
 
 @pytest.mark.parametrize("sq,skv", [(128, 128), (256, 128), (128, 384)])

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from lowered import calls_in, kernel_sizes, pallas_eqns
+from paddle_tpu.ops.pallas import backend
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 NUM_HEADS, HIDDEN = 12, 768
@@ -54,26 +56,6 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _count_eqns(jaxpr):
-    n = 0
-    for eqn in jaxpr.eqns:
-        n += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _count_eqns(sub)
-    return n
-
-
-def _pallas_calls(jaxpr, found):
-    """{kernel name: equations in its body}, nested bodies included."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            name = eqn.params["name"]
-            found[name] = _count_eqns(eqn.params["jaxpr"])
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _pallas_calls(sub, found)
-    return found
-
-
 def _traced_grad(one_chip, batch, seq):
     """jax.grad of the attention as the BERT cells call it: per-key
     bias, in-kernel dropout 0.1, 12 heads of 64."""
@@ -90,8 +72,9 @@ def _traced_grad(one_chip, batch, seq):
         return out.astype(jnp.float32).sum()
 
     grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    # off the TPU the kernels would take their interpreter branch
-    with mock.patch.object(fa, "_interpret", lambda: False):
+    # off the TPU the kernels would take their interpreter branch (a
+    # module-scoped fixture's trace: `described_tpu` is not yet in place)
+    with mock.patch.object(backend, "interpret", lambda: False):
         fa._make_flash_core_bsh.cache_clear()
         try:
             return grad.trace(act, act, act, bias, seed)
@@ -115,16 +98,16 @@ def test_both_kernels_compile_for_the_v5e(traced, cell, no_compile_cache):
 
 @pytest.mark.parametrize("kernel", sorted(PARENT_EQNS))
 def test_traced_size_does_not_go_with_the_tile(traced, kernel):
-    small = _pallas_calls(traced["s1024"].jaxpr.jaxpr, {})[kernel]
-    large = _pallas_calls(traced["s4096"].jaxpr.jaxpr, {})[kernel]
+    small = kernel_sizes(traced["s1024"].jaxpr.jaxpr)[kernel]
+    large = kernel_sizes(traced["s4096"].jaxpr.jaxpr)[kernel]
     # the DMA tile is 512 at S 1024 and 1024 at S 4096, a head's stream
     # 4 and 16 (forward), 2 and 16 (backward) steps long: the body that
     # is traced is one trip's
     assert abs(large - small) <= 0.03 * small, (small, large)
     assert large <= 1.5 * PARENT_EQNS[kernel], (large, PARENT_EQNS[kernel])
     # below S 1024 the whole-tile kernels run, as they were
-    assert _pallas_calls(
-        traced["s512"].jaxpr.jaxpr, {})[kernel] == PARENT_EQNS[kernel]
+    assert kernel_sizes(
+        traced["s512"].jaxpr.jaxpr)[kernel] == PARENT_EQNS[kernel]
 
 
 # ---------------------------------------------------------------------------
@@ -135,7 +118,7 @@ def test_traced_size_does_not_go_with_the_tile(traced, kernel):
 @pytest.mark.parametrize("passes, mosaic_mib", [
     ("fwd", (90.06, 90.88)), ("fwd_bwd", (101.43, 102.25))])
 def test_the_vmem_model_bounds_mosaic_at_ten_heads_of_256(
-        one_chip, no_compile_cache, passes, mosaic_mib):
+        one_chip, no_compile_cache, described_tpu, passes, mosaic_mib):
     """`latent_head_groups` trusts `feasible.py`'s model of the stream
     kernels' residency: twenty heads of 256 at S 4096 (5,120 columns) are
     over it and ten (2,560) under. Mosaic's own scoped allocation at
@@ -176,8 +159,7 @@ def test_the_vmem_model_bounds_mosaic_at_ten_heads_of_256(
             return blocks(sq, skv, hdim, bwd=bwd)[:2] + (int(limit),)
 
         fn = attend if passes == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
-        with mock.patch.object(fa, "_interpret", lambda: False), \
-                mock.patch.object(fa, "_resolve_bsh_blocks", resolve):
+        with mock.patch.object(fa, "_resolve_bsh_blocks", resolve):
             fa._make_flash_core_bsh.cache_clear()
             try:
                 # a new function each time: jit's trace cache would hand
@@ -206,7 +188,8 @@ def test_the_vmem_model_bounds_mosaic_at_ten_heads_of_256(
     ids=["w1_w3", "w2", "nemotron_w1", "nemotron_w2"])
 @pytest.mark.parametrize("form", ["nn", "nt", "tn"])
 def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
-                                                 form, rows, k, n):
+                                                 described_tpu, form, rows,
+                                                 k, n):
     """The LFM2 cell's operands: 32,768 sorted rows against eight
     [2048, 1792] (W1, W3) or [1792, 2048] (W2) matrices in bf16, at the
     chooser's tiles: Mosaic takes the blocks, the transposed contractions
@@ -224,11 +207,10 @@ def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
 
     a = shaped(rows, n if form == "nt" else k)
     b = shaped(rows, n) if form == "tn" else shaped(groups, k, n)
-    with mock.patch.object(gm, "_interpret", lambda: False):
-        assert gm.gmm_tiles(form, a, (groups, k, n)) == (256, k, n)
-        compiled = jax.jit(
-            lambda a, b, sizes: gm._run(form, a, b, sizes, (groups, k, n))
-        ).lower(a, b, shaped(groups, dtype=jnp.int32)).compile()
+    assert gm.gmm_tiles(form, a, (groups, k, n)) == (256, k, n)
+    compiled = jax.jit(
+        lambda a, b, sizes: gm._run(form, a, b, sizes, (groups, k, n))
+    ).lower(a, b, shaped(groups, dtype=jnp.int32)).compile()
     text = compiled.as_text()
     assert f"moe_gmm_{form}" in text and "tpu_custom_call" in text
 
@@ -242,7 +224,8 @@ def test_the_grouped_matmul_compiles_for_the_v5e(one_chip, no_compile_cache,
 @pytest.mark.parametrize("tokens", [8192, 4096], ids=["step", "check"])
 @pytest.mark.parametrize("kernel", ["mhc_post_fwd", "mhc_post_bwd"])
 def test_the_mhc_post_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
-                                                  kernel, tokens):
+                                                  described_tpu, kernel,
+                                                  tokens):
     """The Xing4 cell's operands (two sequences of 4,096 tokens a step, one
     in its check program, four streams of 3,584 bf16 columns, float32
     mappings with the tokens on the lanes) at the chooser's row block:
@@ -262,26 +245,26 @@ def test_the_mhc_post_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
     maps = (shaped(*lead, n * n, dtype=jnp.float32),
             shaped(*lead, n, dtype=jnp.float32))
     turned = shaped(n * n + n, tokens, dtype=jnp.float32)
-    with mock.patch.object(mhc, "_interpret", lambda: False):
-        rows = mhc.mhc_rows(x, y, *maps)
-        assert rows == 128
-        if kernel == "mhc_post_fwd":
-            traced = jax.jit(lambda *a: mhc._mhc_fwd(
-                *a, br=rows, interpret=False)).trace(x, y, turned)
-        else:
-            traced = jax.jit(lambda *a: mhc._mhc_bwd(
-                *a, br=rows, interpret=False)).trace(x, x, y, turned)
+    rows = mhc.mhc_rows(x, y, *maps)
+    assert rows == 128
+    if kernel == "mhc_post_fwd":
+        traced = jax.jit(lambda *a: mhc._mhc_fwd(
+            *a, br=rows, interpret=False)).trace(x, y, turned)
+    else:
+        traced = jax.jit(lambda *a: mhc._mhc_bwd(
+            *a, br=rows, interpret=False)).trace(x, x, y, turned)
     compiled = traced.lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert kernel in text and "tpu_custom_call" in text
-    assert _pallas_calls(traced.jaxpr.jaxpr, {})[kernel] <= {
+    assert kernel_sizes(traced.jaxpr.jaxpr)[kernel] <= {
         "mhc_post_fwd": 250, "mhc_post_bwd": 500}[kernel]
 
 
 @pytest.mark.parametrize("batch", [2, 1], ids=["step", "check"])
 @pytest.mark.parametrize("kernel", ["mhc_map_fwd", "mhc_map_bwd"])
 def test_the_mhc_map_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
-                                                 kernel, batch):
+                                                 described_tpu, kernel,
+                                                 batch):
     """The Xing4 cell's operands (two sequences of 4,096 tokens a step, one
     in its check program, four streams of 3,584 bf16 columns, twenty rounds)
     at the chooser's row block: Mosaic takes the bf16 products with the
@@ -297,8 +280,7 @@ def test_the_mhc_map_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     x = shaped(batch, 4096, width)
-    with mock.patch.object(mhc, "_interpret", lambda: False):
-        rows = mhc.map_rows(x, shaped(width, 24, dtype=jnp.float32), n, iters)
+    rows = mhc.map_rows(x, shaped(width, 24, dtype=jnp.float32), n, iters)
     assert rows == 256
     assert feasible.mhc_map_vmem_bytes(
         kernel[-3:], rows, width, n, 2, iters) <= feasible.MHC_VMEM_BUDGET
@@ -315,14 +297,14 @@ def test_the_mhc_map_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
     twenty = traced(iters)
     text = twenty.lower(lowering_platforms=("tpu",)).compile().as_text()
     assert kernel in text and "tpu_custom_call" in text
-    eqns = _pallas_calls(twenty.jaxpr.jaxpr, {})[kernel]
-    assert eqns == _pallas_calls(traced(2).jaxpr.jaxpr, {})[kernel]
+    eqns = kernel_sizes(twenty.jaxpr.jaxpr)[kernel]
+    assert eqns == kernel_sizes(traced(2).jaxpr.jaxpr)[kernel]
     assert eqns <= {"mhc_map_fwd": 400, "mhc_map_bwd": 900}[kernel]
 
 
 @pytest.mark.parametrize("batch", [2, 1], ids=["step", "check"])
 def test_the_ssd_scan_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
-                                                  batch):
+                                                  described_tpu, batch):
     """The Nemotron cell's scan (two rows of 4,096 positions a step, one in
     its check program; 64 heads of 64 in 8 groups at state 128, chunk 128,
     bf16), its gradient through the custom VJP: Mosaic takes both kernels'
@@ -342,13 +324,12 @@ def test_the_ssd_scan_kernels_compile_for_the_v5e(one_chip, no_compile_cache,
     def loss(*args):
         return ssd.ssd_scan(*args, 128).astype(jnp.float32).sum()
 
-    with mock.patch.object(ssd, "_interpret", lambda: False):
-        assert ssd.use_kernels(4096, 64, 64, 8, 128, 128, jnp.bfloat16)
-        traced = jax.jit(jax.value_and_grad(
-            loss, argnums=tuple(range(6)))).trace(x, dt, vec, bc, bc, vec)
+    assert ssd.use_kernels(4096, 64, 64, 8, 128, 128, jnp.bfloat16)
+    traced = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(6)))).trace(x, dt, vec, bc, bc, vec)
     text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
-    eqns = _pallas_calls(traced.jaxpr.jaxpr, {})
+    eqns = kernel_sizes(traced.jaxpr.jaxpr)
     assert eqns["ssd_scan_fwd"] <= 250 and eqns["ssd_scan_bwd"] <= 650
 
 
@@ -399,279 +380,157 @@ def _in_proj_weight_products(text, program):
     return found
 
 
-def test_the_xing4_cell_step_compiles_and_fits_the_v5e(one_chip,
-                                                       no_compile_cache):
-    """The step of `xing4.0-29b-a4b.tp8ep8share.s4096` as the harness
-    builds it, at the cell's batch and the published widths, compiled for
-    the described chip with its state given as shapes (656 M parameters
-    are not allocated here): Mosaic takes the padded latent-attention
-    flash calls, the grouped products at 3584 x 1024, the stream-mixing
-    kernels and the mappings' forward kernel, the step holds every call the
-    configuration lists, and XLA's
-    buffer assignment reads no more than the `peak_hbm_gb` the configuration
-    states."""
+def _glm(cell, built, text, step, jaxpr):
+    """Twenty heads of 256 / 256 as two calls of ten a block (five trunk
+    blocks and the module's: twelve forward calls, all kernels), on
+    [B, 4096, 2560] slabs; the parameters the configuration states."""
     import numpy as np
 
-    import paddle_tpu.fluid as fluid
-    from benchmark import harness, hlo_text, manifest
-    from paddle_tpu.fluid.executor import Scope
-    from paddle_tpu.ops.pallas import grouped_matmul as gm
-    from paddle_tpu.ops.pallas import mhc
-
-    cell = manifest.load_cell(manifest.load_manifest(),
-                              "xing4.0-29b-a4b.tp8ep8share.s4096")
-    batch = int(cell.traffic["batch"])
-    built = harness.build_program(cell, batch, dropout=True, seed=1)
-    exe, scope = fluid.Executor(), Scope()
-    for program in (built.startup, built.main):
-        for v in program.global_block().vars.values():
-            if v.persistable and v.shape is not None:
-                scope.set_var(v.name, jax.ShapeDtypeStruct(
-                    tuple(v.shape), np.dtype(v.dtype)))
-    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
-                                  harness.batch_rng(1, 1, 0))
-    with mock.patch.object(fa, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False), \
-            mock.patch.object(mhc, "_interpret", lambda: False):
-        fa._make_flash_core_bsh.cache_clear()
-        try:
-            compiled = exe._lower_step(
-                built.main, feed=feed, fetch_list=[built.loss], scope=scope,
-                platforms=("tpu",), sharding=one_chip).compile()
-        finally:
-            fa._make_flash_core_bsh.cache_clear()
-    present = hlo_text.read_step(compiled.as_text()).kernels
-    assert set(cell.config["mosaic_calls"]) <= set(present), present
-    assert {"mhc_post_fwd", "mhc_post_bwd"} <= set(present), present
-    # `mhc_map`'s forward pass is its kernel; its backward pass stays the
-    # composition's, whose float32 copy of the streams this peak rests on
-    # (with `mhc_map_bwd` in its place the step read 14.890 GB, PR 37)
-    assert "mhc_map_fwd" in present and "mhc_map_bwd" not in present
-    mem = compiled.memory_analysis()
-    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
-    # one-sided since PR 33: the stream-mixing kernels took the peak 2 %
-    # under what the configuration (a benchmark file) states
-    stated = cell.config["stated"]["peak_hbm_gb"]
-    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
-    assert 4.0 < peak_gb < 15.75
+    assert sum(int(np.prod(p.shape)) for p in built.main.all_parameters()
+               ) == cell.config["stated"]["parameters"]
+    calls = calls_in(jaxpr)
+    assert (calls["flash_mla_wide_causal_fwd"],
+            calls["flash_mla_wide_causal_bwd"]) == (12, 12), calls
+    forward = [c for c in step.calls.values()
+               if c.kernel == "flash_mla_wide_causal_fwd"]
+    assert {c.operands[0].dims for c in forward} == {
+        (int(cell.traffic["batch"]), 4096, 2560)}
+    assert len({c.instruction for c in forward}) >= 12
+    assert ".remat" not in text
 
 
-def test_the_nemotron_cell_step_compiles_and_fits_the_v5e(one_chip,
-                                                          no_compile_cache):
-    """The step of `nemotron-3-nano-30b-a3b.ep16share.s4096` as the harness
-    builds it, at the cell's batch and the published widths, compiled for
-    the described chip with its state given as shapes (667 M parameters
-    are not allocated here): the chunked state-space scan, the two-matrix
-    experts at 2688 x 1856 and the attention call at H 4096 compile, the
-    step holds every Mosaic call the configuration lists (the BHSD flash
-    kernels: at S 4096 and H 4096 the BSH kernels' whole-sequence residency
-    is over their gate) and, since PR 35, the three grouped-matmul kernels
-    with 1856 as one whole block, and XLA's buffer assignment reads no more
-    than the `peak_hbm_gb` the configuration states."""
-    import numpy as np
-
-    import paddle_tpu.fluid as fluid
-    from benchmark import harness, hlo_text, manifest
-    from paddle_tpu.fluid.executor import Scope
-    from paddle_tpu.ops.pallas import grouped_matmul as gm
-    from paddle_tpu.ops.pallas import ssd_scan as ssd
-
-    cell = manifest.load_cell(manifest.load_manifest(),
-                              "nemotron-3-nano-30b-a3b.ep16share.s4096")
-    batch = int(cell.traffic["batch"])
-    built = harness.build_program(cell, batch, dropout=True, seed=1)
-    exe, scope = fluid.Executor(), Scope()
-    for program in (built.startup, built.main):
-        for v in program.global_block().vars.values():
-            if v.persistable and v.shape is not None:
-                scope.set_var(v.name, jax.ShapeDtypeStruct(
-                    tuple(v.shape), np.dtype(v.dtype)))
-    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
-                                  harness.batch_rng(1, 1, 0))
-    with mock.patch.object(fa, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False), \
-            mock.patch.object(ssd, "_interpret", lambda: False):
-        compiled = exe._lower_step(
-            built.main, feed=feed, fetch_list=[built.loss], scope=scope,
-            platforms=("tpu",), sharding=one_chip).compile()
-    text = compiled.as_text()
-    present = hlo_text.read_step(text).kernels
-    assert set(cell.config["mosaic_calls"]) <= set(present), present
-    # 1856 = 14.5 x 128 is served as one block (PR 35; before it the gate
-    # refused and every product was XLA's): the bounded block's products
-    # are the kernels, and XLA's `ragged-dot` stays for the dropless
-    # fallback's product and weight gradient
-    assert {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} <= set(present), present
-    # since PR 39 the state-space scan is its two kernels (14.421 GB here,
-    # 14.502 with the composition)
-    assert {"ssd_scan_fwd", "ssd_scan_bwd"} <= set(present), present
+def _nemotron(cell, built, text, step, jaxpr):
+    """XLA's `ragged-dot` stays for the dropless fallback's product and
+    weight gradient. d(in_proj) reaches the four in_proj weight-gradient
+    products as the scan's kernels give it, the positions on its lanes (PR
+    43), and the block norm's output keeps the features there; the
+    kernels' layouts under which both agree here (B and C row-major) made
+    XLA turn dx back, and the step 3.5 % slower on the chip (PR 43)."""
     assert "ragged-dot" in text
-    mem = compiled.memory_analysis()
-    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
-    # one-sided since PR 35, as the Xing4 cell's: the configuration (a
-    # benchmark file) states the peak of the step with XLA's products
-    stated = cell.config["stated"]["peak_hbm_gb"]
-    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
-    assert 4.0 < peak_gb < 15.2
-    # the scan's kernels' step (PR 39) read 14.421 GB
-    assert abs(peak_gb - 14.421) <= 0.01 * 14.421, peak_gb
-    # d(in_proj) reaches the four in_proj weight-gradient products as the
-    # scan's kernels give it, the positions on its lanes (PR 43), and the
-    # block norm's output keeps the features there; the kernels' layouts
-    # under which both agree here (B and C row-major) made XLA turn dx
-    # back, and the step 3.5 % slower on the chip (PR 43)
     products = _in_proj_weight_products(text, built.main)
     assert [d for _, d in products] == [0] * 4, products
 
 
-def test_the_glm_cell_step_compiles_and_fits_the_v5e(one_chip,
-                                                     no_compile_cache):
-    """The step of `glm-4.7-flash.ep8share.mtp.s4096` as the harness builds
-    it, at the cell's batch and the published widths, compiled for the
-    described chip with its state given as shapes (707 M parameters are not
-    allocated here): the trunk and the multi-token-prediction module, the
-    embedding table and the head used twice each. Mosaic takes latent
-    attention's twenty heads of 256 / 256 as two calls of ten a block under
-    a name of their own (six blocks: twelve forward calls), and the grouped
-    products at 2048 x 1536; the step holds every call the configuration
-    lists, no attention call is the composition's, XLA rematerializes
-    nothing the program did not ask for, and its buffer assignment reads no
-    more than the `peak_hbm_gb` the configuration states."""
-    import numpy as np
+def _granite(cell, built, text, step, jaxpr):
+    """The one NoPE attention layer is the latent form's wide kernels on
+    32 heads of 64 at the given scale; all nine state-space scans are the
+    scan's kernels, a group of 64 heads in four blocks of sixteen, one a
+    grid cell. Since PR 43 the scan's kernels give dx, dB and dC turned,
+    positions last, and each of the nine in_proj weight-gradient products
+    finds the positions on the lanes of both operands (before, d(in_proj)
+    came features-minor against the block norm's positions-minor output,
+    and one operand was turned for each product)."""
+    import jax.numpy as jnp
 
-    import paddle_tpu.fluid as fluid
-    from benchmark import harness, hlo_text, manifest
-    from paddle_tpu.fluid.executor import Scope
-    from paddle_tpu.ops.pallas import grouped_matmul as gm
-    from paddle_tpu.telemetry import get_registry
-
-    cell = manifest.load_cell(manifest.load_manifest(),
-                              "glm-4.7-flash.ep8share.mtp.s4096")
-    batch = int(cell.traffic["batch"])
-    built = harness.build_program(cell, batch, dropout=True, seed=1)
-    assert sum(int(np.prod(p.shape)) for p in built.main.all_parameters()
-               ) == cell.config["stated"]["parameters"]
-    exe, scope = fluid.Executor(), Scope()
-    for program in (built.startup, built.main):
-        for v in program.global_block().vars.values():
-            if v.persistable and v.shape is not None:
-                scope.set_var(v.name, jax.ShapeDtypeStruct(
-                    tuple(v.shape), np.dtype(v.dtype)))
-    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
-                                  harness.batch_rng(1, 1, 0))
-
-    def traced(impl, form):
-        return get_registry().counter(
-            "attention_lowerings_total", impl=impl, form=form).value
-
-    before = traced("pallas", "mla_wide"), traced("jnp", "mla")
-    with mock.patch.object(fa, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False):
-        fa._make_flash_core_bsh.cache_clear()
-        try:
-            compiled = exe._lower_step(
-                built.main, feed=feed, fetch_list=[built.loss], scope=scope,
-                platforms=("tpu",), sharding=one_chip).compile()
-        finally:
-            fa._make_flash_core_bsh.cache_clear()
-    # five trunk blocks and the module's: six attention calls, all kernels
-    assert traced("pallas", "mla_wide") == before[0] + 6
-    assert traced("jnp", "mla") == before[1]
-    text = compiled.as_text()
-    step = hlo_text.read_step(text)
-    assert set(cell.config["mosaic_calls"]) <= set(step.kernels), step.kernels
-    forward = [c for c in step.calls.values()
-               if c.kernel == "flash_mla_wide_causal_fwd"]
-    assert {c.operands[0].dims for c in forward} == {(batch, 4096, 2560)}
-    assert len({c.instruction for c in forward}) >= 12
-    assert ".remat" not in text
-    mem = compiled.memory_analysis()
-    peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
-    stated = cell.config["stated"]["peak_hbm_gb"]
-    assert peak_gb <= 1.01 * stated, (peak_gb, stated)
-    assert 4.0 < peak_gb <= 15.2
-
-
-def test_the_granite_cell_step_compiles_and_fits_the_v5e(one_chip,
-                                                         no_compile_cache):
-    """The step of `granite-4.0-h-micro.vocab8.s4096` as the harness builds
-    it, at the cell's batch and the published widths, compiled for the
-    described chip with its state given as shapes (772 M parameters are not
-    allocated here): the one NoPE attention layer is the latent form's wide
-    kernels on 32 heads of 64 at the given scale, and all nine state-space
-    scans are the scan's kernels, a group of 64 heads in four blocks of
-    sixteen: the lowering counts nine `pallas` scans, nine splits into four
-    blocks and no refusal by `heads_per_group`. Nothing is rematerialized,
-    and XLA's buffer assignment reads no more than the `peak_hbm_gb` the
-    configuration states."""
-    import numpy as np
-
-    import paddle_tpu.fluid as fluid
-    from benchmark import harness, hlo_text, manifest
-    from paddle_tpu.fluid.executor import Scope
     from paddle_tpu.ops.pallas import ssd_scan as ssd
-    from paddle_tpu.telemetry import get_registry
 
-    cell = manifest.load_cell(manifest.load_manifest(),
-                              "granite-4.0-h-micro.vocab8.s4096")
-    batch = int(cell.traffic["batch"])
-    built = harness.build_program(cell, batch, dropout=True, seed=1)
-    exe, scope = fluid.Executor(), Scope()
-    for program in (built.startup, built.main):
-        for v in program.global_block().vars.values():
-            if v.persistable and v.shape is not None:
-                scope.set_var(v.name, jax.ShapeDtypeStruct(
-                    tuple(v.shape), np.dtype(v.dtype)))
-    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
-                                  harness.batch_rng(1, 1, 0))
-
-    def counted(name, **labels):
-        return get_registry().counter(name, **labels).value
-
-    before = (counted("ssd_scan_gate_refusals_total",
-                      reason="heads_per_group"),
-              counted("ssd_scan_lowerings_total", impl="pallas"),
-              counted("attention_lowerings_total", impl="pallas",
-                      form="mla_wide"),
-              counted("ssd_scan_head_blocks_total", blocks="4"))
-    with mock.patch.object(fa, "_interpret", lambda: False), \
-            mock.patch.object(ssd, "_interpret", lambda: False):
-        fa._make_flash_core_bsh.cache_clear()
-        try:
-            compiled = exe._lower_step(
-                built.main, feed=feed, fetch_list=[built.loss], scope=scope,
-                platforms=("tpu",), sharding=one_chip).compile()
-        finally:
-            fa._make_flash_core_bsh.cache_clear()
-    assert (counted("ssd_scan_gate_refusals_total", reason="heads_per_group"),
-            counted("ssd_scan_lowerings_total", impl="pallas"),
-            counted("attention_lowerings_total", impl="pallas",
-                    form="mla_wide"),
-            counted("ssd_scan_head_blocks_total", blocks="4")) == (
-        before[0], before[1] + 9, before[2] + 1, before[3] + 9)
-    text = compiled.as_text()
-    step = hlo_text.read_step(text)
     assert set(step.kernels) == set(cell.config["mosaic_calls"]) | {
         "ssd_scan_fwd", "ssd_scan_bwd"}, step.kernels
+    calls = calls_in(jaxpr)
+    assert calls == {"ssd_scan_fwd": 9, "ssd_scan_bwd": 9,
+                     "flash_mla_wide_causal_fwd": 1,
+                     "flash_mla_wide_causal_bwd": 1}, calls
+    assert ssd.kernel_fits_reason(4096, 64, 64, 1, 128, 256,
+                                  jnp.bfloat16) is None
+    # grid (batch, group x block, chunk): four blocks a group
+    assert {e.params["grid_mapping"].grid[1] for e in pallas_eqns(jaxpr)
+            if e.params["name"].startswith("ssd_scan")} == {4}
     forward = [c for c in step.calls.values()
                if c.kernel == "flash_mla_wide_causal_fwd"]
-    assert {c.operands[0].dims for c in forward} == {(batch, 4096, 2048)}
+    assert {c.operands[0].dims for c in forward} == {
+        (int(cell.traffic["batch"]), 4096, 2048)}
     assert ".remat" not in text
-    # since PR 43 the scan's kernels give dx, dB and dC turned, positions
-    # last, and each of the nine in_proj weight-gradient products finds the
-    # positions on the lanes of both operands (before, d(in_proj) came
-    # features-minor against the block norm's positions-minor output, and
-    # one operand was turned for each product)
     products = _in_proj_weight_products(text, built.main)
     assert products == [(0, 0)] * 9, products
+
+
+# one row a cell: the kernels the compiled step holds beside the ones the
+# configuration lists, those it must not hold, the bounds of its
+# `peak_hbm_gb`, and the cell's own check. The stated peak bounds each from
+# above since PR 33 (Xing4) and PR 35 (Nemotron): the kernels took the peak
+# under what the configuration (a benchmark file) states.
+CELLS = {
+    # the padded latent-attention flash calls, the grouped products at
+    # 3584 x 1024, the stream-mixing kernels and the mappings' forward
+    # kernel; `mhc_map`'s backward pass stays the composition's, whose
+    # float32 copy of the streams this peak rests on (with `mhc_map_bwd` in
+    # its place the step read 14.890 GB, PR 37)
+    "xing4.0-29b-a4b.tp8ep8share.s4096": dict(
+        present={"mhc_post_fwd", "mhc_post_bwd", "mhc_map_fwd"},
+        absent={"mhc_map_bwd"},
+        peak=lambda gb: 4.0 < gb < 15.75, check=None),
+    # the BHSD flash kernels (at S 4096 and H 4096 the BSH kernels'
+    # whole-sequence residency is over their gate); since PR 35 the three
+    # grouped-matmul kernels with 1856 = 14.5 x 128 as one whole block;
+    # since PR 39 the state-space scan's two kernels (14.421 GB here,
+    # 14.502 with the composition)
+    "nemotron-3-nano-30b-a3b.ep16share.s4096": dict(
+        present={"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn", "ssd_scan_fwd",
+                 "ssd_scan_bwd"},
+        absent=set(),
+        peak=lambda gb: (4.0 < gb < 15.2
+                         and abs(gb - 14.421) <= 0.01 * 14.421),
+        check=_nemotron),
+    # the trunk and the multi-token-prediction module, the embedding table
+    # and the head used twice each; the grouped products at 2048 x 1536
+    "glm-4.7-flash.ep8share.mtp.s4096": dict(
+        present=set(), absent=set(),
+        peak=lambda gb: 4.0 < gb <= 15.2, check=_glm),
+    # the scan's kernels' step (PR 41) read 14.409 GB
+    "granite-4.0-h-micro.vocab8.s4096": dict(
+        present={"ssd_scan_fwd", "ssd_scan_bwd"}, absent=set(),
+        peak=lambda gb: (4.0 < gb <= 15.2
+                         and abs(gb - 14.409) <= 0.01 * 14.409),
+        check=_granite),
+}
+
+
+@pytest.mark.parametrize("name", list(CELLS), ids=[
+    "xing4", "nemotron", "glm", "granite"])
+def test_the_cell_step_compiles_and_fits_the_v5e(
+        one_chip, no_compile_cache, described_tpu, name):
+    """The step of a decoder cell as the harness builds it, at the cell's
+    batch and the published widths, compiled for the described chip with
+    its state given as shapes (the parameters are not allocated here):
+    Mosaic takes every kernel, the step holds every call the configuration
+    lists and the row's own, and XLA's buffer assignment reads no more than
+    the `peak_hbm_gb` the configuration states."""
+    import numpy as np
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import harness, hlo_text, manifest
+    from paddle_tpu.fluid.executor import Scope
+
+    row = CELLS[name]
+    cell = manifest.load_cell(manifest.load_manifest(), name)
+    batch = int(cell.traffic["batch"])
+    built = harness.build_program(cell, batch, dropout=True, seed=1)
+    exe, scope = fluid.Executor(), Scope()
+    for program in (built.startup, built.main):
+        for v in program.global_block().vars.values():
+            if v.persistable and v.shape is not None:
+                scope.set_var(v.name, jax.ShapeDtypeStruct(
+                    tuple(v.shape), np.dtype(v.dtype)))
+    feed = cell.family.make_batch(cell.config, cell.traffic, batch,
+                                  harness.batch_rng(1, 1, 0))
+    fa._make_flash_core_bsh.cache_clear()
+    try:
+        traced = exe._trace_step(built.main, feed=feed,
+                                 fetch_list=[built.loss], scope=scope,
+                                 sharding=one_chip)
+        compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        fa._make_flash_core_bsh.cache_clear()
+    text = compiled.as_text()
+    step = hlo_text.read_step(text)
+    wanted = set(cell.config["mosaic_calls"]) | row["present"]
+    assert wanted <= set(step.kernels), step.kernels
+    assert not row["absent"] & set(step.kernels), step.kernels
+    if row["check"] is not None:
+        row["check"](cell, built, text, step, traced.jaxpr.jaxpr)
     mem = compiled.memory_analysis()
     peak_gb = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                + mem.temp_size_in_bytes - mem.alias_size_in_bytes) / 1e9
     stated = cell.config["stated"]["peak_hbm_gb"]
     assert peak_gb <= 1.01 * stated, (peak_gb, stated)
-    assert 4.0 < peak_gb <= 15.2
-    # the scan's kernels' step (PR 41) read 14.409 GB
-    assert abs(peak_gb - 14.409) <= 0.01 * 14.409, peak_gb
+    assert row["peak"](peak_gb), peak_gb

@@ -14,6 +14,7 @@ import paddle_tpu.fluid as fluid
 from benchmark.models import granitemoehybrid as ref
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid.executor import Scope
+from model_checks import reference_value_and_grad, rel
 from paddle_tpu.models.granite_hybrid import (
     ATTENTION, MAMBA, GraniteHybridConfig,
     build_granite_hybrid_pretrain_program)
@@ -52,19 +53,11 @@ def _settings(cfg):
 def _reference(cfg, params, feed, **how):
     """The benchmark family's reference on the program's own weights: the
     loss and every parameter's gradient, float32 at `highest`."""
-    params = {k: jnp.asarray(v) for k, v in params.items()}
-
     def loss_of(p):
         return ref.reference_loss(_settings(cfg), p, feed["input_ids"],
                                   feed["labels"], **how)
 
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss_of)(params)
-
-
-def _rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.linalg.norm(a - b) / np.linalg.norm(b)
+    return reference_value_and_grad(loss_of, params)
 
 
 @pytest.fixture(scope="module")
@@ -97,7 +90,7 @@ def test_program_against_the_reference(float32_run):
     assert set(grads) == set(want)
     assert abs(loss - float(want_loss)) < 5e-5 * float(want_loss)
     for name, g in grads.items():
-        assert _rel(g, want[name]) < 5e-5, name
+        assert rel(g, want[name]) < 5e-5, name
 
 
 def test_each_fault_is_away_from_the_program(float32_run):
@@ -114,7 +107,7 @@ def test_each_fault_is_away_from_the_program(float32_run):
     assert len(ref.FAULTS) == 7
     for fault in ref.FAULTS:
         other_loss, other = _reference(cfg, params, feed, faults=(fault,))
-        worst = max(_rel(other[name], grads[name]) for _, name, _ in checked)
+        worst = max(rel(other[name], grads[name]) for _, name, _ in checked)
         assert max(worst, abs(float(other_loss) - loss) / loss) > 0.05, fault
     with pytest.raises(ValueError, match="unknown faults"):
         ref.reference_loss(settings, params, feed["input_ids"],
@@ -142,8 +135,8 @@ def test_the_tied_table_takes_the_gradient_of_both_uses(float32_run):
 
     lookup, head = part(True), part(False)
     got = grads["embed_tokens.weight"]
-    assert _rel(lookup + head, got) < 5e-5
-    assert _rel(head, got) > 0.01 and _rel(lookup, got) > 0.01
+    assert rel(lookup + head, got) < 5e-5
+    assert rel(head, got) > 0.01 and rel(lookup, got) > 0.01
 
 
 def test_a_few_adam_steps_under_amp_lower_the_loss():

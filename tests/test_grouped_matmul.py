@@ -6,18 +6,16 @@ rows behind the last group (NaN going in: nobody may read them); the list
 of visits the grid walks; the same forms at matrices one of whose axes is
 a multiple of 64 and not of 128 (one whole block, the inner loop's tail);
 the chooser's tiles at the three decoder cells' operands and its refusals;
-the gate; the counter."""
-from unittest import mock
+the gate; the products a traced gradient calls."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops import attention
+from lowered import calls_in, primitives_in
 from paddle_tpu.ops.pallas import feasible
 from paddle_tpu.ops.pallas import grouped_matmul as gm
-from paddle_tpu.telemetry import get_registry
 
 ROWS, K, N = 1024, 384, 512  # four row tiles of 256; the kernels loop over 128 or 256 columns
 
@@ -32,12 +30,6 @@ LAYOUTS = {
     "the_buffer_full": [300, 200, 500, 24],
     "no_rows_at_all": [0, 0, 0, 0],
 }
-
-
-@pytest.fixture
-def pinned():
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
-        yield
 
 
 def _operands(sizes, dtype, seed=0, k=K, n=N):
@@ -364,36 +356,24 @@ def test_the_inner_loop_and_its_tail(width, chunk, whole, tail):
     assert got == want
 
 
-def _lowerings():
-    series = get_registry().snapshot().get(
-        "moe_grouped_product_lowerings_total", {"series": []})["series"]
-    return {(s["labels"]["impl"], s["labels"]["form"]): s["value"]
-            for s in series}
-
-
-def test_off_the_tpu_every_form_is_ragged_dot_and_the_counter_says_so():
+def test_off_the_tpu_every_form_is_ragged_dot():
+    """The product and its two transposes: three `ragged_dot`s, no
+    kernel."""
     lhs, d_out, rhs, group_sizes = _operands(LAYOUTS["uneven"], jnp.float32)
     for form in gm.FORMS:
         assert gm.gmm_tiles(form, lhs, rhs.shape) is None
-    before = _lowerings()
-    text = str(jax.make_jaxpr(jax.grad(
+    jaxpr = jax.make_jaxpr(jax.grad(
         lambda l, r: jnp.sum(gm.grouped_matmul(l, r, group_sizes)[:10]),
-        argnums=(0, 1)))(jnp.nan_to_num(lhs), rhs))
-    assert text.count("= ragged_dot_general[") == 3
-    assert "pallas_call" not in text
-    after = _lowerings()
-    for form in gm.FORMS:
-        assert after[("ragged_dot", form)] == before.get(
-            ("ragged_dot", form), 0) + 1
-        assert after.get(("pallas", form), 0) == before.get(
-            ("pallas", form), 0)
+        argnums=(0, 1)))(jnp.nan_to_num(lhs), rhs)
+    assert primitives_in(jaxpr.jaxpr)["ragged_dot_general"] == 3
+    assert calls_in(jaxpr.jaxpr) == {}
 
 
-def test_pinned_the_counter_counts_the_kernel(pinned):
+def test_pinned_the_product_is_the_kernel(pinned):
     lhs, _, rhs, group_sizes = _operands(LAYOUTS["uneven"], jnp.float32)
-    before = _lowerings().get(("pallas", "nn"), 0)
-    jax.jit(gm.grouped_matmul).lower(lhs, rhs, group_sizes)
-    assert _lowerings()[("pallas", "nn")] == before + 1
+    jaxpr = jax.make_jaxpr(gm.grouped_matmul)(lhs, rhs, group_sizes)
+    assert calls_in(jaxpr.jaxpr) == {"moe_gmm_nn": 1}
+    assert "ragged_dot_general" not in primitives_in(jaxpr.jaxpr)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float16, jnp.int8])

@@ -11,8 +11,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.ops import attention
-from paddle_tpu.ops.pallas import add_ln, conv_bn, feasible
+from paddle_tpu.ops.pallas import add_ln, backend, conv_bn, feasible
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 
@@ -123,10 +122,9 @@ DISPATCH = {
 
 
 @pytest.mark.parametrize("case", sorted(DISPATCH))
-def test_bsh_dispatch_truth_table(monkeypatch, case):
+def test_bsh_dispatch_truth_table(pinned, case):
     (sq, skv, h, heads, bias, batch, causal), want = DISPATCH[case]
     # on the CPU the gate is closed unless a test forces the kernels
-    monkeypatch.setattr(attention, "FORCE_PALLAS", True)
     assert fa.bsh_dispatch_ok(sq, skv, h, heads, bias=bias, batch=batch,
                               causal=causal) is want
 
@@ -134,9 +132,9 @@ def test_bsh_dispatch_truth_table(monkeypatch, case):
 def test_bsh_dispatch_gate_is_closed_by_flag_and_off_the_chip(monkeypatch):
     args = (512, 512, 768, 12)
     # interpret mode and nothing forced: the jnp composition runs
-    assert fa._interpret() and not attention.FORCE_PALLAS
+    assert backend.interpret() and not backend.FORCE_PALLAS
     assert not fa.bsh_dispatch_ok(*args)
-    monkeypatch.setattr(attention, "FORCE_PALLAS", True)
+    monkeypatch.setattr(backend, "FORCE_PALLAS", True)
     assert fa.bsh_dispatch_ok(*args)
     fluid.flags.set_flags({"FLAGS_use_flash_attention": False})
     try:

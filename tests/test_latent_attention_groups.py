@@ -12,15 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lowered import pallas_calls
 from paddle_tpu.ops import attention
-from paddle_tpu.ops.pallas import feasible
+from paddle_tpu.ops.pallas import backend, feasible
 from paddle_tpu.ops.pallas import flash_attention as fa
-from paddle_tpu.telemetry import get_registry
-
-
-def _lowerings(impl, form):
-    return get_registry().counter(
-        "attention_lowerings_total", impl=impl, form=form).value
 
 
 def _holds(heads, width):
@@ -29,16 +24,21 @@ def _holds(heads, width):
         fa, "bsh_shapes_ok", lambda sq, skv, h: h <= heads * width)
 
 
-def _loss_and_grads(q, k, v, nh, scale, pallas):
+def _loss(nh, scale):
     def loss(q, k, v):
         out = attention.latent_attention(q, k, v, nh, scale, True)
         # weighed, so that every output column has a cotangent of its own
         return (out.astype(jnp.float32)
                 * jnp.linspace(0.5, 1.5, out.shape[-1])).sum()
 
-    with mock.patch.object(attention, "FORCE_PALLAS", pallas):
+    return loss
+
+
+def _loss_and_grads(q, k, v, nh, scale, pallas):
+    with mock.patch.object(backend, "FORCE_PALLAS", pallas):
         fa._make_flash_core_bsh.cache_clear()
-        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return jax.value_and_grad(_loss(nh, scale), argnums=(0, 1, 2))(
+            q, k, v)
 
 
 @pytest.mark.parametrize("nh, dqk, dv, held, groups, form", [
@@ -55,15 +55,18 @@ def test_the_grouped_flash_path_is_the_composition(nh, dqk, dv, held, groups,
             for _ in range(2))
     v = jnp.asarray(rng.randn(2, s, nh * dv) * 0.5, jnp.float32)
     scale = 0.7 * dqk ** -0.5
-    with _holds(held, 64), mock.patch.object(attention, "FORCE_PALLAS", True):
+    with _holds(held, 64), mock.patch.object(backend, "FORCE_PALLAS", True):
         assert attention.latent_head_groups(s, s, nh, 64, batch=2) == groups
+        # one forward and one backward kernel call a group, under the
+        # form's own names, and nothing of the composition
+        fa._make_flash_core_bsh.cache_clear()
+        assert pallas_calls(jax.grad(_loss(nh, scale), argnums=(0, 1, 2)),
+                            q, k, v) == {
+            fa._bsh_kernel_name("fwd", True, form): groups,
+            fa._bsh_kernel_name("bwd", True, form): groups}
     want, want_grads = _loss_and_grads(q, k, v, nh, scale, pallas=False)
-    before = _lowerings("pallas", form), _lowerings("jnp", "mla")
     with _holds(held, 64):
         got, got_grads = _loss_and_grads(q, k, v, nh, scale, pallas=True)
-    # one count the attention call, however many kernel calls it makes
-    assert _lowerings("pallas", form) == before[0] + 1
-    assert _lowerings("jnp", "mla") == before[1]
     np.testing.assert_allclose(got, want, rtol=2e-5)
     for g, w in zip(got_grads, want_grads):
         assert g.shape == w.shape
@@ -81,7 +84,7 @@ def test_the_grouped_flash_path_is_the_composition(nh, dqk, dv, held, groups,
 def test_what_the_chooser_picks(s, nh, width, batch, groups):
     """The fewest equal groups `feasible.py`'s model admits: a function of
     the shapes. Where all heads pass, one call, as before the grouping."""
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
+    with mock.patch.object(backend, "FORCE_PALLAS", True):
         assert attention.latent_head_groups(
             s, s, nh, width, batch=batch) == groups
         held = nh // groups
@@ -95,15 +98,15 @@ def test_what_the_chooser_picks(s, nh, width, batch, groups):
 
 
 def test_no_group_where_not_one_head_passes():
-    with _holds(0, 64), mock.patch.object(attention, "FORCE_PALLAS", True):
+    with _holds(0, 64), mock.patch.object(backend, "FORCE_PALLAS", True):
         assert attention.latent_head_groups(128, 128, 4, 64) is None
         q = jnp.ones((1, 128, 4 * 64), jnp.float32)
-        before = _lowerings("jnp", "mla")
-        attention.latent_attention(q, q, q, 4)
-        assert _lowerings("jnp", "mla") == before + 1
+        # the composition: no kernel
+        assert pallas_calls(lambda q: attention.latent_attention(
+            q, q, q, 4), q) == {}
 
 
-def test_the_wide_calls_carry_their_own_names():
+def test_the_wide_calls_carry_their_own_names(described_tpu):
     """Lowered for the TPU from this CPU process, twenty heads of 256 / 256
     at S 4096 are two `flash_mla_wide_causal_fwd` calls and two `_bwd`, on
     [B, 4096, 2560] slabs; four heads of 192 / 128 stay one
@@ -117,9 +120,8 @@ def test_the_wide_calls_carry_their_own_names():
         v = jax.ShapeDtypeStruct((1, 4096, nh * dv), jnp.bfloat16)
         fa._make_flash_core_bsh.cache_clear()
         try:
-            with mock.patch.object(fa, "_interpret", lambda: False):
-                return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
-                    q, q, v).lower(lowering_platforms=("tpu",)).as_text()
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+                q, q, v).lower(lowering_platforms=("tpu",)).as_text()
         finally:
             fa._make_flash_core_bsh.cache_clear()
 

@@ -10,6 +10,7 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid.executor import Scope
+from model_checks import rel
 from paddle_tpu.models import lfm2_moe_reference as ref
 from paddle_tpu.models.lfm2_moe import (Lfm2MoeConfig,
                                         build_lfm2_moe_pretrain_program,
@@ -44,11 +45,6 @@ def _built(cfg, amp):
         exe, scope, feed
 
 
-def _rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.linalg.norm(a - b) / np.linalg.norm(b)
-
-
 @pytest.mark.parametrize("held, amp, tol", [
     (None, False, 2e-5),       # the whole model, float32
     ((2, 4), False, 2e-5),     # experts 2-5 of 8, float32
@@ -78,7 +74,7 @@ def test_program_against_the_reference(held, amp, tol):
         grad_of[n] for n in names] + tokens_per_expert(main))
     assert abs(float(got[0][0]) - float(want_loss)) < tol * float(want_loss)
     for name, g in zip(names, got[1:1 + len(names)]):
-        assert _rel(g, want[name]) < tol, name
+        assert rel(g, want[name]) < tol, name
     counts = got[1 + len(names):]
     assert len(counts) == 4 and all(c.shape == (cfg.experts_held,)
                                     for c in counts)

@@ -15,75 +15,9 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.executor import Scope
-from paddle_tpu.ops import attention, decoder_ops, moe_ops
-
-INIT = fluid.initializer.TruncatedNormalInitializer(scale=0.3)
-
-
-def _rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
-
-
-def _run(build, feed, amp=False, lowered=None):
-    """Build `out = build(x)` over a fed x, loss = sum(out * w) for a fixed
-    random w, minimize with SGD(0) so that nothing moves, and return (out,
-    {parameter or 'x': gradient}, parameters, w, extras). A list given as
-    `lowered` receives the lowered step's text."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 11
-    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-        x = layers.data("x", shape=list(feed["x"].shape), dtype="float32",
-                        append_batch_size=False)
-        # a zero parameter added to x: its gradient is x's
-        shift = layers.create_parameter(
-            shape=list(feed["x"].shape), dtype="float32", name="x_shift",
-            default_initializer=fluid.initializer.ConstantInitializer(0.0))
-        out, extras = build(layers.elementwise_add(x, shift))
-        w = layers.data("w", shape=list(out.shape), dtype="float32",
-                        append_batch_size=False)
-        loss = layers.reduce_sum(layers.elementwise_mul(
-            layers.cast(out, "float32"), w))
-        opt = fluid.optimizer.SGD(learning_rate=0.0)
-        if amp:
-            opt = mixed_precision.decorate(opt, use_bf16=True)
-        _, pgs = opt.minimize(loss, startup_program=startup)
-    exe, scope = fluid.Executor(), Scope()
-    exe.run(startup, scope=scope)
-    params = {p.name: np.asarray(scope.find_var(p.name))
-              for p in main.all_parameters() if p.name != "x_shift"}
-    rng = np.random.RandomState(5)
-    wv = rng.uniform(0.5, 1.5, out.shape).astype(np.float32)
-    grads = {p.name: g.name for p, g in pgs if g is not None}
-    names = sorted(grads)
-    fetch = [out.name] + [grads[n] for n in names] + [e.name for e in extras]
-    if lowered is not None:
-        lowered.append(exe._lower_step(
-            main, feed={"x": feed["x"], "w": wv}, fetch_list=fetch,
-            scope=scope).as_text(debug_info=True))
-    got = exe.run(main, feed={"x": feed["x"], "w": wv}, fetch_list=fetch,
-                  scope=scope)
-    g = dict(zip(names, got[1:1 + len(names)]))
-    g["x"] = g.pop("x_shift")
-    return got[0], g, params, wv, got[1 + len(names):]
-
-
-def _ref_grads(fn, x, params, w):
-    """Gradients of sum(fn(x, params) * w) in float32 at highest precision."""
-    with jax.default_matmul_precision("highest"):
-        out = fn(jnp.asarray(x), params)
-        gx, gp = jax.grad(
-            lambda x, p: jnp.sum(fn(x, p) * w), argnums=(0, 1))(
-                jnp.asarray(x), {k: jnp.asarray(v) for k, v in params.items()})
-    return out, {"x": gx, **gp}
-
-
-def _assert_close(got_out, got_grads, want_out, want_grads, tol):
-    assert _rel(got_out, want_out) < tol
-    assert set(got_grads) == set(want_grads)
-    for name, g in got_grads.items():
-        assert _rel(g, want_grads[name]) < tol, name
-
+from paddle_tpu.ops import decoder_ops, moe_ops
+from paddle_tpu.ops.pallas import backend
+from model_checks import INIT, assert_close, ref_grads, rel, run_op
 
 # ---------------------------------------------------------------------------
 # rms_norm, rope
@@ -105,10 +39,10 @@ def test_rms_norm_whole_axis_and_per_head(group, amp):
     x = np.random.RandomState(0).randn(2, 6, 32).astype(np.float32)
     attr = fluid.ParamAttr(
         name="n.w", initializer=fluid.initializer.UniformInitializer(0.5, 1.5))
-    got = _run(lambda v: (layers.rms_norm(
+    got = run_op(lambda v: (layers.rms_norm(
         v, 1e-5, group_size=group, param_attr=attr), []), {"x": x}, amp)
-    want = _ref_grads(_rms_ref(group or 32), x, got[2], got[3])
-    _assert_close(got[0], got[1], *want, tol=2e-2 if amp else 1e-5)
+    want = ref_grads(_rms_ref(group or 32), x, got[2], got[3])
+    assert_close(got[0], got[1], *want, tol=2e-2 if amp else 1e-5)
 
 
 def _rope_closed_form(x, head_dim, theta):
@@ -127,19 +61,19 @@ def _rope_closed_form(x, head_dim, theta):
 
 def test_rope_against_the_closed_form_at_positions_0_and_4095():
     x = np.random.RandomState(1).randn(1, 4096, 32).astype(np.float32)
-    out, grads, _, w, _ = _run(
+    out, grads, _, w, _ = run_op(
         lambda v: (layers.rope(v, head_dim=16, theta=1e6), []), {"x": x})
     want = _rope_closed_form(x, 16, 1e6)
     np.testing.assert_array_equal(out[:, 0], x[:, 0])  # angle 0
     for t in (0, 1, 4095):
         np.testing.assert_allclose(out[:, t], want[:, t], atol=2e-6)
-    assert _rel(out, want) < 1e-6
+    assert rel(out, want) < 1e-6
     # a rotation: norms of every pair are kept, and the gradient is the
     # inverse rotation of the cotangent
     np.testing.assert_allclose(
         np.linalg.norm(out, axis=-1), np.linalg.norm(x, axis=-1), rtol=1e-5)
     back = _rope_closed_form(grads["x"], 16, 1e6)
-    assert _rel(back, w) < 1e-6
+    assert rel(back, w) < 1e-6
 
 
 def test_rope_under_amp_keeps_the_input_dtype():
@@ -150,9 +84,9 @@ def test_rope_under_amp_keeps_the_input_dtype():
                       param_attr=fluid.ParamAttr(name="q", initializer=INIT))
         return layers.rope(q, head_dim=8, theta=1e4), []
 
-    out, grads, params, w, _ = _run(build, {"x": x}, amp=True)
+    out, grads, params, w, _ = run_op(build, {"x": x}, amp=True)
     want = _rope_closed_form(x @ params["q"], 8, 1e4)
-    assert _rel(out, want) < 2e-2
+    assert rel(out, want) < 2e-2
 
 
 # ---------------------------------------------------------------------------
@@ -173,13 +107,13 @@ def _short_conv_ref(x, p):
 @pytest.mark.parametrize("amp", [False, True])
 def test_short_conv_against_the_reference(amp):
     x = np.random.RandomState(3).randn(2, 12, 16).astype(np.float32)
-    got = _run(lambda v: (layers.short_conv(
+    got = run_op(lambda v: (layers.short_conv(
         v, 3, param_attr=fluid.ParamAttr(initializer=INIT), name="c"), []),
         {"x": x}, amp)
     assert sorted(got[2]) == ["c.conv", "c.in_proj", "c.out_proj"]
     assert got[2]["c.conv"].shape == (3, 16)
-    want = _ref_grads(_short_conv_ref, x, got[2], got[3])
-    _assert_close(got[0], got[1], *want, tol=3e-2 if amp else 1e-5)
+    want = ref_grads(_short_conv_ref, x, got[2], got[3])
+    assert_close(got[0], got[1], *want, tol=3e-2 if amp else 1e-5)
 
 
 def test_short_conv_is_causal_and_three_taps_long():
@@ -211,13 +145,13 @@ def test_short_conv_is_causal_and_three_taps_long():
 @pytest.mark.parametrize("remat", [False, True])
 def test_swiglu_ffn_against_the_reference(remat):
     x = np.random.RandomState(5).randn(2, 6, 16).astype(np.float32)
-    got = _run(lambda v: (layers.swiglu_ffn(
+    got = run_op(lambda v: (layers.swiglu_ffn(
         v, 24, remat=remat, param_attr=fluid.ParamAttr(initializer=INIT),
         name="f"), []), {"x": x})
-    want = _ref_grads(
+    want = ref_grads(
         lambda x, p: (jax.nn.silu(x @ p["f.w1"]) * (x @ p["f.w3"])) @ p["f.w2"],
         x, got[2], got[3])
-    _assert_close(got[0], got[1], *want, tol=1e-5)
+    assert_close(got[0], got[1], *want, tol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -260,14 +194,14 @@ def _moe_layer(held, remat=False, experts=E, bias=INIT, inter=F):
                                          ((0, 32), True)])
 def test_moe_swiglu_against_the_dense_loop(held, remat):
     x = np.random.RandomState(6).randn(2, 24, H).astype(np.float32)
-    got = _run(_moe_layer(held, remat), {"x": x})
+    got = run_op(_moe_layer(held, remat), {"x": x})
     params = got[2]
     assert params["m.gate"].shape == (H, E)  # the router's own width
     assert params["m.w1"].shape == (held[1], H, F)
-    want_out, want = _ref_grads(_moe_ref(held), x, params, got[3])
+    want_out, want = ref_grads(_moe_ref(held), x, params, got[3])
     want.pop("m.expert_bias")  # a buffer: the program makes it no gradient
     assert "m.expert_bias" not in got[1]
-    _assert_close(got[0], got[1], want_out, want, tol=2e-5)
+    assert_close(got[0], got[1], want_out, want, tol=2e-5)
     # the counter: the rows each held expert received
     with jax.default_matmul_precision("highest"):
         s = jax.nn.sigmoid(jnp.asarray(x) @ params["m.gate"])
@@ -279,12 +213,12 @@ def test_moe_swiglu_against_the_dense_loop(held, remat):
 
 def test_moe_swiglu_under_bf16_amp():
     x = np.random.RandomState(7).randn(2, 24, H).astype(np.float32)
-    got = _run(_moe_layer((0, 8)), {"x": x}, amp=True)
-    want_out, want = _ref_grads(_moe_ref((0, 8)), x, got[2], got[3])
+    got = run_op(_moe_layer((0, 8)), {"x": x}, amp=True)
+    want_out, want = ref_grads(_moe_ref((0, 8)), x, got[2], got[3])
     want.pop("m.expert_bias")
     # the router scores in float32 over the bf16 tokens, so a near-tie can
     # fall the other way: a few per cent, not the 1e-5 of float32
-    _assert_close(got[0], got[1], want_out, want, tol=8e-2)
+    assert_close(got[0], got[1], want_out, want, tol=8e-2)
 
 
 @pytest.mark.parametrize("seq", [24, 128])
@@ -295,7 +229,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(seq):
     assert (moe_ops.sorted_rows(2 * seq * K, 8, E) < 2 * seq * K) == (
         seq == 128)
     x = np.random.RandomState(8).randn(2, seq, H).astype(np.float32)
-    whole = _run(_moe_layer((0, 32)), {"x": x})
+    whole = run_op(_moe_layer((0, 32)), {"x": x})
     params = whole[2]
     with jax.default_matmul_precision("highest"):
         uncut = _moe_ref((0, E))(jnp.asarray(x), {
@@ -374,12 +308,12 @@ def test_the_sorted_block_has_twice_the_expected_rows(pairs, held, experts,
 
 def _share_against_the_dense_loop(bias, lowered=None):
     x = np.random.RandomState(14).randn(1, TOKENS, H).astype(np.float32)
-    got = _run(_moe_layer(SHARE, remat=True, experts=NARROW,
+    got = run_op(_moe_layer(SHARE, remat=True, experts=NARROW,
                           bias=fluid.initializer.NumpyArrayInitializer(bias)),
                {"x": x}, lowered=lowered)
-    want_out, want = _ref_grads(_moe_ref(SHARE), x, got[2], got[3])
+    want_out, want = ref_grads(_moe_ref(SHARE), x, got[2], got[3])
     want.pop("m.expert_bias")
-    _assert_close(got[0], got[1], want_out, want, tol=2e-5)
+    assert_close(got[0], got[1], want_out, want, tol=2e-5)
     return got[4][0]
 
 
@@ -434,16 +368,16 @@ def test_the_grouped_matmul_kernels_agree_with_ragged_dot_through_the_op(
     layer = _moe_layer(SHARE, remat=True, experts=NARROW, inter=inter,
                        bias=fluid.initializer.NumpyArrayInitializer(bias))
     lowered = []
-    want = _run(layer, {"x": x})
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
-        got = _run(layer, {"x": x}, lowered=lowered)
+    want = run_op(layer, {"x": x})
+    with mock.patch.object(backend, "FORCE_PALLAS", True):
+        got = run_op(layer, {"x": x}, lowered=lowered)
     assert {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"} <= set(
         re.findall(r"moe_gmm_[a-z]+", lowered[0]))
     assert (got[4][0].sum() > BOUND) == fallen_back
     assert got[4][0].tolist() == want[4][0].tolist()
     assert set(got[1]) == set(want[1]) == {"m.gate", "m.w1", "m.w2", "m.w3",
                                            "x"}
-    _assert_close(got[0], got[1], want[0], want[1], tol=1e-5)
+    assert_close(got[0], got[1], want[0], want[1], tol=1e-5)
 
 
 def _typed_tokens(rng, both, one):
@@ -495,10 +429,10 @@ def test_both_sides_of_the_bound(present):
         want_out = _moe_ref(SHARE)(ins["X"][0], params)
         want = jax.grad(lambda x, p: (_moe_ref(SHARE)(x, p) ** 2).sum(),
                         argnums=(0, 1))(ins["X"][0], params)
-    assert _rel(out, want_out) < 2e-5
+    assert rel(out, want_out) < 2e-5
     for got, ref in zip(grads, (want[0], *(want[1][n] for n in (
             "m.gate", "m.w1", "m.w3", "m.w2")))):
-        assert _rel(got, ref) < 2e-5
+        assert rel(got, ref) < 2e-5
     with mock.patch.object(moe_ops, "_full_width",
                            lambda *operands: jnp.zeros_like(operands[0])):
         zeroed = _through_the_op(ins)[0]
@@ -651,7 +585,7 @@ def test_the_part_scopes_appear_beneath_the_roles_in_the_lowered_step():
                for n in names)
 
 
-def test_the_bsh_flash_calls_are_named_by_mode():
+def test_the_bsh_flash_calls_are_named_by_mode(described_tpu):
     """Causal calls are `flash_bsh_causal_fwd` / `_bwd`, the others keep
     `flash_bsh_fwd` / `_bwd`: lowered for the TPU from this CPU process,
     heads of 64 at S 128 so that the shape gates pass."""
@@ -659,23 +593,22 @@ def test_the_bsh_flash_calls_are_named_by_mode():
 
     assert fa._bsh_kernel_name("fwd", False) == "flash_bsh_fwd"
     assert fa._bsh_kernel_name("bwd", False) == "flash_bsh_bwd"
-    with mock.patch.object(fa, "_interpret", lambda: False):
-        text = _decoder_step(("tpu",), seq=128, heads=(2, 1),
-                             hidden=128).as_text()
-        kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
-        assert {"flash_bsh_causal_fwd", "flash_bsh_causal_bwd"} <= kernels
-        assert not {"flash_bsh_fwd", "flash_bsh_bwd"} & kernels
+    text = _decoder_step(("tpu",), seq=128, heads=(2, 1),
+                         hidden=128).as_text()
+    kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert {"flash_bsh_causal_fwd", "flash_bsh_causal_bwd"} <= kernels
+    assert not {"flash_bsh_fwd", "flash_bsh_bwd"} & kernels
 
-        def loss(q, k, v):
-            return fa.flash_attention_bsh(
-                q, k, v, num_heads=2, causal=False).astype(jnp.float32).sum()
+    def loss(q, k, v):
+        return fa.flash_attention_bsh(
+            q, k, v, num_heads=2, causal=False).astype(jnp.float32).sum()
 
-        x = jnp.zeros((2, 128, 128), jnp.bfloat16)
+    x = jnp.zeros((2, 128, 128), jnp.bfloat16)
+    fa._make_flash_core_bsh.cache_clear()
+    try:
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            x, x, x).lower(lowering_platforms=("tpu",)).as_text()
+    finally:
         fa._make_flash_core_bsh.cache_clear()
-        try:
-            text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
-                x, x, x).lower(lowering_platforms=("tpu",)).as_text()
-        finally:
-            fa._make_flash_core_bsh.cache_clear()
     kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
     assert kernels == {"flash_bsh_fwd", "flash_bsh_bwd"}

@@ -30,7 +30,8 @@ from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid.executor import Scope
 from paddle_tpu.models.lfm2_moe import (ATTENTION, CONV, Lfm2MoeConfig,
                                         build_lfm2_moe_pretrain_program)
-from paddle_tpu.ops.pallas import flash_attention
+from lowered import kernel_sizes
+from paddle_tpu.ops.pallas import backend
 from paddle_tpu.ops.pallas import grouped_matmul as gm
 
 KERNELS = {"moe_gmm_nn", "moe_gmm_nt", "moe_gmm_tn"}
@@ -44,11 +45,7 @@ BATCH, SEQ = 2, 256  # 512 tokens, 1,024 pairs: a share of 2 of 8 is bounded
 EQN_CEILING = 100
 
 
-@pytest.fixture(autouse=True)
-def _tpu_branches():
-    with mock.patch.object(flash_attention, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False):
-        yield
+pytestmark = pytest.mark.usefixtures("described_tpu")
 
 
 def _step_text(expert_layers):
@@ -83,8 +80,8 @@ def _lowered_step(build, cfg):
 
 @pytest.fixture(scope="module")
 def texts():
-    with mock.patch.object(flash_attention, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False):
+    # module-scoped: the `described_tpu` fixture is not yet in place
+    with mock.patch.object(backend, "interpret", lambda: False):
         return {n: _step_text(n) for n in (1, 3)}
 
 
@@ -162,8 +159,7 @@ def _nemotron_step_text(expert_layers):
 
 @pytest.fixture(scope="module")
 def nemotron_texts():
-    with mock.patch.object(flash_attention, "_interpret", lambda: False), \
-            mock.patch.object(gm, "_interpret", lambda: False):
+    with mock.patch.object(backend, "interpret", lambda: False):
         return {n: _nemotron_step_text(n) for n in (1, 2)}
 
 
@@ -209,14 +205,12 @@ def test_the_two_matrix_bodies_do_not_go_with_the_layers(nemotron_texts):
 @pytest.mark.parametrize("form", gm.FORMS)
 def test_the_traced_body_is_small_and_does_not_go_with_the_rows(form, k, n,
                                                                 rows):
-    from test_flash_bsh_compile import _pallas_calls
-
     a = jax.ShapeDtypeStruct((rows, n if form == "nt" else k), jnp.bfloat16)
     b = (jax.ShapeDtypeStruct((rows, n), jnp.bfloat16) if form == "tn"
          else jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16))
     sizes = jax.ShapeDtypeStruct((8,), jnp.int32)
     jaxpr = jax.make_jaxpr(
         lambda a, b, s: gm._run(form, a, b, s, (8, k, n)))(a, b, sizes)
-    (name, eqns), = _pallas_calls(jaxpr.jaxpr, {}).items()
+    (name, eqns), = kernel_sizes(jaxpr.jaxpr).items()
     assert name == f"moe_gmm_{form}"
     assert eqns <= EQN_CEILING, eqns

@@ -18,6 +18,7 @@ from benchmark.models import nemotron_h as ref
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.executor import Scope
+from model_checks import reference_value_and_grad, rel, run_layer
 from paddle_tpu.models.nemotron_h import (NemotronHConfig,
                                           build_nemotron_h_pretrain_program,
                                           min_decays, tokens_per_expert)
@@ -57,14 +58,8 @@ def _reference_loss_and_grads(cfg, params, feed):
             dataclasses.asdict(cfg), {**bias, **trained}, feed["input_ids"],
             feed["labels"], (cfg.first_expert, cfg.experts_held))
 
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss_of)(
-            {k: v for k, v in params.items() if k not in bias})
-
-
-def _rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.linalg.norm(a - b) / np.linalg.norm(b)
+    return reference_value_and_grad(
+        loss_of, {k: v for k, v in params.items() if k not in bias})
 
 
 @pytest.mark.parametrize("share", [
@@ -102,7 +97,7 @@ def test_program_against_the_reference(share):
         + min_decays(main))
     assert abs(float(got[0][0]) - float(want_loss)) < 5e-5 * float(want_loss)
     for name, g in zip(names, got[1:1 + len(names)]):
-        assert _rel(g, want[name]) < 5e-5, name
+        assert rel(g, want[name]) < 5e-5, name
     counts, decays = got[1 + len(names):][:2], got[1 + len(names):][2:]
     assert all(c.shape == (cfg.experts_held,) for c in counts)
     if share is None:  # all experts held: every pick lands somewhere
@@ -115,23 +110,6 @@ def test_program_against_the_reference(share):
 # ---------------------------------------------------------------------------
 # the share test
 # ---------------------------------------------------------------------------
-
-
-def _run_layer(build, x, weights):
-    """out = build(x) with the named parameters set to `weights`."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-        v = layers.data("x", shape=list(x.shape), dtype="float32",
-                        append_batch_size=False)
-        out = build(v)
-    exe, scope = fluid.Executor(), Scope()
-    exe.run(startup, scope=scope)
-    names = {p.name for p in main.all_parameters()}
-    assert names == set(weights), names ^ set(weights)
-    for name, w in weights.items():
-        assert scope.find_var(name).shape == w.shape, name
-        scope.set_var(name, jnp.asarray(w))
-    return exe.run(main, feed={"x": x}, fetch_list=[out], scope=scope)[0]
 
 
 def test_the_shares_add_up_to_the_uncut_expert_layer():
@@ -157,23 +135,23 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
     def routed_share(first, count):
         held = {k: (val[first:first + count] if k in ("w1", "w2") else val)
                 for k, val in ffn.items() if not k.startswith("shared")}
-        return _run_layer(lambda v: layers.moe_swiglu(
+        return run_layer(lambda v: layers.moe_swiglu(
             v, 16, 48, experts_held=count, first_expert=first, top_k=2,
             norm_topk_prob=True, routed_scaling_factor=2.5, name="m",
             activation="relu2")[0],
             x, {f"m.{k}": val for k, val in held.items()})
 
-    shared = _run_layer(
+    shared = run_layer(
         lambda v: layers.shared_expert(v, 96, name="m.shared_experts",
                                        activation="relu2"), x,
         {f"m.{k}": val for k, val in ffn.items() if k.startswith("shared")})
     routed = [routed_share(first, 4) for first in (0, 4, 8, 12)]
-    assert _rel(sum(routed) + shared, whole) < 1e-5
-    assert _rel(routed[0] + shared, whole) > 0.1  # a share is not the whole
+    assert rel(sum(routed) + shared, whole) < 1e-5
+    assert rel(routed[0] + shared, whole) > 0.1  # a share is not the whole
     # counted with every share it would stand four times
-    assert _rel(sum(routed) + 4 * shared, whole) > 0.1
-    assert _rel(shared, np.asarray(ref.shared_expert(z, ffn))) < 1e-5
-    assert _rel(routed_share(0, 16) + shared, whole) < 1e-5
+    assert rel(sum(routed) + 4 * shared, whole) > 0.1
+    assert rel(shared, np.asarray(ref.shared_expert(z, ffn))) < 1e-5
+    assert rel(routed_share(0, 16) + shared, whole) < 1e-5
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,8 @@ SiLU beside LFM2's three-tap call, the gated grouped norm, the whole
 `mamba2` op through Program -> append_backward -> Executor against the
 reference's mixer, the two-matrix squared-ReLU experts against a loop over
 experts with `moe_swiglu`'s three-matrix lowering left as the parent had
-it, and the scopes and the counter the benchmark reads."""
+it, the scopes the benchmark reads and the grouped products a traced step
+holds."""
 import hashlib
 import re
 
@@ -19,8 +20,8 @@ import paddle_tpu.fluid as fluid
 from benchmark.models import nemotron_h as ref
 from paddle_tpu.fluid import layers
 from paddle_tpu.ops import decoder_ops, ssm_ops
-from paddle_tpu.telemetry import get_registry
-from test_lfm2_ops import INIT, _assert_close, _ref_grads, _rel, _run
+from lowered import calls_in, primitives_in
+from model_checks import INIT, assert_close, ref_grads, rel, run_op
 
 # ---------------------------------------------------------------------------
 # ssd_scan
@@ -73,7 +74,7 @@ def test_ssd_scan_against_the_recurrence_forward_and_every_gradient(seq, chunk):
         w = np.random.default_rng(1).uniform(0.5, 1.5, want.shape)
         grads = jax.grad(lambda *t: jnp.sum(ssm_ops.ssd_scan(*t, chunk) * w),
                          argnums=tuple(range(6)))(*f32)
-    assert _rel(got, want) < 2e-6
+    assert rel(got, want) < 2e-6
     # every input's gradient along a random direction, against the central
     # difference of the float64 recurrence
     rng = np.random.default_rng(2)
@@ -97,12 +98,12 @@ def test_the_state_is_carried_across_chunks_and_heads_read_their_group():
         _recurrence64(**{k: (v[:, z:z + 4] if v.ndim > 1 else v)
                          for k, v in ins.items()}) for z in range(0, 16, 4)],
         axis=1)
-    assert _rel(cut, want) > 0.05
+    assert rel(cut, want) > 0.05
     zero = {**ins, "b": np.repeat(ins["b"][:, :, :1], 2, axis=2),
             "c": np.repeat(ins["c"][:, :, :1], 2, axis=2)}
-    assert _rel(_recurrence64(**zero), want) > 0.3
+    assert rel(_recurrence64(**zero), want) > 0.3
     got = ssm_ops.ssd_scan(*(jnp.asarray(ins[k], jnp.float32) for k in ins), 4)
-    assert _rel(got, want) < 1e-5
+    assert rel(got, want) < 1e-5
 
 
 # ---------------------------------------------------------------------------
@@ -126,11 +127,11 @@ def test_the_causal_convolution_at_three_and_at_four_taps(n_taps):
     bias = rng.normal(size=6).astype(np.float32)
     want = _conv64(x, taps)
     got = decoder_ops.causal_depthwise_conv(jnp.asarray(x), jnp.asarray(taps))
-    assert _rel(got, want) < 1e-6
+    assert rel(got, want) < 1e-6
     # the first position sees the last tap alone
     np.testing.assert_allclose(got[:, 0], taps[-1] * x[:, 0], rtol=1e-6)
     z = want + bias
-    assert _rel(jax.nn.silu(got + bias), z / (1 + np.exp(-z))) < 1e-6
+    assert rel(jax.nn.silu(got + bias), z / (1 + np.exp(-z))) < 1e-6
 
 
 def test_the_gated_norm_is_over_groups_and_behind_the_gate():
@@ -142,10 +143,10 @@ def test_the_gated_norm_is_over_groups_and_behind_the_gate():
             ).reshape(2, 5, 12) * w
     got = ssm_ops.gated_group_norm(*(jnp.asarray(t, jnp.float32)
                                      for t in (y, z, w)), 4, 1e-5)
-    assert _rel(got, want) < 1e-6
+    assert rel(got, want) < 1e-6
     over_all = ssm_ops.gated_group_norm(*(jnp.asarray(t, jnp.float32)
                                           for t in (y, z, w)), 12, 1e-5)
-    assert _rel(over_all, want) > 0.1
+    assert rel(over_all, want) > 0.1
 
 
 # ---------------------------------------------------------------------------
@@ -163,15 +164,13 @@ def _mamba_layer(v, chunk=4):
     return out, [decay]
 
 
-def _scan_lowerings():
-    return get_registry().counter("ssd_scan_lowerings_total", impl="jnp").value
-
-
 def test_mamba2_against_the_reference_mixer():
     x = np.random.RandomState(6).randn(2, 16, 12).astype(np.float32)
-    before = _scan_lowerings()
-    out, grads, params, w, extras = _run(_mamba_layer, {"x": x})
-    assert _scan_lowerings() > before
+    traced = []
+    out, grads, params, w, extras = run_op(_mamba_layer, {"x": x},
+                                           traced=traced)
+    # off the TPU the scan is the composition: the step calls no kernel
+    assert calls_in(traced[0].jaxpr.jaxpr) == {}
     assert {k: v.shape for k, v in params.items()} == {
         "m.in_proj": (12, 16 + 16 + 24 + 4), "m.conv1d.weight": (4, 40),
         "m.conv1d.bias": (40,), "m.dt_bias": (4,), "m.A_log": (4,),
@@ -186,7 +185,7 @@ def test_mamba2_against_the_reference_mixer():
     def fn(v, p):
         return ref.mamba2(v, {k[2:]: t for k, t in p.items()}, MAMBA)
 
-    _assert_close(out, grads, *_ref_grads(fn, x, params, w), tol=2e-5)
+    assert_close(out, grads, *ref_grads(fn, x, params, w), tol=2e-5)
     # each head's smallest decay exp(dt A) over the step's tokens
     proj = x @ params["m.in_proj"]
     step = np.log1p(np.exp(proj[..., -4:] + params["m.dt_bias"]))
@@ -224,7 +223,7 @@ def test_the_scan_lowers_under_its_role_and_both_part_scopes():
 
     x = np.random.RandomState(7).randn(1, 8, 12).astype(np.float32)
     lowered = []
-    _run(_mamba_layer, {"x": x}, lowered=lowered)
+    run_op(_mamba_layer, {"x": x}, lowered=lowered)
     names = set(re.findall(r'"(jit\([a-z_]+\)/[^"]*)"', lowered[0]))
     scan = {n for n in names if "ssd_scan" in n}
     assert scan and all("mamba2" in n.split("ssd_scan")[0] for n in scan)
@@ -260,7 +259,7 @@ def _relu2_layer(held, first, remat=False):
                                                 (4, 8, False)])
 def test_relu2_experts_against_a_loop_over_experts(held, first, remat):
     x = np.random.RandomState(8).randn(2, 16, 32).astype(np.float32)
-    out, grads, params, w, extras = _run(_relu2_layer(held, first, remat),
+    out, grads, params, w, extras = run_op(_relu2_layer(held, first, remat),
                                          {"x": x})
     assert set(params) == {"m.gate", "m.expert_bias", "m.w1", "m.w2"}
     assert params["m.w1"].shape == (held, 32, 24)
@@ -274,7 +273,7 @@ def test_relu2_experts_against_a_loop_over_experts(held, first, remat):
                 **{k[2:]: t for k, t in p.items()}}, settings, (first, held))
 
     trained = {k: v for k, v in params.items() if k != "m.expert_bias"}
-    _assert_close(out, grads, *_ref_grads(fn, x, trained, w), tol=2e-5)
+    assert_close(out, grads, *ref_grads(fn, x, trained, w), tol=2e-5)
     if held == 16:  # every pick lands on a held expert
         assert int(extras[0].sum()) == 2 * 16 * 2
 
@@ -282,7 +281,7 @@ def test_relu2_experts_against_a_loop_over_experts(held, first, remat):
 def test_the_shared_expert_at_relu2_and_what_is_refused():
     x = np.random.RandomState(9).randn(2, 6, 16).astype(np.float32)
     lowered = []
-    got = _run(lambda v: (layers.shared_expert(
+    got = run_op(lambda v: (layers.shared_expert(
         v, 24, remat=True, param_attr=fluid.ParamAttr(initializer=INIT),
         name="se", activation="relu2"), []), {"x": x}, lowered=lowered)
     assert set(got[2]) == {"se.w1", "se.w2"}
@@ -290,7 +289,7 @@ def test_the_shared_expert_at_relu2_and_what_is_refused():
     def fn(v, p):
         return jnp.square(jax.nn.relu(v @ p["se.w1"])) @ p["se.w2"]
 
-    _assert_close(got[0], got[1], *_ref_grads(fn, x, got[2], got[3]),
+    assert_close(got[0], got[1], *ref_grads(fn, x, got[2], got[3]),
                   tol=1e-5)
     from benchmark import part_scopes
 
@@ -298,7 +297,7 @@ def test_the_shared_expert_at_relu2_and_what_is_refused():
     assert {part_scopes.part_of(n) for n in names} - {None} == {
         "shared_expert"}
     with pytest.raises(ValueError, match="'swiglu' or 'relu2'"):
-        _run(lambda v: (layers.shared_expert(v, 24, activation="gelu"), []),
+        run_op(lambda v: (layers.shared_expert(v, 24, activation="gelu"), []),
              {"x": x})
 
 
@@ -312,7 +311,7 @@ def test_moe_swiglu_at_its_defaults_gives_the_parents_bits(held, remat,
     router with recomputation. (The lowered steps of the accepted cells are
     compared tree against tree, PERF.md section 6.)"""
     x = np.random.RandomState(2).randn(2, 16, 32).astype(np.float32)
-    got = _run(lambda v: (lambda o: (o[0], [o[1]]))(layers.moe_swiglu(
+    got = run_op(lambda v: (lambda o: (o[0], [o[1]]))(layers.moe_swiglu(
         v, 16, 24, experts_held=held, first_expert=4 if held == 4 else 0,
         top_k=2, routed_scaling_factor=2.0, remat=remat,
         bias_update_rate=0.01, param_attr=fluid.ParamAttr(initializer=INIT),
@@ -322,20 +321,20 @@ def test_moe_swiglu_at_its_defaults_gives_the_parents_bits(held, remat,
         output)
 
 
-def test_the_grouped_product_counter_counts_the_two_product_form():
-    def count():
-        reg = get_registry()
-        return sum(reg.counter("moe_grouped_product_lowerings_total",
-                               impl="ragged_dot", form=f).value
-                   for f in ("nn", "nt", "tn"))
+def test_the_two_product_form_traces_two_thirds_of_the_grouped_products():
+    """Off the TPU every grouped product and its transposes are XLA's
+    `ragged_dot`, no kernel: the two-matrix experts' step holds two for
+    every three of `moe_swiglu`'s."""
+    def count(build):
+        traced = []
+        run_op(build, {"x": x}, traced=traced)
+        jaxpr = traced[0].jaxpr.jaxpr
+        assert calls_in(jaxpr) == {}
+        return primitives_in(jaxpr)["ragged_dot_general"]
 
     x = np.random.RandomState(10).randn(1, 8, 32).astype(np.float32)
-    before = count()
-    _run(_relu2_layer(16, 0), {"x": x})
-    two = count() - before
-    before = count()
-    _run(lambda v: (lambda o: (o[0], [o[1]]))(layers.moe_swiglu(
+    two = count(_relu2_layer(16, 0))
+    three = count(lambda v: (lambda o: (o[0], [o[1]]))(layers.moe_swiglu(
         v, 16, 24, top_k=2, param_attr=fluid.ParamAttr(initializer=INIT),
-        name="m")), {"x": x})
-    three = count() - before
+        name="m")))
     assert two > 0 and three * 2 == two * 3

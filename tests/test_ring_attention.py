@@ -174,7 +174,7 @@ def test_ring_flash_path_matches_jnp_ring():
     match the jnp ring block math."""
     from jax.sharding import Mesh
 
-    from paddle_tpu.ops import attention as attn_mod
+    from paddle_tpu.ops.pallas import backend
     from paddle_tpu.parallel.ring_attention import ring_attention_global
 
     devs = np.array(jax.devices()[:4])
@@ -189,8 +189,8 @@ def test_ring_flash_path_matches_jnp_ring():
     bias = jnp.asarray((1e4 * (maskrow - 1.0)).astype(np.float32))
 
     def run(force_flash):
-        old = attn_mod.FORCE_PALLAS
-        attn_mod.FORCE_PALLAS = force_flash
+        old = backend.FORCE_PALLAS
+        backend.FORCE_PALLAS = force_flash
         try:
             out = jax.jit(
                 lambda q, k, v, bias: ring_attention_global(
@@ -205,7 +205,7 @@ def test_ring_flash_path_matches_jnp_ring():
                 )
             ))(q)
         finally:
-            attn_mod.FORCE_PALLAS = old
+            backend.FORCE_PALLAS = old
         return np.asarray(out), np.asarray(g)
 
     out_flash, g_flash = run(True)    # interpret-mode kernel path on CPU
@@ -220,7 +220,7 @@ def test_ring_flash_path_causal_matches_jnp_ring():
     (offset-causal blocks), not fall back to jnp — and match it."""
     from jax.sharding import Mesh
 
-    from paddle_tpu.ops import attention as attn_mod
+    from paddle_tpu.ops.pallas import backend
     from paddle_tpu.parallel import ring_attention as ring_mod
 
     devs = np.array(jax.devices()[:4])
@@ -234,8 +234,8 @@ def test_ring_flash_path_causal_matches_jnp_ring():
     calls = {"n": 0}
 
     def run(force_flash, count=False):
-        old = attn_mod.FORCE_PALLAS
-        attn_mod.FORCE_PALLAS = force_flash
+        old = backend.FORCE_PALLAS
+        backend.FORCE_PALLAS = force_flash
         from paddle_tpu.ops.pallas import flash_attention as fa
 
         counted = fa.flash_block_with_lse
@@ -262,7 +262,7 @@ def test_ring_flash_path_causal_matches_jnp_ring():
                 )
             )(q)
         finally:
-            attn_mod.FORCE_PALLAS = old
+            backend.FORCE_PALLAS = old
             if count:
                 fa.flash_block_with_lse = fa_orig
         return np.asarray(out), np.asarray(g)
@@ -279,7 +279,7 @@ def test_ring_flash_path_dropout_dispatches_and_regularizes():
     differs from no-dropout) and unbiased in magnitude."""
     from jax.sharding import Mesh
 
-    from paddle_tpu.ops import attention as attn_mod
+    from paddle_tpu.ops.pallas import backend
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.parallel.ring_attention import ring_attention_global
 
@@ -297,8 +297,8 @@ def test_ring_flash_path_dropout_dispatches_and_regularizes():
         assert kw.get("dropout_prob", 0.0) > 0.0
         return orig(*a, **kw)
 
-    old = attn_mod.FORCE_PALLAS
-    attn_mod.FORCE_PALLAS = True
+    old = backend.FORCE_PALLAS
+    backend.FORCE_PALLAS = True
     fa.flash_block_with_lse = wrapper
     try:
         out_drop = jax.jit(
@@ -308,7 +308,7 @@ def test_ring_flash_path_dropout_dispatches_and_regularizes():
             )
         )(q)
     finally:
-        attn_mod.FORCE_PALLAS = old
+        backend.FORCE_PALLAS = old
         fa.flash_block_with_lse = orig
     assert calls["n"] > 0, "dropout config did not dispatch the kernel path"
     out0 = np.asarray(jax.jit(

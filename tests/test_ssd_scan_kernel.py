@@ -10,8 +10,8 @@ called at their own layouts, every per-position array turned, positions
 last (two groups of 8 or one of 16 heads at chunk 128, one of 64 in blocks
 of 16 or 8 at chunk 256, bf16 and float32); the gate's
 refusals and the composition they leave bit for bit; the scopes, roles and
-counters of a `mamba2` step with the kernels pinned; the VMEM model at the
-Nemotron and Granite cells' shapes."""
+kernel calls of a `mamba2` step with the kernels pinned; the VMEM model at
+the Nemotron and Granite cells' shapes."""
 import functools
 import re
 from unittest import mock
@@ -24,23 +24,17 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.executor import Scope
-from paddle_tpu.ops import attention, ssm_ops
-from paddle_tpu.ops.pallas import feasible
+from lowered import calls_in, pallas_calls, pallas_eqns
+from model_checks import INIT, rel, run_op
+from paddle_tpu.ops import ssm_ops
+from paddle_tpu.ops.pallas import backend, feasible
 from paddle_tpu.ops.pallas import ssd_scan as ssd
-from paddle_tpu.telemetry import get_registry
-from test_lfm2_ops import INIT, _rel, _run
 from test_nemotron_ops import _recurrence64
 
 NAMES = ("x", "dt", "a", "b", "c", "d")
 # the smallest shapes the gate admits: chunk and state one lane tile, two
 # groups of eight heads of 16 columns (a group's x one lane tile)
 GROUPS, PER, P, N, Q = 2, 8, 16, 128, 128
-
-
-@pytest.fixture
-def pinned():
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
-        yield
 
 
 def _inputs(seq, seed=0, batch=2, p=P, h=GROUPS * PER, groups=GROUPS):
@@ -87,7 +81,7 @@ def test_forward_and_every_cotangent_against_the_composition_float32(pinned):
     got = _vjp(_kernels, args, g)
     for name, u, v in zip(("y",) + NAMES, got, want):
         assert u.dtype == v.dtype and u.shape == v.shape, name
-        assert _rel(u, v) < 2e-5, (name, _rel(u, v))
+        assert rel(u, v) < 2e-5, (name, rel(u, v))
 
 
 @pytest.mark.parametrize("heads, chunk, seq, blocks", [
@@ -101,7 +95,7 @@ def test_a_split_group_against_the_composition(pinned, heads, chunk, seq,
     off)."""
     ins = _inputs(seq, seed=13, batch=1, h=heads, groups=1)
     args = _arrays(ins, jnp.float32)
-    assert ssd.head_blocks(seq, heads, P, 1, N, chunk, jnp.float32) == blocks
+    assert ssd._cell(heads, 1, P, N, seq, chunk, 4).blocks == blocks
     g = jnp.asarray(np.random.default_rng(14).normal(size=ins["x"].shape),
                     jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -109,7 +103,7 @@ def test_a_split_group_against_the_composition(pinned, heads, chunk, seq,
     got = _vjp(lambda *t: ssd.ssd_scan(*t, chunk), args, g)
     for name, u, v in zip(("y",) + NAMES, got, want):
         assert u.dtype == v.dtype and u.shape == v.shape, name
-        assert _rel(u, v) < 2e-5, (name, _rel(u, v))
+        assert rel(u, v) < 2e-5, (name, rel(u, v))
 
 
 def _turned(t):
@@ -157,8 +151,8 @@ def test_the_kernels_take_and_give_turned_arrays(pinned, heads, groups, chunk,
         jax.clear_caches()
     try:
         with mock.patch.object(feasible, "SSD_VMEM_BUDGET", budget):
-            assert ssd.head_blocks(seq, heads, p, groups, N, chunk,
-                                   dtype) == blocks
+            assert ssd._cell(heads, groups, p, N, seq, chunk,
+                             jnp.dtype(dtype).itemsize).blocks == blocks
             grids = [e.params["grid_mapping"].grid for e in jax.make_jaxpr(
                 functools.partial(ssd._scan_fwd.__wrapped__, **kw))(
                     *turned).eqns if e.primitive.name == "pallas_call"]
@@ -184,8 +178,8 @@ def test_the_kernels_take_and_give_turned_arrays(pinned, heads, groups, chunk,
     for name, u, c, v in zip(("y",) + NAMES, got, comp, want):
         assert u.shape == v.shape, name
         bound = (2e-5 if dtype == jnp.float32
-                 else max(2 * _rel(c, v), 2.0 ** -8))
-        assert _rel(u, v) < bound, (name, _rel(u, v), bound)
+                 else max(2 * rel(c, v), 2.0 ** -8))
+        assert rel(u, v) < bound, (name, rel(u, v), bound)
     assert y_t.dtype == dx_t.dtype == db_t.dtype == dtype
 
 
@@ -197,7 +191,7 @@ def test_forward_and_every_cotangent_against_the_recurrence(pinned):
     w = np.random.default_rng(4).uniform(0.5, 1.5, want.shape)
     args = _arrays(ins, jnp.float32)
     got = _vjp(_kernels, args, jnp.asarray(w, jnp.float32))
-    assert _rel(got[0], want) < 1e-5
+    assert rel(got[0], want) < 1e-5
     rng = np.random.default_rng(5)
     for name, grad in zip(NAMES, got[1:]):
         assert np.all(np.isfinite(np.asarray(grad))), name
@@ -229,8 +223,8 @@ def test_bf16_is_as_close_to_float32_as_the_composition(pinned):
     got = _vjp(_kernels, args16, g)
     for name, u, c, v in zip(("y",) + NAMES, got, comp, want):
         assert u.dtype == c.dtype, name
-        assert _rel(u, v) < max(2 * _rel(c, v), 2.0 ** -8), (
-            name, _rel(u, v), _rel(c, v))
+        assert rel(u, v) < max(2 * rel(c, v), 2.0 ** -8), (
+            name, rel(u, v), rel(c, v))
 
 
 def test_a_ragged_row_is_continued_with_dt_zero(pinned):
@@ -243,7 +237,7 @@ def test_a_ragged_row_is_continued_with_dt_zero(pinned):
     assert ssm_ops.scan_kernels((1, 3 * Q, GROUPS * PER, P),
                                 (1, 3 * Q, GROUPS, N), Q, jnp.float32)
     got = ssm_ops.ssd_scan(*args, Q)
-    assert got.shape == want.shape and _rel(got, want) < 1e-5
+    assert got.shape == want.shape and rel(got, want) < 1e-5
     w = np.random.default_rng(9).uniform(0.5, 1.5, want.shape)
     grads = jax.grad(lambda *t: jnp.sum(ssm_ops.ssd_scan(*t, Q) * w),
                      argnums=tuple(range(6)))(*args)
@@ -254,7 +248,7 @@ def test_a_ragged_row_is_continued_with_dt_zero(pinned):
                   if u.ndim > 1 else u for u in t], chunk=Q)[:, :300] * w),
             argnums=tuple(range(6)))(*args)
     for name, u, v in zip(NAMES, grads, plain):
-        assert _rel(u, v) < 2e-5, name
+        assert rel(u, v) < 2e-5, name
 
 
 def test_the_state_is_carried_and_each_head_reads_its_group(pinned):
@@ -263,14 +257,14 @@ def test_the_state_is_carried_and_each_head_reads_its_group(pinned):
     ins = {**_inputs(3 * Q, seed=10, batch=1), "d": np.zeros(GROUPS * PER)}
     want = _recurrence64(**ins)
     got = np.asarray(_kernels(*_arrays(ins, jnp.float32)))
-    assert _rel(got, want) < 1e-5
+    assert rel(got, want) < 1e-5
     swapped = {**ins, "b": ins["b"][:, :, ::-1], "c": ins["c"][:, :, ::-1]}
-    assert _rel(_recurrence64(**swapped), want) > 0.1
+    assert rel(_recurrence64(**swapped), want) > 0.1
     cut = np.concatenate([
         _recurrence64(**{k: (v[:, z:z + Q] if v.ndim > 1 else v)
                          for k, v in ins.items()}) for z in range(0, 3 * Q, Q)],
         axis=1)
-    assert _rel(cut, want) > 0.05
+    assert rel(cut, want) > 0.05
 
 
 @pytest.mark.parametrize("shape, why", [
@@ -306,7 +300,7 @@ def test_a_refused_shape_runs_the_composition_bit_for_bit():
                         argnums=tuple(range(6)))(*args)
 
     plain = run()
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
+    with mock.patch.object(backend, "FORCE_PALLAS", True):
         assert not ssm_ops.scan_kernels((1, 256, 16, 16), (1, 256, 2, 64), Q,
                                         jnp.float32)
         pinned_ = run()
@@ -320,20 +314,15 @@ def test_off_the_tpu_the_gate_leaves_the_composition():
 
 
 # ---------------------------------------------------------------------------
-# a mamba2 step with the kernels: scopes, roles, counter
+# a mamba2 step with the kernels: scopes, roles, kernel calls
 # ---------------------------------------------------------------------------
-
-
-def _lowerings(impl):
-    return get_registry().counter("ssd_scan_lowerings_total",
-                                  impl=impl).value
 
 
 def test_a_mamba2_step_lowers_the_kernels_under_their_scopes(pinned):
     """`ssd_scan_fwd` under the role `forward`, `ssd_scan_bwd` under
     `backward`; among the accepted part names plus `ssd_scan` both are the
-    scan's, plus `mamba2` the mixer's; the counter counts one `pallas` a
-    `mamba2` op and no `jnp`."""
+    scan's, plus `mamba2` the mixer's; the traced step calls each kernel
+    once for the one `mamba2` op, and nothing else."""
     from benchmark import part_scopes, roles
 
     def build(v):
@@ -343,11 +332,10 @@ def test_a_mamba2_step_lowers_the_kernels_under_their_scopes(pinned):
         return out, [decay]
 
     x = np.random.RandomState(12).randn(1, 2 * Q, 32).astype(np.float32)
-    before = _lowerings("pallas"), _lowerings("jnp")
-    lowered = []
-    _run(build, {"x": x}, lowered=lowered)
-    assert _lowerings("pallas") - before[0] >= 1
-    assert _lowerings("jnp") == before[1]
+    lowered, traced = [], []
+    run_op(build, {"x": x}, lowered=lowered, traced=traced)
+    assert calls_in(traced[0].jaxpr.jaxpr) == {"ssd_scan_fwd": 1,
+                                               "ssd_scan_bwd": 1}
     names = set(re.findall(r'"(jit\([a-z_]+\)/[^"]*)"', lowered[0]))
     for entry, role in (("_scan_fwd", "forward"), ("_scan_bwd", "backward")):
         calls = {n for n in names if n.endswith(f"/jit({entry})")}
@@ -360,8 +348,9 @@ def test_a_mamba2_step_lowers_the_kernels_under_their_scopes(pinned):
                 n, part_scopes.PARTS + ("mamba2",)) == "mamba2", n
 
 
-def test_the_counter_counts_one_pallas_a_mamba2_op(pinned):
-    """Two `mamba2` ops in one program: two `pallas` lowerings a trace."""
+def test_the_step_calls_each_kernel_once_a_mamba2_op(pinned):
+    """Two `mamba2` ops in one program: the traced step calls
+    `ssd_scan_fwd` twice and `ssd_scan_bwd` twice."""
     def build(v):
         for name in ("m0", "m1"):
             v, _ = layers.mamba2(
@@ -379,10 +368,11 @@ def test_the_counter_counts_one_pallas_a_mamba2_op(pinned):
     exe = fluid.Executor()
     scope = Scope()
     exe.run(startup, scope=scope)
-    before = _lowerings("pallas")
-    exe._lower_step(main, feed={"x": np.zeros((1, Q, 32), np.float32)},
-                    fetch_list=[loss], scope=scope)
-    assert _lowerings("pallas") - before == 2
+    traced = exe._trace_step(main, feed={"x": np.zeros((1, Q, 32),
+                                                      np.float32)},
+                             fetch_list=[loss], scope=scope)
+    assert calls_in(traced.jaxpr.jaxpr) == {"ssd_scan_fwd": 2,
+                                            "ssd_scan_bwd": 2}
 
 
 def test_the_vmem_model_admits_the_nemotron_cell_with_margin():
@@ -402,7 +392,6 @@ def test_the_vmem_model_admits_the_nemotron_cell_with_margin():
     assert not ssd.kernel_fits(16 * s, 64, 64, 8, 128, 128, jnp.bfloat16)
     cell = ssd._cell(64, 8, 64, 128, s, Q, 2)
     assert (cell.groups, cell.per, cell.blocks) == (2, 8, 1)
-    assert ssd.head_blocks(s, 64, 64, 8, 128, 128, jnp.bfloat16) == 1
 
 
 def test_the_vmem_model_admits_the_granite_cell_in_blocks_of_sixteen():
@@ -419,11 +408,10 @@ def test_the_vmem_model_admits_the_granite_cell_in_blocks_of_sixteen():
         assert est <= 0.6 * feasible.SSD_VMEM_BUDGET, (pass_, est)
     cell = ssd._cell(64, 1, 64, 128, s, q, 2)
     assert (cell.groups, cell.per, cell.blocks) == (1, 16, 4)
-    assert ssd.head_blocks(s, 64, 64, 1, 128, q, jnp.bfloat16) == 4
 
 
 # ---------------------------------------------------------------------------
-# why the gate refused: the first check that failed, counted
+# why the gate refused: the first check that failed
 # ---------------------------------------------------------------------------
 
 
@@ -453,56 +441,60 @@ def test_the_gate_names_the_first_check_that_refused(sizes, reason):
     assert ssd.kernel_fits(**sizes) == (reason is None)
 
 
-def _refusals(reason):
-    return get_registry().counter("ssd_scan_gate_refusals_total",
-                                  reason=reason).value
-
-
-def _trace_scan(heads, groups):
-    """`ssm_ops.ssd_scan` traced once at two chunks of Q, heads of P."""
+def _scan_args(heads, groups):
+    """The operands of `ssm_ops.ssd_scan` at two chunks of Q, heads of P."""
     x = jax.ShapeDtypeStruct((1, 2 * Q, heads, P), jnp.float32)
     dt = jax.ShapeDtypeStruct((1, 2 * Q, heads), jnp.float32)
     vec = jax.ShapeDtypeStruct((heads,), jnp.float32)
     bc = jax.ShapeDtypeStruct((1, 2 * Q, groups, N), jnp.float32)
-    jax.eval_shape(functools.partial(ssm_ops.ssd_scan, chunk=Q),
-                   x, dt, vec, bc, bc, vec)
+    return x, dt, vec, bc, bc, vec
 
 
-def test_a_refusal_is_counted_where_the_kernels_would_run():
-    """Twenty heads in one group: refused by `heads_per_group` and
-    counted once a trace where the kernels would run (pinned here, as on
-    the TPU); off the TPU the composition is the path and nothing is
-    counted; a shape the gate admits is no refusal."""
-    before = _refusals("heads_per_group")
-    _trace_scan(20, 1)
-    assert _refusals("heads_per_group") == before
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
-        _trace_scan(20, 1)
-        assert _refusals("heads_per_group") == before + 1
-        counted = {r: _refusals(r) for r in ("dtype", "groups", "lanes",
-                                              "heads_per_group", "vmem")}
-        _trace_scan(GROUPS * PER, GROUPS)
-        assert {r: _refusals(r) for r in counted} == counted
+def _scan_calls(heads, groups):
+    """The kernels `ssm_ops.ssd_scan` calls, traced once forward."""
+    return pallas_calls(functools.partial(ssm_ops.ssd_scan, chunk=Q),
+                        *_scan_args(heads, groups))
 
 
-def _head_blocks(blocks):
-    return get_registry().counter("ssd_scan_head_blocks_total",
-                                  blocks=blocks).value
+def _reason(heads, groups):
+    """The gate's first refusal at the traced shapes."""
+    return ssd.kernel_fits_reason(2 * Q, heads, P, groups, N, Q, jnp.float32)
 
 
-def test_a_split_group_is_counted_by_its_blocks():
-    """Thirty-two heads in one group where the kernels run: one `pallas`
-    lowering, no refusal, and one count under `blocks="2"`; two groups of
-    eight heads, which a cell takes whole, count no blocks; off the TPU
-    nothing is counted."""
-    before = _head_blocks("2")
-    _trace_scan(32, 1)
-    assert _head_blocks("2") == before
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
-        counted = _lowerings("pallas"), _refusals("heads_per_group")
-        _trace_scan(32, 1)
-        assert (_lowerings("pallas"), _refusals("heads_per_group")) == (
-            counted[0] + 1, counted[1])
-        assert _head_blocks("2") == before + 1
-        _trace_scan(GROUPS * PER, GROUPS)
-        assert _head_blocks("2") == before + 1
+def test_a_refusal_names_its_reason_where_the_kernels_would_run():
+    """Twenty heads in one group: refused by `heads_per_group`, and where
+    the kernels would run (pinned here, as on the TPU) the traced scan
+    calls no kernel; off the TPU the composition is the path too; a shape
+    the gate admits is no refusal and calls the forward kernel."""
+    assert _scan_calls(20, 1) == {}
+    with mock.patch.object(backend, "FORCE_PALLAS", True):
+        assert _scan_calls(20, 1) == {}
+        assert _reason(20, 1) == "heads_per_group"
+        assert _scan_calls(GROUPS * PER, GROUPS) == {"ssd_scan_fwd": 1}
+        assert _reason(GROUPS * PER, GROUPS) is None
+
+
+def _grids(heads, groups):
+    """The grid of each kernel call of the scan's gradient, by name."""
+    def loss(*args):
+        return jnp.sum(ssm_ops.ssd_scan(*args, Q))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 3, 4)))(
+        *_scan_args(heads, groups))
+    return {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+            for e in pallas_eqns(jaxpr.jaxpr)}
+
+
+def test_a_split_group_runs_one_grid_cell_a_block():
+    """Thirty-two heads in one group where the kernels run: both kernels
+    are called, no refusal, and the group is split into two blocks of
+    sixteen, one a grid cell (grid [batch, blocks, chunks]); two groups of
+    eight heads, which a cell takes whole, are one cell; off the TPU no
+    kernel is called."""
+    assert _grids(32, 1) == {}
+    with mock.patch.object(backend, "FORCE_PALLAS", True):
+        assert _reason(32, 1) is None
+        assert _grids(32, 1) == {"ssd_scan_fwd": (1, 2, 2),
+                                 "ssd_scan_bwd": (1, 2, 4)}
+        assert _grids(GROUPS * PER, GROUPS) == {"ssd_scan_fwd": (1, 1, 2),
+                                                "ssd_scan_bwd": (1, 1, 4)}

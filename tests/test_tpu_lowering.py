@@ -1,16 +1,14 @@
 """Pre-flight for chip time: lower the real programs for the TPU platform
 from this CPU process and check that the Mosaic calls are there.
 
-With `_interpret` patched to False the Pallas modules take their TPU
-branches (in-kernel PRNG, no interpreter), and
-`lower(lowering_platforms=("tpu",))` runs Pallas' TPU lowering with its
-block-shape checks and jax's "Mosaic kernels cannot be automatically
-partitioned" check. Mosaic itself lives in libtpu and only runs when the
+With `backend.interpret` patched to False (the `described_tpu` fixture)
+the Pallas modules take their TPU branches (in-kernel PRNG, no
+interpreter), and `lower(lowering_platforms=("tpu",))` runs Pallas' TPU
+lowering with its block-shape checks and jax's "Mosaic kernels cannot be
+automatically partitioned" check. Mosaic itself lives in libtpu and only runs when the
 chip compiles, so a kernel that lowers here can still be refused there —
 but everything this file catches costs no chip time.
 """
-from unittest import mock
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,16 +17,10 @@ import pytest
 import chip_smoke
 import paddle_tpu.fluid as fluid
 from paddle_tpu.models.bert import BertConfig, random_pretrain_batch
-from paddle_tpu.ops.pallas import add_ln, conv_bn, flash_attention
+from paddle_tpu.ops.pallas import add_ln
 from paddle_tpu.ops.pallas import paged_attention as paged
 
-@pytest.fixture(autouse=True)
-def _tpu_branches():
-    with mock.patch.object(flash_attention, "_interpret", lambda: False), \
-            mock.patch.object(add_ln, "_interpret", lambda: False), \
-            mock.patch.object(conv_bn, "_interpret", lambda: False), \
-            mock.patch.object(paged, "_interpret", lambda: False):
-        yield
+pytestmark = pytest.mark.usefixtures("described_tpu")
 
 
 def _kernel_names(text):

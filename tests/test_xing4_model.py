@@ -5,7 +5,6 @@ and under bf16 AMP, the whole model and a held share of it; the share test
 (head shares and expert shares add up to the uncut sublayers, the shared
 expert counted once); a few optimizer steps; what can be fetched beside
 the loss."""
-import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +14,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.executor import Scope
+from model_checks import rel, run_layer
 from paddle_tpu.models import xing4_reference as ref
 from paddle_tpu.models.xing4 import (Xing4Config,
                                      build_xing4_pretrain_program,
@@ -47,11 +47,6 @@ def _built(cfg, amp):
     feed = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
     return main, loss, {p.name: g.name for p, g in pgs if g is not None}, \
         exe, scope, feed
-
-
-def _rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.linalg.norm(a - b) / np.linalg.norm(b)
 
 
 @pytest.mark.parametrize("share, amp, tol", [
@@ -97,7 +92,7 @@ def test_program_against_the_reference(share, amp, tol):
             assert np.abs(np.asarray(want[name])[:, 8:]).max() < 1e-6
         else:
             w = want[name]
-        assert _rel(g, w) < tol, name
+        assert rel(g, w) < tol, name
     rest = got[1 + len(names):]
     counts, gaps = rest[:2], rest[2:]
     assert all(c.shape == (cfg.experts_held,) for c in counts)
@@ -111,23 +106,6 @@ def test_program_against_the_reference(share, amp, tol):
 # ---------------------------------------------------------------------------
 # the share test
 # ---------------------------------------------------------------------------
-
-
-def _run_layer(build, x, weights):
-    """out = build(x) with the named parameters set to `weights`."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-        v = layers.data("x", shape=list(x.shape), dtype="float32",
-                        append_batch_size=False)
-        out = build(v)
-    exe, scope = fluid.Executor(), Scope()
-    exe.run(startup, scope=scope)
-    names = {p.name for p in main.all_parameters()}
-    assert names == set(weights), names ^ set(weights)
-    for name, w in weights.items():
-        assert scope.find_var(name).shape == w.shape, name
-        scope.set_var(name, jnp.asarray(w))
-    return exe.run(main, feed={"x": x}, fetch_list=[out], scope=scope)[0]
 
 
 def test_the_shares_add_up_to_the_uncut_sublayers():
@@ -157,15 +135,15 @@ def test_the_shares_add_up_to_the_uncut_sublayers():
         held["kv_b_proj"] = attn["kv_b_proj"][
             :, first * 40:(first + count) * 40]
         held["o_proj"] = attn["o_proj"][first * 16:(first + count) * 16]
-        return _run_layer(lambda v: layers.mla(
+        return run_layer(lambda v: layers.mla(
             v, count, 48, 32, 24, 8, 16, cfg.softmax_scale,
             theta=cfg.rope_theta, inv_freq=cfg.inv_freq, name="a"),
             x, {f"a.{k}": val for k, val in held.items()})
 
     parts = [mla_share(first, 2) for first in (0, 2, 4, 6)]
-    assert _rel(sum(parts), whole) < 1e-5
-    assert _rel(parts[0], whole) > 0.3  # a share is not the whole
-    assert _rel(mla_share(0, 8), whole) < 1e-5
+    assert rel(sum(parts), whole) < 1e-5
+    assert rel(parts[0], whole) > 0.3  # a share is not the whole
+    assert rel(mla_share(0, 8), whole) < 1e-5
 
     # ---- feed-forward: routed experts split, the shared expert whole
     ffn = {"gate": w(64, 16) * 5, "expert_bias": w(16) * 0.1,
@@ -180,19 +158,19 @@ def test_the_shares_add_up_to_the_uncut_sublayers():
         held = {k: (val[first:first + count] if k in ("w1", "w3", "w2")
                     else val)
                 for k, val in ffn.items() if not k.startswith("shared")}
-        return _run_layer(lambda v: layers.moe_swiglu(
+        return run_layer(lambda v: layers.moe_swiglu(
             v, 16, 32, experts_held=count, first_expert=first, top_k=2,
             norm_topk_prob=True, routed_scaling_factor=2.0, name="m")[0],
             x, {f"m.{k}": val for k, val in held.items()})
 
-    shared = _run_layer(
+    shared = run_layer(
         lambda v: layers.shared_expert(v, 32, name="m.shared_experts"), x,
         {f"m.{k}": val for k, val in ffn.items() if k.startswith("shared")})
     routed = [routed_share(first, 4) for first in (0, 4, 8, 12)]
-    assert _rel(sum(routed) + shared, whole) < 1e-5
+    assert rel(sum(routed) + shared, whole) < 1e-5
     # counted with every share it would stand four times
-    assert _rel(sum(routed) + 4 * shared, whole) > 0.1
-    assert _rel(shared, np.asarray(ref.shared_expert(z, ffn))) < 1e-5
+    assert rel(sum(routed) + 4 * shared, whole) > 0.1
+    assert rel(shared, np.asarray(ref.shared_expert(z, ffn))) < 1e-5
 
 
 # ---------------------------------------------------------------------------

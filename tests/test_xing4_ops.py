@@ -19,9 +19,10 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.models import xing4_reference as ref
 from paddle_tpu.models.xing4 import Xing4Config, yarn_inv_freq
-from paddle_tpu.ops import attention, latent_ops
-from paddle_tpu.telemetry import get_registry
-from test_lfm2_ops import INIT, _assert_close, _ref_grads, _rel, _run
+from lowered import calls_in
+from model_checks import INIT, assert_close, ref_grads, rel, run_op
+from paddle_tpu.ops import attention
+from paddle_tpu.ops.pallas import backend
 
 YARN = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
         "mscale": 1, "mscale_all_dim": 1,
@@ -56,11 +57,6 @@ def _attention_build(nh, dqk, dv, scale):
     return build
 
 
-def _lowerings(impl, form):
-    return get_registry().counter("attention_lowerings_total", impl=impl,
-                                  form=form).value
-
-
 @pytest.mark.parametrize("dqk, dv, scale", [
     (24, 16, 0.31),    # MLA's shape: wider q / k than v, a given scale
     (16, 16, 0.31),    # one width, a given scale
@@ -70,30 +66,36 @@ def test_attention_with_unequal_widths_and_a_given_scale(dqk, dv, scale):
     nh = 2
     x = np.random.RandomState(1).randn(2, 12, nh * (2 * dqk + dv)).astype(
         np.float32)
-    before = _lowerings("jnp", "mla")
-    got = _run(_attention_build(nh, dqk, dv, scale), {"x": x})
+    traced = []
+    with mock.patch.object(attention, "latent_attention",
+                           wraps=attention.latent_attention) as latent:
+        got = run_op(_attention_build(nh, dqk, dv, scale), {"x": x},
+                     traced=traced)
     assert got[0].shape == (2, 12, nh * dv)
-    assert _lowerings("jnp", "mla") > before
-    want = _ref_grads(_attention_ref(nh, dqk, dv, scale or dqk ** -0.5),
+    # the latent form, as the composition: no kernel in the step
+    assert latent.called
+    assert calls_in(traced[0].jaxpr.jaxpr) == {}
+    want = ref_grads(_attention_ref(nh, dqk, dv, scale or dqk ** -0.5),
                       x, {}, got[3])
-    _assert_close(got[0], got[1], *want, tol=1e-5)
+    assert_close(got[0], got[1], *want, tol=1e-5)
 
 
 def test_attention_at_equal_widths_is_what_it_was():
-    """No scale given and one width: the op takes its old path, counted as
-    `mha`, its lowered text has no trace of the latent form, and the result
-    is the composition at 1/sqrt(d)."""
+    """No scale given and one width: the op takes its old path, never the
+    latent form, and the result is the composition at 1/sqrt(d)."""
     nh, d = 2, 16
     x = np.random.RandomState(2).randn(2, 12, nh * 3 * d).astype(np.float32)
-    lowered = []
-    before = _lowerings("jnp", "mha"), _lowerings("jnp", "mla")
-    got = _run(_attention_build(nh, d, d, None), {"x": x}, lowered=lowered)
-    assert _lowerings("jnp", "mha") > before[0]
-    assert _lowerings("jnp", "mla") == before[1]
-    want = _ref_grads(_attention_ref(nh, d, d, d ** -0.5), x, {}, got[3])
-    _assert_close(got[0], got[1], *want, tol=1e-5)
+    traced = []
+    with mock.patch.object(attention, "latent_attention",
+                           wraps=attention.latent_attention) as latent:
+        got = run_op(_attention_build(nh, d, d, None), {"x": x},
+                     traced=traced)
+    assert not latent.called
+    assert calls_in(traced[0].jaxpr.jaxpr) == {}
+    want = ref_grads(_attention_ref(nh, d, d, d ** -0.5), x, {}, got[3])
+    assert_close(got[0], got[1], *want, tol=1e-5)
     with pytest.raises(RuntimeError, match="neither BiasQK nor dropout"):
-        _run(lambda v: (layers.fused_multihead_attention(
+        run_op(lambda v: (layers.fused_multihead_attention(
             v, v, v, num_heads=nh, dropout_prob=0.1, softmax_scale=0.5), []),
             {"x": x[..., :nh * d]})
 
@@ -109,12 +111,14 @@ def test_the_padded_flash_calls_carry_their_own_names():
          ).astype(np.float32)
     scale = Xing4Config().softmax_scale
     assert scale == pytest.approx(0.14468, rel=1e-4)
-    before = _lowerings("pallas", "mla")
-    with mock.patch.object(attention, "FORCE_PALLAS", True):
-        got = _run(_attention_build(nh, dqk, dv, scale), {"x": x})
-    assert _lowerings("pallas", "mla") > before
-    want = _ref_grads(_attention_ref(nh, dqk, dv, scale), x, {}, got[3])
-    _assert_close(got[0], got[1], *want, tol=2e-5)
+    traced = []
+    with mock.patch.object(backend, "FORCE_PALLAS", True):
+        got = run_op(_attention_build(nh, dqk, dv, scale), {"x": x},
+                     traced=traced)
+    assert calls_in(traced[0].jaxpr.jaxpr) == {"flash_mla_causal_fwd": 1,
+                                               "flash_mla_causal_bwd": 1}
+    want = ref_grads(_attention_ref(nh, dqk, dv, scale), x, {}, got[3])
+    assert_close(got[0], got[1], *want, tol=2e-5)
 
     def loss(q, k, v):
         return attention.latent_attention(
@@ -124,7 +128,7 @@ def test_the_padded_flash_calls_carry_their_own_names():
     v = jnp.zeros((1, s, nh * dv), jnp.bfloat16)
     fa._make_flash_core_bsh.cache_clear()
     try:
-        with mock.patch.object(fa, "_interpret", lambda: False):
+        with mock.patch.object(backend, "interpret", lambda: False):
             text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
                 q, q, v).lower(lowering_platforms=("tpu",)).as_text()
     finally:
@@ -167,16 +171,16 @@ def test_rope_at_a_table_given_by_the_model():
     d, heads = 8, 3
     table = [1.0, 0.37, 0.011, 0.0007]
     x = np.random.RandomState(4).randn(2, 10, heads * d).astype(np.float32)
-    got = _run(lambda v: (layers.rope(v, d, inv_freq=table), []), {"x": x})
+    got = run_op(lambda v: (layers.rope(v, d, inv_freq=table), []), {"x": x})
     # by hand, in float64
     xs = x.astype(np.float64).reshape(2, 10, heads, 2, d // 2)
     angle = np.arange(10)[:, None] * np.asarray(table)[None, :]
     c, s = np.cos(angle)[None, :, None], np.sin(angle)[None, :, None]
     want = np.stack([xs[..., 0, :] * c - xs[..., 1, :] * s,
                      xs[..., 1, :] * c + xs[..., 0, :] * s], -2)
-    assert _rel(got[0], want.reshape(x.shape)) < 1e-6
+    assert rel(got[0], want.reshape(x.shape)) < 1e-6
     with pytest.raises(RuntimeError, match="frequencies"):
-        _run(lambda v: (layers.rope(v, d, inv_freq=table[:3]), []), {"x": x})
+        run_op(lambda v: (layers.rope(v, d, inv_freq=table[:3]), []), {"x": x})
 
 
 # ---------------------------------------------------------------------------
@@ -209,11 +213,6 @@ def _hc_attrs():
             initializer=fluid.initializer.NormalInitializer(0.0, 1.0)))
 
 
-def _mhc_map_lowerings(impl):
-    return get_registry().counter("mhc_map_lowerings_total",
-                                  impl=impl).value
-
-
 @pytest.mark.parametrize("iters, bound, B, S, C, impl", [
     (20, 1e-4, 2, 5, 16, "jnp"), (1, 1e-2, 2, 5, 16, "jnp"),
     # whole lane tiles and 128 rows, the kernels pinned (the worst of 128
@@ -222,7 +221,6 @@ def _mhc_map_lowerings(impl):
 ], ids=["jnp-20", "jnp-1", "pallas-20", "pallas-1"])
 def test_mhc_map_against_numpy_and_sinkhorn(iters, bound, B, S, C, impl):
     x = np.random.RandomState(6).randn(B, S, N * C).astype(np.float32)
-    before = _mhc_map_lowerings(impl)
 
     def build(v):
         pre, post, res, gap = layers.mhc_map(
@@ -230,15 +228,20 @@ def test_mhc_map_against_numpy_and_sinkhorn(iters, bound, B, S, C, impl):
             **_hc_attrs())
         return layers.concat([pre, post, res], axis=2), [gap]
 
-    with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
-        out, grads, params, w, (gap,) = _run(build, {"x": x})
-    assert _mhc_map_lowerings(impl) > before
+    traced = []
+    with mock.patch.object(backend, "FORCE_PALLAS", impl == "pallas"):
+        out, grads, params, w, (gap,) = run_op(build, {"x": x},
+                                               traced=traced)
+    # the kernel's forward pass where pinned; the backward pass is the
+    # composition's either way
+    assert calls_in(traced[0].jaxpr.jaxpr) == (
+        {"mhc_map_fwd": 1} if impl == "pallas" else {})
     assert set(params) == {"hc.phi", "hc.b", "hc.alpha"}
     assert params["hc.phi"].shape == (N * C, 2 * N + N * N)
     pre, post, res = _maps_numpy(x, params["hc.phi"], params["hc.b"],
                                  params["hc.alpha"], iters)
     want = np.concatenate([pre, post, res.reshape(B, S, N * N)], -1)
-    assert _rel(out, want) < 1e-5
+    assert rel(out, want) < 1e-5
     assert (0 < pre).all() and (pre < 1).all() and (post < 2).all()
     # H_res after the last round: columns exact, rows as far as the rounds
     # brought them; the op reports the worst of both, stream by stream
@@ -262,12 +265,7 @@ def test_mhc_map_against_numpy_and_sinkhorn(iters, bound, B, S, C, impl):
             settings)
         return jnp.concatenate([a, b_, m.reshape(B, S, N * N)], -1)
 
-    _assert_close(out, grads, *_ref_grads(fn, x, params, w), tol=2e-5)
-
-
-def _mhc_post_lowerings(impl):
-    return get_registry().counter("mhc_post_lowerings_total",
-                                  impl=impl).value
+    assert_close(out, grads, *ref_grads(fn, x, params, w), tol=2e-5)
 
 
 @pytest.mark.parametrize("B, S, C, impl", [
@@ -277,7 +275,6 @@ def _mhc_post_lowerings(impl):
 def test_mhc_mix_against_numpy(B, S, C, impl):
     rng = np.random.RandomState(7)
     x = rng.randn(B, S, N * C + C + 2 * N + N * N).astype(np.float32)
-    before = _mhc_post_lowerings(impl)
 
     def build(v):
         streams = layers.slice(v, [2], [0], [N * C])
@@ -303,9 +300,11 @@ def test_mhc_mix_against_numpy(B, S, C, impl):
         return jnp.concatenate(
             [u, s.sum(2), mixed.reshape(B, S, N * C)], -1)
 
-    with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
-        got = _run(build, {"x": x})
-    assert _mhc_post_lowerings(impl) > before
+    traced = []
+    with mock.patch.object(backend, "FORCE_PALLAS", impl == "pallas"):
+        got = run_op(build, {"x": x}, traced=traced)
+    assert calls_in(traced[0].jaxpr.jaxpr) == (
+        {"mhc_post_fwd": 1, "mhc_post_bwd": 1} if impl == "pallas" else {})
     # by hand for one token: stream 1 of X' = sum_j H_res[1, j] X_j + H_post[1] y
     s0 = x[0, 0, :N * C].reshape(N, C).astype(np.float64)
     at = N * C + C
@@ -313,7 +312,7 @@ def test_mhc_mix_against_numpy(B, S, C, impl):
     want1 = hr[1] @ s0 + x[0, 0, at + N + 1] * x[0, 0, N * C:at]
     np.testing.assert_allclose(got[0][0, 0, 2 * C + C:2 * C + 2 * C], want1,
                                rtol=1e-5, atol=1e-6)
-    _assert_close(got[0], got[1], *_ref_grads(fn, x, {}, got[3]), tol=1e-5)
+    assert_close(got[0], got[1], *ref_grads(fn, x, {}, got[3]), tol=1e-5)
 
 
 def test_the_mappings_stay_float32_under_amp():
@@ -365,14 +364,14 @@ def _parts(lowered_text):
 def test_the_shared_expert_is_a_swiglu_under_its_own_scope():
     x = np.random.RandomState(8).randn(2, 6, 16).astype(np.float32)
     lowered = []
-    got = _run(lambda v: (layers.shared_expert(
+    got = run_op(lambda v: (layers.shared_expert(
         v, 24, remat=True, param_attr=fluid.ParamAttr(initializer=INIT),
         name="se"), []), {"x": x}, lowered=lowered)
 
     def fn(v, p):
         return (jax.nn.silu(v @ p["se.w1"]) * (v @ p["se.w3"])) @ p["se.w2"]
 
-    _assert_close(got[0], got[1], *_ref_grads(fn, x, got[2], got[3]),
+    assert_close(got[0], got[1], *ref_grads(fn, x, got[2], got[3]),
                   tol=1e-5)
     assert _parts(lowered[0]) == {"shared_expert"}
 
@@ -390,7 +389,6 @@ def test_every_new_op_lowers_under_its_part_scope(width, seq, impl, map_impl):
     cfg = Xing4Config.tiny(heads_held=4)
     x = np.random.RandomState(9).randn(1, seq, width).astype(np.float32)
     lowered = []
-    before = _mhc_post_lowerings(impl), _mhc_map_lowerings(map_impl)
 
     def build(v):
         streams = layers.expand(v, [1, 1, 4])
@@ -402,10 +400,8 @@ def test_every_new_op_lowers_under_its_part_scope(width, seq, impl, map_impl):
             param_attr=fluid.ParamAttr(initializer=INIT), name="attn")
         return layers.mhc_post(streams, y, res, post), []
 
-    with mock.patch.object(attention, "FORCE_PALLAS", impl == "pallas"):
-        _run(build, {"x": x}, lowered=lowered)
-    assert _mhc_post_lowerings(impl) > before[0]
-    assert _mhc_map_lowerings(map_impl) > before[1]
+    with mock.patch.object(backend, "FORCE_PALLAS", impl == "pallas"):
+        run_op(build, {"x": x}, lowered=lowered)
     assert _parts(lowered[0]) == {"mla", "mhc_map", "mhc_mix"}
     backward = "\n".join(line for line in lowered[0].splitlines()
                          if "/backward/" in line)
@@ -436,7 +432,7 @@ def test_mla_against_the_reference_sublayer():
     cfg = Xing4Config.tiny()
     settings = ref.reference_settings(cfg)
     x = np.random.RandomState(10).randn(2, 16, 64).astype(np.float32)
-    got = _run(lambda v: (layers.mla(
+    got = run_op(lambda v: (layers.mla(
         v, 8, 48, 32, 24, 8, 16, cfg.softmax_scale, theta=cfg.rope_theta,
         inv_freq=cfg.inv_freq, param_attr=fluid.ParamAttr(initializer=INIT),
         name="a"), []), {"x": x})
@@ -449,5 +445,5 @@ def test_mla_against_the_reference_sublayer():
     def fn(v, p):
         return ref.mla(v, {k[2:]: w for k, w in p.items()}, settings)
 
-    _assert_close(got[0], got[1], *_ref_grads(fn, x, got[2], got[3]),
+    assert_close(got[0], got[1], *ref_grads(fn, x, got[2], got[3]),
                   tol=2e-5)
